@@ -27,10 +27,6 @@ import json
 import os
 
 import jax
-
-if os.environ.get("JAX_PLATFORMS"):
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 import numpy as np
 
 from stochastic_gradient_push_tpu.data import (

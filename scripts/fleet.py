@@ -40,8 +40,8 @@ signal.signal(signal.SIGPIPE, signal.SIG_DFL)
 CHILD_ENV = dict(os.environ)
 
 # coordinator and supervisor are pure host work (tailers, planner
-# numpy, msgpack reshard); never let a platform plugin grab an
-# accelerator
+# numpy, msgpack reshard), and a chip belongs to one process at a time:
+# they must never initialise the accelerator a child needs
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))

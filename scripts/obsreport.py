@@ -25,8 +25,8 @@ import json
 import os
 import sys
 
-# report building is pure host work; never let a platform plugin pull in
-# an accelerator runtime just to read JSON (same pattern as plan.py)
+# report building is pure host work; never pull in an accelerator
+# runtime just to read JSON (same pattern as plan.py)
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(

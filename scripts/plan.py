@@ -21,7 +21,7 @@ import sys
 # die quietly when piped into `head` instead of tracebacking
 signal.signal(signal.SIGPIPE, signal.SIG_DFL)
 
-# importing the package pulls in jax (compat shims); force CPU so the
+# importing the package pulls in jax; force CPU so the
 # planner behaves identically on dev boxes, CI, and TPU hosts
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 

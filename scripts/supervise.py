@@ -32,7 +32,8 @@ signal.signal(signal.SIGPIPE, signal.SIG_DFL)
 CHILD_ENV = dict(os.environ)
 
 # the supervisor itself is pure host work (tailer, planner numpy,
-# msgpack reshard); never let a platform plugin grab an accelerator
+# msgpack reshard), and a chip belongs to one process at a time: it must
+# never initialise the accelerator its child needs
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))

@@ -10,11 +10,7 @@ threads, no process groups, no pinned-memory staging.
 
 __version__ = "0.1.0"
 
-from .compat import ensure_jax_compat  # noqa: F401
-
-ensure_jax_compat()
-
-from .topology import (  # noqa: E402,F401
+from .topology import (  # noqa: F401
     GRAPH_TOPOLOGIES,
     MIXING_STRATEGIES,
     DynamicBipartiteExponentialGraph,

@@ -19,6 +19,7 @@ import numpy as np
 
 from ..parallel import collectives
 from ..parallel.collectives import as_scalar
+from ..parallel.pipeline import pvary_missing
 from ..topology.schedule import GossipSchedule
 from .api import GossipAlgorithm, GossipState, Params
 
@@ -54,6 +55,13 @@ def drain_in_flight(params, ps_weight, in_flight):
         (jax.tree.map(jnp.zeros_like, in_p), jnp.zeros_like(in_w))
         for in_p, in_w in in_flight)
     return params, ps_weight, drained
+
+
+def _vary_like(tree, like):
+    """Mark every leaf of ``tree`` varying over the manual mesh axes the
+    matching leaf of ``like`` varies over (no-op outside ``shard_map``)."""
+    return jax.tree.map(
+        lambda a, b: pvary_missing(a, tuple(jax.typeof(b).vma)), tree, like)
 
 
 def drain_state(state):
@@ -586,12 +594,17 @@ class PushSumGossip(GossipAlgorithm):
             return params, ps_weight, in_flight
         fire = (as_scalar(tick_next) % self.global_avg_every) == 0
 
+        # the psum inside global_average returns values that no longer
+        # vary over the gossip axis; cond needs both branches to return
+        # the operands' own varying type
         if in_flight is None:
             return jax.lax.cond(
-                fire, lambda o: self.global_average(*o), lambda o: o,
-                (params, ps_weight))
+                fire, lambda o: _vary_like(self.global_average(*o), o),
+                lambda o: o, (params, ps_weight))
         return jax.lax.cond(
-            fire, lambda o: self.global_average(o[0], o[1], in_flight=o[2]),
+            fire,
+            lambda o: _vary_like(
+                self.global_average(o[0], o[1], in_flight=o[2]), o),
             lambda o: o, (params, ps_weight, in_flight))
 
 

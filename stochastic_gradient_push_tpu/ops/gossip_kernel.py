@@ -45,8 +45,11 @@ wait at the bottom — exactly the start/done split the XLA lane's
 collective-permute pair gets, now with in-VMEM decode on the landing
 side.  On the interpret CI mesh the Pallas interpreter discharges each
 remote copy synchronously, so split and fused numerics are identical.
-A live-TPU capture of the compiled pipeline is the carried ROADMAP
-item.
+The compiled pair first ran on four TPU v5e chips in PR 21
+(``chip_smoke.py --chips 4``): one round at ResNet-50's payload is
+bit-identical to the XLA lane on the f32 and the int8 wire.  Its speed
+is not measured (ROADMAP S4); the default lane stays ``xla`` until it
+is.
 
 :func:`gossip_edge_axpy` remains as the fused convenience spelling —
 now literally ``gossip_edge_wait(gossip_edge_start(...), acc)`` — so
@@ -99,8 +102,13 @@ GOSSIP_KERNELS = ("auto", "pallas", "xla")
 # DMA issue cost, shallow enough to leave VMEM for the train step
 DEFAULT_CHUNK_ELEMS = 64 * 1024
 
-# ceiling on chunks per call (bounds the per-chunk DMA semaphore
-# arrays); larger payloads get proportionally larger chunks
+# TPU vector lane width: float chunks are laid out in whole lanes
+_LANES = 128
+# an int8 VMEM tile is 32 sublanes deep
+_INT8_SUBLANES = 32
+
+# ceiling on chunks (grid steps) per call; larger payloads get
+# proportionally larger chunks
 _MAX_CHUNKS = 256
 
 # barrier-semaphore id pool the collective layer cycles per transport
@@ -193,14 +201,33 @@ def _chunk_layout(n_decoded: int, block: int | None, chunk_elems: int):
         raise ValueError(f"chunk_elems must be >= 1, got {chunk_elems}")
     blk = int(block) if block else 1
     rows_total = max(1, -(-n_decoded // blk))   # ceil: codec blocks
-    # a chunk never exceeds the payload: padding is bounded by one
-    # chunk's ragged tail, not by the chunk target
+    # the chunk shrinks to the payload: padding is bounded by one
+    # chunk's ragged tail (plus lane alignment), not by the chunk target
     rows_per_chunk = max(1, min(int(chunk_elems) // blk, rows_total))
-    nb = -(-rows_total // rows_per_chunk)
-    if nb > _MAX_CHUNKS:
+    if -(-rows_total // rows_per_chunk) > _MAX_CHUNKS:
         rows_per_chunk = -(-rows_total // _MAX_CHUNKS)
-        nb = -(-rows_total // rows_per_chunk)
+    # whole tiles once a chunk spans one: 128 lanes of floats (the
+    # chunk tiles as [c // 128, 128], _tile), 32 sublanes of int8 rows
+    align = _INT8_SUBLANES if block else _LANES
+    if rows_per_chunk > align:
+        rows_per_chunk = -(-rows_per_chunk // align) * align
+    nb = -(-rows_total // rows_per_chunk)
     return rows_per_chunk, rows_per_chunk * blk, nb
+
+
+def _tile(kind: str, rows: int, c: int, block: int | None):
+    """The ``[R, L]`` tile one decoded chunk is laid out as, on the wire
+    side and the accumulator side alike.  Mosaic wants the last two dims
+    of every VMEM block to be the array's own or ``(8, 128)``-divisible,
+    and slices HBM refs only on untiled leading dims — so a chunk is
+    never a row of a ``[NB, c]`` array: int8 keeps the codec's
+    ``[rows, block]`` (scales ride as a ``[rows, 1]`` column), the float
+    lanes fold ``c`` into whole 128-lane rows (a sub-lane remainder
+    payload stays one short row)."""
+    if kind == "int8":
+        return rows, int(block)
+    lanes = _LANES if c % _LANES == 0 else c
+    return c // lanes, lanes
 
 
 def _pad_rows(a, rows: int):
@@ -219,8 +246,9 @@ def _pad_rows(a, rows: int):
 @dataclasses.dataclass
 class TransportHandle:
     """Opaque result of :func:`gossip_edge_start`: the landed encoded
-    receive buffers (each ``[E, NB, ...]``) plus the static layout the
-    wait side needs to pull, decode and fold them.  A pytree, so it
+    receive buffers (each ``[E, NB, R, L]``, see :func:`_tile`) plus the
+    static layout the wait side needs to pull, decode and fold them.  A
+    pytree, so it
     rides FIFO slots, ``lax.cond`` branches and jit boundaries; between
     a start and its wait the buffers hold WIRE bytes — nothing outside
     :func:`gossip_edge_wait` / :meth:`decode_edges` may interpret them.
@@ -256,12 +284,10 @@ class TransportHandle:
         kernel: drains, health views, interpret-mode checks.  Fold the
         edges sequentially (``for e: acc += dec[e]``) to stay
         bit-aligned with the kernel's per-edge accumulation."""
-        kind, n, rows, _c, nb, ne, _interp = self.meta
+        kind, n, _rows, _c, _nb, ne, _interp = self.meta
         if kind == "int8":
             q, scale = self.recv
-            qf = q.astype(jnp.float32).reshape(ne, nb * rows, -1)
-            s = scale.reshape(ne, nb * rows)
-            return (qf * s[:, :, None]).reshape(ne, -1)[:, :n]
+            return (q.astype(jnp.float32) * scale).reshape(ne, -1)[:, :n]
         return self.recv[0].reshape(ne, -1)[:, :n].astype(jnp.float32)
 
 
@@ -277,13 +303,14 @@ def empty_transport_handle(spec, n_decoded: int, num_edges: int,
     kind = spec.kind
     block = spec.block if kind == "int8" else None
     rows, c, nb = _chunk_layout(n_decoded, block, chunk_elems)
+    tile = _tile(kind, rows, c, block)
     if kind == "int8":
-        recv = (jnp.zeros((num_edges, nb, rows, int(block)), jnp.int8),
-                jnp.zeros((num_edges, nb, rows), jnp.float32))
+        recv = (jnp.zeros((num_edges, nb) + tile, jnp.int8),
+                jnp.zeros((num_edges, nb, rows, 1), jnp.float32))
     elif kind == "bf16":
-        recv = (jnp.zeros((num_edges, nb, c), jnp.bfloat16),)
+        recv = (jnp.zeros((num_edges, nb) + tile, jnp.bfloat16),)
     else:
-        recv = (jnp.zeros((num_edges, nb, c), jnp.float32),)
+        recv = (jnp.zeros((num_edges, nb) + tile, jnp.float32),)
     return TransportHandle(
         recv=recv, meta=(kind, int(n_decoded), rows, c, nb,
                          int(num_edges), bool(interpret)))
@@ -299,7 +326,9 @@ def _edge_start_kernel(nparts: int, nb: int, ne: int, compiled: bool,
 
     Ref layout: ``refs = (*part_refs, *out_refs, *send_sems,
     *recv_sems)`` — parts and outs full-shape in ANY (the kernel only
-    touches them through DMA), semaphores per (edge, chunk).
+    touches them through DMA), two DMA semaphore slots per part: the
+    pipeline never has more than two chunks in flight, and the chip's
+    semaphore memory holds a few hundred in all, not one per chunk.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -317,13 +346,14 @@ def _edge_start_kernel(nparts: int, nb: int, ne: int, compiled: bool,
         # wait it is the Mosaic idiom (the semaphores carry identity)
         e = gg // nb
         k = gg - e * nb
+        slot = gg % 2
         dmas = []
         for i in range(nparts):
             dmas.append(pltpu.make_async_remote_copy(
                 src_ref=part_refs[i].at[pl.ds(e, 1), pl.ds(k, 1)],
                 dst_ref=out_refs[i].at[pl.ds(e, 1), pl.ds(k, 1)],
-                send_sem=send_sems[i].at[e, k],
-                recv_sem=recv_sems[i].at[e, k],
+                send_sem=send_sems[i].at[slot],
+                recv_sem=recv_sems[i].at[slot],
                 device_id=tbl_ref[e, 0],
                 device_id_type=pltpu.DeviceIdType.LOGICAL,
             ))
@@ -379,27 +409,60 @@ def _edge_start_kernel(nparts: int, nb: int, ne: int, compiled: bool,
         dma.wait()
 
 
+def _wire_rows(part):
+    """A chunked part ``[E, NB, ...]`` as the lane-dense
+    ``[E, NB, Rp, 128]`` rows the transport moves (zero-padded).
+    Mosaic slices an HBM ref for DMA only where the slice matches the
+    ref's tiled layout, and a minor dim short of 128 lanes (int8's
+    ``[rows, block]``, the ``[rows, 1]`` scale column) is padded there;
+    whole rows of 128 are not.  A no-op view for the float lanes."""
+    e, nb = part.shape[:2]
+    flat = part.reshape(e, nb, -1)
+    pad = -flat.shape[2] % _LANES
+    if pad:
+        flat = jnp.pad(flat, ((0, 0), (0, 0), (0, pad)))
+    return flat.reshape(e, nb, -1, _LANES)
+
+
+def _from_wire_rows(rows, shape):
+    """Inverse of :func:`_wire_rows`: the landed rows back in the
+    part's own ``shape``."""
+    e, nb = shape[:2]
+    m = int(np.prod(shape[2:]))
+    return rows.reshape(e, nb, -1)[:, :, :m].reshape(shape)
+
+
 def _edge_start_call(interpret: bool, collective_id: int, ne: int,
                      nb: int, tbl, parts_chunks):
     """Build and invoke the transport pallas_call: inputs are the
-    per-edge chunked parts (each ``[E, NB, ...]``), outputs the landed
-    encoded buffers of identical shape on the destination ranks."""
+    per-edge chunked parts (each ``[E, NB, Rp, 128]``), outputs the
+    landed encoded buffers of identical shape on the destination
+    ranks."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    from .flash_attention import _sds
+
     nparts = len(parts_chunks)
+    # interpret=True is the HLO interpreter: its discharge of a remote
+    # copy is synchronous and it cannot signal remote semaphores, so it
+    # runs the barrier-free program.  The Mosaic TPU interpreter
+    # (pltpu.InterpretParams) simulates DMA and semaphores across the
+    # mesh and runs the compiled-mode program, barrier included.
+    hlo_interpreter = isinstance(interpret, bool) and interpret
     kernel = functools.partial(_edge_start_kernel, nparts, nb, ne,
-                               not interpret)
+                               not hlo_interpreter)
     return pl.pallas_call(
         kernel,
-        out_shape=tuple(jax.ShapeDtypeStruct(p.shape, p.dtype)
+        # the landed buffers vary over the gossip axis like the
+        # axis_index-derived table, whatever the parts' own type
+        out_shape=tuple(_sds(p.shape, p.dtype, tbl, p)
                         for p in parts_chunks),
         grid=(ne * nb,),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] +
-                 [pl.BlockSpec(memory_space=pltpu.ANY)] * nparts,
-        out_specs=tuple([pl.BlockSpec(memory_space=pltpu.ANY)] * nparts),
-        scratch_shapes=(
-            [pltpu.SemaphoreType.DMA((ne, nb))] * (2 * nparts)),
+                 [pl.BlockSpec(memory_space=pl.ANY)] * nparts,
+        out_specs=tuple([pl.BlockSpec(memory_space=pl.ANY)] * nparts),
+        scratch_shapes=[pltpu.SemaphoreType.DMA((2,))] * (2 * nparts),
         # collective_id keys the entry-barrier semaphore and
         # coordinates the remote-DMA buffer addresses across the SPMD
         # programs on a real mesh.  Two calls that could execute
@@ -409,7 +472,7 @@ def _edge_start_call(interpret: bool, collective_id: int, ne: int,
         # ordered by their handle data dependency, and TPU's single
         # compute stream executes custom calls sequentially in schedule
         # order, which backstops any id reuse across the pool boundary
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             collective_id=collective_id),
         interpret=interpret,
     )(tbl, *parts_chunks)
@@ -489,21 +552,24 @@ def gossip_edge_start(parts, dests, axis_name: str, spec,
             lambda a: _pad_rows(a, nb * rows).reshape(nb, rows,
                                                       a.shape[1]))(q)
         s_chunks = jax.vmap(
-            lambda a: _pad_rows(a, nb * rows).reshape(nb, rows))(scale)
+            lambda a: _pad_rows(a, nb * rows).reshape(nb, rows, 1))(scale)
         parts_chunks = (q_chunks, s_chunks)
     else:
         (w,) = parts
         n = int(n_decoded) if n_decoded is not None else w.shape[1]
         rows, c, nb = _chunk_layout(n, None, chunk_elems)
+        tile = _tile(kind, rows, c, None)
         parts_chunks = (jax.vmap(
-            lambda a: _pad_rows(a.reshape(-1), nb * c).reshape(nb, c))(w),)
+            lambda a: _pad_rows(a.reshape(-1), nb * c).reshape(
+                (nb,) + tile))(w),)
 
     recv = _edge_start_call(interpret, int(collective_id), ne, nb, tbl,
-                            parts_chunks)
+                            tuple(_wire_rows(p) for p in parts_chunks))
     if not isinstance(recv, (tuple, list)):
         recv = (recv,)
     return TransportHandle(
-        recv=tuple(recv),
+        recv=tuple(_from_wire_rows(r, p.shape)
+                   for r, p in zip(recv, parts_chunks)),
         meta=(kind, n, rows, c, nb, ne, bool(interpret)))
 
 
@@ -521,14 +587,15 @@ def _edge_wait_kernel(kind: str, ne: int, out_dtype, acc_ref, *refs):
     part_refs = refs[:-1]
     out_ref = refs[-1]
 
-    # in-VMEM decode; elementwise op order matches WireCodec.decode
-    # exactly (bit parity with the XLA lane)
+    # in-VMEM decode on the chunk's own [R, L] tile (no in-kernel
+    # reshape); elementwise op order matches WireCodec.decode exactly
+    # (bit parity with the XLA lane)
     if kind == "int8":
         q = part_refs[0][0, 0].astype(jnp.float32)     # [R, block]
-        scale = part_refs[1][0, 0]                     # [R]
-        dec = (q * scale[:, None]).reshape(1, -1).astype(out_dtype)
+        scale = part_refs[1][0, 0]                     # [R, 1]
+        dec = (q * scale).astype(out_dtype)[None]
     else:  # "f32" passthrough / "bf16" widen — one astype covers both
-        dec = part_refs[0][0, 0].reshape(1, -1).astype(out_dtype)
+        dec = part_refs[0][0, 0].astype(out_dtype)[None]
 
     @pl.when(e == 0)
     def _init():
@@ -546,35 +613,24 @@ def _edge_wait_call(kind: str, interpret: bool, acc_chunks, recv, ne: int):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    nb, c = acc_chunks.shape
+    from .flash_attention import _sds
+
+    nb = acc_chunks.shape[0]
     kernel = functools.partial(_edge_wait_kernel, kind, ne,
                                acc_chunks.dtype)
-    if kind == "int8":
-        in_specs = [
-            pl.BlockSpec((1, c), lambda k, e: (k, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1) + recv[0].shape[2:],
-                         lambda k, e: (e, k, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1) + recv[1].shape[2:],
-                         lambda k, e: (e, k, 0),
-                         memory_space=pltpu.VMEM),
-        ]
-    else:
-        in_specs = [
-            pl.BlockSpec((1, c), lambda k, e: (k, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, c), lambda k, e: (e, k, 0),
-                         memory_space=pltpu.VMEM),
-        ]
+    # every block's last two dims are its array's own (_tile)
+    acc_spec = pl.BlockSpec((1,) + acc_chunks.shape[1:],
+                            lambda k, e: (k, 0, 0),
+                            memory_space=pltpu.VMEM)
     return pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct(acc_chunks.shape,
-                                       acc_chunks.dtype),
+        out_shape=_sds(acc_chunks.shape, acc_chunks.dtype,
+                       acc_chunks, *recv),
         grid=(nb, ne),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, c), lambda k, e: (k, 0),
-                               memory_space=pltpu.VMEM),
+        in_specs=[acc_spec] + [
+            pl.BlockSpec((1, 1) + r.shape[2:], lambda k, e: (e, k, 0, 0),
+                         memory_space=pltpu.VMEM) for r in recv],
+        out_specs=acc_spec,
         interpret=interpret,
     )(acc_chunks, *recv)
 
@@ -594,7 +650,8 @@ def gossip_edge_wait(handle: TransportHandle, acc, weight=None):
         raise ValueError(
             f"accumulator has {acc.size} elements but the transport "
             f"handle landed a {n}-element payload")
-    acc_chunks = _pad_rows(acc.reshape(-1), nb * c).reshape(nb, c)
+    acc_chunks = _pad_rows(acc.reshape(-1), nb * c).reshape(
+        (nb,) + handle.recv[0].shape[2:])
     out = _edge_wait_call(kind, interpret, acc_chunks, handle.recv, ne)
     out = out.reshape(-1)[:n].reshape(acc.shape)
     if weight is not None:
@@ -704,9 +761,11 @@ def _selftest() -> int:
         return tuple(t[None] for t in (k_f32, x_f32, k_i8, x_i8,
                                        s_f32, folded, seq))
 
+    # interpret mode: see train/step.py::shard_train_step on check_vma
     fn = jax.jit(jax.shard_map(both_lanes, mesh=mesh,
                                in_specs=P(GOSSIP_AXIS),
-                               out_specs=(P(GOSSIP_AXIS),) * 7))
+                               out_specs=(P(GOSSIP_AXIS),) * 7,
+                               check_vma=False))
     k_f32, x_f32, k_i8, x_i8, s_f32, folded, seq = map(
         np.asarray, jax.block_until_ready(fn(x)))
     if not np.array_equal(k_f32, x_f32):

@@ -544,7 +544,7 @@ def _hier_round_fn(hsched: HierarchicalSchedule, round_idx: int,
                    axis_name: str, comm_dtype=None, codec=None,
                    kernel=None, buckets=1):
     """One compiled hierarchical round: leader ppermute, then the exact
-    intra-slice average as ONE grouped ``psum`` over the slice sub-axis
+    intra-slice average as ONE grouped all-reduce over the slice sub-axis
     (ICI-local; the ``slice_size − 1`` rotate-permutations of the table
     representation collapse into a single collective).  Numerically this
     applies exactly ``W_intra @ W_inter(round)`` — the matrices the
@@ -575,15 +575,37 @@ def _hier_round_fn(hsched: HierarchicalSchedule, round_idx: int,
 
 def intra_average(tree, hsched: HierarchicalSchedule, axis_name: str):
     """The exact intra-slice average of a hierarchical round: ONE grouped
-    ``lax.psum`` over the slice sub-axis (ICI-local), numerically
+    all-reduce over the slice sub-axis (ICI-local), numerically
     ``W_intra @ tree``.  Public because the overlap consume path applies
     it separately: the delegate (DCN) share is deferred in flight while
     this cheap local collective stays at the bottom of the step."""
-    groups = [list(g) for g in hsched.slice_groups]
-    inv_s = 1.0 / hsched.slice_size
-    return jax.tree.map(
-        lambda a: lax.psum(a * jnp.asarray(inv_s, a.dtype), axis_name,
-                           axis_index_groups=groups), tree)
+    return _grouped_average(tree, axis_name, hsched.slice_groups)
+
+
+def _grouped_average(tree, axis_name: str, groups):
+    """Exact average inside each of ``groups`` (equal-sized rank blocks
+    of ``axis_name``), bit-identical across a group's members.
+
+    The all-reduce is spelled as its two halves — grouped
+    ``psum_scatter`` then grouped ``all_gather`` over the flattened leaf
+    — because ``lax.psum(..., axis_index_groups=)`` has no rule under
+    ``shard_map``'s varying-axes check, while both halves are
+    varying→varying collectives the checker types."""
+    groups = [list(g) for g in groups]
+    g = len(groups[0])
+
+    def average(a):
+        flat = jnp.ravel(a * jnp.asarray(1.0 / g, a.dtype))
+        pad = -flat.size % g
+        if pad:
+            flat = jnp.pad(flat, (0, pad))
+        part = lax.psum_scatter(flat, axis_name, scatter_dimension=0,
+                                axis_index_groups=groups, tiled=True)
+        full = lax.all_gather(part, axis_name, axis=0,
+                              axis_index_groups=groups, tiled=True)
+        return jnp.reshape(full[:a.size], a.shape)
+
+    return jax.tree.map(average, tree)
 
 
 def _synth_round_fn(ssched: SynthesizedSchedule, phase_idx: int,
@@ -591,23 +613,18 @@ def _synth_round_fn(ssched: SynthesizedSchedule, phase_idx: int,
                     kernel=None, buckets=1):
     """One compiled synthesized phase: an edge phase is one ``ppermute``
     round through the compact per-phase tables (full wire-codec path),
-    a psum phase is ONE grouped ``lax.psum`` over the spec's equal rank
+    a psum phase is ONE grouped all-reduce over the spec's equal rank
     blocks — numerically exactly the ``g − 1`` rotate-permutation
     matrix the verifier checks.  The error-feedback residual rides edge
     phases only and passes through psum phases untouched (an exact
     collective has no quantization error to account).  The Pallas
     ``kernel`` lane follows the same split: edge phases take the fused
-    transport, psum phases stay grouped ``lax.psum``."""
+    transport, psum phases stay a grouped XLA all-reduce."""
     if ssched.phase_kinds[phase_idx] == "psum":
-        groups = [list(g) for g in ssched.phase_groups[phase_idx]]
-        inv_g = 1.0 / len(groups[0])
+        groups = ssched.phase_groups[phase_idx]
 
         def mix(tree, tick, residual):
-            out = jax.tree.map(
-                lambda a: lax.psum(a * jnp.asarray(inv_g, a.dtype),
-                                   axis_name, axis_index_groups=groups),
-                tree)
-            return out, residual
+            return _grouped_average(tree, axis_name, groups), residual
 
         return mix
     return _round_fn(ssched.edge_phase_schedule(phase_idx), 0, axis_name,

@@ -37,17 +37,11 @@ def pvary_missing(x, axes):
     """Mark ``x`` varying over any of ``axes`` it isn't already varying
     over (idempotent pvary — a plain pvary/pcast raises on an
     already-varying axis)."""
-    try:
-        have = jax.typeof(x).vma
-    except (AttributeError, TypeError):
-        # older jax: no jax.typeof, or avals without vma tracking
-        have = frozenset()
+    have = jax.typeof(x).vma
     need = tuple(a for a in axes if a not in have)
     if not need:
         return x
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, need, to="varying")
-    return lax.pvary(x, need)
+    return lax.pcast(x, need, to="varying")
 
 
 def pipeline_spmd(body: tp.Callable, x_micro: jnp.ndarray,

@@ -226,14 +226,42 @@ def build_parser() -> argparse.ArgumentParser:
     return p
 
 
+def _flash_ok(seq_len: int) -> bool:
+    # the pallas kernel needs the (clamped) 128 block to divide seq_len
+    return seq_len % min(128, seq_len) == 0
+
+
+def resolve_attention(flag: str | None, seq_len: int, sp: int,
+                      backend: str, log) -> str:
+    """The ``--attn`` auto rule: ring under sequence parallelism, the
+    flash kernel on TPU when ``seq_len`` tiles, full attention elsewhere.
+    Shape is the only thing that routes an auto-selected flash to
+    blockwise; a kernel the chip's compiler rejects fails the run."""
+    if flag is None:
+        if sp > 1:
+            return "ring"
+        if backend != "tpu":
+            return "full"
+        if not _flash_ok(seq_len):
+            log.info(f"seq_len {seq_len} not divisible by the flash "
+                     "kernel block; falling back to blockwise attention")
+            return "blockwise"
+        return "flash"
+    if flag == "flash" and not _flash_ok(seq_len):
+        raise SystemExit(
+            f"--attn flash needs seq_len divisible by "
+            f"{min(128, seq_len)} (got {seq_len}); use "
+            "--attn blockwise or a padded seq_len")
+    return flag
+
+
 def main(argv=None):
+    from ..utils.compile_cache import place_compile_cache
+
+    place_compile_cache()
     args = build_parser().parse_args(argv)
 
     import jax
-
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
     import jax.numpy as jnp
     import numpy as np
 
@@ -450,63 +478,8 @@ def main(argv=None):
         log.info(f"process {proc_index}/{proc_count}: multihost LM over "
                  f"{mesh}")
 
-    def _flash_ok(seq_len: int) -> bool:
-        # the pallas kernel needs the (clamped) 128 block to divide seq_len
-        return seq_len % min(128, seq_len) == 0
-
-    def _flash_compiles() -> bool:
-        """Compile-and-run a tiny flash forward on the live backend.
-
-        The kernels' Mosaic lowering is only exercised on a real chip —
-        interpret-mode tests cannot catch layout rejections (round-2
-        lesson), so an auto-selected flash path probes once and falls
-        back to blockwise instead of stranding the whole run.  The probe
-        uses the RUN's dtype, head_dim, and (block-clamped) seq_len —
-        Mosaic layouts are shape/dtype-specific, so a fixed probe shape
-        could pass while the real model still fails."""
-        try:
-            from ..ops.flash_attention import (default_block,
-                                               flash_attention_forward)
-
-            dtype = (jnp.bfloat16 if args.precision == "bf16"
-                     else jnp.float32)
-            head_dim = args.d_model // args.n_heads
-            # the run's auto-selected block at the run's FULL seq_len:
-            # Mosaic layouts are shape-specific, so a shorter probe could
-            # pass while the real length still fails.  batch 1 x 1 head
-            # keeps the full-length probe cheap at any seq_len.
-            blk = default_block(args.seq_len)
-            t = args.seq_len
-            x = jnp.zeros((1, 1, t, head_dim), dtype)
-            jax.block_until_ready(
-                flash_attention_forward(x, x, x, causal=True,
-                                        block_q=blk, block_k=blk))
-            return True
-        except Exception as e:  # sgplint: disable=SGPL007
-            # (deliberate Mosaic-fallback catch: any compile or runtime
-            # rejection of the probe means "use blockwise attention";
-            # the error class is backend-version-dependent)
-            log.warning(
-                f"flash-attention probe failed ({type(e).__name__}: "
-                f"{str(e)[:200]}); falling back to blockwise attention")
-            return False
-
-    attn = args.attn
-    if attn is None:
-        attn = "ring" if sp > 1 else (
-            "flash" if jax.default_backend() == "tpu" else "full")
-        if attn == "flash" and not _flash_ok(args.seq_len):
-            log.info(f"seq_len {args.seq_len} not divisible by the flash "
-                     "kernel block; falling back to blockwise attention")
-            attn = "blockwise"
-        elif attn == "flash" and not _flash_compiles():
-            attn = "blockwise"  # auto-selected only: explicit --attn
-            # flash lets the real error surface instead
-    elif attn == "flash" and not _flash_ok(args.seq_len):
-        raise SystemExit(
-            f"--attn flash needs seq_len divisible by "
-            f"{min(128, args.seq_len)} (got {args.seq_len}); use "
-            "--attn blockwise or a padded seq_len")
+    attn = resolve_attention(args.attn, args.seq_len, sp,
+                             jax.default_backend(), log)
     ring_family = attn in ("ring", "ring_flash")
     if sp > 1 and not ring_family:
         raise SystemExit("--sp > 1 requires ring attention")
@@ -1048,8 +1021,8 @@ def main(argv=None):
     last_val = None
     last_stats_emit = start_step
     # step-indexed jax.profiler capture (shared with the image harness;
-    # utils/profiling.py tunnel caveat: a hung profiler RPC abandons the
-    # window and the run continues untraced)
+    # utils/profiling.py: a profiler start that hangs or fails is logged
+    # as an error and the run continues untraced)
     from ..utils.profiling import ProfileWindow
 
     pw = ProfileWindow(args.profile_dir,
@@ -1208,7 +1181,8 @@ def main(argv=None):
         # Trainer's fit() finally); finish() is idempotent
         rt.finish(step=steps_done)
 
-    result = {"final_loss": loss_meter.val, "avg_loss": loss_meter.avg,
+    result = {"attn": attn,
+              "final_loss": loss_meter.val, "avg_loss": loss_meter.avg,
               "tokens_per_sec": tokens_per_step
               * (steps_done - start_step)
               / (time.time() - t0 - val_time)}

@@ -14,7 +14,7 @@ clocks.  This module folds them into one Chrome-trace/Perfetto JSON:
   so the three layers can't collide;
 * **tid = rank·phase** — a host trace's (rank, phase-track) pairs map
   to distinct threads named ``r{rank}·{phase}``, preserving the
-  per-phase span taxonomy inside each host process;
+  per-phase span vocabulary inside each host process;
 * **clock alignment** — each source trace exports ``epoch_s`` (the
   wall-clock instant of its ts=0, :meth:`SpanTracer.to_chrome`); the
   merge re-bases every source onto ``min(epoch)`` so skewed hosts land

@@ -91,9 +91,9 @@ class TrainerConfig:
     # edge exchange into one remote-DMA kernel (in-VMEM wire decode +
     # mixing axpy; TPU only — a typed KernelBackendError elsewhere),
     # "auto" picks pallas on TPU.  Default "xla" (ppermute+decode): the
-    # kernel is parity-pinned through the Pallas interpreter but has no
-    # live-TPU capture yet — opt in explicitly until that lands, then
-    # flip this to "auto" (ROADMAP carried item).  Overlap rounds ride
+    # kernel pair runs on four chips and matches the xla lane bit for
+    # bit per round (chip_smoke.py --chips 4), but its speed is not
+    # measured — the default is decided by ROADMAP S4.  Overlap rounds ride
     # the kernel lane first-class: the split start/wait transport
     # launches the remote DMA at the top of the step and lands it at
     # the bottom, so compute actually hides the wire
@@ -157,8 +157,9 @@ class TrainerConfig:
     # step-indexed jax.profiler capture (utils/profiling.ProfileWindow):
     # when set, global steps [profile_start_step, profile_start_step +
     # profile_steps) are captured as a TensorBoard XPlane dump under
-    # profile_dir.  One-shot and tunnel-guarded: a hung profiler RPC
-    # abandons the window instead of stalling the run.  The dump path is
+    # profile_dir.  One-shot and guarded: a profiler start that hangs or
+    # fails is logged as an error and abandons the window instead of
+    # stalling the run.  The dump path is
     # stamped into run_meta so obsreport/fleetmon can point at it
     profile_dir: str | None = None
     profile_start_step: int = 2
@@ -495,13 +496,16 @@ class Trainer:
                 grad_accum=self.cfg.grad_accum,
                 health_axis=(self.gossip_axis if self.monitor is not None
                              else None))
+            lane = getattr(alg, "gossip_kernel", None)
+            check_vma = lane is None or not lane.interpret
             if scan > 1:
                 fn = shard_scanned_train_step(
                     step, self.mesh, scan, self.gossip_axis,
-                    self.local_axis)
+                    self.local_axis, check_vma=check_vma)
             else:
                 fn = shard_train_step(
-                    step, self.mesh, self.gossip_axis, self.local_axis)
+                    step, self.mesh, self.gossip_axis, self.local_axis,
+                    check_vma=check_vma)
             self._step_cache[key] = (alg, fn)
         return self._step_cache[key]
 

@@ -214,7 +214,8 @@ def build_eval_step(model, algorithm: GossipAlgorithm,
 
 
 def shard_train_step(step_fn, mesh, axis_name: str = GOSSIP_AXIS,
-                     local_axis: str | None = None):
+                     local_axis: str | None = None,
+                     check_vma: bool = True):
     """Wrap a per-rank step for a gossip mesh.
 
     Globally, every state leaf carries a leading gossip-rank dimension
@@ -228,6 +229,12 @@ def shard_train_step(step_fn, mesh, axis_name: str = GOSSIP_AXIS,
     (one shard per device), while state shards over the node axis only —
     the step's intra-node ``pmean`` keeps local replicas identical, which is
     what makes the node-only state sharding valid.
+
+    ``check_vma=False`` is for a step whose gossip rides the Pallas
+    kernel lane in *interpret* mode (``KernelLane.interpret``, tests
+    only): the interpreter evaluates the kernel body on the step's own
+    tracers and cannot type its mix of varying and unvarying operands.
+    A compiled kernel is an opaque custom call and keeps the check.
     """
     batch_spec = (P(axis_name) if local_axis is None
                   else P((axis_name, local_axis)))
@@ -242,13 +249,14 @@ def shard_train_step(step_fn, mesh, axis_name: str = GOSSIP_AXIS,
     sharded = jax.shard_map(
         wrapped, mesh=mesh,
         in_specs=(P(axis_name), batch_spec, batch_spec),
-        out_specs=(P(axis_name), P(axis_name)))
+        out_specs=(P(axis_name), P(axis_name)), check_vma=check_vma)
     return jax.jit(sharded, donate_argnums=(0,))
 
 
 def shard_scanned_train_step(step_fn, mesh, n_steps: int,
                              axis_name: str = GOSSIP_AXIS,
-                             local_axis: str | None = None):
+                             local_axis: str | None = None,
+                             check_vma: bool = True):
     """Fuse ``n_steps`` train steps into ONE compiled program via
     ``lax.scan``.
 
@@ -260,6 +268,7 @@ def shard_scanned_train_step(step_fn, mesh, n_steps: int,
 
     Batches gain a leading scan dimension: ``images[n_steps, world, ...]``.
     Returns ``(state, metrics)`` with metrics stacked ``[world, n_steps]``.
+    ``check_vma`` as in :func:`shard_train_step`.
     """
     batch_spec = (P(None, axis_name) if local_axis is None
                   else P(None, (axis_name, local_axis)))
@@ -283,7 +292,7 @@ def shard_scanned_train_step(step_fn, mesh, n_steps: int,
     sharded = jax.shard_map(
         wrapped, mesh=mesh,
         in_specs=(P(axis_name), batch_spec, batch_spec),
-        out_specs=(P(axis_name), P(axis_name)))
+        out_specs=(P(axis_name), P(axis_name)), check_vma=check_vma)
     return jax.jit(sharded, donate_argnums=(0,))
 
 

@@ -44,11 +44,11 @@ def bad_transport(x):
     a = pl.pallas_call(
         functools.partial(_leaky_kernel, 4),
         out_shape=x,
-        compiler_params=pltpu.TPUCompilerParams(collective_id=7),  # EXPECT: SGPL013
+        compiler_params=pltpu.CompilerParams(collective_id=7),  # EXPECT: SGPL013
     )(x)
     b = pl.pallas_call(
         _conditional_wait_kernel,
         out_shape=x,
-        compiler_params=pltpu.TPUCompilerParams(collective_id=7),  # EXPECT: SGPL013
+        compiler_params=pltpu.CompilerParams(collective_id=7),  # EXPECT: SGPL013
     )(a)
     return pl.pallas_call(_barrier_arity_kernel, out_shape=x)(b)

@@ -46,7 +46,7 @@ def edge_transport(x, leaf_slot):
     return pl.pallas_call(
         functools.partial(_edge_kernel, 2),
         out_shape=x,
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             collective_id=leaf_slot % COLLECTIVE_ID_SLOTS),
     )(staged)
 
@@ -57,5 +57,5 @@ def pinned_probe(x):
     return pl.pallas_call(
         _local_stage_kernel,
         out_shape=x,
-        compiler_params=pltpu.TPUCompilerParams(collective_id=3),
+        compiler_params=pltpu.CompilerParams(collective_id=3),
     )(x)

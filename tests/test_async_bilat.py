@@ -83,12 +83,7 @@ def test_trainer_bilat_async_converges_replicas(tmp_path):
     """End-to-end through the Trainer: local-SGD compiled step + host
     averaging keeps replicas in consensus (spread far below a no-comm
     control) and records a staleness distribution."""
-    import os
-
     import jax
-
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
 
     from stochastic_gradient_push_tpu.algorithms.api import GossipAlgorithm
     from stochastic_gradient_push_tpu.data import (

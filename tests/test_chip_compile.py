@@ -1,0 +1,209 @@
+"""Ask the chip's compiler, without the chip.
+
+The TPU compiler is installed next to the CPU backend and compiles for a
+topology that is *described*, not attached (``v5e:2x2``, the four-chip
+host).  Interpret mode cannot see what Mosaic refuses — a slice off the
+``(8, 128)`` tiling, a block over the VMEM limit, too many semaphores —
+so the kernels of the main path are compiled here at their real widths.
+Nothing runs: these tests say a program *builds* for the chip, never that
+it is right or fast.
+
+All chip compiles live in THIS file and the topology is described inside
+a fixture: the process that loads the TPU library keeps it until exit, so
+a second xdist worker must never try (a second file could land on one).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from stochastic_gradient_push_tpu.ops import gossip_kernel as gk
+from stochastic_gradient_push_tpu.ops.flash_attention import (
+    default_block, flash_attention)
+from stochastic_gradient_push_tpu.ops.ring_flash import ring_flash_attention
+from stochastic_gradient_push_tpu.parallel import (
+    GOSSIP_AXIS, collectives, make_gossip_mesh, wire)
+from stochastic_gradient_push_tpu.serve.engine import ServeConfig
+from stochastic_gradient_push_tpu.serve.paged_attention import (
+    paged_attention_decode)
+from stochastic_gradient_push_tpu.topology import (
+    NPeerDynamicDirectedExponentialGraph, build_schedule)
+
+WORLD = 4
+# ResNet-50's parameter count: the flat payload one gossip round moves
+RESNET50_PARAMS = 25_557_032
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    saved_log_dir = os.environ.get("TPU_LOG_DIR")
+    os.environ["TPU_LOG_DIR"] = "disabled"  # else the compiler logs to /tmp
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    finally:
+        if saved_log_dir is None:
+            os.environ.pop("TPU_LOG_DIR")
+        else:
+            os.environ["TPU_LOG_DIR"] = saved_log_dir
+    # a chip compile is written to the persistent cache but cannot be read
+    # back without a chip: keep these compiles out of it
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh(topo):
+    return make_gossip_mesh(WORLD, devices=topo.devices)
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """The kernel auto rules ask ``jax.default_backend()``, which still
+    says cpu here; steer them onto their TPU branch for the test."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _per_rank(mesh, shape, dtype):
+    return jax.ShapeDtypeStruct(
+        (WORLD,) + shape, dtype,
+        sharding=NamedSharding(mesh, P(GOSSIP_AXIS)))
+
+
+def _sharded(f, mesh, n_out):
+    """jit(shard_map) of a per-rank ``f`` over world-stacked arguments."""
+    def wrapped(*args):
+        out = f(*(a[0] for a in args))
+        return tuple(o[None] for o in out)
+
+    return jax.jit(jax.shard_map(
+        wrapped, mesh=mesh, in_specs=P(GOSSIP_AXIS),
+        out_specs=(P(GOSSIP_AXIS),) * n_out))
+
+
+@pytest.mark.parametrize("shape", [(8, 12, 1024, 64), (2, 8, 4096, 64)],
+                         ids=["t1024", "t4096"])
+def test_flash_forward_and_backward_compile(one_chip, on_tpu, shape):
+    """The flagship LM's attention (and the longest captured length) at
+    the auto block: forward, dq and dk/dv kernels via ``jax.grad``."""
+    assert default_block(shape[2]) == 512
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(
+            jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+
+
+def test_push_sum_round_is_a_collective_permute(mesh):
+    """The XLA lane's push-sum round on four chips is a real
+    ``collective-permute`` (on one chip it degenerates to a copy)."""
+    sched = build_schedule(
+        NPeerDynamicDirectedExponentialGraph(WORLD, peers_per_itr=1))
+
+    def round_(p, w, phase):
+        return collectives.mix_push_sum(p, w, phase, sched, GOSSIP_AXIS)
+
+    text = _sharded(round_, mesh, 2).lower(
+        _per_rank(mesh, (RESNET50_PARAMS,), jnp.float32),
+        _per_rank(mesh, (), jnp.float32),
+        _per_rank(mesh, (), jnp.int32)).compile().as_text()
+    assert "collective-permute" in text
+    assert "tpu_custom_call" not in text
+
+
+@pytest.mark.parametrize("codec", [None, wire.Int8Codec(64)],
+                         ids=["f32", "int8"])
+def test_gossip_start_wait_pair_compiles(mesh, codec):
+    """The Pallas transport at ResNet-50's payload: the remote-DMA start
+    kernel (entry barrier, ``collective_id``, two semaphore slots) and
+    the local decode+axpy wait kernel, both Mosaic custom calls."""
+    dests = np.asarray([(r + 1) % WORLD for r in range(WORLD)])
+
+    def pair(x):
+        if codec is None:
+            parts, spec = (x.reshape(1, -1),), wire.F32.kernel_spec()
+        else:
+            parts, spec = codec.encode(x), codec.kernel_spec()
+        handle = gk.gossip_edge_start(parts, dests, GOSSIP_AXIS, spec,
+                                      n_decoded=x.size)
+        return (gk.gossip_edge_wait(handle, x * 0.5),)
+
+    text = _sharded(pair, mesh, 1).lower(
+        _per_rank(mesh, (RESNET50_PARAMS,), jnp.float32)
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+
+
+def test_ring_flash_tick_compiles(mesh):
+    """One ring-flash pass over four sequence shards: flash-kernel ticks
+    joined by ``collective-permute`` rotations, forward and backward."""
+    def grads(q, k, v):
+        def loss(q, k, v):
+            return ring_flash_attention(
+                q, k, v, GOSSIP_AXIS, causal=True,
+                use_pallas=True).astype(jnp.float32).sum()
+
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    x = _per_rank(mesh, (2, 12, 1024, 64), jnp.bfloat16)
+    text = _sharded(grads, mesh, 3).lower(x, x, x).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "collective-permute" in text
+
+
+def test_grouped_average_compiles(mesh):
+    """The intra-slice exact average (hierarchical / synthesized psum
+    phases): grouped reduce-scatter + all-gather, which XLA may fuse back
+    into one grouped all-reduce."""
+    def average(p):
+        return (collectives._grouped_average(
+            p, GOSSIP_AXIS, [[0, 1], [2, 3]]),)
+
+    text = _sharded(average, mesh, 1).lower(
+        _per_rank(mesh, (RESNET50_PARAMS,), jnp.float32)
+    ).compile().as_text()
+    assert "all-reduce" in text or "reduce-scatter" in text
+
+
+def test_paged_decode_kernel_compiles(one_chip):
+    """serve/'s decode kernel at the engine's default page shape — not on
+    the main path; compiled once so ROADMAP R8 starts from a known
+    state."""
+    batch, heads, head_dim = 8, 12, 64
+    cfg = ServeConfig(n_heads=heads)
+    pages = jax.ShapeDtypeStruct(
+        (heads, cfg.num_pages + 1, cfg.page_size, head_dim), jnp.bfloat16,
+        sharding=one_chip)
+    text = jax.jit(
+        lambda *a: paged_attention_decode(*a, use_pallas=True)).lower(
+        jax.ShapeDtypeStruct((batch, heads, head_dim), jnp.bfloat16,
+                             sharding=one_chip),
+        pages, pages,
+        jax.ShapeDtypeStruct((batch, cfg.max_pages_per_seq), jnp.int32,
+                             sharding=one_chip),
+        jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=one_chip),
+    ).compile().as_text()
+    assert "tpu_custom_call" in text

@@ -360,12 +360,6 @@ def test_moe_pp_ep_sp_4d_trains(tmp_path):
     assert np.isfinite(r["val_loss"])
 
 
-@pytest.mark.skipif(
-    jax.__version_info__ < (0, 5),
-    reason="marginal 6-step convergence threshold (3.6) calibrated on "
-           "newer jax; under the jax<0.5 compat shim the ep x sp run "
-           "still trains (finite, decreasing loss) but lands ~0.07 above "
-           "it")
 def test_moe_ep_with_ring_sp_trains(tmp_path):
     """ep x sp: expert parallelism (all_to_all over ep) composed with
     ring sequence parallelism on the 3-D (gossip, ep, seq) mesh."""

@@ -183,7 +183,8 @@ def _run(sched, staleness, buckets, rounds=ROUNDS, overlap=True,
     mesh = make_gossip_mesh(WORLD)
     fn = jax.jit(jax.shard_map(step, mesh=mesh,
                                in_specs=(P(GOSSIP_AXIS),) * 2,
-                               out_specs=(P(GOSSIP_AXIS),) * 2))
+                               out_specs=(P(GOSSIP_AXIS),) * 2,
+                               check_vma=False))
     rng = np.random.default_rng(3)
     params = {"w": rng.normal(size=(WORLD, 24)).astype(np.float32),
               "b": rng.normal(size=(WORLD, 5)).astype(np.float32)}

@@ -15,6 +15,12 @@ fallback lane).
 Dispatch is serialized (every call drains before the next, per the PR-8
 CPU-collective deadlock note), and the sweep lives in ONE test so two
 compiled mesh programs never run concurrently.
+
+Every ``shard_map`` that holds an INTERPRETED kernel is built with
+``check_vma=False``: the Pallas interpreters evaluate the kernel body on
+the enclosing trace's own values and cannot type its mix of varying and
+unvarying operands (jax 0.9; jax's own remote-DMA tests do the same).
+A compiled kernel is an opaque custom call and needs no such thing.
 """
 
 import jax
@@ -172,8 +178,8 @@ class TestFlagPlumbing:
     def test_trainer_config_default(self):
         from stochastic_gradient_push_tpu.train.loop import TrainerConfig
 
-        # conservative default until the kernel's live-TPU capture
-        # lands: pallas/auto are explicit opt-ins
+        # the default until ROADMAP S4 measures the lanes on the chip:
+        # pallas/auto are explicit opt-ins
         assert TrainerConfig().gossip_kernel == "xla"
 
     def test_cli_default_and_rejection(self):
@@ -295,7 +301,8 @@ def test_edge_axpy_matches_ppermute_decode(n, chunk):
         return tuple(outs)
 
     fn = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P(GOSSIP_AXIS),
-                               out_specs=(P(GOSSIP_AXIS),) * 8))
+                               out_specs=(P(GOSSIP_AXIS),) * 8,
+                               check_vma=False))
     x = np.random.default_rng(n).normal(
         size=(WORLD, n)).astype(np.float32)
     res = [np.asarray(a) for a in jax.block_until_ready(fn(x))]
@@ -355,6 +362,56 @@ def test_compiled_mode_kernel_carries_the_entry_barrier():
         "semaphore signals have no discharge rule)")
 
 
+def test_compiled_mode_program_runs_under_the_tpu_interpreter():
+    """The program the chip runs — entry barrier, depth-2 chunk pipeline
+    on two semaphore slots, remote DMA of lane-dense wire rows — executed
+    by the Mosaic TPU interpreter, which simulates semaphores and DMA
+    across the mesh (``interpret=True`` is the HLO interpreter and runs
+    the barrier-free program).  Multi-chunk, two edges folded, f32 and
+    int8: bit-equal to the HLO interpreter and to numpy, no data race."""
+    from jax._src.pallas.mosaic.interpret import (
+        interpret_pallas_call as tpu_interpreter)
+    from jax.experimental.pallas import tpu as pltpu
+
+    from stochastic_gradient_push_tpu.ops.gossip_kernel import (
+        gossip_edge_start, gossip_edge_wait)
+
+    world, n = 4, 5000
+    mesh = make_gossip_mesh(world)
+    d1 = np.asarray([(r + 1) % world for r in range(world)])
+    d2 = np.asarray([(r + 2) % world for r in range(world)])
+    codec = wire.Int8Codec(64)
+    x = np.random.default_rng(0).normal(size=(world, n)).astype(np.float32)
+
+    def f(xr):
+        xr = xr.reshape(-1)
+        acc = xr * 0.25
+        outs = []
+        for mode in (pltpu.InterpretParams(detect_races=True), True):
+            handle = gossip_edge_start(
+                (jnp.stack([xr, xr * 0.5]),), np.stack([d1, d2]),
+                GOSSIP_AXIS, wire.F32.kernel_spec(), n_decoded=n,
+                interpret=mode, chunk_elems=512, collective_id=6)
+            outs.append(gossip_edge_wait(handle, acc)[None])
+            outs.append(gossip_edge_axpy(
+                acc, codec.encode(xr), d1, GOSSIP_AXIS,
+                codec.kernel_spec(), interpret=mode, chunk_elems=1024,
+                collective_id=7)[None])
+        return tuple(outs)
+
+    fn = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P(GOSSIP_AXIS),
+                               out_specs=(P(GOSSIP_AXIS),) * 4,
+                               check_vma=False))
+    assert "get_barrier_semaphore" in str(jax.make_jaxpr(fn)(x))
+    tpu_f32, tpu_i8, hlo_f32, hlo_i8 = map(np.asarray, fn(x))
+    np.testing.assert_array_equal(tpu_f32, hlo_f32)
+    np.testing.assert_array_equal(tpu_i8, hlo_i8)
+    np.testing.assert_array_equal(
+        tpu_f32, x * 0.25 + np.roll(x, 1, axis=0)
+        + 0.5 * np.roll(x, 2, axis=0))
+    assert not tpu_interpreter.races.races_found
+
+
 def test_dests_must_be_a_permutation():
     # the barrier handshakes with the permutation's inverse at this
     # rank, which only exists for a bijection — reject garbage early
@@ -380,7 +437,8 @@ def _run_rounds(schedule, kernel, codec=None, ef=False, faults=None,
     mesh = make_gossip_mesh(WORLD)
     fn = jax.jit(jax.shard_map(step, mesh=mesh,
                                in_specs=(P(GOSSIP_AXIS),) * 2,
-                               out_specs=(P(GOSSIP_AXIS),) * 2))
+                               out_specs=(P(GOSSIP_AXIS),) * 2,
+                               check_vma=kernel is None))
     rng = np.random.default_rng(0)
     params = {"w": rng.normal(size=(WORLD, leaf)).astype(np.float32),
               "b": rng.normal(size=(WORLD, 5)).astype(np.float32)}

@@ -64,12 +64,6 @@ def test_hierarchical_mesh_training_step():
     np.testing.assert_allclose(w, np.ones_like(w), atol=1e-4)
 
 
-@pytest.mark.skipif(
-    jax.__version_info__ < (0, 5),
-    reason="collectives inside the differentiated forward (local-axis BN "
-           "sync) transpose differently under the vma-less shard_map "
-           "compat shim (check_rep=False) in jax<0.5, so hierarchical and "
-           "flat grads legitimately disagree there")
 def test_hierarchical_local_grads_match_wider_batch():
     """One hierarchical step (2 local devices x batch B) must equal a flat
     gossip step with per-rank batch 2B: exact local averaging is just a

@@ -1,13 +1,7 @@
 """DevicePrefetcher (data/prefetch.py): overlap H2D with compute."""
 
-import os
-
 import jax
 import numpy as np
-
-if os.environ.get("JAX_PLATFORMS"):
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 from jax.sharding import PartitionSpec as P
 
 from stochastic_gradient_push_tpu.data import (DistributedSampler,

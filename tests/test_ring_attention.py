@@ -127,14 +127,6 @@ class TestRingFlash:
             ring_flash_attention)
         from stochastic_gradient_push_tpu.parallel import make_gossip_mesh
 
-        if not causal and jax.__version_info__ < (0, 5):
-            pytest.skip(
-                "non-causal ring flash: every tick's mode is the constant "
-                "FULL, and the resulting program shape makes jax<0.5's "
-                "SPMD partitioner emit an unsupported PartitionId op on "
-                "the CPU mesh; the causal variants exercise the same "
-                "merge/ppermute machinery and pass")
-
         world = 4
         mesh = make_gossip_mesh(world)
         q, k, v = qkv
