@@ -1,0 +1,121 @@
+"""``BENCHMARK.json`` and the data files it names: everything loads, every
+name refers to something that exists, and a later PR's new files are found
+without editing one that is there."""
+
+import os
+import re
+
+import pytest
+
+from bench_toy import REPO, TOY_CELL, make_toy_root
+from benchmark import spec
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+BENCH = spec.load_benchmark(REPO)
+CELLS = [w["name"] for w in BENCH["workloads"]]
+METRICS = BENCH["end_to_end"] + BENCH["per_layer"]
+
+
+def test_benchmark_json_keeps_to_the_contracts_shape():
+    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
+                          "workloads", "end_to_end", "per_layer"}
+    assert 1 <= BENCH["run_seconds"] <= 51
+    # a full check with 24 cells has to fit the driver's 43200 s
+    assert (2 + 14 * 24) * (BENCH["run_seconds"] + 60) + 24 * 180 + 1200 \
+        <= 43200
+    for path in BENCH["paths"]:
+        assert os.path.isdir(os.path.join(REPO, path))
+    assert all(os.path.exists(os.path.join(REPO, w)) for w in
+               BENCH["command"] if "/" in w)
+    names = [m["name"] for m in METRICS]
+    assert len(set(names)) == len(names) and len(set(CELLS)) == len(CELLS)
+    for m in METRICS:
+        assert NAME.match(m["name"]) and UNIT.match(m["unit"])
+        assert m["better"] in ("lower", "higher")
+        assert m["source"] in SOURCES
+    for m in BENCH["end_to_end"]:
+        assert set(m) <= {"name", "unit", "better", "bound", "source",
+                          "workloads"}
+        assert m["source"] in ("host_clock", "device_trace")
+        assert 0.01 <= m["bound"] <= 0.1
+    assert "setup_s" in names
+    for m in BENCH["per_layer"]:
+        assert set(m) <= {"name", "unit", "better", "source", "layer",
+                          "moves", "workloads"}
+    pairs = [(w["config"], w["traffic"]) for w in BENCH["workloads"]]
+    assert len(set(pairs)) == len(pairs)
+    four = [w for w in BENCH["workloads"] if w["chips"] == 4]
+    assert all(w["chips"] in (1, 4) for w in BENCH["workloads"])
+    assert len(four) <= max(1, len(CELLS) // 4)
+    for w in BENCH["workloads"]:
+        assert NAME.match(w["name"]) and NAME.match(w["traffic"])
+        assert 1 <= len(w["why"]) <= 200 and "\n" not in w["why"]
+    used = {w["config"] for w in BENCH["workloads"]}
+    assert used == {c["name"] for c in BENCH["configs"]}
+    for c in BENCH["configs"]:
+        assert c["file"].startswith(tuple(p + "/" for p in BENCH["paths"]))
+        assert 1 <= len(c["source"]) <= 200 and 1 <= len(c["why"]) <= 200
+        assert c["reduced"] == []      # nothing cut: published sizes
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_every_file_a_cell_names_loads_and_agrees(cell):
+    loaded = spec.load_cell(REPO, cell)
+    assert loaded.traffic["ranks"] == loaded.chips
+    assert loaded.loss_n >= 10
+    builder = spec.load_plugin(REPO, "builders", loaded.builder)
+    argv = builder.argv_of(loaded, 2 ** 31 + 7)
+    assert "--seed" in argv and all(isinstance(a, str) for a in argv)
+    reported = {m["name"] for m in loaded.end_to_end}
+    assert {"setup_s", "step_ms"} <= reported and loaded.per_layer
+    for m in loaded.per_layer:
+        # a layer metric is reported only where the metric it moves is
+        assert m["moves"] in reported, m["name"]
+        assert callable(spec.load_reader(REPO, m))
+
+
+def test_every_name_refers_to_something_that_exists():
+    end_to_end = {m["name"] for m in BENCH["end_to_end"]}
+    for m in METRICS:
+        assert set(m.get("workloads", [])) <= set(CELLS), m["name"]
+    for m in BENCH["per_layer"]:
+        assert m["moves"] in end_to_end
+        assert os.path.isfile(spec.data_path(REPO, "layer_metrics",
+                                             m["name"]))
+    # no file without an entry: a metric or cell file nobody names
+    for kind, names in (("layer_metrics", {m["name"]
+                                           for m in BENCH["per_layer"]}),
+                        ("workloads", set(CELLS)),
+                        ("configs", {c["name"] for c in BENCH["configs"]}),
+                        ("traffic", {w["traffic"]
+                                     for w in BENCH["workloads"]})):
+        on_disk = {f[:-5] for f in os.listdir(
+            os.path.join(REPO, "benchmark", kind)) if f.endswith(".json")}
+        assert on_disk == names, kind
+    with pytest.raises(KeyError, match="no workload named"):
+        spec.load_cell(REPO, "no_such_cell")
+
+
+def test_a_later_pr_adds_files_and_entries_and_edits_none(tmp_path):
+    root = make_toy_root(str(tmp_path / "bench"))
+    toy = spec.load_cell(root, TOY_CELL)
+    assert toy.config["model"] == "tiny_cnn" and toy.chips == 2
+    added = [m for m in toy.per_layer if m["name"] == "toy_steps"]
+    assert added and added[0]["params"] == {"scale": 1e3}
+    assert spec.load_reader(root, added[0]).__name__ == "steps_per_s"
+    # the accepted files are byte for byte what they were
+    for kind in ("configs", "workloads", "traffic", "layer_metrics",
+                 "readers", "builders"):
+        for f in os.listdir(os.path.join(REPO, "benchmark", kind)):
+            if f.startswith("__"):
+                continue
+            with open(os.path.join(REPO, "benchmark", kind, f), "rb") as a, \
+                    open(os.path.join(root, "benchmark", kind, f),
+                         "rb") as b:
+                assert a.read() == b.read(), f
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        assert TOY_CELL not in f.read()
+    with pytest.raises(FileNotFoundError):
+        spec.load_plugin(root, "readers", "no_such_reader")
