@@ -45,6 +45,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..parallel.ring_attention import blockwise_attention
+from ..telemetry import names
 
 __all__ = ["flash_attention", "flash_attention_forward",
            "flash_attention_backward"]
@@ -213,6 +214,7 @@ def flash_attention_forward(q, k, v, causal: bool = False,
         ],
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
+        name=names.KERNEL_FLASH_FWD,
     )(qf, kf, vf)
     if return_lse:
         out, lse = results
@@ -364,6 +366,7 @@ def flash_attention_backward(q, k, v, out, lse, do, causal: bool = False,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
+        name=names.KERNEL_FLASH_DQ,
     )(qf, kf, vf, dof, lsef, delta)
 
     def q_map(bh, kj, qi):
@@ -398,6 +401,7 @@ def flash_attention_backward(q, k, v, out, lse, do, causal: bool = False,
         ],
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
+        name=names.KERNEL_FLASH_DKV,
     )(kf, vf, qf, dof, lsef, delta)
     return (dq.reshape(b, h, t, d), dk.reshape(b, h, t, d),
             dv.reshape(b, h, t, d))

@@ -87,6 +87,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..telemetry import names
+
 __all__ = ["KernelBackendError", "KernelLane", "GOSSIP_KERNELS",
            "DEFAULT_CHUNK_ELEMS", "COLLECTIVE_ID_SLOTS",
            "TransportHandle", "empty_transport_handle",
@@ -475,6 +477,7 @@ def _edge_start_call(interpret: bool, collective_id: int, ne: int,
         compiler_params=pltpu.CompilerParams(
             collective_id=collective_id),
         interpret=interpret,
+        name=names.KERNEL_GOSSIP_START,
     )(tbl, *parts_chunks)
 
 
@@ -632,6 +635,7 @@ def _edge_wait_call(kind: str, interpret: bool, acc_chunks, recv, ne: int):
                          memory_space=pltpu.VMEM) for r in recv],
         out_specs=acc_spec,
         interpret=interpret,
+        name=names.KERNEL_GOSSIP_WAIT,
     )(acc_chunks, *recv)
 
 

@@ -74,6 +74,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from ..telemetry import names
 from ..topology.hierarchical import HierarchicalSchedule
 from ..topology.schedule import GossipSchedule
 from ..topology.synthesized import SynthesizedSchedule
@@ -463,20 +464,25 @@ def _round_fn(schedule: GossipSchedule, phase_idx: int, axis_name: str,
                     # so a dropped+corrupted message is 0, never 0·NaN
                     msg = jnp.where(keep > 0, msg, jnp.zeros_like(msg))
                 if send_codec is not None and msg.size > 1:
-                    parts = send_codec.encode(msg)
+                    # the codec's own work is named apart from the round
+                    # it rides in (telemetry/names.py: sgp.gossip.wire)
+                    with jax.named_scope(names.SCOPE_WIRE):
+                        parts = send_codec.encode(msg)
                     if j in sent:
                         sent[j].append(parts)
                     else:
-                        acc[j] = acc[j] + send_codec.decode(
-                            tuple(lax.ppermute(p, axis_name, pairs)
-                                  for p in parts), msg)
+                        landed = tuple(lax.ppermute(p, axis_name, pairs)
+                                       for p in parts)
+                        with jax.named_scope(names.SCOPE_WIRE):
+                            acc[j] = acc[j] + send_codec.decode(landed, msg)
                     if res_in is not None:
                         # quantization error of what was attempted on the
                         # wire (zero for a dropped edge: Q(0) == 0) —
                         # computed from the SAME encoded parts both
                         # transport lanes ship, so the residual
                         # telescopes against the union of bucketed sends
-                        q_err = msg - send_codec.decode(parts, msg)
+                        with jax.named_scope(names.SCOPE_WIRE):
+                            q_err = msg - send_codec.decode(parts, msg)
                         if inject:
                             # carry rule: when this rank did not put its
                             # residual on the wire (w₀ == 0 or the edge

@@ -74,17 +74,59 @@ DEFAULT_PS_WEIGHT_FLOOR = 1e-2
 DEFAULT_MASS_TOL = 1e-3
 
 
-def _probe_leaf(params):
-    """Deterministic probe: the largest parameter leaf (ties broken by
-    tree order), raveled.  Large leaves dominate consensus error and a
-    fixed choice keeps the signal comparable across steps."""
+def _strided_sample(leaf, slots: int):
+    """About ``slots`` elements spread over the whole of ``leaf``, as one
+    strided slice in the leaf's own shape.  No reshape of the leaf itself:
+    flattening a tiled array is a payload-sized copy on the chip, while a
+    strided slice reads only what it keeps."""
+    from jax import lax
+
+    if leaf.size <= slots:
+        return leaf.reshape(-1)
+    counts = [1] * leaf.ndim
+    left = slots
+    # smallest dimensions first, each taking an equal share of what is left
+    order = sorted(range(leaf.ndim), key=lambda d: leaf.shape[d])
+    for n, d in enumerate(order):
+        share = int(left ** (1.0 / (leaf.ndim - n)) + 1e-9)   # floor of root
+        counts[d] = max(1, min(leaf.shape[d], share))
+        left = max(1, left // counts[d])
+    strides = [size // c for size, c in zip(leaf.shape, counts)]
+    limits = [(c - 1) * st + 1 for c, st in zip(counts, strides)]
+    return lax.slice(leaf, (0,) * leaf.ndim, limits, strides).reshape(-1)
+
+
+def _probe(params, slots: int):
+    """Deterministic probe of the parameters: strided slots from every
+    leaf, ``slots`` of them shared out in proportion to the leaves' sizes
+    and at least one a leaf.  Returns the concatenated sample, each slot's
+    weight (how many elements of its leaf it stands for) and the number of
+    parameters, so that the weighted mean square over the sample estimates
+    the mean square over all of them.
+
+    The head of the single largest leaf, which this replaces, read 0.0 on
+    ResNet-50 while the replicas differed by 3e-3 (PR 21).  That leaf is a
+    3x3 kernel of the last stage, inside a residual branch whose closing
+    BatchNorm scale starts at zero: early in training its gradient, and
+    with it the replicas' disagreement there, is at float32's resolution
+    (1.8e-9 RMS after eight steps on four CPU ranks, against 1.5e-5 on
+    the shortcut kernel beside it and 2.4e-5 over all parameters; PR 26),
+    and the monitor rounds to eight places."""
     import jax
+    import jax.numpy as jnp
+    import numpy as np
 
     leaves = jax.tree.leaves(params)
     if not leaves:
         raise ValueError("health_signals needs at least one param leaf")
-    best = max(range(len(leaves)), key=lambda i: leaves[i].size)
-    return leaves[best].reshape(-1)
+    total = sum(leaf.size for leaf in leaves)
+    samples = [_strided_sample(
+        leaf, max(1, round(slots * leaf.size / total))).astype(jnp.float32)
+        for leaf in leaves]
+    weights = np.concatenate([
+        np.full(s.size, leaf.size / s.size, np.float32)
+        for leaf, s in zip(leaves, samples)])
+    return jnp.concatenate(samples), weights, total
 
 
 def health_signals(params, grads, ps_weight, axis_name: str,
@@ -103,8 +145,10 @@ def health_signals(params, grads, ps_weight, axis_name: str,
     holds.  Pass it whenever the algorithm runs overlap; ``None``/empty
     is the sync no-op.
 
-    Cost: two scalar psums, one pmin/pmax pair, one ``probe_slots``-wide
-    pmean+psum, and one elementwise isfinite sweep — noise next to a
+    Cost: two scalar psums, one pmin/pmax pair, one pmean+psum about
+    ``probe_slots`` wide (plus a slot for every small leaf) over strided
+    slices of the leaves (no copy of a leaf), and one elementwise
+    isfinite sweep — noise next to a
     forward/backward (plus ``staleness`` per-leaf adds under overlap).
     """
     import jax
@@ -128,13 +172,12 @@ def health_signals(params, grads, ps_weight, axis_name: str,
                 ~jnp.isfinite(leaf.astype(jnp.float32))).astype(jnp.float32)
         return lax.psum(total, axis_name)
 
-    probe = _probe_leaf(params)
-    slots = min(probe_slots, probe.size)
-    probe = probe[:slots].astype(jnp.float32) / w   # de-biased view
+    probe, slot_weight, probed = _probe(params, probe_slots)
+    probe = probe / w   # de-biased view
     center = lax.pmean(probe, axis_name)
     residual = jnp.sqrt(
-        lax.psum(jnp.sum((probe - center) ** 2), axis_name)
-        / (world * slots))
+        lax.psum(jnp.sum(slot_weight * (probe - center) ** 2), axis_name)
+        / (world * probed))
 
     out = {
         "consensus_residual": residual,

@@ -810,6 +810,15 @@ def main(argv=None):
         return {"final_loss": None, "avg_loss": None,
                 "tokens_per_sec": 0.0, "already_complete": True}
 
+    # step-indexed jax.profiler capture (shared with the image harness;
+    # utils/profiling.py: a profiler start that hangs or fails is logged
+    # as an error and the run continues untraced)
+    from ..utils.profiling import ProfileWindow
+
+    pw = ProfileWindow(args.profile_dir,
+                       start_step=args.profile_start_step,
+                       num_steps=args.profile_steps)
+
     def save_ckpt(st, step):
         """Checkpoint ``st`` (draining overlap in-flight shares into
         params first — algorithms.drain_state, the shared fold — so
@@ -827,7 +836,8 @@ def main(argv=None):
             # the run's consensus health at save time rides with the
             # state it describes (resilience/monitor.py)
             meta["health"] = monitor.last_payload
-        with rt.span("checkpoint_save", "checkpoint"):
+        with rt.span("checkpoint_save", "checkpoint"), \
+                pw.span("checkpoint_save"):
             if use_orbax:
                 # orbax steps are keyed by id: pass the step explicitly
                 # (the live sharded state on pods, host conversion
@@ -1003,7 +1013,7 @@ def main(argv=None):
         nonlocal val_time
         t_val = time.time()
         vals = []
-        with rt.span("validate", "eval"):
+        with rt.span("validate", "eval"), pw.span("validate"):
             for vt, vy in lm_batches(val_corpus, dp * ep, sp,
                                      args.batch_size, args.seq_len,
                                      seed=1):
@@ -1020,37 +1030,47 @@ def main(argv=None):
 
     last_val = None
     last_stats_emit = start_step
-    # step-indexed jax.profiler capture (shared with the image harness;
-    # utils/profiling.py: a profiler start that hangs or fails is logged
-    # as an error and the run continues untraced)
-    from ..utils.profiling import ProfileWindow
 
-    pw = ProfileWindow(args.profile_dir,
-                       start_step=args.profile_start_step,
-                       num_steps=args.profile_steps)
+    def fetched(batches):
+        """``batches`` with each draw marked as the loop's data fetch."""
+        it = iter(batches)
+        while True:
+            with pw.span("data_fetch"):
+                try:
+                    batch = next(it)
+                except StopIteration:
+                    return
+            yield batch
+
     try:
         while steps_done < args.num_steps:
-            for tokens, targets in lm_batches(corpus, dp * ep, sp,
-                                              args.batch_size, args.seq_len,
-                                              seed=args.seed + epoch):
+            for tokens, targets in fetched(lm_batches(
+                    corpus, dp * ep, sp, args.batch_size, args.seq_len,
+                    seed=args.seed + epoch)):
                 if skip_batches:
                     skip_batches -= 1
                     continue
                 if pw.enabled:
                     pw.maybe_start(steps_done + 1)
-                state, metrics = train_fn(state, globalize(shape_batch(tokens)),
-                                          globalize(shape_batch(targets)))
-                if serialize:
-                    jax.block_until_ready(state)
+                # the loop's phases by name in a --profile_dir capture
+                # (telemetry/names.py); shared no-ops when none is active
+                with pw.step(steps_done + 1):
+                    with pw.span("data_fetch"):
+                        x = globalize(shape_batch(tokens))
+                        y = globalize(shape_batch(targets))
+                    with pw.span("dispatch"):
+                        state, metrics = train_fn(state, x, y)
+                    if serialize or pw.active:
+                        # the capture must cover the dispatched step even
+                        # when the loop itself runs unserialized
+                        with pw.span("fence"):
+                            jax.block_until_ready(state)
                 steps_done += 1
                 if rt.comm is not None:
                     # step tick is 0-based (matches the algorithm's phase
                     # counter); host integer math, dispatch stays async
                     rt.comm.on_step(steps_done - 1)
                 if pw.active:
-                    # the capture must cover the dispatched step even when
-                    # the loop itself runs unserialized
-                    jax.block_until_ready(state)
                     pw.maybe_stop(steps_done)
                 if steps_done % args.print_freq == 0                     or steps_done >= args.num_steps:
                     guard = (watchdog.step()
@@ -1058,7 +1078,8 @@ def main(argv=None):
                              else contextlib.nullcontext())
                     with guard, rt.span("metrics_fetch", "step",
                                         {"step": steps_done}
-                                        if rt.enabled else None):
+                                        if rt.enabled else None), \
+                            pw.span("metrics_fetch"):
                         mh = host_metrics(metrics)
                     prints_done += 1
                     if monitor is not None:
@@ -1087,7 +1108,8 @@ def main(argv=None):
                             event = policy.assess(report)
                             if event.action == "global-average":
                                 with rt.span("recovery_global_average",
-                                             "recovery"):
+                                             "recovery"), \
+                                        pw.span("recovery_global_average"):
                                     if getattr(alg, "overlap", False):
                                         new_p, new_w, new_fl = recovery(
                                             state.params,

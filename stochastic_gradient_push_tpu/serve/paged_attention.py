@@ -46,6 +46,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.flash_attention import NEG_INF, _compiler_params, _sds
 from ..ops.gossip_kernel import resolve_use_pallas
+from ..telemetry import names
 
 __all__ = ["MODEL_AXIS", "paged_attention_decode",
            "paged_attention_reference", "sharded_paged_decode"]
@@ -195,6 +196,7 @@ def _paged_decode_pallas(q, k_pages, v_pages, page_indices, lengths,
         out_shape=_sds((b, hkv, group, d), q.dtype, qg),
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
+        name=names.KERNEL_PAGED_ATTENTION,
     )(page_indices.astype(jnp.int32), lengths.astype(jnp.int32),
       qg, k_pages, v_pages)
     return out.reshape(b, h, d)

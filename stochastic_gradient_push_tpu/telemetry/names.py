@@ -1,0 +1,50 @@
+"""The one vocabulary of trace names: what the compiled step, the Pallas
+kernels and the host loops call themselves inside a ``jax.profiler``
+capture.  Defined once so that the three places that use a name — the
+program that writes it, the benchmark reader that sums it
+(``benchmark/readers/program_trace.py``) and PERF.md's table — cannot
+drift apart.  Nothing here imports anything: ``ops/`` and ``utils/`` read
+it without pulling the telemetry package's own dependencies.
+
+One clock: every name below lands in the profiler's XPlane — scopes and
+kernel names on the device planes (as the operations' ``op_name``), host
+spans and the step marker on the host plane — so an idle gap of the
+device can be put down to the host span it fell in.  ``SpanTracer``'s
+``trace.json`` (tracer.py) stays a separate, host-only operator's view.
+"""
+
+# -- device side: jax.named_scope around the phases of the train step ----
+SCOPE_PRE_STEP = "sgp.pre_step"          # overlap launch, de-bias
+SCOPE_FORWARD = "sgp.forward"            # model apply + loss; autodiff
+#                                          marks its transpose (backward)
+SCOPE_REDUCE_GRADS = "sgp.reduce_grads"  # exact gradient averaging
+SCOPE_OPTIMIZER = "sgp.optimizer"        # LR schedule, tx.update, update
+SCOPE_GOSSIP = "sgp.gossip"              # post_step: the push-sum round
+SCOPE_WIRE = "sgp.gossip.wire"           # nested: the wire codec
+SCOPE_HEALTH = "sgp.health"              # grad norm + health signals
+STEP_SCOPES = (SCOPE_PRE_STEP, SCOPE_FORWARD, SCOPE_REDUCE_GRADS,
+               SCOPE_OPTIMIZER, SCOPE_GOSSIP, SCOPE_HEALTH)
+
+# -- the jitted steps' names: the compiled module is ``jit_<name>`` on the
+# trace's "XLA Modules" line, which tells the step from set-up's programs
+MODULE_TRAIN_STEP = "sgp_train_step"
+MODULE_TRAIN_STEP_SCAN = "sgp_train_step_scan"
+MODULE_LM_TRAIN_STEP = "sgp_lm_train_step"
+MODULE_LM_TRAIN_STEP_SCAN = "sgp_lm_train_step_scan"
+
+# -- pallas_call names ---------------------------------------------------
+KERNEL_FLASH_FWD = "flash_fwd"
+KERNEL_FLASH_DQ = "flash_dq"
+KERNEL_FLASH_DKV = "flash_dkv"
+KERNEL_GOSSIP_START = "gossip_edge_start"
+KERNEL_GOSSIP_WAIT = "gossip_edge_wait"
+KERNEL_PAGED_ATTENTION = "paged_attention"
+
+# -- host side: ProfileWindow.span / .step (utils/profiling.py) ----------
+HOST_SPAN_PREFIX = "sgp:"
+HOST_STEP = "sgp_step"
+# the loops' spans, under the names SpanTracer already gives them
+HOST_SPANS = ("data_fetch", "dispatch", "fence", "metrics_fetch", "health",
+              "async_bilat", "checkpoint_save", "validate",
+              "recovery_global_average")
+

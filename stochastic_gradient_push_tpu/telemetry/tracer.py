@@ -1,14 +1,19 @@
 """Host-side span tracer exporting Chrome-trace / Perfetto JSON.
 
-The reference's only timeline view was TensorBoard XPlane dumps from
-``jax.profiler`` (utils/profiling.py), which capture the *device* but
-say nothing about the host loop — where
-stragglers, data stalls, checkpoint I/O and recovery averages actually
-live.  This tracer is the complementary instrument: pure-host wall-clock
-spans around the loop's phases (data fetch, compiled step, gossip round,
-scheduled/reactive global averages, checkpoint I/O, validation), written
-as a standard ``trace.json`` that chrome://tracing and ui.perfetto.dev
-load directly, keyed by rank (pid) and phase (tid).
+Two views of one run, each for its own reader.  A ``jax.profiler``
+capture (``--profile_dir``, utils/profiling.py) is the one trace on one
+clock: the device planes with the step's phases and kernels by name, and
+on the host plane the loops' ``sgp:`` spans and ``sgp_step`` markers
+(telemetry/names.py) — a few steps long, read with the profiler's tools
+or ``benchmark/trace_reduce.py``.  This tracer is the operator's
+whole-run view: pure-host wall-clock spans around the loop's phases (data
+fetch, compiled step, gossip round, scheduled/reactive global averages,
+checkpoint I/O, validation) for EVERY step of the run, written as a
+standard ``trace.json`` that chrome://tracing and ui.perfetto.dev load
+directly, keyed by rank (pid) and phase (tid), merged across hosts by
+``tracemerge`` and summarized by ``scripts/obsreport.py``.  It runs on
+``time.time`` and cannot be overlaid on a device trace; where a device
+idle gap is to be explained, take a ``--profile_dir`` capture.
 
 Two invariants the train loop relies on:
 

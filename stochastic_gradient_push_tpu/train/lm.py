@@ -31,7 +31,9 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..algorithms.api import GossipAlgorithm
 from ..parallel.collectives import as_scalar
 from ..parallel.mesh import GOSSIP_AXIS
+from ..telemetry import names
 from .state import TrainState
+from .step import restack
 
 SEQ_AXIS = "seq"
 TP_AXIS = "tp"
@@ -315,23 +317,27 @@ def build_lm_train_step(model, algorithm: GossipAlgorithm, tx, lr_schedule,
     if grad_accum < 1:
         raise ValueError("grad_accum must be >= 1")
 
+    # phases named as in the image step (train/step.py says how and why)
+
     def train_step(state: TrainState, tokens, targets):
-        params, gstate = algorithm.pre_step(state.params, state.gossip)
-        z = algorithm.eval_params(params, gstate)
+        with jax.named_scope(names.SCOPE_PRE_STEP):
+            params, gstate = algorithm.pre_step(state.params, state.gossip)
+            z = algorithm.eval_params(params, gstate)
 
         def loss_fn(p, toks, tgts):
-            logits, mutated = model.apply(
-                {"params": p}, toks, train=True,
-                mutable=["losses", "moe_metrics"])
-            ce = lm_loss(logits, tgts)
-            loss = ce
-            sown = jax.tree.leaves(mutated.get("losses", {}))
-            if sown:
-                loss = loss + moe_loss_coef * sum(
-                    jnp.mean(l) for l in sown) / len(sown)
-            dropped = jax.tree.leaves(mutated.get("moe_metrics", {}))
-            dropped = (sum(jnp.mean(d) for d in dropped) / len(dropped)
-                       if dropped else jnp.float32(0.0))
+            with jax.named_scope(names.SCOPE_FORWARD):
+                logits, mutated = model.apply(
+                    {"params": p}, toks, train=True,
+                    mutable=["losses", "moe_metrics"])
+                ce = lm_loss(logits, tgts)
+                loss = ce
+                sown = jax.tree.leaves(mutated.get("losses", {}))
+                if sown:
+                    loss = loss + moe_loss_coef * sum(
+                        jnp.mean(l) for l in sown) / len(sown)
+                dropped = jax.tree.leaves(mutated.get("moe_metrics", {}))
+                dropped = (sum(jnp.mean(d) for d in dropped) / len(dropped)
+                           if dropped else jnp.float32(0.0))
             return loss, (ce, dropped)
 
         if grad_accum == 1:
@@ -351,8 +357,9 @@ def build_lm_train_step(model, algorithm: GossipAlgorithm, tx, lr_schedule,
                 toks, tgts = xy
                 (l, (c, d)), g = jax.value_and_grad(
                     loss_fn, has_aux=True)(z, toks, tgts)
-                return (jax.tree.map(jnp.add, g_sum, g), loss_sum + l,
-                        ce_sum + c, drop_sum + d), None
+                with jax.named_scope(names.SCOPE_REDUCE_GRADS):
+                    return (jax.tree.map(jnp.add, g_sum, g), loss_sum + l,
+                            ce_sum + c, drop_sum + d), None
 
             zero_g = jax.tree.map(jnp.zeros_like, z)
             # scalar accumulators derive from the (device-varying) tokens
@@ -360,73 +367,79 @@ def build_lm_train_step(model, algorithm: GossipAlgorithm, tx, lr_schedule,
             zero_s = jnp.sum(tokens * 0.0).astype(jnp.float32)
             (g_sum, loss, ce, dropped), _ = lax.scan(
                 accum, (zero_g, zero_s, zero_s, zero_s), (xs, ys))
-            grads = jax.tree.map(lambda g: g / grad_accum, g_sum)
-            loss = loss / grad_accum
-            ce = ce / grad_accum
-            dropped = dropped / grad_accum
+            with jax.named_scope(names.SCOPE_REDUCE_GRADS):
+                grads = jax.tree.map(lambda g: g / grad_accum, g_sum)
+                loss = loss / grad_accum
+                ce = ce / grad_accum
+                dropped = dropped / grad_accum
 
-        if seq_axis is not None:
-            # params are invariant over seq → autodiff psums grads over the
-            # seq shards; divide to get the global token mean
-            n_seq = lax.axis_size(seq_axis)
-            grads = jax.tree.map(lambda g: g / n_seq, grads)
-            loss = lax.pmean(loss, seq_axis)
-            ce = lax.pmean(ce, seq_axis)
-            dropped = lax.pmean(dropped, seq_axis)
-        if ep_axis is not None:
-            # the objective is the MEAN over ep shards of per-shard loss.
-            # Replicated params are ep-invariant → autodiff psums their
-            # grads across shards; expert slices live on one shard each,
-            # but the all_to_all transpose accumulates every shard's
-            # cotangents into them just the same (each expert processes
-            # slots from ALL shards).  Both arrive as the SUM over shards
-            # → divide everything by n_ep for the mean.  (Exempting
-            # expert slices would train them with an effective n_ep× lr;
-            # pinned by test_expert_parallel_lm.py::
-            # test_ep_train_step_matches_full_expert_model.)
-            n_ep = lax.axis_size(ep_axis)
-            grads = jax.tree.map(lambda g: g / n_ep, grads)
-            loss = lax.pmean(loss, ep_axis)
-            ce = lax.pmean(ce, ep_axis)
-            dropped = lax.pmean(dropped, ep_axis)
-        grads = algorithm.reduce_grads(grads)
+        with jax.named_scope(names.SCOPE_REDUCE_GRADS):
+            if seq_axis is not None:
+                # params are invariant over seq → autodiff psums grads over
+                # the seq shards; divide to get the global token mean
+                n_seq = lax.axis_size(seq_axis)
+                grads = jax.tree.map(lambda g: g / n_seq, grads)
+                loss = lax.pmean(loss, seq_axis)
+                ce = lax.pmean(ce, seq_axis)
+                dropped = lax.pmean(dropped, seq_axis)
+            if ep_axis is not None:
+                # the objective is the MEAN over ep shards of per-shard
+                # loss.  Replicated params are ep-invariant → autodiff psums
+                # their grads across shards; expert slices live on one shard
+                # each, but the all_to_all transpose accumulates every
+                # shard's cotangents into them just the same (each expert
+                # processes slots from ALL shards).  Both arrive as the SUM
+                # over shards → divide everything by n_ep for the mean.
+                # (Exempting expert slices would train them with an
+                # effective n_ep× lr; pinned by test_expert_parallel_lm.py::
+                # test_ep_train_step_matches_full_expert_model.)
+                n_ep = lax.axis_size(ep_axis)
+                grads = jax.tree.map(lambda g: g / n_ep, grads)
+                loss = lax.pmean(loss, ep_axis)
+                ce = lax.pmean(ce, ep_axis)
+                dropped = lax.pmean(dropped, ep_axis)
+            grads = algorithm.reduce_grads(grads)
 
-        step = as_scalar(state.step)
-        lr = lr_schedule(step // itr_per_epoch, step % itr_per_epoch,
-                         itr_per_epoch)
-        updates, opt_state = tx.update(grads, state.opt_state, params)
-        params = jax.tree.map(
-            lambda p, u: p - lr.astype(p.dtype) * u, params, updates)
-        params, gstate = algorithm.post_step(params, gstate)
+        with jax.named_scope(names.SCOPE_OPTIMIZER):
+            step = as_scalar(state.step)
+            lr = lr_schedule(step // itr_per_epoch, step % itr_per_epoch,
+                             itr_per_epoch)
+            updates, opt_state = tx.update(grads, state.opt_state, params)
+            params = jax.tree.map(
+                lambda p, u: p - lr.astype(p.dtype) * u, params, updates)
+            next_step = state.step + 1
+        with jax.named_scope(names.SCOPE_GOSSIP):
+            params, gstate = algorithm.post_step(params, gstate)
 
-        # perplexity from the bare cross-entropy, not the MoE-augmented
-        # objective; moe_dropped makes capacity overflow observable;
-        # grad_norm (utils/flatten.py) for divergence triage — averaged
-        # over seq/ep shards (each shard's expert-slice VALUES differ —
-        # different experts live there — so the raw norm varies over ep
-        # and would break the metrics' replication)
-        from ..utils.flatten import global_norm
-        gn = global_norm(grads)
-        for ax in (seq_axis, ep_axis):
-            if ax is not None:
-                gn = lax.pmean(gn, ax)
-        metrics = {"loss": loss, "ppl": jnp.exp(ce), "lr": lr,
-                   "moe_dropped": dropped, "grad_norm": gn}
-        if health_axis is not None:
-            # consensus health AFTER the gossip round (resilience/):
-            # each signal is a collective over the gossip axis and — on a
-            # dp×sp mesh — seq-invariant, since params and the seq-psummed
-            # grads are replicated over seq.  (ep shards hold different
-            # expert slices, so health composes with the flat dp/sp
-            # meshes only; the CLI enforces that.)
-            from ..resilience.monitor import health_signals
-            # the overlap FIFO rides along so the monitor observes the
-            # DRAINED view (in-flight mass is not a leak)
-            metrics.update(health_signals(
-                params, grads, gstate.ps_weight, health_axis,
-                ef_residual=gstate.ef_residual,
-                in_flight=gstate.in_flight))
-        return state.replace(step=state.step + 1, params=params,
+        with jax.named_scope(names.SCOPE_HEALTH):
+            # perplexity from the bare cross-entropy, not the MoE-augmented
+            # objective; moe_dropped makes capacity overflow observable;
+            # grad_norm (utils/flatten.py) for divergence triage — averaged
+            # over seq/ep shards (each shard's expert-slice VALUES differ —
+            # different experts live there — so the raw norm varies over ep
+            # and would break the metrics' replication)
+            from ..utils.flatten import global_norm
+            gn = global_norm(grads)
+            for ax in (seq_axis, ep_axis):
+                if ax is not None:
+                    gn = lax.pmean(gn, ax)
+            metrics = {"loss": loss, "ppl": jnp.exp(ce), "lr": lr,
+                       "moe_dropped": dropped, "grad_norm": gn}
+            if health_axis is not None:
+                # consensus health AFTER the gossip round (resilience/):
+                # each signal is a collective over the gossip axis and — on
+                # a dp×sp mesh — seq-invariant, since params and the
+                # seq-psummed grads are replicated over seq.  (ep shards
+                # hold different expert slices, so health composes with the
+                # flat dp/sp meshes only; the CLI enforces that.)
+                from ..resilience.monitor import health_signals
+                # the overlap FIFO rides along so the monitor observes the
+                # DRAINED view (in-flight mass is not a leak)
+                metrics.update(health_signals(
+                    params, grads, gstate.ps_weight, health_axis,
+                    ef_residual=gstate.ef_residual,
+                    in_flight=gstate.in_flight))
+        return state.replace(step=next_step, params=params,
                              opt_state=opt_state, gossip=gstate), metrics
 
     return train_step
@@ -451,9 +464,7 @@ def shard_lm_train_step(step_fn, mesh, gossip_axis: str = GOSSIP_AXIS,
         sq_state = jax.tree.map(lambda a: a[0], state)
         sq = lambda t: jax.tree.map(
             lambda a: a.reshape(a.shape[squeeze_n:]), t)
-        new_state, metrics = step_fn(sq_state, sq(tokens), sq(targets))
-        return (jax.tree.map(lambda a: a[None], new_state),
-                jax.tree.map(lambda a: a[None], metrics))
+        return restack(*step_fn(sq_state, sq(tokens), sq(targets)))
 
     kwargs = {}
     if tp:
@@ -466,6 +477,7 @@ def shard_lm_train_step(step_fn, mesh, gossip_axis: str = GOSSIP_AXIS,
         wrapped, mesh=mesh,
         in_specs=(state_spec, batch_spec, batch_spec),
         out_specs=(state_spec, P(gossip_axis)), **kwargs)
+    sharded.__name__ = names.MODULE_LM_TRAIN_STEP
     return jax.jit(sharded, donate_argnums=(0,))
 
 
@@ -544,16 +556,15 @@ def shard_scanned_lm_step(step_fn, mesh, n_steps: int,
             toks, tgts = batch
             return step_fn(st, toks, tgts)
 
-        new_state, metrics = lax.scan(
+        return restack(*lax.scan(
             body, jax.tree.map(lambda a: a[0], state),
-            (sq(tokens), sq(targets)))
-        return (jax.tree.map(lambda a: a[None], new_state),
-                jax.tree.map(lambda a: a[None], metrics))
+            (sq(tokens), sq(targets))))
 
     sharded = jax.shard_map(
         wrapped, mesh=mesh,
         in_specs=(P(gossip_axis), batch_spec, batch_spec),
         out_specs=(P(gossip_axis), P(gossip_axis)))
+    sharded.__name__ = names.MODULE_LM_TRAIN_STEP_SCAN
     return jax.jit(sharded, donate_argnums=(0,))
 
 

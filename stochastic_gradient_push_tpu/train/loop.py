@@ -813,7 +813,8 @@ class Trainer:
                                              "checkpoint",
                                              {"epoch": epoch}
                                              if self.telemetry.enabled
-                                             else None):
+                                             else None), \
+                            self.profile.span("checkpoint_save"):
                         self.cluster.save_checkpoint(
                             self._save_state(state), meta,
                             epoch_id=epoch_id, is_best=is_best,
@@ -943,7 +944,8 @@ class Trainer:
                               epoch * itr_per_epoch + itr)
         state = self._drain_in_flight(state)  # nothing in flight on disk
         meta = self._ckpt_meta(epoch, itr, best_prec1, begin_time, meters)
-        with self.telemetry.span("checkpoint_save", "checkpoint"):
+        with self.telemetry.span("checkpoint_save", "checkpoint"), \
+                self.profile.span("checkpoint_save"):
             self.cluster.save_checkpoint(self._save_state(state), meta,
                                          requeue_on_signal=True)
         # only reachable if the flag vanished between check and save
@@ -1037,11 +1039,12 @@ class Trainer:
                 # cap tail: single steps, never a remainder-sized program
                 target = 1
             pending = []
-            for _ in range(target):
-                try:
-                    pending.append(next(it))
-                except StopIteration:
-                    break
+            with self.profile.span("data_fetch"):
+                for _ in range(target):
+                    try:
+                        pending.append(next(it))
+                    except StopIteration:
+                        break
             if not pending:
                 break
             if 1 < len(pending) < target:
@@ -1054,17 +1057,19 @@ class Trainer:
 
             alg, train_fn = self._train_fn(
                 ppi, itr_per_epoch, chunk if chunk > 1 else 1)
-            if chunk > 1:
-                x = np.stack([b[0] for b in pending])
-                y = np.stack([b[1] for b in pending])
-            else:
-                x, y = pending[0]
-            if self.proc_count > 1:
-                # loader rows cover only this process's ranks; assemble
-                # the global array (per-process feeding on a pod)
-                spec = self._batch_spec(scanned=chunk > 1)
-                x = make_global_batch(self.mesh, spec, x)
-                y = make_global_batch(self.mesh, spec, y)
+            with self.profile.span("data_fetch"):
+                if chunk > 1:
+                    x = np.stack([b[0] for b in pending])
+                    y = np.stack([b[1] for b in pending])
+                else:
+                    x, y = pending[0]
+                if self.proc_count > 1:
+                    # loader rows cover only this process's ranks;
+                    # assemble the global array (per-process feeding on a
+                    # pod)
+                    spec = self._batch_spec(scanned=chunk > 1)
+                    x = make_global_batch(self.mesh, spec, x)
+                    y = make_global_batch(self.mesh, spec, y)
             elapsed_data = time.time() - batch_time  # includes host stacking
             nn_time = time.time()
             warm_key = (ppi, itr_per_epoch, chunk, np.shape(x))
@@ -1077,43 +1082,55 @@ class Trainer:
             guard = (self.watchdog.step()
                      if self.watchdog is not None and timed
                      else contextlib.nullcontext())
-            if self.profile.enabled:
+            # the loop's phases by name in a --profile_dir capture
+            # (telemetry/names.py); shared no-ops when none is active
+            prof = self.profile
+            gstep = epoch * itr_per_epoch + i + 1
+            if prof.enabled:
                 # capture window keyed on the GLOBAL step (resume-safe);
                 # a scanned chunk starts/stops around the whole program —
                 # the profiler cannot cut inside one compiled scan
-                self.profile.maybe_start(epoch * itr_per_epoch + i + 1)
-            with guard:
-                state, metrics = train_fn(state, x, y)
-                jax.block_until_ready(state)
-            if self.profile.enabled:
-                self.profile.maybe_stop(epoch * itr_per_epoch + i + chunk)
-            if self._async_bilat is not None:
-                # wall-clock-async AD-PSGD: expose the fresh params to the
-                # host averaging thread and adopt whatever (stale)
-                # displacement it has ready — the thread worked while the
-                # device computed this step
-                gstep = epoch * itr_per_epoch + i + chunk
-                self._async_bilat.publish(gstep, state.params)
-                new_params, adopted = self._async_bilat.maybe_adopt(
-                    gstep, state.params)
-                if adopted:
-                    state = state.replace(params=new_params)
-            if self.proc_count > 1:
-                # metrics come back sharded across hosts; all-gather the
-                # tiny per-rank vectors so every process logs full rows
-                metrics = to_host(metrics, self.mesh)
-            # metrics: [world] for a single step, [world, chunk] when
-            # scanned — normalize to [world, chunk]
-            to_arr = lambda m: np.asarray(m).reshape(
-                self.gossip_world, chunk)
-            slices = {
-                "n": pending[0][0].shape[0] * pending[0][0].shape[1],
-                "loss": to_arr(metrics["loss"]),
-                "top1": to_arr(metrics["top1"]),
-                "top5": to_arr(metrics["top5"]),
-                "grad_norm": (to_arr(metrics["grad_norm"])
-                              if "grad_norm" in metrics else None),
-            }
+                prof.maybe_start(gstep)
+            with prof.step(gstep):
+                with guard:
+                    with prof.span("dispatch"):
+                        state, metrics = train_fn(state, x, y)
+                    with prof.span("fence"):
+                        jax.block_until_ready(state)
+                if self._async_bilat is not None:
+                    # wall-clock-async AD-PSGD: expose the fresh params to
+                    # the host averaging thread and adopt whatever (stale)
+                    # displacement it has ready — the thread worked while
+                    # the device computed this step
+                    with prof.span("async_bilat"):
+                        last = gstep + chunk - 1
+                        self._async_bilat.publish(last, state.params)
+                        new_params, adopted = \
+                            self._async_bilat.maybe_adopt(last,
+                                                          state.params)
+                        if adopted:
+                            state = state.replace(params=new_params)
+                with prof.span("metrics_fetch"):
+                    if self.proc_count > 1:
+                        # metrics come back sharded across hosts;
+                        # all-gather the tiny per-rank vectors so every
+                        # process logs full rows
+                        metrics = to_host(metrics, self.mesh)
+                    # metrics: [world] for a single step, [world, chunk]
+                    # when scanned — normalize to [world, chunk]
+                    to_arr = lambda m: np.asarray(m).reshape(
+                        self.gossip_world, chunk)
+                    slices = {
+                        "n": (pending[0][0].shape[0]
+                              * pending[0][0].shape[1]),
+                        "loss": to_arr(metrics["loss"]),
+                        "top1": to_arr(metrics["top1"]),
+                        "top5": to_arr(metrics["top5"]),
+                        "grad_norm": (to_arr(metrics["grad_norm"])
+                                      if "grad_norm" in metrics else None),
+                    }
+            if prof.enabled:
+                prof.maybe_stop(gstep + chunk - 1)
             elapsed_nn = time.time() - nn_time
             elapsed_batch = time.time() - batch_time
             record(i + 1, slices, chunk, elapsed_nn, elapsed_batch,
@@ -1123,25 +1140,24 @@ class Trainer:
                 # spans reuse the loop's OWN timestamps (no extra clock
                 # reads or syncs in the hot path); comm accounting is
                 # host integer math against the analytic model
-                gstep0 = epoch * itr_per_epoch + i + 1
                 tel.trace_complete("data_fetch", "data", batch_time,
                                    elapsed_data)
                 span_args = {"steps": chunk, "timed": timed}
                 if tel.comm is not None:
                     m = tel.comm.model
                     span_args["gossip"] = sum(
-                        m.gossip_fires(gstep0 + j) for j in range(chunk))
+                        m.gossip_fires(gstep + j) for j in range(chunk))
                     span_args["global_avg"] = sum(
-                        m.global_avg_fires(gstep0 + j)
+                        m.global_avg_fires(gstep + j)
                         for j in range(chunk))
                     for j in range(chunk):
-                        tel.comm.on_step(gstep0 + j)
+                        tel.comm.on_step(gstep + j)
                 tel.trace_complete("train_step", "step", nn_time,
                                    elapsed_nn, span_args)
                 ke = tel.metrics_every
-                if ke and any((gstep0 + j) % ke == 0
+                if ke and any((gstep + j) % ke == 0
                               for j in range(chunk)):
-                    last = gstep0 + chunk - 1
+                    last = gstep + chunk - 1
                     tel.registry.emit("step_stats", {
                         "epoch": epoch,
                         "loss": round(float(slices["loss"].mean()), 6),
@@ -1155,9 +1171,9 @@ class Trainer:
                     # per-iteration samples feed the p50/p99 straggler view
                     for _ in range(chunk):
                         self.monitor.record_step_time(elapsed_batch / chunk)
-                state = self._observe_health(
-                    state, alg, metrics,
-                    epoch * itr_per_epoch + i + 1, chunk)
+                with prof.span("health"):
+                    state = self._observe_health(state, alg, metrics,
+                                                 gstep, chunk)
             i += chunk
             if self.cluster is not None \
                     and self.cluster.any_rank_signalled():
@@ -1187,7 +1203,7 @@ class Trainer:
                 make_recovery_fn(alg, self.mesh, self.gossip_axis), alg)
         return self._recovery_cache[key][0]
 
-    def _observe_health(self, state, alg, metrics, gstep0, chunk):
+    def _observe_health(self, state, alg, metrics, gstep, chunk):
         """Digest one chunk's health signals; fire recovery when the
         policy says so.  Scanned chunks are observed per inner iteration
         but recovered AFTER the chunk (a compiled scan cannot be
@@ -1205,13 +1221,14 @@ class Trainer:
             # each signal is a collective over the gossip axis — every
             # rank carries the same value; read shard 0
             sig = {k: float(arrs[k][0, j]) for k in keys}
-            report = self.monitor.observe(gstep0 + j, sig)
+            report = self.monitor.observe(gstep + j, sig)
             if report.unhealthy and self.recovery_policy is not None:
                 event = self.recovery_policy.assess(report)
                 if event.action == "global-average" \
                         and hasattr(alg, "global_average"):
                     with self.telemetry.span("recovery_global_average",
-                                             "recovery"):
+                                             "recovery"), \
+                            self.profile.span("recovery_global_average"):
                         if getattr(alg, "overlap", False):
                             # fold + drain the in-flight FIFO: pending
                             # shares are counted exactly once in Σx/Σw
@@ -1247,7 +1264,8 @@ class Trainer:
         top5 = Meter(ptag="Prec@5")
         rank_top1 = np.zeros(self.gossip_world)
         n_batches, n_samples = 0, 0
-        with self.telemetry.span("validate", "eval"):
+        with self.telemetry.span("validate", "eval"), \
+                self.profile.span("validate"):
             for x, y in val_loader:
                 if self.proc_count > 1:
                     spec = self._batch_spec(scanned=False)

@@ -35,12 +35,32 @@ from jax.sharding import PartitionSpec as P
 from ..algorithms.api import GossipAlgorithm
 from ..parallel.collectives import as_scalar
 from ..parallel.mesh import GOSSIP_AXIS
+from ..telemetry import names
 from .metrics import accuracy_topk, kl_div_loss, one_hot
 from .state import TrainState
 
 __all__ = ["build_train_step", "build_eval_step", "shard_train_step",
-           "shard_scanned_train_step", "shard_eval_step",
+           "shard_scanned_train_step", "shard_eval_step", "restack",
            "replicate_state", "unreplicate", "replica_spread"]
+
+
+def restack(new_state: TrainState, metrics):
+    """Put the shard's leading dimension back on a step's outputs, each
+    part under the scope that produced it.  The expand is free, but it is
+    the last operation on its array: where the compiler fuses it with the
+    update that feeds it, the whole fusion carries the expand's name, and
+    an unnamed expand would file the optimizer's fusions under no scope."""
+    def under(scope, tree):
+        with jax.named_scope(scope):
+            return jax.tree.map(lambda a: a[None], tree)
+
+    return (new_state.replace(
+        step=under(names.SCOPE_OPTIMIZER, new_state.step),
+        params=under(names.SCOPE_GOSSIP, new_state.params),
+        batch_stats=under(names.SCOPE_FORWARD, new_state.batch_stats),
+        opt_state=under(names.SCOPE_OPTIMIZER, new_state.opt_state),
+        gossip=under(names.SCOPE_GOSSIP, new_state.gossip)),
+        under(names.SCOPE_HEALTH, metrics))
 
 
 def _device_normalize(images):
@@ -94,24 +114,40 @@ def build_train_step(model, algorithm: GossipAlgorithm, tx, lr_schedule,
     if grad_accum < 1:
         raise ValueError("grad_accum must be >= 1")
 
+    # The step names its own phases (telemetry/names.py): every
+    # operation below is traced under one ``jax.named_scope`` of the
+    # vocabulary, which the compiled program carries as metadata (no
+    # run-time cost) and a device trace shows as the operation's op_name.
+    # The forward scope sits INSIDE loss_fn so autodiff marks its
+    # transpose: backward operations read ``transpose(jvp(sgp.forward))``.
+    # Accuracy metrics count as forward; under grad_accum the microbatch
+    # sums count as gradient reduction.
+
     def train_step(state: TrainState, images, labels):
-        images = _device_normalize(images)
-        params, gstate = algorithm.pre_step(state.params, state.gossip)
-        z = algorithm.eval_params(params, gstate)
+        with jax.named_scope(names.SCOPE_FORWARD):
+            images = _device_normalize(images)
+        with jax.named_scope(names.SCOPE_PRE_STEP):
+            params, gstate = algorithm.pre_step(state.params, state.gossip)
+            z = algorithm.eval_params(params, gstate)
 
         def loss_fn(p, x, y, batch_stats):
-            out, mutated = model.apply(
-                {"params": p, "batch_stats": batch_stats},
-                x, train=True, mutable=["batch_stats"])
-            loss = kl_div_loss(
-                out, one_hot(y, num_classes, label_smoothing))
+            with jax.named_scope(names.SCOPE_FORWARD):
+                out, mutated = model.apply(
+                    {"params": p, "batch_stats": batch_stats},
+                    x, train=True, mutable=["batch_stats"])
+                loss = kl_div_loss(
+                    out, one_hot(y, num_classes, label_smoothing))
             return loss, (out, mutated["batch_stats"])
+
+        def accuracy(out, y):
+            with jax.named_scope(names.SCOPE_FORWARD):
+                return accuracy_topk(out, y, topk=(1, 5))
 
         if grad_accum == 1:
             (loss, (logits, batch_stats)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(z, images, labels,
                                        state.batch_stats)
-            top1, top5 = accuracy_topk(logits, labels, topk=(1, 5))
+            top1, top5 = accuracy(logits, labels)
         else:
             b = images.shape[0]
             if b % grad_accum:
@@ -126,9 +162,10 @@ def build_train_step(model, algorithm: GossipAlgorithm, tx, lr_schedule,
                 x, y = xy
                 (l, (out, bstats)), g = jax.value_and_grad(
                     loss_fn, has_aux=True)(z, x, y, bstats)
-                a1, a5 = accuracy_topk(out, y, topk=(1, 5))
-                return (jax.tree.map(jnp.add, g_sum, g), loss_sum + l,
-                        t1_sum + a1, t5_sum + a5, bstats), None
+                a1, a5 = accuracy(out, y)
+                with jax.named_scope(names.SCOPE_REDUCE_GRADS):
+                    return (jax.tree.map(jnp.add, g_sum, g), loss_sum + l,
+                            t1_sum + a1, t5_sum + a5, bstats), None
 
             zero_g = jax.tree.map(jnp.zeros_like, z)
             # scalar accumulators derive from the (device-varying) images so
@@ -137,57 +174,63 @@ def build_train_step(model, algorithm: GossipAlgorithm, tx, lr_schedule,
             (g_sum, loss_sum, t1_sum, t5_sum, batch_stats), _ = lax.scan(
                 accum, (zero_g, zero_s, zero_s, zero_s,
                         state.batch_stats), (xs, ys))
-            grads = jax.tree.map(lambda g: g / grad_accum, g_sum)
-            loss = loss_sum / grad_accum
-            top1 = t1_sum / grad_accum
-            top5 = t5_sum / grad_accum
+            with jax.named_scope(names.SCOPE_REDUCE_GRADS):
+                grads = jax.tree.map(lambda g: g / grad_accum, g_sum)
+                loss = loss_sum / grad_accum
+                top1 = t1_sum / grad_accum
+                top5 = t5_sum / grad_accum
 
-        if local_axis is not None:
-            # exact intra-node averaging of gradients and BN statistics
-            # (≙ the local all-reduce group, distributed.py:551-562, and BN
-            # buffer sync :269-276).  Params are *invariant* over the local
-            # axis (sharded over the node axis only), so autodiff already
-            # psums grads over local devices — divide by the axis size to
-            # turn that sum into the mean.
-            n_local = lax.axis_size(local_axis)
-            grads = jax.tree.map(lambda g: g / n_local, grads)
-            batch_stats = jax.tree.map(
-                lambda b: lax.pmean(b, local_axis), batch_stats)
-        grads = algorithm.reduce_grads(grads)
+        with jax.named_scope(names.SCOPE_REDUCE_GRADS):
+            if local_axis is not None:
+                # exact intra-node averaging of gradients and BN statistics
+                # (≙ the local all-reduce group, distributed.py:551-562, and
+                # BN buffer sync :269-276).  Params are *invariant* over the
+                # local axis (sharded over the node axis only), so autodiff
+                # already psums grads over local devices — divide by the
+                # axis size to turn that sum into the mean.
+                n_local = lax.axis_size(local_axis)
+                grads = jax.tree.map(lambda g: g / n_local, grads)
+                batch_stats = jax.tree.map(
+                    lambda b: lax.pmean(b, local_axis), batch_stats)
+            grads = algorithm.reduce_grads(grads)
 
-        step = as_scalar(state.step)
-        epoch = step // itr_per_epoch
-        itr = step % itr_per_epoch
-        lr = lr_schedule(epoch, itr, itr_per_epoch)
+        with jax.named_scope(names.SCOPE_OPTIMIZER):
+            step = as_scalar(state.step)
+            epoch = step // itr_per_epoch
+            itr = step % itr_per_epoch
+            lr = lr_schedule(epoch, itr, itr_per_epoch)
 
-        updates, opt_state = tx.update(grads, state.opt_state, params)
-        params = jax.tree.map(
-            lambda p, u: p - lr.astype(p.dtype) * u, params, updates)
+            updates, opt_state = tx.update(grads, state.opt_state, params)
+            params = jax.tree.map(
+                lambda p, u: p - lr.astype(p.dtype) * u, params, updates)
+            next_step = state.step + 1
 
-        params, gstate = algorithm.post_step(params, gstate)
+        with jax.named_scope(names.SCOPE_GOSSIP):
+            params, gstate = algorithm.post_step(params, gstate)
 
-        # grad-norm observability (the reference logs none; handy for
-        # divergence triage) — one reduce over the raveled grads
-        from ..utils.flatten import global_norm
-        metrics = {"loss": loss, "top1": top1, "top5": top5, "lr": lr,
-                   "grad_norm": global_norm(grads)}
-        if local_axis is not None:
-            metrics = jax.tree.map(
-                lambda m: lax.pmean(m, local_axis), metrics)
-        if health_axis is not None:
-            # consensus health AFTER the gossip round: the signals see the
-            # state the next step will train on.  Already identical across
-            # ranks (each is a collective), so the local-axis pmean above
-            # must not re-average them — append afterwards.  The overlap
-            # FIFO rides along so the monitor observes the DRAINED view
-            # (in-flight mass is not a leak).
-            from ..resilience.monitor import health_signals
-            metrics.update(health_signals(
-                params, grads, gstate.ps_weight, health_axis,
-                ef_residual=gstate.ef_residual,
-                in_flight=gstate.in_flight))
+        with jax.named_scope(names.SCOPE_HEALTH):
+            # grad-norm observability (the reference logs none; handy for
+            # divergence triage) — one reduce over the raveled grads
+            from ..utils.flatten import global_norm
+            metrics = {"loss": loss, "top1": top1, "top5": top5, "lr": lr,
+                       "grad_norm": global_norm(grads)}
+            if local_axis is not None:
+                metrics = jax.tree.map(
+                    lambda m: lax.pmean(m, local_axis), metrics)
+            if health_axis is not None:
+                # consensus health AFTER the gossip round: the signals see
+                # the state the next step will train on.  Already identical
+                # across ranks (each is a collective), so the local-axis
+                # pmean above must not re-average them — append afterwards.
+                # The overlap FIFO rides along so the monitor observes the
+                # DRAINED view (in-flight mass is not a leak).
+                from ..resilience.monitor import health_signals
+                metrics.update(health_signals(
+                    params, grads, gstate.ps_weight, health_axis,
+                    ef_residual=gstate.ef_residual,
+                    in_flight=gstate.in_flight))
         new_state = state.replace(
-            step=state.step + 1, params=params, batch_stats=batch_stats,
+            step=next_step, params=params, batch_stats=batch_stats,
             opt_state=opt_state, gossip=gstate)
         return new_state, metrics
 
@@ -241,15 +284,14 @@ def shard_train_step(step_fn, mesh, axis_name: str = GOSSIP_AXIS,
 
     def wrapped(state, images, labels):
         squeeze = lambda t: jax.tree.map(lambda a: a[0], t)
-        unsqueeze = lambda t: jax.tree.map(lambda a: a[None], t)
-        new_state, metrics = step_fn(
-            squeeze(state), squeeze(images), squeeze(labels))
-        return unsqueeze(new_state), unsqueeze(metrics)
+        return restack(*step_fn(
+            squeeze(state), squeeze(images), squeeze(labels)))
 
     sharded = jax.shard_map(
         wrapped, mesh=mesh,
         in_specs=(P(axis_name), batch_spec, batch_spec),
         out_specs=(P(axis_name), P(axis_name)), check_vma=check_vma)
+    sharded.__name__ = names.MODULE_TRAIN_STEP
     return jax.jit(sharded, donate_argnums=(0,))
 
 
@@ -284,15 +326,13 @@ def shard_scanned_train_step(step_fn, mesh, n_steps: int,
             st, metrics = step_fn(st, im, lb)
             return st, metrics
 
-        new_state, metrics = lax.scan(body, squeeze(state),
-                                      (images, labels))
-        return (jax.tree.map(lambda a: a[None], new_state),
-                jax.tree.map(lambda a: a[None], metrics))
+        return restack(*lax.scan(body, squeeze(state), (images, labels)))
 
     sharded = jax.shard_map(
         wrapped, mesh=mesh,
         in_specs=(P(axis_name), batch_spec, batch_spec),
         out_specs=(P(axis_name), P(axis_name)), check_vma=check_vma)
+    sharded.__name__ = names.MODULE_TRAIN_STEP_SCAN
     return jax.jit(sharded, donate_argnums=(0,))
 
 

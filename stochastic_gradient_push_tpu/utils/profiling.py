@@ -7,6 +7,12 @@ gossip thread's flag (distributed.py:36, :349-352).  Here:
 * :func:`trace` — ``jax.profiler`` trace context producing TensorBoard-
   loadable XPlane dumps of the actual device timeline (compute/collective
   overlap included), something the reference cannot see at all.
+  Captures start with the Python tracer off (``python_tracer_level = 0``:
+  a frame event per Python call swamps the host plane and slows the loop
+  it is meant to observe); the host plane instead holds the loops' own
+  spans, which :meth:`ProfileWindow.span` / :meth:`ProfileWindow.step`
+  write under the names of ``telemetry/names.py`` — the same clock as the
+  device planes, so an idle gap can be put down to the span it fell in.
   The profiler entry points here run the ``jax.profiler`` calls on a
   guarded timeout thread: a ``start_trace``/``stop_trace`` that does not
   return within ``timeout`` seconds, or raises, is logged as an ERROR
@@ -26,11 +32,12 @@ import contextlib
 import threading
 import time
 
+from ..telemetry.names import HOST_SPAN_PREFIX, HOST_STEP
+from ..telemetry.tracer import _NULL_SPAN
 from .logging import make_logger
 
 __all__ = ["trace", "start_trace_guarded", "stop_trace_guarded",
-           "ProfileWindow", "StepWatchdog", "HEARTBEAT_TIMEOUT",
-           "fenced_ms"]
+           "ProfileWindow", "StepWatchdog", "HEARTBEAT_TIMEOUT"]
 
 HEARTBEAT_TIMEOUT = 300  # seconds, matching distributed.py:36
 
@@ -102,9 +109,12 @@ def start_trace_guarded(log_dir: str,
             "hung start_trace completed late; stopping the trace")
         jax.profiler.stop_trace()
 
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
     try:
         return _call_with_timeout(
-            lambda: jax.profiler.start_trace(log_dir), timeout, "start",
+            lambda: jax.profiler.start_trace(
+                log_dir, profiler_options=options), timeout, "start",
             on_late_completion=undo_late_start)
     except (RuntimeError, OSError, ValueError) as e:
         # RuntimeError: profiler already active; OSError: unwritable
@@ -165,6 +175,12 @@ class ProfileWindow:
     guarded profiler entry points apply (module docstring): a failed
     start is logged as an error, abandoned, and the window is never
     retried — a second 60 s stall would just burn another step.
+
+    While a capture is active :meth:`span` and :meth:`step` put the
+    loop's own phases into it (``sgp:<name>`` / ``sgp_step`` on the host
+    plane); otherwise both return one shared no-op context — no clock
+    read, no allocation, nothing of ``jax.profiler`` touched — so the
+    loops wrap their phases unconditionally.
     """
 
     def __init__(self, profile_dir: str | None, start_step: int = 2,
@@ -179,6 +195,25 @@ class ProfileWindow:
     @property
     def enabled(self) -> bool:
         return self.profile_dir is not None
+
+    def span(self, name: str):
+        """Context manager marking a host phase of the loop (a name of
+        ``telemetry.names.HOST_SPANS``) in the active capture."""
+        if not self.active:
+            return _NULL_SPAN
+        import jax
+
+        return jax.profiler.TraceAnnotation(HOST_SPAN_PREFIX + name)
+
+    def step(self, step_num: int):
+        """Context manager marking one iteration (GLOBAL step number) in
+        the active capture."""
+        if not self.active:
+            return _NULL_SPAN
+        import jax
+
+        return jax.profiler.StepTraceAnnotation(HOST_STEP,
+                                                step_num=int(step_num))
 
     def maybe_start(self, step: int) -> bool:
         """Start the trace iff ``step`` enters the window; True while a
@@ -210,36 +245,6 @@ class ProfileWindow:
         if self.active:
             self.active = False
             stop_trace_guarded(self.timeout)
-
-
-def fenced_ms(fn, *args, steps: int = 10, warmup: int = 1) -> float:
-    """Amortized wall-clock milliseconds per call of ``fn(*args)``,
-    fenced by a HOST READBACK of the result.
-
-    The fence materializes on the host bytes that depend on the
-    computation (same discipline as bench.py's ``fence``): the readback
-    slices the first output leaf down to ONE element on-device (a
-    data-dependent gather) and pulls only that scalar, so the fence
-    costs a 2-byte transfer, not a full-tensor copy inside the timed
-    region.
-    """
-    import jax as _jax
-    import numpy as _np
-
-    def _fence(r):
-        leaf = _jax.tree_util.tree_leaves(r)[0]
-        nd = getattr(leaf, "ndim", 0)
-        _np.asarray(_jax.device_get(leaf[(0,) * nd] if nd else leaf))
-
-    r = None
-    for _ in range(max(1, warmup)):
-        r = fn(*args)
-    _fence(r)
-    t0 = time.perf_counter()
-    for _ in range(steps):
-        r = fn(*args)
-    _fence(r)
-    return (time.perf_counter() - t0) / steps * 1e3
 
 
 class StepWatchdog:

@@ -14,6 +14,7 @@ a second xdist worker must never try (a second file could land on one).
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +32,7 @@ from stochastic_gradient_push_tpu.parallel import (
 from stochastic_gradient_push_tpu.serve.engine import ServeConfig
 from stochastic_gradient_push_tpu.serve.paged_attention import (
     paged_attention_decode)
+from stochastic_gradient_push_tpu.telemetry import names
 from stochastic_gradient_push_tpu.topology import (
     NPeerDynamicDirectedExponentialGraph, build_schedule)
 
@@ -100,6 +102,16 @@ def _sharded(f, mesh, n_out):
         out_specs=(P(GOSSIP_AXIS),) * n_out))
 
 
+def _kernel_names(compiled_text: str) -> set[str]:
+    """What the chip's trace will call the program's Pallas kernels: the
+    names of the compiled ``tpu_custom_call`` instructions, without the
+    compiler's ``.N``.  (A ``pallas_call`` without ``name=`` is called
+    after the enclosing module scope: ``attn.12``.)"""
+    return {m.group(1) for m in re.finditer(
+        r'%([A-Za-z_]\w*?)(?:\.\d+)? = [^\n]*'
+        r'custom_call_target="tpu_custom_call"', compiled_text)}
+
+
 @pytest.mark.parametrize("shape", [(8, 12, 1024, 64), (2, 8, 4096, 64)],
                          ids=["t1024", "t4096"])
 def test_flash_forward_and_backward_compile(one_chip, on_tpu, shape):
@@ -109,12 +121,19 @@ def test_flash_forward_and_backward_compile(one_chip, on_tpu, shape):
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
 
     def loss(q, k, v):
-        return flash_attention(q, k, v, causal=True).astype(
-            jnp.float32).sum()
+        # under the step's forward scope, as in the program: autodiff's
+        # jvp(…)/transpose(…) wrap the outermost scope, and the kernels'
+        # own names stay bare
+        with jax.named_scope(names.SCOPE_FORWARD):
+            return flash_attention(q, k, v, causal=True).astype(
+                jnp.float32).sum()
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         x, x, x).compile().as_text()
     assert text.count("tpu_custom_call") == 3
+    assert _kernel_names(text) == {names.KERNEL_FLASH_FWD,
+                                   names.KERNEL_FLASH_DQ,
+                                   names.KERNEL_FLASH_DKV}
 
 
 def test_push_sum_round_is_a_collective_permute(mesh):
@@ -155,6 +174,8 @@ def test_gossip_start_wait_pair_compiles(mesh, codec):
         _per_rank(mesh, (RESNET50_PARAMS,), jnp.float32)
     ).compile().as_text()
     assert text.count("tpu_custom_call") == 2
+    assert _kernel_names(text) == {names.KERNEL_GOSSIP_START,
+                                   names.KERNEL_GOSSIP_WAIT}
 
 
 def test_ring_flash_tick_compiles(mesh):
@@ -207,3 +228,4 @@ def test_paged_decode_kernel_compiles(one_chip):
         jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=one_chip),
     ).compile().as_text()
     assert "tpu_custom_call" in text
+    assert _kernel_names(text) == {names.KERNEL_PAGED_ATTENTION}

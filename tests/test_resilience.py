@@ -684,3 +684,79 @@ def test_sgd_cli_chaos_end_to_end(tmp_path, capfd):
         CheckpointManager
     ckpt = CheckpointManager(str(tmp_path), rank=0, world_size=8)
     assert ckpt.exists()
+
+
+# -- the consensus probe on a model-shaped tree ------------------------------
+
+def _resnet_shaped_world(world, quiet, seed=0, sigma=1e-3):
+    """ResNet-50's own parameter tree (161 leaves, a quarter of the
+    width), identical on every rank but for a per-rank perturbation of
+    size ``sigma`` on every leaf outside ``quiet``."""
+    from stochastic_gradient_push_tpu.models.resnet import resnet50
+
+    shapes = jax.eval_shape(
+        lambda: resnet50(num_classes=100, num_filters=16).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 32, 32, 3)))["params"])
+    rng = np.random.default_rng(seed)
+    leaves, treedef = jax.tree.flatten(shapes)
+    order = sorted(range(len(leaves)), key=lambda i: -leaves[i].size)
+    still = {order[i] for i in quiet}
+    stacked = []
+    for i, leaf in enumerate(leaves):
+        base = rng.normal(size=leaf.shape).astype(np.float32)
+        noise = rng.normal(size=(world,) + leaf.shape).astype(np.float32)
+        stacked.append(base[None] + (0.0 if i in still else sigma) * noise)
+    return jax.tree.unflatten(treedef, stacked), stacked, order
+
+
+@pytest.mark.parametrize("quiet", [(), (0,), (0, 1, 2)],
+                         ids=["all-differ", "largest-leaf-agrees",
+                              "three-largest-agree"])
+def test_consensus_probe_sees_what_the_largest_leaf_does_not(quiet):
+    """PR 21's blind spot: replicas that agree on the largest leaf (a
+    last-stage 3x3 kernel behind a zero-initialised BatchNorm scale early
+    in training) and differ everywhere else read a residual of exactly 0
+    from the head of that leaf.  The probe now reads strided slots of
+    every leaf, in proportion to its size: above zero, and within a
+    factor of three of the true RMS deviation over all the parameters."""
+    world = 4
+    mesh = make_gossip_mesh(world)
+    params, stacked, order = _resnet_shaped_world(world, quiet)
+    flat = np.concatenate([a.reshape(world, -1) for a in stacked], axis=1)
+    true_rms = float(np.sqrt(np.mean((flat - flat.mean(0)) ** 2)))
+
+    def probe(p, w):
+        sig = health_signals(jax.tree.map(lambda a: a[0], p), None, w[0],
+                             GOSSIP_AXIS)
+        return sig["consensus_residual"][None]
+
+    residual = float(np.asarray(jax.jit(jax.shard_map(
+        probe, mesh=mesh, in_specs=(P(GOSSIP_AXIS), P(GOSSIP_AXIS)),
+        out_specs=P(GOSSIP_AXIS)))(
+            params, jnp.ones((world,), jnp.float32)))[0])
+    assert residual > 0.0
+    assert true_rms / 3 <= residual <= true_rms * 3, (residual, true_rms)
+    if quiet:
+        head = stacked[order[0]].reshape(world, -1)[:, :256]
+        assert np.sqrt(np.mean((head - head.mean(0)) ** 2)) == 0.0
+
+
+def test_consensus_probe_copies_no_leaf():
+    """The cost the docstring promises: strided slices of the leaves in
+    their own shape — no flattening reshape of a whole leaf, which on the
+    chip's tiled layouts is a payload-sized copy."""
+    from stochastic_gradient_push_tpu.resilience.monitor import (
+        DEFAULT_PROBE_SLOTS, _strided_sample)
+
+    leaf = jnp.arange(3 * 3 * 512 * 512, dtype=jnp.float32).reshape(
+        3, 3, 512, 512)
+    jaxpr = jax.make_jaxpr(lambda a: _strided_sample(a, 16))(leaf)
+    sizes = [v.aval.size for eqn in jaxpr.eqns for v in eqn.outvars]
+    assert max(sizes) <= 16, jaxpr
+    sample = np.asarray(_strided_sample(leaf, 16))
+    assert 8 <= sample.size <= 16
+    # spread over the whole leaf, not its head
+    assert sample.max() > 0.5 * leaf.size
+    small = jnp.arange(10.0)
+    assert np.array_equal(np.asarray(
+        _strided_sample(small, DEFAULT_PROBE_SLOTS)), np.arange(10.0))
