@@ -1,30 +1,41 @@
 """Pallas TPU flash-attention kernels: fused forward AND backward.
 
-The transformer path's compute hot spot.  All three kernels share one
-schedule shape: a 3-D grid whose two major dimensions are parallel
-(batch·head and the output block) and whose MINOR dimension walks the
-streamed axis with ``arbitrary`` semantics — so Pallas double-buffers the
-streamed k/v (or q/do) block fetches behind the matmuls instead of
-parking whole ``[seq, d]`` operands in VMEM per cell (the round-3 design,
-whose dk/dv kernel lost to XLA 122.8 ms vs 68.6 ms at t=4096 in an
-earlier installation's capture).  Running state lives in fp32 VMEM scratch
-that persists across the minor grid steps: the forward carries the
-online-softmax ``(m, den, acc)`` triple, the backward kernels carry their
-gradient accumulators, and outputs are written once on the last minor
-step.  VMEM per cell is O(block²), independent of sequence length.
+The transformer path's compute hot spot.  Every kernel has one schedule
+shape: a 3-D grid over batch·head (``parallel``), an output block, and a
+MINOR dimension that walks the streamed axis with ``arbitrary`` semantics
+— so Pallas double-buffers the streamed k/v (or q/do) block fetches behind
+the matmuls instead of parking whole ``[seq, d]`` operands in VMEM per
+cell (the round-3 design, whose dk/dv kernel lost to XLA 122.8 ms vs
+68.6 ms at t=4096 in an earlier installation's capture).  Running state
+lives in fp32 VMEM scratch that persists across grid steps: the forward
+carries the online-softmax ``(m, den, acc)`` triple, the backward its
+gradient accumulators, and outputs are written once, on the last step
+that adds to them.
 
-Backward (``jax.custom_vjp``) is the standard flash-attention-2
-decomposition:
+Backward (``jax.custom_vjp``) is flash-attention-2's, in ONE kernel
+wherever :func:`fused_backward_fits`:
 
-* dQ kernel, grid ``(bh, q-block, k-step)``: streams k/v, recomputes
-  ``p = exp(s - lse)``, accumulates ``dq += ds @ k``.
-* dK/dV kernel, grid ``(bh, k-block, q-step)``: streams q/do, accumulates
-  ``dv += pᵀ @ do`` and ``dk += dsᵀ @ q``.
+* Fused kernel, grid ``(bh, k-block, q-step)``: per visible tile
+  ``s = q·kᵀ``, the mask, ``p = exp(s - lse)``, ``dp = do·vᵀ`` and
+  ``ds = p·(dp - delta)`` are computed once and feed all three products:
+  ``dv += pᵀ·do`` and ``dk += dsᵀ·q`` into ``[block_k, d]`` accumulators
+  written on the k-block's last q-step, ``dq[q-block rows] += ds·k`` into
+  a ``[seq, d]`` accumulator that lives for the whole sweep of one bh and
+  is written once (both inner grid dims are ``arbitrary`` for its sake).
+  Five products a tile, every operand read once.  VMEM is O(block²)
+  plus dq for one sequence, which is what the shape rule budgets.
+* Beyond the budget (``seq`` over 8192, heads wider than 128) the
+  dq + dk/dv PAIR: a dQ kernel, grid ``(bh, q-block, k-step)``, streams
+  k/v and accumulates ``dq``; a dK/dV kernel on the fused kernel's grid
+  accumulates ``dk`` and ``dv``.  Each recomputes ``p`` and ``ds`` — seven
+  products a tile, operands read twice — but VMEM stays O(block²) at any
+  length.  Same operands, same casts, same order of accumulation: the
+  two give the same bits.
 
 The per-row residuals travel in compact ``[rows, 1]`` layouts: the
 forward's logsumexp and ``delta = rowsum(do · o)``, the latter computed
 once outside the kernels (a fused XLA elementwise-reduce) so ``o`` is not
-an operand of either backward kernel.  Causal runs skip the empty
+an operand of any backward kernel.  Causal runs skip the empty
 triangle two ways: masked minor steps are compute-gated with ``pl.when``,
 and their index maps clamp into the visible range so no new block is ever
 fetched for a skipped step.
@@ -32,7 +43,7 @@ fetched for a skipped step.
 On non-TPU backends ``flash_attention`` transparently falls back to the
 pure-JAX blockwise implementation
 (parallel/ring_attention.py::blockwise_attention); Pallas interpret mode
-exercises all three kernels in tests against that same oracle.
+exercises every kernel in tests against that same oracle.
 """
 
 from __future__ import annotations
@@ -48,7 +59,7 @@ from ..parallel.ring_attention import blockwise_attention
 from ..telemetry import names
 
 __all__ = ["flash_attention", "flash_attention_forward",
-           "flash_attention_backward"]
+           "flash_attention_backward", "fused_backward_fits"]
 
 NEG_INF = -1e30
 
@@ -80,6 +91,13 @@ def default_block(t: int) -> int:
     return min(128, t)
 
 
+# What the fused backward may keep in VMEM on top of its tiles: half of
+# the 16 MiB a v5e kernel may scope, which holds dq for 8192 tokens; the
+# tiles' 512 × 512 fp32 intermediates and operand blocks need most of the
+# other half (tests/test_chip_compile.py compiles both sides of it).
+FUSED_BWD_VMEM_BUDGET = 8 * 2 ** 20
+
+
 def _sds(shape, dtype, *like):
     """ShapeDtypeStruct typed varying over every manual mesh axis any of
     ``like`` varies over — required for pallas_call outputs inside a
@@ -88,13 +106,14 @@ def _sds(shape, dtype, *like):
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
-def _compiler_params(interpret: bool):
+def _compiler_params(interpret: bool, middle: str = "parallel"):
     """Minor grid dim walks the streamed axis: revisited outputs/scratch
-    require ``arbitrary``; the two major dims are parallel."""
+    require ``arbitrary``; batch·head is parallel, and so is the middle
+    dim unless state is carried across it (the fused backward's dq)."""
     if interpret:
         return None
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
+        dimension_semantics=("parallel", middle, "arbitrary"))
 
 
 def _causal_mask(s, qi, kj, block_q: int, block_k: int):
@@ -103,6 +122,13 @@ def _causal_mask(s, qi, kj, block_q: int, block_k: int):
     k_pos = kj * block_k + jax.lax.broadcasted_iota(
         jnp.int32, s.shape, 1)
     return jnp.where(q_pos >= k_pos, s, NEG_INF)
+
+
+def _visible(qi, kj, block_q: int, block_k: int, causal: bool):
+    """Whether tile (q-block ``qi``, k-block ``kj``) holds any unmasked
+    pair."""
+    return (qi * block_q + block_q - 1 >= kj * block_k) if causal \
+        else (qi >= 0)
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q: int,
@@ -124,10 +150,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q: int,
         den_ref[:] = jnp.zeros_like(den_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    visible = (qi * block_q + block_q - 1 >= kj * block_k) if causal \
-        else (kj >= 0)
-
-    @pl.when(visible)
+    @pl.when(_visible(qi, kj, block_q, block_k, causal))
     def _compute():
         d = q_ref.shape[-1]
         q = q_ref[:].astype(jnp.float32) * (d ** -0.5)
@@ -223,6 +246,92 @@ def flash_attention_forward(q, k, v, causal: bool = False,
     return out.reshape(b, h, t, d)
 
 
+def _tile_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, kj,
+               block_q: int, block_k: int, causal: bool):
+    """What every backward product of one visible tile is made from:
+    ``p = exp(s - lse)`` and ``ds = p * (dp - delta)``, both
+    ``[block_q, block_k]`` fp32, with the fp32 operands they came from
+    (``q`` already scaled by ``d ** -0.5``)."""
+    q = q_ref[:].astype(jnp.float32) * (q_ref.shape[-1] ** -0.5)
+    k = k_ref[:].astype(jnp.float32)
+    v = v_ref[:].astype(jnp.float32)
+    do = do_ref[:].astype(jnp.float32)
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)                # [bq, bk]
+    if causal:
+        s = _causal_mask(s, qi, kj, block_q, block_k)
+    p = jnp.exp(s - lse_ref[:])                            # [bq, bk]
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)                # [bq, bk]
+    ds = p * (dp - delta_ref[:])
+    return q, k, do, p, ds
+
+
+def _dq_term(ds, k):
+    """One tile's addend to dq, ``[block_q, d]``."""
+    return jax.lax.dot_general(
+        ds, k, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32) * (k.shape[-1] ** -0.5)
+
+
+def _dkv_terms(p, ds, q, do):
+    """One tile's addends to dk and dv, ``[block_k, d]`` each."""
+    dv = jax.lax.dot_general(
+        p, do, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)                # [bk, d]
+    dk = jax.lax.dot_general(
+        ds, q, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)                # [bk, d]
+    return dk, dv
+
+
+def _flash_bwd_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
+                      dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, *dq_scratch,
+                      block_q: int, block_k: int, causal: bool):
+    """Fused backward cell (bh, k-block, q-step).  Refs: k/v/dk/dv
+    [block_k, d]; q/do [block_q, d] (streamed); lse/delta
+    [block_q, SCALAR_COLS]; dq [t, d], one block a bh.  Scratch, fp32:
+    dk/dv accumulators [block_k, d], alive over one k-block's q-steps, and
+    the dq accumulator [t, d], alive over the whole (k-block, q-step)
+    sweep of one bh — the dq block itself where dq is fp32, which is
+    resident for just that sweep."""
+    kj, qi = pl.program_id(1), pl.program_id(2)
+    nk, nq = pl.num_programs(1), pl.num_programs(2)
+    dq_acc = dq_scratch[0] if dq_scratch else dq_ref
+
+    @pl.when((kj == 0) & (qi == 0))
+    def _init_dq():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    @pl.when(qi == 0)
+    def _init_dkv():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    @pl.when(_visible(qi, kj, block_q, block_k, causal))
+    def _compute():
+        q, k, do, p, ds = _tile_p_ds(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, kj,
+            block_q, block_k, causal)
+        dk, dv = _dkv_terms(p, ds, q, do)
+        dv_acc[:] += dv
+        dk_acc[:] += dk
+        rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+        dq_acc[rows, :] += _dq_term(ds, k)
+
+    @pl.when(qi == nq - 1)
+    def _finalize_dkv():
+        dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
+
+    if dq_scratch:
+        @pl.when((kj == nk - 1) & (qi == nq - 1))
+        def _finalize_dq():
+            dq_ref[:] = dq_acc[:].astype(dq_ref.dtype)
+
+
 def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                      dq_ref, acc_ref, *, block_q: int, block_k: int,
                      causal: bool):
@@ -236,30 +345,12 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    visible = (qi * block_q + block_q - 1 >= kj * block_k) if causal \
-        else (kj >= 0)
-
-    @pl.when(visible)
+    @pl.when(_visible(qi, kj, block_q, block_k, causal))
     def _compute():
-        d = q_ref.shape[-1]
-        scale = d ** -0.5
-        q = q_ref[:].astype(jnp.float32) * scale
-        k = k_ref[:].astype(jnp.float32)
-        v = v_ref[:].astype(jnp.float32)
-        do = do_ref[:].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [bq, bk]
-        if causal:
-            s = _causal_mask(s, qi, kj, block_q, block_k)
-        p = jnp.exp(s - lse_ref[:])                        # [bq, bk]
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [bq, bk]
-        ds = p * (dp - delta_ref[:])
-        acc_ref[:] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+        _, k, _, _, ds = _tile_p_ds(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, kj,
+            block_q, block_k, causal)
+        acc_ref[:] += _dq_term(ds, k)
 
     @pl.when(kj == nk - 1)
     def _finalize():
@@ -280,33 +371,14 @@ def _flash_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    visible = (qi * block_q + block_q - 1 >= kj * block_k) if causal \
-        else (qi >= 0)
-
-    @pl.when(visible)
+    @pl.when(_visible(qi, kj, block_q, block_k, causal))
     def _compute():
-        d = k_ref.shape[-1]
-        scale = d ** -0.5
-        k = k_ref[:].astype(jnp.float32)
-        v = v_ref[:].astype(jnp.float32)
-        q = q_ref[:].astype(jnp.float32) * scale
-        do = do_ref[:].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [bq, bk]
-        if causal:
-            s = _causal_mask(s, qi, kj, block_q, block_k)
-        p = jnp.exp(s - lse_ref[:])                        # [bq, bk]
-        dv_acc[:] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [bk, d]
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [bq, bk]
-        ds = p * (dp - delta_ref[:])
-        dk_acc[:] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [bk, d]
+        q, _, do, p, ds = _tile_p_ds(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, kj,
+            block_q, block_k, causal)
+        dk, dv = _dkv_terms(p, ds, q, do)
+        dv_acc[:] += dv
+        dk_acc[:] += dk
 
     @pl.when(qi == nq - 1)
     def _finalize():
@@ -314,16 +386,29 @@ def _flash_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
         dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
 
 
+def fused_backward_fits(t: int, d: int) -> bool:
+    """The backward's shape rule: one fused kernel while dq for the whole
+    sequence stays inside :data:`FUSED_BWD_VMEM_BUDGET`, the dq + dk/dv
+    pair beyond.  A row of dq takes a 128-lane register at any head size
+    up to 128, eight bytes a lane: an fp32 accumulator under a two-deep
+    16-bit dq block, or a two-deep fp32 dq block accumulated in place.
+    Wider heads stay with the pair: their tiles leave dq no such room
+    (t4096 / d256 fp32 is refused fused and compiles as the pair)."""
+    return d <= 128 and t * 128 * 8 <= FUSED_BWD_VMEM_BUDGET
+
+
 def flash_attention_backward(q, k, v, out, lse, do, causal: bool = False,
                              block_q: int = 128, block_k: int = 128,
                              interpret: bool = False):
-    """Fused Pallas backward: returns ``(dq, dk, dv)``.
+    """Pallas backward: returns ``(dq, dk, dv)``, from one fused kernel
+    where :func:`fused_backward_fits` and from the dq + dk/dv pair where
+    the sequence is too long for it.
 
     ``lse`` is the forward's row logsumexp ``[b, h, seq]``; it and
     ``delta = rowsum(do · out)`` (computed here, once, as a fused XLA
     reduce) ship in the compact ``[rows, 1]`` layout, so no
     lane-broadcast scalar array ever exists in HBM and ``out`` is not an
-    operand of either kernel.
+    operand of any kernel.
     """
     b, h, t, d = q.shape
     block_q = min(block_q, t)
@@ -340,6 +425,80 @@ def flash_attention_backward(q, k, v, out, lse, do, causal: bool = False,
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1).reshape(b * h, t)[..., None]
 
+    backward = _backward_fused if fused_backward_fits(t, d) \
+        else _backward_pair
+    dq, dk, dv = backward(qf, kf, vf, dof, lsef, delta, causal, block_q,
+                          block_k, interpret)
+    return (dq.reshape(b, h, t, d), dk.reshape(b, h, t, d),
+            dv.reshape(b, h, t, d))
+
+
+def _dkv_specs(t: int, d: int, block_q: int, block_k: int, causal: bool):
+    """Grid and operand specs of the (bh, k-block, q-step) schedule, for
+    operands in the order k, v, q, do, lse, delta; and the k-block spec
+    dk and dv are written through."""
+    def q_map(bh, kj, qi):
+        if causal:
+            # the first visible q-step for this k-block; earlier (masked)
+            # steps alias it so no block is fetched for them
+            qi = jnp.maximum(qi, (kj * block_k) // block_q)
+        return (bh, qi, 0)
+
+    k_col = pl.BlockSpec((None, block_k, d),
+                         lambda bh, kj, qi: (bh, kj, 0))
+    in_specs = [
+        k_col,                                              # k
+        k_col,                                              # v
+        pl.BlockSpec((None, block_q, d), q_map),            # q
+        pl.BlockSpec((None, block_q, d), q_map),            # do
+        pl.BlockSpec((None, block_q, SCALAR_COLS), q_map),  # lse
+        pl.BlockSpec((None, block_q, SCALAR_COLS), q_map),  # delta
+    ]
+    return (t // block_k, t // block_q), in_specs, k_col
+
+
+def _backward_fused(qf, kf, vf, dof, lsef, delta, causal, block_q,
+                    block_k, interpret):
+    """One kernel over grid (bh, k-block, q-step): every visible tile's
+    ``p`` and ``ds`` computed once, dk/dv written a k-block, dq carried in
+    VMEM over the whole sweep and written once a bh — so both inner dims
+    are ``arbitrary``."""
+    bh, t, d = qf.shape
+    inner, in_specs, k_col = _dkv_specs(t, d, block_q, block_k, causal)
+    return pl.pallas_call(
+        functools.partial(_flash_bwd_kernel, block_q=block_q,
+                          block_k=block_k, causal=causal),
+        grid=(bh,) + inner,
+        in_specs=in_specs,
+        out_specs=[
+            pl.BlockSpec((None, t, d), lambda bh, kj, qi: (bh, 0, 0)),
+            k_col,
+            k_col,
+        ],
+        out_shape=[
+            _sds((bh, t, d), qf.dtype, qf),
+            _sds((bh, t, d), kf.dtype, kf),
+            _sds((bh, t, d), vf.dtype, vf),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d), jnp.float32),
+            # an fp32 dq accumulates in its own block: no scratch for it
+        ] + ([] if qf.dtype == jnp.float32
+             else [pltpu.VMEM((t, d), jnp.float32)]),
+        compiler_params=_compiler_params(interpret, middle="arbitrary"),
+        interpret=interpret,
+        name=names.KERNEL_FLASH_BWD,
+    )(kf, vf, qf, dof, lsef, delta)
+
+
+def _backward_pair(qf, kf, vf, dof, lsef, delta, causal, block_q, block_k,
+                   interpret):
+    """The dq kernel, grid (bh, q-block, k-step), then the dk/dv kernel,
+    grid (bh, k-block, q-step): VMEM O(block²) at any length, every
+    tile's ``p`` and ``ds`` computed twice."""
+    bh, t, d = qf.shape
+
     def kv_map(bh, qi, kj):
         if causal:
             kj = jnp.minimum(kj, (qi * block_q + block_q - 1) // block_k)
@@ -352,7 +511,7 @@ def flash_attention_backward(q, k, v, out, lse, do, causal: bool = False,
     dq = pl.pallas_call(
         functools.partial(_flash_dq_kernel, block_q=block_q,
                           block_k=block_k, causal=causal),
-        grid=(b * h, t // block_q, t // block_k),
+        grid=(bh, t // block_q, t // block_k),
         in_specs=[
             q_row,                                          # q
             pl.BlockSpec((None, block_k, d), kv_map),       # k
@@ -362,38 +521,23 @@ def flash_attention_backward(q, k, v, out, lse, do, causal: bool = False,
             s_row,                                          # delta
         ],
         out_specs=q_row,
-        out_shape=_sds((b * h, t, d), q.dtype, qf),
+        out_shape=_sds((bh, t, d), qf.dtype, qf),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
         name=names.KERNEL_FLASH_DQ,
     )(qf, kf, vf, dof, lsef, delta)
 
-    def q_map(bh, kj, qi):
-        if causal:
-            # the first visible q-step for this k-block; earlier (masked)
-            # steps alias it so no block is fetched for them
-            qi = jnp.maximum(qi, (kj * block_k) // block_q)
-        return (bh, qi, 0)
-
-    k_col = pl.BlockSpec((None, block_k, d),
-                         lambda bh, kj, qi: (bh, kj, 0))
+    inner, in_specs, k_col = _dkv_specs(t, d, block_q, block_k, causal)
     dk, dv = pl.pallas_call(
         functools.partial(_flash_dkv_kernel, block_q=block_q,
                           block_k=block_k, causal=causal),
-        grid=(b * h, t // block_k, t // block_q),
-        in_specs=[
-            k_col,                                          # k
-            k_col,                                          # v
-            pl.BlockSpec((None, block_q, d), q_map),        # q
-            pl.BlockSpec((None, block_q, d), q_map),        # do
-            pl.BlockSpec((None, block_q, SCALAR_COLS), q_map),   # lse
-            pl.BlockSpec((None, block_q, SCALAR_COLS), q_map),   # delta
-        ],
+        grid=(bh,) + inner,
+        in_specs=in_specs,
         out_specs=[k_col, k_col],
         out_shape=[
-            _sds((b * h, t, d), k.dtype, kf),
-            _sds((b * h, t, d), v.dtype, vf),
+            _sds((bh, t, d), kf.dtype, kf),
+            _sds((bh, t, d), vf.dtype, vf),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
@@ -403,8 +547,7 @@ def flash_attention_backward(q, k, v, out, lse, do, causal: bool = False,
         interpret=interpret,
         name=names.KERNEL_FLASH_DKV,
     )(kf, vf, qf, dof, lsef, delta)
-    return (dq.reshape(b, h, t, d), dk.reshape(b, h, t, d),
-            dv.reshape(b, h, t, d))
+    return dq, dk, dv
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))

@@ -19,10 +19,11 @@ Two structural tricks make the composition exact:
   ticks.
 * **Global-LSE backward**: flash-attention-2's backward needs only the
   FINAL row logsumexp and ``delta = rowsum(do · out)``; per-tick calls
-  of the fused dq/dkv kernels with the merged lse yield exactly that
-  tick's gradient contribution.  dq accumulates locally; dk/dv
-  accumulators ride around the ring WITH their k/v blocks and arrive
-  home after a full rotation.
+  of the flash backward (one fused kernel at any shard length up to
+  8192, the dq + dk/dv pair beyond: ``fused_backward_fits``) with the
+  merged lse yield exactly that tick's gradient contribution.  dq
+  accumulates locally; dk/dv accumulators ride around the ring WITH
+  their k/v blocks and arrive home after a full rotation.
 
 Causality needs no position plumbing: a tick is either fully visible
 (``causal=False`` kernels), the aligned diagonal block

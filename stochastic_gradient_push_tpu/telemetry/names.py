@@ -34,8 +34,9 @@ MODULE_LM_TRAIN_STEP_SCAN = "sgp_lm_train_step_scan"
 
 # -- pallas_call names ---------------------------------------------------
 KERNEL_FLASH_FWD = "flash_fwd"
-KERNEL_FLASH_DQ = "flash_dq"
-KERNEL_FLASH_DKV = "flash_dkv"
+KERNEL_FLASH_BWD = "flash_bwd"            # the fused backward
+KERNEL_FLASH_DQ = "flash_dq"              # the pair it gives way to
+KERNEL_FLASH_DKV = "flash_dkv"            # beyond its VMEM budget
 KERNEL_GOSSIP_START = "gossip_edge_start"
 KERNEL_GOSSIP_WAIT = "gossip_edge_wait"
 KERNEL_PAGED_ATTENTION = "paged_attention"

@@ -25,7 +25,8 @@ from jax.sharding import PartitionSpec as P
 
 from stochastic_gradient_push_tpu.ops import gossip_kernel as gk
 from stochastic_gradient_push_tpu.ops.flash_attention import (
-    default_block, flash_attention)
+    default_block, flash_attention, flash_attention_backward,
+    fused_backward_fits)
 from stochastic_gradient_push_tpu.ops.ring_flash import ring_flash_attention
 from stochastic_gradient_push_tpu.parallel import (
     GOSSIP_AXIS, collectives, make_gossip_mesh, wire)
@@ -112,12 +113,22 @@ def _kernel_names(compiled_text: str) -> set[str]:
         r'custom_call_target="tpu_custom_call"', compiled_text)}
 
 
-@pytest.mark.parametrize("shape", [(8, 12, 1024, 64), (2, 8, 4096, 64)],
-                         ids=["t1024", "t4096"])
-def test_flash_forward_and_backward_compile(one_chip, on_tpu, shape):
-    """The flagship LM's attention (and the longest captured length) at
-    the auto block: forward, dq and dk/dv kernels via ``jax.grad``."""
+@pytest.mark.parametrize("shape,backward", [
+    ((8, 12, 1024, 64), {names.KERNEL_FLASH_BWD}),
+    ((2, 8, 4096, 64), {names.KERNEL_FLASH_BWD}),
+    ((1, 8, 8192, 64), {names.KERNEL_FLASH_BWD}),
+    ((1, 4, 16384, 64), {names.KERNEL_FLASH_DQ, names.KERNEL_FLASH_DKV}),
+], ids=["t1024", "t4096", "t8192", "t16384_pair"])
+def test_flash_forward_and_backward_compile(one_chip, on_tpu, shape,
+                                            backward):
+    """The flagship LM's attention, the longest captured length and the
+    longest the fused backward holds dq for (a 4 MB accumulator under a
+    two-deep 2 MB block), at the auto block via ``jax.grad``: the forward
+    kernel and ONE backward kernel; one length beyond the budget, where
+    the dq + dk/dv pair takes over."""
     assert default_block(shape[2]) == 512
+    assert fused_backward_fits(*shape[2:]) == (
+        backward == {names.KERNEL_FLASH_BWD})
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
 
     def loss(q, k, v):
@@ -130,10 +141,26 @@ def test_flash_forward_and_backward_compile(one_chip, on_tpu, shape):
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         x, x, x).compile().as_text()
-    assert text.count("tpu_custom_call") == 3
-    assert _kernel_names(text) == {names.KERNEL_FLASH_FWD,
-                                   names.KERNEL_FLASH_DQ,
-                                   names.KERNEL_FLASH_DKV}
+    assert text.count("tpu_custom_call") == 1 + len(backward)
+    assert _kernel_names(text) == {names.KERNEL_FLASH_FWD} | backward
+
+
+@pytest.mark.parametrize("d", [64, 128])
+def test_fused_backward_compiles_at_the_budget_in_fp32(one_chip, d):
+    """The shape rule knows ``t`` and ``d`` only, so the longest sequence
+    it admits must build at either dtype: an fp32 dq is accumulated in its
+    own resident block, and costs what bf16's accumulator and block do."""
+    t = 8192
+    assert fused_backward_fits(t, d) and not fused_backward_fits(2 * t, d)
+    x = jax.ShapeDtypeStruct((1, 32, t, d), jnp.float32, sharding=one_chip)
+    lse = jax.ShapeDtypeStruct((1, 32, t), jnp.float32, sharding=one_chip)
+
+    def bwd(q, k, v, out, lse, do):
+        return flash_attention_backward(q, k, v, out, lse, do, causal=True,
+                                        block_q=512, block_k=512)
+
+    text = jax.jit(bwd).lower(x, x, x, x, lse, x).compile().as_text()
+    assert _kernel_names(text) == {names.KERNEL_FLASH_BWD}
 
 
 def test_push_sum_round_is_a_collective_permute(mesh):

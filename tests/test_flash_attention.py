@@ -1,6 +1,11 @@
 """Pallas flash-attention kernel vs the pure-JAX oracle (interpret mode on
 CPU; the same kernel compiles for real on TPU)."""
 
+import importlib
+import json
+import os
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,11 +13,18 @@ import pytest
 
 from stochastic_gradient_push_tpu.ops.flash_attention import (
     flash_attention,
+    flash_attention_backward,
     flash_attention_forward,
+    fused_backward_fits,
 )
+from stochastic_gradient_push_tpu.telemetry import names
 from stochastic_gradient_push_tpu.parallel.ring_attention import (
     blockwise_attention,
 )
+
+# the module itself: the package attribute of that name is the function
+fa = importlib.import_module(
+    "stochastic_gradient_push_tpu.ops.flash_attention")
 
 B, H, T, D = 2, 2, 64, 16
 
@@ -22,6 +34,31 @@ def qkv():
     rng = np.random.default_rng(7)
     return [jnp.asarray(rng.normal(size=(B, H, T, D)), jnp.float32)
             for _ in range(3)]
+
+
+@pytest.fixture(params=["fused", "pair"])
+def backward(request, monkeypatch):
+    """Both sides of the backward's shape rule at the tests' small
+    shapes: as the rule picks (fused), and with no VMEM budget at all
+    (the dq + dk/dv pair)."""
+    if request.param == "pair":
+        monkeypatch.setattr(fa, "FUSED_BWD_VMEM_BUDGET", 0)
+    assert fused_backward_fits(T, D) == (request.param == "fused")
+    return request.param
+
+
+def _backward_kernels(shape, dtype):
+    """Names of the Pallas kernels ``flash_attention_backward`` calls at
+    ``shape`` (traced, not run)."""
+    x = jax.ShapeDtypeStruct(shape, dtype)
+    lse = jax.ShapeDtypeStruct(shape[:3], jnp.float32)
+    block = fa.default_block(shape[2])
+    jaxpr = jax.make_jaxpr(lambda q, k, v, out, lse, do: (
+        flash_attention_backward(q, k, v, out, lse, do, causal=True,
+                                 block_q=block, block_k=block)))(
+        x, x, x, x, lse, x)
+    return [e.params["name"] for e in jaxpr.eqns
+            if e.primitive.name == "pallas_call"]
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -47,9 +84,6 @@ def test_flash_kernel_wide_head_dim():
     want = blockwise_attention(q, k, v, 32, causal=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
-    from stochastic_gradient_push_tpu.ops.flash_attention import (
-        flash_attention_backward)
-
     out, lse = flash_attention_forward(q, k, v, causal=True, block_q=32,
                                        block_k=32, interpret=True,
                                        return_lse=True)
@@ -122,10 +156,7 @@ def test_flash_kernel_bf16(qkv):
 
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("block", [16, 32])
-def test_flash_backward_kernels_match_oracle(qkv, causal, block):
-    from stochastic_gradient_push_tpu.ops.flash_attention import (
-        flash_attention_backward)
-
+def test_flash_backward_kernels_match_oracle(qkv, causal, block, backward):
     q, k, v = qkv
     out, lse = flash_attention_forward(q, k, v, causal=causal,
                                        block_q=block, block_k=block,
@@ -144,10 +175,7 @@ def test_flash_backward_kernels_match_oracle(qkv, causal, block):
 
 
 @pytest.mark.parametrize("block_q,block_k", [(16, 32), (32, 16)])
-def test_flash_backward_mixed_block_sizes(qkv, block_q, block_k):
-    from stochastic_gradient_push_tpu.ops.flash_attention import (
-        flash_attention_backward)
-
+def test_flash_backward_mixed_block_sizes(qkv, block_q, block_k, backward):
     q, k, v = qkv
     out, lse = flash_attention_forward(q, k, v, causal=True,
                                        block_q=block_q, block_k=block_k,
@@ -174,3 +202,67 @@ def test_forward_lse_matches_reference(qkv):
     want = jax.scipy.special.logsumexp(s, axis=-1)
     np.testing.assert_allclose(np.asarray(lse), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["fp32", "bf16"])
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("block_q,block_k", [(16, 16), (64, 64), (16, 32),
+                                             (32, 16)])
+def test_fused_backward_equals_the_pair(qkv, dtype, causal, block_q,
+                                        block_k):
+    """The fused kernel is the pair's arithmetic in the pair's order (dq
+    over k-blocks ascending, dk/dv over q-blocks ascending): the same
+    bits, whether dq accumulates in scratch (bf16) or in its own block
+    (fp32)."""
+    q, k, v = (x.astype(dtype) for x in qkv)
+    out, lse = flash_attention_forward(q, k, v, causal=causal,
+                                       block_q=block_q, block_k=block_k,
+                                       interpret=True, return_lse=True)
+    rng = np.random.default_rng(5)
+    do = jnp.asarray(rng.normal(size=(B, H, T, D)), dtype)
+    flat = [x.reshape(B * H, T, -1) for x in (q, k, v, do)]
+    rows = [lse.reshape(B * H, T, 1),
+            jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                    -1).reshape(B * H, T, 1)]
+    fused = fa._backward_fused(*flat, *rows, causal, block_q, block_k, True)
+    pair = fa._backward_pair(*flat, *rows, causal, block_q, block_k, True)
+    for got, want in zip(fused, pair):
+        assert got.dtype == want.dtype == dtype
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+
+
+def test_fused_backward_shape_rule():
+    """Fused while dq for the whole sequence fits the VMEM budget, the
+    pair beyond; ``t`` and ``d`` decide, nothing else."""
+    assert fused_backward_fits(T, D)
+    assert fused_backward_fits(1024, 64)          # the flagship LM
+    assert fused_backward_fits(8192, 64)
+    assert fused_backward_fits(8192, 128)
+    assert not fused_backward_fits(16384, 64)
+    assert not fused_backward_fits(32768, 64)     # a 32k ring shard
+    assert not fused_backward_fits(1024, 256)     # wider than a register
+    one, two = [names.KERNEL_FLASH_BWD], [names.KERNEL_FLASH_DQ,
+                                          names.KERNEL_FLASH_DKV]
+    for dtype in (jnp.bfloat16, jnp.float32):
+        for heads in (1, 4):
+            assert _backward_kernels((1, heads, 8192, 64), dtype) == one
+            assert _backward_kernels((1, heads, 16384, 64), dtype) == two
+        assert _backward_kernels((2, 2, 1024, 128), dtype) == one
+        assert _backward_kernels((2, 2, 1024, 256), dtype) == two
+
+
+def test_the_benchmark_reads_the_fused_backward_by_its_name():
+    """``flash_bwd_ms`` is a pattern on the custom call's name: it must
+    match what this module calls its fused kernel, with or without the
+    compiler's ``.N``, and none of the other flash kernels."""
+    path = os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                        "layer_metrics", "flash_bwd_ms.json")
+    with open(path) as f:
+        pattern = re.compile(json.load(f)["params"]["pattern"])
+    assert pattern.search(names.KERNEL_FLASH_BWD)
+    assert pattern.search(names.KERNEL_FLASH_BWD + ".23")
+    for other in (names.KERNEL_FLASH_FWD, names.KERNEL_FLASH_DQ,
+                  names.KERNEL_FLASH_DKV, names.KERNEL_FLASH_BWD + "_x.1"):
+        assert not pattern.search(other)
