@@ -105,10 +105,13 @@ def build(cell, seed: int) -> Job:
             channels=channels)
     else:
         flops = None    # no required-operations function: no mfu_pct
-    def reference_check(state):
+    def reference_check(state, control=None):
         """Eight seeded images through the program's model in training
         mode (its compute dtype, batch statistics) and through the plain
-        float32 reference, on rank 0's de-biased weights; one program."""
+        float32 reference, on rank 0's de-biased weights; one program.
+        With ``control`` (an operand rounding, ``compare.rounded_to``) the
+        reference computed in that lower precision stands in the
+        program's place."""
         from benchmark.reference import compare, resnet as plain
 
         stages = tuple(RESNETS[args.model].keywords["stage_sizes"])
@@ -120,6 +123,9 @@ def build(cell, seed: int) -> Job:
             images = images[0, :8]
             with jax.default_matmul_precision("highest"):
                 theirs = plain.resnet_logits(z, images, stages)
+                if control is not None:
+                    return plain.resnet_logits(z, images, stages,
+                                               control), theirs
             ours, _ = model.apply(
                 {"params": z, "batch_stats": one(batch_stats)}, images,
                 train=True, mutable=["batch_stats"])
@@ -128,10 +134,8 @@ def build(cell, seed: int) -> Job:
         ours, theirs = both(state.params, state.gossip, state.batch_stats,
                             batches[0][0])
         labels = batches[0][1][0, :8]
-        return compare.compare(
-            ours, theirs,
-            lambda logits: plain.classification_loss(logits, labels),
-            cell.config["reference"])
+        return compare.compare(ours, theirs, plain.classification_loss,
+                               labels, cell.config["reference"])
 
     return Job(
         reference_check=(reference_check if "reference" in cell.config

@@ -159,10 +159,13 @@ def build(cell, seed: int) -> Job:
     shape = dict(n_layers=args.n_layers, d_model=args.d_model,
                  d_ff=args.d_ff, vocab=args.vocab_size,
                  seq_len=args.seq_len)
-    def reference_check(state):
-        """Two seeded sequences through the program's model (its compute
-        dtype, its attention) and through the plain float32 reference, on
-        rank 0's de-biased weights; one program."""
+    def reference_check(state, control=None):
+        """Two seeded sequences (all there are, where the batch holds
+        fewer) through the program's model (its compute dtype, its
+        attention) and through the plain float32 reference, on rank 0's
+        de-biased weights; one program.  With ``control`` (an operand
+        rounding, ``compare.rounded_to``) the reference computed in that
+        lower precision stands in the program's place."""
         from benchmark.reference import compare, lm as plain
 
         @jax.jit
@@ -172,13 +175,15 @@ def build(cell, seed: int) -> Job:
             tokens = tokens[0, :2]
             with jax.default_matmul_precision("highest"):
                 theirs = plain.lm_logits(z, tokens, args.n_heads)
+                if control is not None:
+                    return plain.lm_logits(z, tokens, args.n_heads,
+                                           operand=control), theirs
             return model.apply({"params": z}, tokens), theirs
 
         ours, theirs = both(state.params, state.gossip, batches[0][0])
         targets = batches[0][1][0, :2]
-        return compare.compare(
-            ours, theirs, lambda logits: plain.lm_loss(logits, targets),
-            cell.config["reference"])
+        return compare.compare(ours, theirs, plain.lm_loss, targets,
+                               cell.config["reference"])
 
     return Job(
         reference_check=(reference_check if "reference" in cell.config

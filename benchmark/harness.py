@@ -84,10 +84,13 @@ def _fetch_loss(metrics) -> np.ndarray:
     return np.asarray(metrics["loss"], np.float64).reshape(-1)
 
 
-def run_window(job, seconds: float, trace_dir: str | None):
-    """The measured loop.  Returns per-step host clocks ``[steps, 5]``
-    (start, picked, dispatched, fenced, fetched), per-step per-rank losses
-    and whether a trace was taken."""
+def run_window(job, seconds: float, trace_dir: str | None,
+               min_steps: int = 1):
+    """The measured loop: steps until ``seconds`` have passed and, where a
+    test asks for it, at least ``min_steps`` are done (``run.py`` never
+    does: a run's window is its seconds).  Returns per-step host clocks
+    ``[steps, 5]`` (start, picked, dispatched, fenced, fetched), per-step
+    per-rank losses and whether a trace was taken."""
     import jax
     from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
@@ -124,7 +127,7 @@ def run_window(job, seconds: float, trace_dir: str | None):
         clocks.append((t_start, t_pick, t_dispatch, t_fence, t_fetch))
         n += 1
         t_start = t_fetch
-        if t_fetch - t0 >= seconds:
+        if t_fetch - t0 >= seconds and n >= min_steps:
             break
     if traced and n <= TRACE_SKIP + TRACE_STEPS:
         jax.profiler.stop_trace()
@@ -182,6 +185,19 @@ def device_info(job) -> tuple[dict, list]:
              "memory_peak_bytes": peak}, stats)
 
 
+def drop_step(job) -> None:
+    """Unload the step: the memory its program reserves for temporaries
+    goes back to the allocator.  The window is over; nothing calls it
+    again."""
+    import gc
+
+    import jax
+
+    job.step = None
+    jax.clear_caches()
+    gc.collect()
+
+
 def _set_up(root, cell, seed, process_start, compiles, checks):
     """Everything before the first timed step; returns the job, the
     warm-up steps' losses and the seconds each phase took."""
@@ -218,8 +234,6 @@ def _set_up(root, cell, seed, process_start, compiles, checks):
     if cell.chips > 1:
         checks["placement"] = _placement(job, compiled)
     checks["ps_weight_error_before"] = _ps_weight_error(job)
-    if job.reference_check is not None:
-        checks["reference"] = job.reference_check(job.state)
     phase("checks_before_window")
     checks["setup_phases_s"] = phases
     return job, warm_up
@@ -251,6 +265,29 @@ def _verdicts(cell, job, checks, values, trajectory) -> dict:
         verdicts["step_moves_state_between_chips"] = \
             where["collective_permutes"] > 0
     return verdicts
+
+
+def _compared(job, checks, values) -> dict:
+    """Each number ``_verdicts`` holds against a limit, beside that limit:
+    what the driver's record keeps of a run that is not correct."""
+    first = checks["loss_first"]
+    out = {
+        "first_loss_off_random": [abs(first - job.initial_loss)
+                                  / job.initial_loss,
+                                  INITIAL_LOSS_TOLERANCE],
+        "loss_at_n_under_first": [values.get("loss_at_n"), first],
+        "ps_weight_error": [max((e for e in (
+            checks["ps_weight_error_before"],
+            checks["ps_weight_error_after"]) if e is not None),
+            default=None), PS_WEIGHT_TOLERANCE],
+        "compiled_in_window": [checks["compilations_in_window"], 0],
+    }
+    if "reference" in checks:
+        ref = checks["reference"]
+        out["logit_error"] = [ref["logit_error"], ref["logit_tolerance"]]
+        out["loss_error"] = [ref["loss_error"], ref["loss_tolerance"]]
+    return {name: {"value": v, "limit": limit}
+            for name, (v, limit) in out.items()}
 
 
 def _per_layer(root, cell, reading: Reading, trace_dir, device, log) -> dict:
@@ -288,10 +325,12 @@ def _per_layer(root, cell, reading: Reading, trace_dir, device, log) -> dict:
 
 
 def run_cell(root: str, workload: str, seed: int, seconds: float,
-             trace: bool, process_start: float, log=print) -> dict:
+             trace: bool, process_start: float, log=print,
+             min_steps: int = 1) -> dict:
     """Run one cell on whatever backend JAX has (``run.py`` refuses
     anything but the cell's TPU chips before it gets here) and return the
-    result line as a dict."""
+    result line as a dict.  ``min_steps`` is for the tests, whose machine's
+    speed must not decide how far a toy window gets."""
     cell = spec.load_cell(root, workload)
     checks: dict[str, tp.Any] = {}
     with CompileCounter() as compiles:
@@ -305,7 +344,7 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
             trace_dir = os.path.join(root, OUT_DIR, "trace", workload)
             shutil.rmtree(trace_dir, ignore_errors=True)
         clocks, losses, traced, window_s = run_window(
-            job, seconds, trace_dir)
+            job, seconds, trace_dir, min_steps)
         checks["compilations_in_window"] = compiles.count - built
     log(f"{workload}: {built} programs built in set-up, "
         f"{checks['compilations_in_window']} in the window; set-up "
@@ -340,16 +379,24 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
         loss_expected_first=job.initial_loss,
         ps_weight_error_after=_ps_weight_error(job),
         steps_in_trajectory=len(trajectory), resolved=job.resolved)
+    device, checks["memory_stats"] = device_info(job)
+    log(f"{workload}: memory peak {device['memory_peak_bytes'] / 1e9:.3f} "
+        f"GB; allocator {checks['memory_stats'][0]}; program "
+        f"{checks['program_bytes']}")
+    if job.reference_check is not None:
+        # the plain reference, once the window has closed and the memory
+        # peak has been read, on the state the window left, the step
+        # unloaded: outside set-up, and with room for its own buffers
+        # (beside the t8192 step's temporaries 1.6 GB of logits have none)
+        clock = time.time()
+        drop_step(job)
+        checks["reference"] = job.reference_check(job.state)
+        checks["reference"]["seconds"] = time.time() - clock
     verdicts = checks["verdicts"] = _verdicts(cell, job, checks, values,
                                               trajectory)
     for name, ok in verdicts.items():
         if not ok:
             log(f"{workload}: CHECK FAILED: {name}")
-
-    device, checks["memory_stats"] = device_info(job)
-    log(f"{workload}: memory peak {device['memory_peak_bytes'] / 1e9:.3f} "
-        f"GB; allocator {checks['memory_stats'][0]}; program "
-        f"{checks['program_bytes']}")
     result = {"correct": all(verdicts.values()), "attempted": steps,
               "failed": int(np.sum(~np.all(np.isfinite(losses), axis=1)))}
 
@@ -376,7 +423,8 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
             for m in cell.end_to_end if m["name"] in values}
     result.update(device=device, checks=checks, workload=workload, seed=seed,
                   end_to_end_of_this_run={**values,
-                                          "step_walls_ms": quantiles})
+                                          "step_walls_ms": quantiles},
+                  compared=_compared(job, checks, values))
     return result
 
 
@@ -410,4 +458,12 @@ def _breakdown(reading: Reading) -> dict:
 
 
 def print_result(result: dict) -> None:
+    """The line the driver reads, last on standard output, its ``compared``
+    key last in it; and the same numbers, last on standard error."""
+    import sys
+
     print(json.dumps(result, default=str), flush=True)
+    for name, c in result["compared"].items():
+        print(f"compared {name}: {c['value']} limit {c['limit']}",
+              file=sys.stderr)
+    sys.stderr.flush()

@@ -140,26 +140,21 @@ def op_names(xplane_path: str) -> dict[str, str]:
 # -- the reduction ----------------------------------------------------------
 
 def self_seconds(events) -> dict[str, float]:
-    """Seconds by operation (keyed by the whole instruction), a nested
-    operation's time taken out of the ``while`` or ``conditional`` that
-    holds it.  As ``trace_reduce.self_seconds``, but on whole picoseconds:
-    an event's end is its start plus its duration in floating point, so
-    the end of one operation can read a hair after the start of the next,
-    which that function then takes for nested and subtracts (2-4 % of the
-    busy time on the chip's traces, PR 26)."""
-    out: dict[str, int] = {}
-    stack: list[tuple[int, str]] = []        # (end, key) of open operations
-    for start, end, key in sorted(
-            ((round(e.start * 1e12), round(e.end * 1e12),
-              e.detail or e.name) for e in events),
-            key=lambda row: (row[0], -row[1])):
-        while stack and stack[-1][0] <= start:
-            stack.pop()
-        if stack:
-            out[stack[-1][1]] -= end - start
-        out[key] = out.get(key, 0) + end - start
-        stack.append((end, key))
-    return {key: ps * 1e-12 for key, ps in out.items()}
+    """``trace_reduce.self_seconds`` keyed by the whole instruction, which
+    is what ``op_names`` is keyed by."""
+    return tr.self_seconds(events, key=lambda e: e.detail or e.name)
+
+
+def instruction_seconds(trace: tr.Trace, window) -> list[dict[str, float]]:
+    """Device self seconds by instruction over ``window``, one table a
+    chip; reduced once a trace and window, and kept on the trace."""
+    kept = getattr(trace, "program_self_seconds", None)
+    if kept is None or kept[0] != window:
+        kept = trace.program_self_seconds = (window, [
+            self_seconds([e for e in events
+                          if e.end > window[0] and e.start < window[1]])
+            for events in trace.devices.values()])
+    return kept[1]
 
 
 def phase_seconds(trace: tr.Trace, window, names: dict[str, str]):
@@ -169,18 +164,45 @@ def phase_seconds(trace: tr.Trace, window, names: dict[str, str]):
     chips = len(trace.devices)
     phases: dict[str, float] = {}
     ops: dict[str, dict[str, float]] = {}
-    for events in trace.devices.values():
-        inside = [e for e in events
-                  if e.end > window[0] and e.start < window[1]]
-        for instruction, seconds in self_seconds(inside).items():
+    for table in instruction_seconds(trace, window):
+        for instruction, seconds in table.items():
             phase = phase_of(names.get(instruction, ""))
             phases[phase] = phases.get(phase, 0.0) + seconds / chips
-            table = ops.setdefault(phase, {})
+            by_name = ops.setdefault(phase, {})
             name = tr.label(instruction)
-            table[name] = table.get(name, 0.0) + seconds / chips
+            by_name[name] = by_name.get(name, 0.0) + seconds / chips
     if not set(phases) - {UNSCOPED}:
         return None
     return phases, {p: tr.top(t, HEAVIEST) for p, t in ops.items()}
+
+
+def scope_seconds(trace: tr.Trace, window, names: dict[str, str],
+                  pattern: str) -> float | None:
+    """Device self seconds over ``window``, mean over the chips, of the
+    operations whose ``op_name`` matches ``pattern``: a
+    ``jax.named_scope`` wherever it stands in the path, forward
+    (``jvp(scope)``) and transposed alike.  ``None`` when no operation of
+    the window matches."""
+    rx = re.compile(pattern)
+    found = [seconds for table in instruction_seconds(trace, window)
+             for instruction, seconds in table.items()
+             if rx.search(names.get(instruction, ""))]
+    return sum(found) / len(trace.devices) if found else None
+
+
+def _op_names(reading) -> dict[str, str]:
+    """The run's instruction -> ``op_name`` table, read once from the
+    cell's own trace and kept on it."""
+    trace = reading.trace
+    if not hasattr(trace, "program_op_names"):
+        root = os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))))
+        try:
+            trace.program_op_names = op_names(tr.find_xplane(os.path.join(
+                root, harness.OUT_DIR, "trace", reading.cell.name)))
+        except (OSError, ValueError, IndexError):
+            trace.program_op_names = {}
+    return trace.program_op_names
 
 
 def _phases(reading):
@@ -190,13 +212,7 @@ def _phases(reading):
         return None
     if not hasattr(trace, "program_phases"):
         clock = time.perf_counter()
-        root = os.path.dirname(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))))
-        try:
-            names = op_names(tr.find_xplane(os.path.join(
-                root, harness.OUT_DIR, "trace", reading.cell.name)))
-        except (OSError, ValueError, IndexError):
-            names = {}
+        names = _op_names(reading)
         found = phase_seconds(trace, reading.window, names)
         trace.program_phases = found[0] if found else None
         cell, steps = reading.cell.name, reading.traced_steps
@@ -217,6 +233,20 @@ def phase_ms(reading):
         return None
     return phases.get(reading.params["phase"], 0.0) * 1e3 \
         / reading.traced_steps
+
+
+def scope_ms(reading):
+    """Device self time of the operations under the scope
+    ``params.pattern`` names (a regular expression on ``op_name``): a new
+    ``jax.named_scope`` of the program becomes a per-layer metric by a
+    ``layer_metrics/*.json`` file alone."""
+    trace = reading.trace
+    if trace is None or not trace.devices or not reading.traced_steps:
+        return None
+    seconds = scope_seconds(trace, reading.window, _op_names(reading),
+                            reading.params["pattern"])
+    return None if seconds is None else \
+        seconds * 1e3 / reading.traced_steps
 
 
 def kernel_ms(reading):

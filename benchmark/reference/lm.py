@@ -27,31 +27,73 @@ def _rope(x, base=10000.0):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
 
 
-def _attention(x, p, n_heads):
+Q_BLOCK = 1024      # query rows whose scores are held at once
+
+
+def _same(a):
+    return a
+
+
+def _attention(x, p, n_heads, q_block=Q_BLOCK, operand=_same):
+    """Causal softmax attention.  The scores of ``q_block`` query rows
+    against every key are held at a time (``[B, H, q_block, T]``; whole,
+    ``[16, 8192, 8192]`` float32 is 4.3 GB a layer): each row's softmax is
+    over its own keys, so the blocks are the whole form's rows, computed
+    one block after another.  ``q_block=None``, a sequence no longer than
+    a block or one it does not divide: all rows at once."""
     b, t, e = x.shape
     d = e // n_heads
-    heads = lambda w: (x @ w["kernel"]).reshape(b, t, n_heads, d) \
-        .transpose(0, 2, 1, 3)
+    heads = lambda w: (operand(x) @ operand(w["kernel"])) \
+        .reshape(b, t, n_heads, d).transpose(0, 2, 1, 3)
     q, k, v = _rope(heads(p["q"])), _rope(heads(p["k"])), heads(p["v"])
-    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * d ** -0.5
-    causal = jnp.tril(jnp.ones((t, t), bool))
-    weights = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
-    out = jnp.einsum("bhqk,bhkd->bhqd", weights, v)
-    return out.transpose(0, 2, 1, 3).reshape(b, t, e) @ p["o"]["kernel"]
+    q, k, v = operand(q), operand(k), operand(v)
+
+    def rows(q_rows, first):
+        # q_rows: [B, H, n, D], the queries at positions first .. first+n-1
+        scores = jnp.einsum("bhqd,bhkd->bhqk", q_rows, k) * d ** -0.5
+        at = first + jnp.arange(q_rows.shape[2])
+        causal = at[:, None] >= jnp.arange(t)[None]
+        weights = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf),
+                                 axis=-1)
+        return jnp.einsum("bhqk,bhkd->bhqd", operand(weights), v)
+
+    if q_block is None or t <= q_block or t % q_block:
+        out = rows(q, 0)
+    else:
+        blocks = q.reshape(b, n_heads, t // q_block, q_block, d)
+        out = jax.lax.map(lambda a: rows(*a), (
+            blocks.transpose(2, 0, 1, 3, 4), jnp.arange(0, t, q_block)))
+        out = out.transpose(1, 2, 0, 3, 4).reshape(b, n_heads, t, d)
+    return operand(out.transpose(0, 2, 1, 3).reshape(b, t, e)) \
+        @ operand(p["o"]["kernel"])
 
 
-def lm_logits(params, tokens, n_heads: int):
-    """``[B, T]`` tokens to ``[B, T, vocab]`` float32 logits."""
+def lm_logits(params, tokens, n_heads: int, q_block=Q_BLOCK, operand=_same):
+    """``[B, T]`` tokens to ``[B, T, vocab]`` float32 logits.  ``operand``
+    is applied to both operands of every matrix product: the identity for
+    the reference, a rounding to a lower precision for its control
+    (``compare.rounded_to``)."""
     params = jax.tree.map(lambda a: jnp.asarray(a, jnp.float32), params)
     x = params["embed"]["embedding"][tokens]
     n_layers = sum(1 for k in params if k.startswith("block_"))
-    for i in range(n_layers):
-        p = params[f"block_{i}"]
-        x = x + _attention(_layer_norm(x, p["ln1"]), p["attn"], n_heads)
-        h = _layer_norm(x, p["ln2"]) @ p["up"]["kernel"] + p["up"]["bias"]
+
+    def block(x, p):
+        x = x + _attention(_layer_norm(x, p["ln1"]), p["attn"], n_heads,
+                           q_block, operand)
+        h = operand(_layer_norm(x, p["ln2"])) @ operand(p["up"]["kernel"]) \
+            + p["up"]["bias"]
         h = jax.nn.gelu(h, approximate=True)
-        x = x + h @ p["down"]["kernel"] + p["down"]["bias"]
-    return _layer_norm(x, params["ln_f"]) @ params["lm_head"]["kernel"]
+        return x + operand(h) @ operand(p["down"]["kernel"]) \
+            + p["down"]["bias"], None
+
+    # the blocks are alike, so one is compiled and run over the stack of
+    # their weights: unrolled, 24 float32 blocks at precision highest are
+    # a 240 MB program that takes the compiler half a minute
+    x, _ = jax.lax.scan(block, x, jax.tree.map(
+        lambda *a: jnp.stack(a),
+        *(params[f"block_{i}"] for i in range(n_layers))))
+    return operand(_layer_norm(x, params["ln_f"])) \
+        @ operand(params["lm_head"]["kernel"])
 
 
 def lm_loss(logits, targets):

@@ -13,10 +13,14 @@ import jax.numpy as jnp
 from jax import lax
 
 
-def _conv(x, kernel, stride=1, pad=0):
+def _same(a):
+    return a
+
+
+def _conv(x, kernel, stride=1, pad=0, operand=_same):
     padding = pad if isinstance(pad, str) else [(pad, pad), (pad, pad)]
     return lax.conv_general_dilated(
-        x, kernel, (stride, stride), padding,
+        operand(x), operand(kernel), (stride, stride), padding,
         dimension_numbers=("NHWC", "HWIO", "NHWC"))
 
 
@@ -26,24 +30,27 @@ def _batch_norm(x, p, eps=1e-5):
     return (x - mean) / jnp.sqrt(var + eps) * p["scale"] + p["bias"]
 
 
-def _bottleneck(x, p, stride):
-    y = jax.nn.relu(_batch_norm(_conv(x, p["Conv_0"]["kernel"]),
-                                p["BatchNorm_0"]))
-    y = jax.nn.relu(_batch_norm(
-        _conv(y, p["Conv_1"]["kernel"], stride, "SAME"), p["BatchNorm_1"]))
-    y = _batch_norm(_conv(y, p["Conv_2"]["kernel"]), p["BatchNorm_2"])
+def _bottleneck(x, p, stride, operand):
+    conv = lambda a, name, *how: _conv(a, p[name]["kernel"], *how,
+                                       operand=operand)
+    y = jax.nn.relu(_batch_norm(conv(x, "Conv_0"), p["BatchNorm_0"]))
+    y = jax.nn.relu(_batch_norm(conv(y, "Conv_1", stride, "SAME"),
+                                p["BatchNorm_1"]))
+    y = _batch_norm(conv(y, "Conv_2"), p["BatchNorm_2"])
     if "conv_proj" in p:
-        x = _batch_norm(_conv(x, p["conv_proj"]["kernel"], stride),
-                        p["norm_proj"])
+        x = _batch_norm(conv(x, "conv_proj", stride), p["norm_proj"])
     return jax.nn.relu(x + y)
 
 
-def resnet_logits(params, images, stage_sizes=(3, 4, 6, 3)):
+def resnet_logits(params, images, stage_sizes=(3, 4, 6, 3), operand=_same):
     """``[B, H, W, C]`` images to ``[B, classes]`` float32 logits, batch
-    statistics in every BatchNorm."""
+    statistics in every BatchNorm.  ``operand`` is applied to both operands
+    of every convolution and of the classifier's product: the identity for
+    the reference, a rounding to a lower precision for its control
+    (``compare.rounded_to``)."""
     params = jax.tree.map(lambda a: jnp.asarray(a, jnp.float32), params)
     x = _conv(jnp.asarray(images, jnp.float32),
-              params["conv_init"]["kernel"], 2, 3)
+              params["conv_init"]["kernel"], 2, 3, operand)
     x = jax.nn.relu(_batch_norm(x, params["bn_init"]))
     x = lax.reduce_window(x, -jnp.inf, lax.max, (1, 3, 3, 1), (1, 2, 2, 1),
                           [(0, 0), (1, 1), (1, 1), (0, 0)])
@@ -51,10 +58,12 @@ def resnet_logits(params, images, stage_sizes=(3, 4, 6, 3)):
     for stage, count in enumerate(stage_sizes):
         for j in range(count):
             stride = 2 if stage > 0 and j == 0 else 1
-            x = _bottleneck(x, params[f"Bottleneck_{block}"], stride)
+            x = _bottleneck(x, params[f"Bottleneck_{block}"], stride,
+                            operand)
             block += 1
     x = x.mean((1, 2))
-    return x @ params["fc"]["kernel"] + params["fc"]["bias"]
+    return operand(x) @ operand(params["fc"]["kernel"]) \
+        + params["fc"]["bias"]
 
 
 def classification_loss(logits, labels):

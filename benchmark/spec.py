@@ -3,7 +3,8 @@
 ``<root>/BENCHMARK.json`` lists cells, configurations and metrics; each
 name resolves to a file of its own under ``<root>/benchmark/``:
 
-* ``configs/<config>.json``        the model's sizes and its builder
+* ``configs/<config>.json``        the model's sizes and its builder; what is
+  cut from the source under ``published`` and ``deployment`` (``check_cut``)
 * ``workloads/<cell>.json``        the program's flags for the cell, ``loss_n``
 * ``traffic/<traffic>.json``       parameters of the one batch generator
 * ``layer_metrics/<metric>.json``  the reader a per-layer metric names
@@ -77,6 +78,42 @@ class Cell:
         return self.config["builder"]
 
 
+def _size(value):
+    """What a cut may only lower: a number itself, a list (a layer
+    pattern) or a group by its length."""
+    return value if isinstance(value, (int, float)) else len(value)
+
+
+def check_cut(config_entry: dict, config: dict) -> None:
+    """The contract for a cut configuration.  Every key the entry lists
+    under ``reduced`` is a key of the configuration's file; the file states
+    under ``published`` the source's value of exactly those keys, none
+    smaller than what is held here; and a cut names the ``deployment`` it
+    stands for.  A configuration with nothing cut keeps ``reduced: []`` and
+    no ``published``.  Raises ``ValueError`` naming the key."""
+    name, reduced = config_entry["name"], list(config_entry["reduced"])
+    published = config.get("published", {})
+    for key in reduced:
+        if key not in config:
+            raise ValueError(f"configuration {name}: reduced key {key!r} "
+                             f"is no key of {config_entry['file']}")
+        if key not in published:
+            raise ValueError(f"configuration {name}: reduced key {key!r} "
+                             "has no value under 'published'")
+        if _size(config[key]) > _size(published[key]):
+            raise ValueError(
+                f"configuration {name}: {key!r} holds {config[key]}, above "
+                f"the published {published[key]}: a cut only takes away")
+    extra = sorted(set(published) - set(reduced))
+    if extra or ("published" in config and not reduced):
+        raise ValueError(f"configuration {name}: 'published' states "
+                         f"{extra}, which 'reduced' does not list: a "
+                         "configuration with nothing cut has no 'published'")
+    if reduced and not str(config.get("deployment", "")).strip():
+        raise ValueError(f"configuration {name}: reduced {reduced} needs a "
+                         "'deployment' that says what the cut stands for")
+
+
 def _in_cell(metric: dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
@@ -87,6 +124,7 @@ def load_cell(root: str, name: str) -> Cell:
     config_entry = _by_name(bench["configs"], entry["config"],
                             "configuration")
     config = _load_json(os.path.join(root, config_entry["file"]))
+    check_cut(config_entry, config)
     cell_file = _load_json(data_path(root, "workloads", name))
     per_layer = []
     for m in bench["per_layer"]:

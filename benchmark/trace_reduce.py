@@ -212,12 +212,22 @@ def collective_intervals(events, in_flight=()) -> list[Interval]:
     return union(out)
 
 
+def _picoseconds(seconds: float) -> int:
+    """An event's end is its start plus its duration in floating point,
+    so the end of one operation can read 1e-17 s after the start of the
+    next, which follows it back to back.  On whole picoseconds (the
+    trace's own unit) the two are equal, and nothing reads as nested that
+    is not."""
+    return round(seconds * 1e12)
+
+
 def innermost(events) -> list[Event]:
     """The operations that hold no other operation: a ``conditional`` or a
     ``while`` spans its body and is not itself work."""
     ordered = sorted(events, key=lambda ev: (ev.start, -ev.end))
     return [e for e, after in zip(ordered, ordered[1:] + [None])
-            if after is None or after.start >= e.end]
+            if after is None
+            or _picoseconds(after.start) >= _picoseconds(e.end)]
 
 
 def exposed_collective_intervals(events, in_flight=()) -> list[Interval]:
@@ -236,21 +246,24 @@ def matching_seconds(events, pattern: str, window: Interval) -> float:
         *window))
 
 
-def self_seconds(events) -> dict[str, float]:
-    """Seconds by operation (named by :func:`label`), a nested
-    operation's time taken out of the one that holds it (a ``while`` and
-    its body)."""
-    out: dict[str, float] = {}
-    stack: list[tuple[Event, str]] = []
-    for e in sorted(events, key=lambda ev: (ev.start, -ev.end)):
-        while stack and stack[-1][0].end <= e.start:
+def self_seconds(events, key=None) -> dict[str, float]:
+    """Seconds by operation (named by ``key(event)``; by default by
+    :func:`label`), a nested operation's time taken out of the one that
+    holds it (a ``while`` and its body).  Counted on whole picoseconds, so
+    the parts add up to the busy time."""
+    key = key or (lambda e: label(e.detail) if e.detail else e.name)
+    out: dict[str, int] = {}
+    stack: list[tuple[int, str]] = []        # (end, name) of open operations
+    for start, end, name in sorted(
+            ((_picoseconds(e.start), _picoseconds(e.end), key(e))
+             for e in events), key=lambda row: (row[0], -row[1])):
+        while stack and stack[-1][0] <= start:
             stack.pop()
         if stack:
-            out[stack[-1][1]] -= e.seconds
-        name = label(e.detail) if e.detail else e.name
-        out[name] = out.get(name, 0.0) + e.seconds
-        stack.append((e, name))
-    return out
+            out[stack[-1][1]] -= end - start
+        out[name] = out.get(name, 0) + end - start
+        stack.append((end, name))
+    return {name: ps * 1e-12 for name, ps in out.items()}
 
 
 def idle_gaps(events, window: Interval) -> list[Interval]:

@@ -11,6 +11,17 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 
 TOY_CELL = "toy_cnn_sgp_w2"
 TOY_LM_CELL = "toy_lm_sgp_w1"
+TOY_CUT_CELL = "toy_lm_cut_sgp_w1"
+TOY_LM = {"builder": "lm_trainer", "n_embd": 32, "n_layer": 2,
+          "n_head": 4, "n_inner": 64, "vocab_size": 64,
+          "n_positions": 32, "precision": "fp32",
+          "reference": {"logit_tolerance": 1e-4, "loss_tolerance": 1e-4}}
+# a configuration cut the way a model_config PR cuts one: depth and the
+# vocabulary's rows held here, the source's values beside them
+TOY_LM_CUT = {**TOY_LM, "published": {"n_layer": 8, "vocab_size": 512},
+              "deployment": "four pipeline stages of two layers, the "
+                            "vocabulary split over eight chips"}
+TOY_LM_CUT_REDUCED = ["n_layer", "vocab_size"]
 
 
 def _write(path, obj):
@@ -22,7 +33,10 @@ def _write(path, obj):
             json.dump(obj, f)
 
 
-def make_toy_root(root: str) -> str:
+def make_toy_root(root: str, cut_config: dict = TOY_LM_CUT,
+                  cut_reduced: list = TOY_LM_CUT_REDUCED) -> str:
+    """``cut_config`` and ``cut_reduced`` are the cut toy configuration's
+    file and its entry's ``reduced``: the tests hand in malformed ones."""
     data = os.path.join(root, "benchmark")
     shutil.copytree(os.path.join(REPO, "benchmark"), data,
                     ignore=shutil.ignore_patterns("__pycache__"))
@@ -36,11 +50,10 @@ def make_toy_root(root: str) -> str:
     _write(os.path.join(data, "workloads", TOY_CELL + ".json"),
            {"flags": ["--dataset", "synthetic", "--lr", "0.5"],
             "itr_per_epoch": 100, "loss_n": 40})
-    _write(os.path.join(data, "configs", "toy_lm.json"),
-           {"builder": "lm_trainer", "n_embd": 32, "n_layer": 2,
-            "n_head": 4, "n_inner": 64, "vocab_size": 64,
-            "n_positions": 32, "precision": "fp32",
-            "reference": {"logit_tolerance": 1e-4, "loss_tolerance": 1e-4}})
+    _write(os.path.join(data, "configs", "toy_lm.json"), TOY_LM)
+    _write(os.path.join(data, "configs", "toy_lm_cut.json"), cut_config)
+    _write(os.path.join(data, "workloads", TOY_CUT_CELL + ".json"),
+           {"flags": ["--lr", "8.0"], "loss_n": 40})
     _write(os.path.join(data, "traffic", "toy_tokens_w1.json"),
            {"kind": "tokens", "ranks": 1, "batch_per_rank": 8,
             "seq_len": 32, "vocab": 64, "zipf_exponent": 1.1,
@@ -64,11 +77,15 @@ def make_toy_root(root: str) -> str:
         {"name": "toy_cnn", "source": "test", "reduced": [], "why": "toy",
          "file": "benchmark/configs/toy_cnn.json"},
         {"name": "toy_lm", "source": "test", "reduced": [], "why": "toy",
-         "file": "benchmark/configs/toy_lm.json"}]
+         "file": "benchmark/configs/toy_lm.json"},
+        {"name": "toy_lm_cut", "source": "test", "reduced": cut_reduced,
+         "why": "toy", "file": "benchmark/configs/toy_lm_cut.json"}]
     bench["workloads"] += [
         {"name": TOY_CELL, "config": "toy_cnn", "traffic": "toy_images_w2",
          "chips": 2, "why": "toy"},
         {"name": TOY_LM_CELL, "config": "toy_lm",
+         "traffic": "toy_tokens_w1", "chips": 1, "why": "toy"},
+        {"name": TOY_CUT_CELL, "config": "toy_lm_cut",
          "traffic": "toy_tokens_w1", "chips": 1, "why": "toy"}]
     # the replicas' spread is read wherever there are several replicas: in
     # the toy cell too, whether or not the benchmark has such a cell today

@@ -11,7 +11,8 @@ import time
 import numpy as np
 import pytest
 
-from bench_toy import REPO, TOY_CELL, TOY_LM_CELL, make_toy_root
+from bench_toy import (REPO, TOY_CELL, TOY_CUT_CELL, TOY_LM_CELL,
+                       make_toy_root)
 from benchmark import harness, spec
 from benchmark.traffic.generate import make_batches
 
@@ -22,6 +23,9 @@ TOKENS = {"kind": "tokens", "ranks": 2, "batch_per_rank": 4, "seq_len": 64,
           "vocab": 101, "zipf_exponent": 1.1, "hidden_states": 4,
           "stay": 0.9, "resident_batches": 3}
 RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+# the toy window is counted in steps, so that the machine's speed does not
+# decide how far it gets: past loss_n (40) and past the traced stretch
+TOY_STEPS = 45
 DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
 
 
@@ -72,17 +76,29 @@ def test_unknown_traffic_kind_is_an_error():
 
 
 @pytest.mark.parametrize("cell,trace", [(TOY_CELL, False), (TOY_CELL, True),
-                                        (TOY_LM_CELL, True)])
+                                        (TOY_LM_CELL, True),
+                                        (TOY_CUT_CELL, False)])
 def test_toy_cell_runs_through_the_harness_and_prints_the_contracts_line(
         toy_root, cell, trace, capsys):
-    result = harness.run_cell(toy_root, cell, 2 ** 31 + 11, 1.0, trace,
-                              time.time())
+    result = harness.run_cell(toy_root, cell, 2 ** 31 + 11, 0.2, trace,
+                              time.time(), min_steps=TOY_STEPS)
     harness.print_result(result)
-    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    printed = capsys.readouterr()
+    line = json.loads(printed.out.strip().splitlines()[-1])
     assert RESULT_KEYS <= set(line) and DEVICE_KEYS <= set(line["device"])
     assert line["correct"] is True, line["checks"]["verdicts"]
-    assert line["failed"] == 0 and line["attempted"] >= 40
+    assert line["failed"] == 0 and line["attempted"] >= TOY_STEPS
     assert line["checks"]["compilations_in_window"] == 0
+    # each number compared beside its limit: last in the line, and the
+    # last lines on standard error
+    assert list(line)[-1] == "compared"
+    assert {"first_loss_off_random", "loss_at_n_under_first",
+            "compiled_in_window"} <= set(line["compared"])
+    assert all(set(c) == {"value", "limit"}
+               for c in line["compared"].values())
+    last = printed.err.strip().splitlines()[-len(line["compared"]):]
+    assert [row.split(":")[0] for row in last] == [
+        "compared " + name for name in line["compared"]]
     loaded = spec.load_cell(toy_root, cell)
     assert line["device"]["count"] == loaded.chips
     # the plain reference joins ``correct`` where the configuration's file
@@ -91,6 +107,14 @@ def test_toy_cell_runs_through_the_harness_and_prints_the_contracts_line(
     if "reference" in loaded.config:
         assert line["checks"]["reference"]["ok"] is True
         assert 0 < line["checks"]["reference"]["logit_error"] < 1e-4
+        assert line["compared"]["logit_error"] == {
+            "value": line["checks"]["reference"]["logit_error"],
+            "limit": 1e-4}
+        # it runs once the window has closed, the memory peak read and
+        # the step unloaded: no part of set-up
+        assert line["checks"]["reference"]["seconds"] > 0
+        assert "reference" not in " ".join(
+            line["checks"]["setup_phases_s"])
     if trace:
         # the per-layer metrics of the cell whose readers found something
         # to read: host clocks and the replicas' spread here, nothing from
@@ -119,6 +143,72 @@ def test_a_window_too_short_to_reach_n_is_not_correct(toy_root):
     assert "loss_at_n" not in result["metrics"]
     assert result["correct"] is False
     assert result["checks"]["verdicts"]["loss_fell"] is False
+
+
+class _Frozen:
+    """The program's step with the timed path broken underneath: it
+    computes its metrics and hands back the state it was given."""
+
+    def __init__(self, step):
+        self.step, self.lower = step, step.lower
+
+    def __call__(self, state, x, y):
+        import jax
+        import jax.numpy as jnp
+
+        kept = jax.tree.map(jnp.copy, state)     # the step donates its state
+        return kept, self.step(state, x, y)[1]
+
+
+def test_a_step_that_returns_its_state_unchanged_is_not_correct(
+        toy_root, monkeypatch):
+    """The rest of a run as it is, the step frozen: nothing is learnt, the
+    loss at n is the first loss, and ``correct`` comes out false."""
+    load_plugin = spec.load_plugin
+
+    def frozen_builder(root, kind, name):
+        module = load_plugin(root, kind, name)
+        if kind != "builders":
+            return module
+
+        def build(cell, seed):
+            job = module.build(cell, seed)
+            job.step = _Frozen(job.step)
+            return job
+        return type("frozen", (), {"build": staticmethod(build)})
+
+    monkeypatch.setattr(spec, "load_plugin", frozen_builder)
+    result = harness.run_cell(toy_root, TOY_LM_CELL, 5, 0.2, False,
+                              time.time(), min_steps=TOY_STEPS)
+    assert result["attempted"] >= TOY_STEPS and result["failed"] == 0
+    verdicts = result["checks"]["verdicts"]
+    assert verdicts["loss_fell"] is False and result["correct"] is False
+    assert verdicts["agrees_with_plain_reference"] is True
+    seen = result["compared"]["loss_at_n_under_first"]
+    assert seen["value"] >= seen["limit"] * (1 - 1e-3)
+
+
+def test_the_control_in_the_next_lower_precision_is_not_correct(toy_root):
+    """The reference computed one precision below the configuration's
+    (bfloat16 for the toy's float32), put in the program's place, on the
+    state some steps of the program's own step leave: the cell's own
+    comparison, with its own tolerance, refuses it on every seed, where
+    it passes the program."""
+    from benchmark import control
+    from benchmark.reference import compare
+
+    assert compare.NEXT_LOWER == {"fp32": "bfloat16", "bf16": "float8_e4m3fn"}
+    for seed in (3, 2 ** 31 + 5, 77):
+        got = control.readings(toy_root, TOY_LM_CELL, seed, steps=5)
+        assert got["program"]["ok"] is True
+        assert got["control"]["ok"] is False
+        # not by a hair: bfloat16 keeps 8 bits of float32's 24
+        assert got["control"]["logit_error"] > \
+            10 * got["control"]["logit_tolerance"]
+        assert got["control"]["logit_error"] > \
+            30 * got["program"]["logit_error"]
+    with pytest.raises(ValueError, match="nothing is compared"):
+        control.readings(toy_root, TOY_CELL, 1, steps=0)
 
 
 def test_run_py_refuses_a_backend_that_is_not_the_cells_tpu_chips():

@@ -224,13 +224,26 @@ def test_nothing_to_read_is_none_and_never_raises(tmp_path, trace):
     assert pt.phase_ms(_reading(str(tmp_path), trace, "fwd")) is None
 
 
-@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_dq", "flash_dkv"])
-def test_the_three_flash_kernels_are_told_apart_by_name(kernel):
+def _kernel_metric(name):
+    with open(spec.data_path(REPO, "layer_metrics", name + "_ms")) as f:
+        return json.load(f)
+
+
+# the pair (a shard beyond 8192 tokens still runs it) has no metric since
+# PR 28: its pattern is given here, so that the pattern reader stays tested
+PAIR = {"reader": "program_trace:kernel_ms",
+        "params": {"pattern": r"^flash_d(q|kv)(\.\d+)?$"}}
+
+
+@pytest.mark.parametrize("kernel,metric,ms", [
+    ("flash_fwd", None, 2e3), ("flash_bwd", None, 1.5e3),
+    ("flash_dq+flash_dkv", PAIR, 7e3)],
+    ids=["flash_fwd", "flash_bwd", "the-pair-inline"])
+def test_the_three_flash_kernels_are_told_apart_by_name(kernel, metric, ms):
     """The metrics' own files give the kernel reader a pattern on the
     custom call's name: each kernel's calls and no other, and nothing
     (not zero) for a program whose kernels carry no name yet."""
-    with open(spec.data_path(REPO, "layer_metrics", kernel + "_ms")) as f:
-        metric = json.load(f)
+    metric = metric or _kernel_metric(kernel)
     assert metric["reader"] == "program_trace:kernel_ms"
     call = ('%{0} = bf16[64,1024,64]{{2,1,0}} custom-call(%q), '
             'custom_call_target="tpu_custom_call"')
@@ -241,15 +254,84 @@ def test_the_three_flash_kernels_are_told_apart_by_name(kernel):
         _event(call.format("flash_dkv.3"), 5.0, 9.0),
         _event(call.format("attn.7"), 9.0, 10.0),
         _event(call.format("flash_fwd_tail.1"), 10.0, 11.0),
+        _event(call.format("flash_bwd.31"), 11.0, 12.5),
     ]
     reading = types.SimpleNamespace(
         trace=tr.Trace({0: events}, [], STEPS), traced_steps=1,
         window=WINDOW, params=metric["params"])
-    got = spec.load_reader(REPO, metric)(reading)
-    assert got == pytest.approx(
-        {"flash_fwd": 2e3, "flash_dq": 3e3, "flash_dkv": 4e3}[kernel])
+    assert spec.load_reader(REPO, metric)(reading) == pytest.approx(ms)
     reading.trace = tr.Trace(
         {0: [_event(call.format("attn.7"), 0.0, 1.0)]}, [], STEPS)
     assert spec.load_reader(REPO, metric)(reading) is None
     reading.trace = None
     assert spec.load_reader(REPO, metric)(reading) is None
+
+
+# -- a scope of the program's own, by a metric file alone ---------------
+
+def _scope_reading(root, trace, pattern):
+    return types.SimpleNamespace(
+        trace=trace, window=WINDOW, params={"pattern": pattern},
+        traced_steps=len(trace.steps) if trace is not None else 0,
+        cell=types.SimpleNamespace(name=CELL))
+
+
+@pytest.mark.parametrize("pattern,seconds", [
+    # forward and transposed alike: FWD 2.5 + BWD 4 + REMAT 1
+    (r"sgp\.forward", 7.5),
+    # a scope inside a scope, wherever it stands in the path
+    (r"/block_0/up/", 7.5), (r"sgp\.gossip\.wire", 1.0),
+    # the conditional's own 3 s, the permute's 2, the codec's 1
+    (r"sgp\.gossip(/|$)", 6.0),
+    (r"sgp\.(pre_step|reduce_grads)", 0.75),
+    (r"rematted_computation", 1.0)])
+def test_scope_seconds_sums_self_time_under_a_pattern(pattern, seconds):
+    trace = tr.Trace({0: CHIP0}, [], STEPS)
+    assert pt.scope_seconds(trace, WINDOW, OPS, pattern) == \
+        pytest.approx(seconds)
+
+
+def test_scope_seconds_is_a_mean_over_chips_and_none_where_nothing_matches():
+    trace = tr.Trace({0: CHIP0, 1: [e for e in CHIP0 if e.detail != REMAT]},
+                     [], STEPS)
+    assert pt.scope_seconds(trace, WINDOW, OPS, "rematted_computation") == \
+        pytest.approx(0.5)                               # (1.0 + 0.0) / 2
+    assert pt.scope_seconds(trace, WINDOW, OPS, r"sgp\.forward") == \
+        pytest.approx(7.0)                               # (7.5 + 6.5) / 2
+    assert pt.scope_seconds(trace, WINDOW, OPS, r"sgp\.scan") is None
+    assert pt.scope_seconds(trace, WINDOW, {}, r"sgp\.forward") is None
+    # an operation the window leaves out is not read
+    assert pt.scope_seconds(trace, (0.0, 8.0), OPS, r"sgp\.health") is None
+
+
+def test_scope_ms_reads_the_cells_own_trace_beside_phase_ms(tmp_path):
+    """A metric file alone: the reader, the pattern, and the trace the run
+    left; it shares the trace's names and self times with ``phase_ms``."""
+    import shutil
+
+    root = str(tmp_path)
+    shutil.copytree(os.path.join(REPO, "benchmark"),
+                    os.path.join(root, "benchmark"),
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    _write_trace(root, CELL, _xplane("/device:TPU:0", OPS))
+    metric = {"reader": "program_trace:scope_ms",
+              "params": {"pattern": r"sgp\.forward"}}
+    reader = spec.load_reader(root, metric)
+    trace = tr.Trace({0: CHIP0}, [], [Event(tr.STEP_NAME, 0.0, 10.0),
+                                      Event(tr.STEP_NAME, 10.0, 20.0)])
+    reading = _scope_reading(root, trace, metric["params"]["pattern"])
+    assert reader(reading) == pytest.approx(7.5e3 / 2)
+    # what the pattern reads is the forward and the backward phase together
+    reading.params = {"phase": "fwd"}
+    fwd = pt.phase_ms(reading)
+    reading.params = {"phase": "bwd"}
+    assert fwd + pt.phase_ms(reading) == pytest.approx(7.5e3 / 2)
+
+
+@pytest.mark.parametrize("trace", [
+    None, tr.Trace({}, [], STEPS), tr.Trace({0: CHIP0}, [], []),
+    tr.Trace({0: CHIP0}, [], STEPS)],
+    ids=["no-trace", "no-chip", "no-step", "no-xplane-to-name-the-ops"])
+def test_scope_ms_with_nothing_to_read_is_none(tmp_path, trace):
+    assert pt.scope_ms(_scope_reading(str(tmp_path), trace,
+                                      r"sgp\.forward")) is None

@@ -99,6 +99,28 @@ def test_self_time_takes_a_nested_operation_out_of_its_parent():
     assert tr.top(by_name, 2) == [["fusion.1", 3.0], [kernel, 2.5]]
 
 
+def test_back_to_back_operations_1e_17_s_apart_are_not_nested():
+    """0.1 + 0.2 reads 5.6e-17 s after 0.3, where the next operation
+    starts: on whole picoseconds the two follow each other, each keeps its
+    own time, both are innermost, and the parts add up to the busy time."""
+    events = [Event("fusion.1", 0.1, 0.1 + 0.2), Event("fusion.2", 0.3, 0.5),
+              Event("while.3", 0.5, 0.9), Event("fusion.4", 0.5, 0.5 + 0.4)]
+    assert 0 < events[0].end - events[1].start < 1e-16      # the hair
+    got = tr.self_seconds(events)
+    assert got["fusion.1"] == pytest.approx(0.2)
+    assert got["fusion.2"] == pytest.approx(0.2)
+    # a body that ends with its while, to the picosecond, is still inside it
+    assert got["while.3"] == pytest.approx(0.0, abs=1e-12)
+    assert got["fusion.4"] == pytest.approx(0.4)
+    assert sum(got.values()) == pytest.approx(
+        tr.busy_seconds(events, (0.0, 1.0)), abs=1e-12)
+    assert [e.name for e in tr.innermost(events)] == [
+        "fusion.1", "fusion.2", "fusion.4"]
+    # keyed as the caller asks: the scope readers key by instruction
+    assert tr.self_seconds(events[:2], key=lambda e: "all") == {
+        "all": pytest.approx(0.4)}
+
+
 def test_the_chips_instruction_text_gives_a_name_and_a_label():
     fusion = ("%fusion.13 = (f32[256]{0:T(256)S(1)}, /*index=5*/bf16[256,56,"
               "56,256]{3,0,2,1:T(8,128)(2,1)}) fusion(f32[256]{0:T(256)S(1)} "
@@ -225,3 +247,107 @@ def test_plain_references_agree_with_the_programs_models_in_float32():
         params, images, (1, 1, 1, 1))
     assert float(jnp.abs(ours - theirs).max()) < 1e-3 * float(
         jnp.abs(theirs).max())
+
+
+@pytest.mark.parametrize("seq_len,q_block", [(64, 16), (64, 32), (48, 16)])
+def test_blocked_reference_attention_is_the_whole_forms_rows(seq_len, q_block):
+    """The plain LM's attention in blocks of query rows (what lets its
+    [16, 8192, 8192] scores fit a chip) against all rows at once: the same
+    rows, to float32 rounding, in the logits and in the loss."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.reference import lm as plain
+
+    keys = jax.random.split(jax.random.PRNGKey(5), 12)
+    dense = lambda k, i, o: {"kernel": jax.random.normal(k, (i, o)) * i ** -0.5}
+    norm = lambda k: {"scale": 1 + 0.1 * jax.random.normal(k, (32,)),
+                      "bias": 0.1 * jax.random.normal(k, (32,))}
+    block = lambda ks: {
+        "ln1": norm(ks[0]), "ln2": norm(ks[1]),
+        "attn": {n: dense(k, 32, 32) for n, k in zip("qkvo", ks[2:6])},
+        "up": {**dense(ks[6], 32, 64), "bias": jnp.zeros(64)},
+        "down": {**dense(ks[7], 64, 32), "bias": jnp.zeros(32)}}
+    params = {"embed": {"embedding": jax.random.normal(keys[8], (97, 32))},
+              "block_0": block(keys), "block_1": block(keys[::-1]),
+              "ln_f": norm(keys[9]), "lm_head": dense(keys[10], 32, 97)}
+    tokens = jax.random.randint(keys[11], (2, seq_len), 0, 97)
+    whole = plain.lm_logits(params, tokens, 4, q_block=None)
+    blocked = jax.jit(plain.lm_logits, static_argnums=(2, 3))(
+        params, tokens, 4, q_block)
+    assert whole.shape == blocked.shape == (2, seq_len, 97)
+    assert float(jnp.abs(whole - blocked).max()) <= 2e-6 * float(
+        jnp.abs(whole).max())
+    assert float(plain.lm_loss(blocked, tokens)) == pytest.approx(
+        float(plain.lm_loss(whole, tokens)), abs=1e-6)
+    # the blocks are taken where they divide the sequence, and a sequence
+    # of the t1024 cell's length is one block: the form PR 24 accepted
+    assert plain.Q_BLOCK == 1024
+
+
+def test_compare_reads_the_largest_gap_and_the_loss_gap_in_one_program():
+    import jax.numpy as jnp
+
+    from benchmark.reference import compare, lm as plain
+
+    theirs = jnp.asarray([[[2.0, -4.0, 1.0], [0.5, 0.0, -1.0]]])
+    ours = theirs.at[0, 1, 2].add(0.2)
+    targets = jnp.asarray([[0, 1]])
+    tolerance = {"logit_tolerance": 0.06, "loss_tolerance": 0.05}
+    got = compare.compare(ours, theirs, plain.lm_loss, targets, tolerance)
+    assert got["logit_error"] == pytest.approx(0.2 / 4.0)
+    assert got["loss_error"] == pytest.approx(abs(
+        float(plain.lm_loss(ours, targets))
+        - float(plain.lm_loss(theirs, targets))))
+    assert got["ok"] is True and got["logit_tolerance"] == 0.06
+    tight = {"logit_tolerance": 0.04, "loss_tolerance": 0.05}
+    assert compare.compare(ours, theirs, plain.lm_loss, targets,
+                           tight)["ok"] is False
+
+
+def test_gpt2_mediums_attention_at_t8192_is_a_third_of_the_step():
+    """The t8192 cell's reason: attention's required operations against
+    the step's, and a roofline that is compute-bound."""
+    shape = dict(n_layers=24, d_model=1024, d_ff=4096, vocab=50257,
+                 seq_len=8192)
+    step = required_ops.lm_train_flops(1, **shape)
+    assert step == pytest.approx(27.3e12, rel=0.01)
+    at = dict(batch=1, heads=16, seq_len=8192, head_dim=64)
+    flash = required_ops.flash_flops(**at)
+    # 7 products where the step's count has 6 (the backward's recomputed
+    # scores are the kernel's requirement, not the mathematics')
+    assert 24 * sum(flash.values()) * 6 / 7 / step == pytest.approx(
+        0.36, abs=0.01)
+    assert required_ops.roofline_seconds(
+        sum(flash.values()), sum(required_ops.flash_bytes(**at).values()),
+        required_ops.peaks("TPU v5 lite"))["bound"] == "compute"
+
+
+@pytest.mark.parametrize("precision,least,most", [
+    ("fp32", 3e-3, 0.1), ("bf16", 0.05, 1.0)])
+def test_the_resnet_references_control_rounds_every_product(precision, least,
+                                                            most):
+    """``operand`` reaches the stem, every block's convolutions, the
+    projection and the classifier: the control one precision down departs
+    from the reference by that precision's rounding, no less and not
+    wildly more."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.reference import compare, resnet as plain
+    from stochastic_gradient_push_tpu.models.resnet import Bottleneck, ResNet
+
+    net = ResNet(stage_sizes=[1, 1, 1, 1], block_cls=Bottleneck,
+                 num_classes=10)
+    images = jax.random.normal(jax.random.PRNGKey(2), (4, 32, 32, 3))
+    params = jax.jit(lambda key, x: net.init(key, x, train=True))(
+        jax.random.PRNGKey(0), images)["params"]
+    params = jax.jit(lambda t: jax.tree.map(       # no zero scales
+        lambda a: a + 0.05 * jax.random.normal(
+            jax.random.PRNGKey(3), a.shape), t))(params)
+    logits = jax.jit(plain.resnet_logits, static_argnums=(2, 3))
+    theirs = logits(params, images, (1, 1, 1, 1), plain._same)
+    control = logits(params, images, (1, 1, 1, 1),
+                     compare.rounded_to(precision))
+    gap = float(jnp.abs(control - theirs).max() / jnp.abs(theirs).max())
+    assert least < gap < most

@@ -7,7 +7,8 @@ import re
 
 import pytest
 
-from bench_toy import REPO, TOY_CELL, make_toy_root
+from bench_toy import (REPO, TOY_CELL, TOY_CUT_CELL, TOY_LM_CELL,
+                       TOY_LM_CUT, TOY_LM_CUT_REDUCED, make_toy_root)
 from benchmark import spec
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -57,7 +58,66 @@ def test_benchmark_json_keeps_to_the_contracts_shape():
     for c in BENCH["configs"]:
         assert c["file"].startswith(tuple(p + "/" for p in BENCH["paths"]))
         assert 1 <= len(c["source"]) <= 200 and 1 <= len(c["why"]) <= 200
-        assert c["reduced"] == []      # nothing cut: published sizes
+        # what is cut is listed, and the file keeps the contract for cuts
+        assert len(c["reduced"]) <= 16 and all(
+            NAME.match(k) for k in c["reduced"])
+        spec.check_cut(c, spec._load_json(os.path.join(REPO, c["file"])))
+
+
+def _without(config, *keys):
+    return {k: v for k, v in config.items() if k not in keys}
+
+
+@pytest.mark.parametrize("config,reduced,refusal", [
+    (TOY_LM_CUT, TOY_LM_CUT_REDUCED, None),
+    (TOY_LM_CUT, TOY_LM_CUT_REDUCED + ["n_experts"],
+     "reduced key 'n_experts' is no key of"),
+    (_without(TOY_LM_CUT, "published"), TOY_LM_CUT_REDUCED,
+     "reduced key 'n_layer' has no value under 'published'"),
+    ({**TOY_LM_CUT, "published": {"n_layer": 8}}, TOY_LM_CUT_REDUCED,
+     "reduced key 'vocab_size' has no value under 'published'"),
+    ({**TOY_LM_CUT, "published": {"n_layer": 1, "vocab_size": 512}},
+     TOY_LM_CUT_REDUCED, "'n_layer' holds 2, above the published 1"),
+    (_without(TOY_LM_CUT, "deployment"), TOY_LM_CUT_REDUCED,
+     "needs a 'deployment'"),
+    ({**TOY_LM_CUT, "deployment": "  "}, TOY_LM_CUT_REDUCED,
+     "needs a 'deployment'"),
+    (TOY_LM_CUT, ["n_layer"],
+     r"'published' states \['vocab_size'\], which 'reduced' does not list"),
+    (TOY_LM_CUT, [], "nothing cut has no 'published'"),
+    ({**TOY_LM_CUT, "published": {}}, [], "nothing cut has no 'published'"),
+], ids=["keeps-the-contract", "reduced-key-the-file-lacks",
+        "published-missing", "published-lacks-a-reduced-key",
+        "held-above-published", "no-deployment", "blank-deployment",
+        "published-states-what-reduced-does-not-list",
+        "published-with-nothing-reduced", "empty-published-with-nothing-reduced"])
+def test_a_cut_configuration_loads_and_a_malformed_one_is_refused(
+        tmp_path, config, reduced, refusal):
+    """The contract for ``reduced`` (``spec.check_cut``), through
+    ``load_cell``: a run refuses what this test refuses, naming the key."""
+    root = make_toy_root(str(tmp_path / "bench"), config, reduced)
+    # the configurations beside it load whatever this one holds
+    assert spec.load_cell(root, TOY_LM_CELL).config["n_layer"] == 2
+    if refusal is None:
+        cut = spec.load_cell(root, TOY_CUT_CELL)
+        assert cut.config["published"] == {"n_layer": 8, "vocab_size": 512}
+        assert cut.config["n_layer"] == 2 and cut.config["deployment"]
+        argv = spec.load_plugin(root, "builders", cut.builder).argv_of(cut, 1)
+        assert argv[argv.index("--n_layers") + 1] == "2"
+    else:
+        with pytest.raises(ValueError, match=refusal) as raised:
+            spec.load_cell(root, TOY_CUT_CELL)
+        assert "toy_lm_cut" in str(raised.value)
+
+
+def test_a_cut_may_shorten_a_layer_pattern_and_never_lengthen_it():
+    entry = {"name": "hybrid", "file": "f.json", "reduced": ["layer_types"]}
+    config = {"layer_types": ["mamba", "attention"], "deployment": "a period",
+              "published": {"layer_types": ["mamba", "attention"] * 4}}
+    spec.check_cut(entry, config)
+    config["published"]["layer_types"] = ["mamba"]
+    with pytest.raises(ValueError, match="'layer_types' holds"):
+        spec.check_cut(entry, config)
 
 
 @pytest.mark.parametrize("cell", CELLS)
