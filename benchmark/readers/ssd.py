@@ -1,0 +1,27 @@
+"""The state-space scan's share of its roofline: required operations and
+bytes (``benchmark/required_ops_hybrid.py``) against the device time the
+trace shows under the program's ``lm.ssd`` scope.  It reads the same work
+whatever implements the scan: products in XLA today, a kernel later."""
+
+from benchmark import required_ops, required_ops_hybrid
+
+
+def ssd_roofline_pct(reading):
+    """Least time the chip could take for the scan of one step — forward
+    and backward, every ``mamba`` layer — over the time
+    ``params.time_metric`` measured for it (recomputation included there,
+    not here).  Nothing where the builder gives no ``ssd`` shapes or the
+    time was not read."""
+    measured_ms = reading.values.get(reading.params["time_metric"])
+    s = reading.job.shapes.get("ssd")
+    if not measured_ms or not s:
+        return None
+    shape = {k: s[k] for k in ("batch", "seq_len", "heads", "head_dim",
+                               "state", "groups")}
+    flops = required_ops_hybrid.ssd_flops(chunk=s["chunk"], **shape)
+    nbytes = required_ops_hybrid.ssd_bytes(itemsize=s["itemsize"], **shape)
+    least = sum(
+        required_ops.roofline_seconds(flops[p], nbytes[p],
+                                      reading.peak)["seconds"]
+        for p in ("forward", "backward")) * s["layers"]
+    return 100.0 * least * 1e3 / measured_ms

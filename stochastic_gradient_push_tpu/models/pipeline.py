@@ -62,6 +62,10 @@ class PipelineStageLM(nn.Module):
 
     def setup(self):
         cfg = self.cfg
+        if cfg.layer_types is not None:
+            raise ValueError(
+                "a layer pattern × pipeline is not built: the stage stack "
+                "is one uniform nn.scan of the dense block")
         if cfg.moe_experts > 0 and cfg.moe_every != 1:
             raise ValueError(
                 "MoE × pipeline requires moe_every=1: the stage stack is "

@@ -17,6 +17,12 @@ first-class in-repo model family, built TPU-first:
 * optional switch-MoE feed-forward blocks with experts sharded over an
   ``ep`` mesh axis (models/moe.py): set ``moe_experts > 0`` and every
   ``moe_every``-th block routes tokens to experts via all_to_all
+* a layer *pattern* (``layer_types``): each block's mixer is causal
+  attention or the Mamba-2 state-space mixer (models/ssm.py), and the
+  hybrid families' other parts — grouped-query heads, RMSNorm, a
+  SiLU-gated MLP, no positions, a tied head, the four multipliers — are
+  fields of the one config; ``config_from_source`` fills them from a
+  published ``config.json``.  The defaults are the GPT-2-shaped model
 """
 
 from __future__ import annotations
@@ -29,8 +35,11 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..parallel.ring_attention import blockwise_attention, ring_attention
+from .ssm import Mamba2Mixer, SSMConfig
 
-__all__ = ["TransformerLM", "TransformerConfig"]
+__all__ = ["TransformerLM", "TransformerConfig", "config_from_source"]
+
+LAYER_TYPES = ("attention", "mamba")
 
 
 def _rope(x: jnp.ndarray, positions: jnp.ndarray,
@@ -70,6 +79,99 @@ class TransformerConfig(tp.NamedTuple):
     moe_every: int = 2                # every k-th block uses MoE
     ep_axis: str | None = None        # mesh axis experts shard over
     moe_capacity_factor: float = 1.25
+    # -- the hybrid families' parts; every default is the dense model's --
+    # one mixer a layer, from LAYER_TYPES (None: attention throughout)
+    layer_types: tuple[str, ...] | None = None
+    ssm: SSMConfig | None = None      # the "mamba" layers' sizes
+    n_kv_heads: int | None = None     # grouped-query heads (None: n_heads)
+    norm: str = "layernorm"           # layernorm | rmsnorm
+    norm_eps: float = 1e-6
+    mlp: str = "gelu"                 # gelu (biased) | swiglu (no bias)
+    positions: str = "rotary"         # rotary | none
+    tie_embeddings: bool = False      # logits from the embedding table
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    # the softmax scale (None: head_dim ** -0.5)
+    attention_multiplier: float | None = None
+    logits_scaling: float = 1.0       # logits are divided by it
+
+    def layer_type(self, i: int) -> str:
+        return self.layer_types[i] if self.layer_types else "attention"
+
+    def check_pattern(self) -> None:
+        """Raises ``ValueError`` for a pattern this config cannot build."""
+        types = self.layer_types
+        if types is None:
+            return
+        if len(types) != self.n_layers:
+            raise ValueError(f"layer_types names {len(types)} layers, "
+                             f"n_layers is {self.n_layers}")
+        unknown = sorted(set(types) - set(LAYER_TYPES))
+        if unknown:
+            raise ValueError(f"layer types {unknown} are none of "
+                             f"{LAYER_TYPES}")
+        if "mamba" in types and self.ssm is None:
+            raise ValueError("a 'mamba' layer needs the mixer's sizes "
+                             "(TransformerConfig.ssm)")
+
+
+def config_from_source(src: dict, **runtime) -> TransformerConfig:
+    """The config of a model described by its source's ``config.json``
+    keys (the ``granitemoehybrid`` family, dense: Mamba-2 and
+    grouped-query attention layers, each followed by a SiLU-gated MLP).
+    ``runtime`` gives what no source states (``dtype``, ``attn_impl``,
+    ``remat``, ...).  Keys beside the source's own are ignored; a value
+    the model code does not compute raises ``ValueError``."""
+    wanted = {"model_type": ("granitemoehybrid",), "hidden_act": ("silu",),
+              "normalization_function": ("rmsnorm",),
+              "position_embedding_type": ("nope", "rope"),
+              "num_local_experts": (0,), "attention_bias": (False,),
+              "mamba_proj_bias": (False,)}
+    for key, values in wanted.items():
+        if src.get(key, values[0]) not in values:
+            raise ValueError(f"source config {key}={src[key]!r}: the model "
+                             f"computes {key} in {values} only")
+    d_model = src["hidden_size"]
+    ssm = SSMConfig(
+        n_heads=src["mamba_n_heads"], d_head=src["mamba_d_head"],
+        d_state=src["mamba_d_state"], n_groups=src["mamba_n_groups"],
+        d_conv=src["mamba_d_conv"], chunk_size=src["mamba_chunk_size"],
+        conv_bias=src["mamba_conv_bias"])
+    if ssm.n_heads * ssm.d_head != src["mamba_expand"] * d_model:
+        raise ValueError(
+            f"mamba_n_heads * mamba_d_head = {ssm.n_heads * ssm.d_head} "
+            f"is not mamba_expand * hidden_size = "
+            f"{src['mamba_expand'] * d_model}")
+    cfg = TransformerConfig(
+        vocab_size=src["vocab_size"], d_model=d_model,
+        n_layers=src["num_hidden_layers"],
+        n_heads=src["num_attention_heads"],
+        n_kv_heads=src["num_key_value_heads"],
+        d_ff=src["shared_intermediate_size"],
+        layer_types=tuple(src["layer_types"]), ssm=ssm,
+        norm="rmsnorm", norm_eps=src["rms_norm_eps"], mlp="swiglu",
+        positions={"nope": "none", "rope": "rotary"}[
+            src["position_embedding_type"]],
+        tie_embeddings=src["tie_word_embeddings"],
+        embedding_multiplier=src["embedding_multiplier"],
+        residual_multiplier=src["residual_multiplier"],
+        attention_multiplier=src["attention_multiplier"],
+        logits_scaling=src["logits_scaling"], **runtime)
+    cfg.check_pattern()
+    return cfg
+
+
+def _norm(cfg: TransformerConfig, name: str):
+    """The config's normalisation, float32."""
+    if cfg.norm == "rmsnorm":
+        return nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32, name=name)
+    if cfg.norm != "layernorm":
+        raise ValueError(f"unknown norm {cfg.norm}")
+    return nn.LayerNorm(epsilon=cfg.norm_eps, dtype=jnp.float32, name=name)
+
+
+def _scaled(x, by: float):
+    return x if by == 1.0 else x * by
 
 
 class _Attention(nn.Module):
@@ -79,20 +181,38 @@ class _Attention(nn.Module):
     def __call__(self, x, positions):
         cfg = self.cfg
         head_dim = cfg.d_model // cfg.n_heads
-        dense = lambda name: nn.Dense(
-            cfg.d_model, use_bias=False, dtype=cfg.dtype, name=name)
-        q = dense("q")(x)
-        k = dense("k")(x)
-        v = dense("v")(x)
+        kv_heads = cfg.n_kv_heads or cfg.n_heads
+        if cfg.n_heads % kv_heads:
+            raise ValueError(f"{cfg.n_heads} query heads do not divide "
+                             f"over {kv_heads} key-value heads")
+        dense = lambda name, heads: nn.Dense(
+            heads * head_dim, use_bias=False, dtype=cfg.dtype, name=name)
+        q = dense("q", cfg.n_heads)(x)
+        k = dense("k", kv_heads)(x)
+        v = dense("v", kv_heads)(x)
 
-        def split(t):  # [B,T,E] → [B,H,T,D]
-            b, s, _ = t.shape
-            return t.reshape(b, s, cfg.n_heads, head_dim).transpose(
+        def split(t):  # [B,T,heads·D] → [B,heads,T,D]
+            b, s, e = t.shape
+            return t.reshape(b, s, e // head_dim, head_dim).transpose(
                 0, 2, 1, 3)
 
         q, k, v = split(q), split(k), split(v)
-        q = _rope(q, positions)
-        k = _rope(k, positions)
+        if cfg.positions == "rotary":
+            q = _rope(q, positions)
+            k = _rope(k, positions)
+        elif cfg.positions != "none":
+            raise ValueError(f"unknown positions {cfg.positions}")
+        if cfg.attention_multiplier is not None:
+            # every backend below scales by head_dim ** -0.5; the factor
+            # that makes that the config's scale goes into q (an exact
+            # power of two for the published 1/64 at heads of 64)
+            q = q * (cfg.attention_multiplier * head_dim ** 0.5)
+        if kv_heads != cfg.n_heads:
+            # grouped-query heads by repeating k, v: query head h reads
+            # key-value head h // (n_heads / kv_heads); the repeat's
+            # transpose sums the group's gradients
+            k = jnp.repeat(k, cfg.n_heads // kv_heads, axis=1)
+            v = jnp.repeat(v, cfg.n_heads // kv_heads, axis=1)
 
         if cfg.attn_impl == "ring":
             if cfg.seq_axis is None:
@@ -133,7 +253,7 @@ class _Attention(nn.Module):
             raise ValueError(f"unknown attn_impl {cfg.attn_impl}")
 
         b, h, s, d = out.shape
-        out = out.transpose(0, 2, 1, 3).reshape(b, s, cfg.d_model)
+        out = out.transpose(0, 2, 1, 3).reshape(b, s, h * d)
         return nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
                         name="o")(out)
 
@@ -182,21 +302,38 @@ class _MoEFFN(nn.Module):
 class _Block(nn.Module):
     cfg: TransformerConfig
     use_moe: bool = False
+    layer_type: str = "attention"     # the mixer, from LAYER_TYPES
 
     @nn.compact
     def __call__(self, x, positions):
         cfg = self.cfg
-        ln = lambda name: nn.LayerNorm(dtype=jnp.float32, name=name)
-        x = x + _Attention(cfg, name="attn")(ln("ln1")(x), positions)
-        h = ln("ln2")(x)
+        res = cfg.residual_multiplier
+        h = _norm(cfg, "ln1")(x)
+        if self.layer_type == "mamba":
+            mixed = Mamba2Mixer(cfg.ssm, cfg.d_model, dtype=cfg.dtype,
+                                norm_eps=cfg.norm_eps, name="ssm")(h)
+        else:
+            mixed = _Attention(cfg, name="attn")(h, positions)
+        x = x + _scaled(mixed, res)
+        h = _norm(cfg, "ln2")(x)
         if self.use_moe:
             # dropped (over-capacity) tokens contribute zero here and ride
             # the residual connection through unchanged
-            return x + _MoEFFN(cfg, name="moe")(h)
-        h = nn.Dense(cfg.d_ff, dtype=cfg.dtype, name="up")(h)
-        h = nn.gelu(h)
-        h = nn.Dense(cfg.d_model, dtype=cfg.dtype, name="down")(h)
-        return x + h
+            return x + _scaled(_MoEFFN(cfg, name="moe")(h), res)
+        if cfg.mlp == "swiglu":
+            # one product for gate and up, as the source's input_linear
+            gate, up = jnp.split(nn.Dense(
+                2 * cfg.d_ff, use_bias=False, dtype=cfg.dtype,
+                name="gate_up")(h), 2, axis=-1)
+            h = nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
+                         name="down")(nn.silu(gate) * up)
+        elif cfg.mlp == "gelu":
+            h = nn.Dense(cfg.d_ff, dtype=cfg.dtype, name="up")(h)
+            h = nn.gelu(h)
+            h = nn.Dense(cfg.d_model, dtype=cfg.dtype, name="down")(h)
+        else:
+            raise ValueError(f"unknown mlp {cfg.mlp}")
+        return x + _scaled(h, res)
 
 
 class TransformerLM(nn.Module):
@@ -215,6 +352,7 @@ class TransformerLM(nn.Module):
         cfg = self.cfg
         if cfg.moe_experts > 0 and cfg.moe_every < 1:
             raise ValueError("moe_every must be >= 1 when moe_experts > 0")
+        cfg.check_pattern()
         b, t = tokens.shape
         if cfg.attn_impl in ("ring", "ring_flash"):
             offset = lax.axis_index(cfg.seq_axis) * t
@@ -222,17 +360,23 @@ class TransformerLM(nn.Module):
             offset = 0
         positions = offset + jnp.arange(t)
 
-        x = nn.Embed(cfg.vocab_size, cfg.d_model,
-                     embedding_init=nn.initializers.normal(0.02),
-                     dtype=cfg.dtype, name="embed")(tokens)
+        embed = nn.Embed(cfg.vocab_size, cfg.d_model,
+                         embedding_init=nn.initializers.normal(0.02),
+                         dtype=cfg.dtype, name="embed")
+        x = _scaled(embed(tokens), cfg.embedding_multiplier)
         block = _Block
         if cfg.remat:
             block = nn.remat(_Block)
         for i in range(cfg.n_layers):
             use_moe = (cfg.moe_experts > 0
                        and i % cfg.moe_every == cfg.moe_every - 1)
-            x = block(cfg, use_moe=use_moe, name=f"block_{i}")(x, positions)
-        x = nn.LayerNorm(dtype=jnp.float32, name="ln_f")(x)
-        logits = nn.Dense(cfg.vocab_size, use_bias=False,
-                          dtype=cfg.dtype, name="lm_head")(x)
-        return jnp.asarray(logits, jnp.float32)
+            x = block(cfg, use_moe=use_moe, layer_type=cfg.layer_type(i),
+                      name=f"block_{i}")(x, positions)
+        x = _norm(cfg, "ln_f")(x)
+        if cfg.tie_embeddings:
+            logits = embed.attend(x)
+        else:
+            logits = nn.Dense(cfg.vocab_size, use_bias=False,
+                              dtype=cfg.dtype, name="lm_head")(x)
+        return _scaled(jnp.asarray(logits, jnp.float32),
+                       1.0 / cfg.logits_scaling)

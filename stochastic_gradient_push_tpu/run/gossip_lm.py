@@ -123,6 +123,13 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--n_heads", default=8, type=int)
     p.add_argument("--d_ff", default=1024, type=int)
     p.add_argument("--seq_len", default=256, type=int)
+    p.add_argument("--model_json", default=None, type=str,
+                   help="a model described by its source's config.json "
+                        "keys (models/transformer.py::config_from_source: "
+                        "Mamba-2 and grouped-query attention layers in a "
+                        "pattern, RMSNorm, gated MLP, tied head); "
+                        "replaces --vocab_size, --d_model, --n_layers, "
+                        "--n_heads and --d_ff; flat data-parallel mesh only")
     p.add_argument("--attn", default=None,
                    choices=[None, "full", "blockwise", "flash", "ring",
                             "ring_flash"],
@@ -255,19 +262,77 @@ def resolve_attention(flag: str | None, seq_len: int, sp: int,
     return flag
 
 
+def resolve_model_json(args) -> None:
+    """``--model_json``: load the source's keys into ``args.model_source``
+    and set the five size flags they replace, so that the data, the
+    checks and the log see the model's own sizes."""
+    args.model_source = None
+    if not args.model_json:
+        return
+    if (args.sp, args.tp, args.ep, args.pp) != (1, 1, 1, 1) \
+            or args.moe_experts:
+        raise SystemExit("--model_json builds a layer pattern, which "
+                         "composes with the flat data-parallel mesh only "
+                         "(not --sp/--tp/--ep/--pp > 1 or --moe_experts)")
+    with open(args.model_json) as f:
+        src = args.model_source = json.load(f)
+    for flag, key in (("vocab_size", "vocab_size"),
+                      ("d_model", "hidden_size"),
+                      ("n_layers", "num_hidden_layers"),
+                      ("n_heads", "num_attention_heads"),
+                      ("d_ff", "shared_intermediate_size")):
+        if key not in src:
+            raise SystemExit(f"--model_json {args.model_json}: no {key!r}")
+        setattr(args, flag, src[key])
+
+
+def model_from_args(args, attn: str, seq_axis=None, ep_axis=None):
+    """The model the flags describe — the one place ``TransformerConfig``
+    is built from ``args`` (``resolve_model_json`` ran before).  ``attn``
+    is ``resolve_attention``'s answer; ``--pp > 1`` gives one pipeline
+    stage's slice of the stack."""
+    import jax.numpy as jnp
+
+    from ..models.transformer import (TransformerConfig, TransformerLM,
+                                      config_from_source)
+    from .gossip_sgd import _str_bool as sb
+
+    runtime = dict(
+        max_len=args.seq_len,
+        dtype=jnp.bfloat16 if args.precision == "bf16" else jnp.float32,
+        attn_impl=attn, seq_axis=seq_axis,
+        attn_block_size=args.attn_block or None,
+        attn_block_k=args.attn_block_k or None,
+        remat=sb(args.remat))
+    if args.model_source is not None:
+        try:
+            return TransformerLM(config_from_source(args.model_source,
+                                                    **runtime))
+        except (KeyError, ValueError) as e:
+            raise SystemExit(f"--model_json {args.model_json}: {e!r}")
+    cfg = TransformerConfig(
+        vocab_size=args.vocab_size, d_model=args.d_model,
+        n_layers=args.n_layers, n_heads=args.n_heads, d_ff=args.d_ff,
+        moe_experts=args.moe_experts, moe_every=args.moe_every,
+        ep_axis=ep_axis, **runtime)
+    if args.pp > 1:
+        from ..models import PipelineStageLM
+        return PipelineStageLM(cfg, n_local_layers=args.n_layers // args.pp)
+    return TransformerLM(cfg)
+
+
 def main(argv=None):
     from ..utils.compile_cache import place_compile_cache
 
     place_compile_cache()
     args = build_parser().parse_args(argv)
+    resolve_model_json(args)
 
     import jax
-    import jax.numpy as jnp
     import numpy as np
 
     from ..algorithms import all_reduce, dpsgd, sgp
     from ..data.lm import lm_batches, synthetic_lm_corpus
-    from ..models.transformer import TransformerConfig, TransformerLM
     from ..parallel import GOSSIP_AXIS
     from ..topology import build_schedule
     from ..train import LRSchedule, sgd
@@ -509,22 +574,9 @@ def main(argv=None):
             f"--batch_size {args.batch_size} not divisible by "
             f"--grad_accum {args.grad_accum}")
 
-    cfg = TransformerConfig(
-        vocab_size=args.vocab_size, d_model=args.d_model,
-        n_layers=args.n_layers, n_heads=args.n_heads, d_ff=args.d_ff,
-        max_len=args.seq_len,
-        dtype=jnp.bfloat16 if args.precision == "bf16" else jnp.float32,
-        attn_impl=attn, seq_axis=SEQ_AXIS if ring_family else None,
-        attn_block_size=args.attn_block or None,
-        attn_block_k=args.attn_block_k or None,
-        remat=sb(args.remat),
-        moe_experts=args.moe_experts, moe_every=args.moe_every,
-        ep_axis=EP_AXIS if ep > 1 else None)
-    if pp > 1:
-        from ..models import PipelineStageLM
-        model = PipelineStageLM(cfg, n_local_layers=args.n_layers // pp)
-    else:
-        model = TransformerLM(cfg)
+    model = model_from_args(args, attn,
+                            seq_axis=SEQ_AXIS if ring_family else None,
+                            ep_axis=EP_AXIS if ep > 1 else None)
 
     if sb(args.all_reduce):
         reject_push_sum_wire_knobs(args)

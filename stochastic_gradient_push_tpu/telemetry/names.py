@@ -25,6 +25,11 @@ SCOPE_HEALTH = "sgp.health"              # grad norm + health signals
 STEP_SCOPES = (SCOPE_PRE_STEP, SCOPE_FORWARD, SCOPE_REDUCE_GRADS,
                SCOPE_OPTIMIZER, SCOPE_GOSSIP, SCOPE_HEALTH)
 
+# -- inside sgp.forward: the LM's state-space mixer (models/ssm.py) ------
+SCOPE_SSM_MIXER = "lm.ssm_mixer"         # the whole Mamba-2 mixer
+SCOPE_CONV1D = "lm.conv1d"               # nested: causal depthwise conv
+SCOPE_SSD = "lm.ssd"                     # nested: the chunked scan alone
+
 # -- the jitted steps' names: the compiled module is ``jit_<name>`` on the
 # trace's "XLA Modules" line, which tells the step from set-up's programs
 MODULE_TRAIN_STEP = "sgp_train_step"
