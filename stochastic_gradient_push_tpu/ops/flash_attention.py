@@ -12,6 +12,16 @@ carries the online-softmax ``(m, den, acc)`` triple, the backward its
 gradient accumulators, and outputs are written once, on the last step
 that adds to them.
 
+The forward's tile body keeps its per-row state off the cross-lane unit,
+which — not the MXU, not the mask, not the operands' casts — set its pace
+(a k-step with ``[rows, 1]`` state cost 4 lane broadcasts and 2 lane
+reductions a row group; TPU v5e, PERF.md §6, PR 30): the running maximum
+is held in all 128 lanes of a row and the running denominator as 128
+lane-partial sums, so one row maximum is the only cross-lane reduction
+of a k-step and the denominator's row sum is taken once a q-block, in
+``_finalize``.  ``out`` and ``lse`` differ from a row sum taken every
+tile by fp32 rounding of a reordered sum, nothing more.
+
 Backward (``jax.custom_vjp``) is flash-attention-2's, in ONE kernel
 wherever :func:`fused_backward_fits`:
 
@@ -49,6 +59,7 @@ exercises every kernel in tests against that same oracle.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -69,8 +80,11 @@ NEG_INF = -1e30
 # costs t floats instead of the 128·t a lane-broadcast layout would.
 SCALAR_COLS = 1
 
-# fp32 running-state scratch keeps a full [rows, 128] lane so stores hit
-# the native register layout; only column 0 is meaningful
+# fp32 running-state scratch is a full [rows, 128] register column, so the
+# forward's per-row state never takes a cross-lane move inside the k-sweep:
+# the running maximum holds the same value in every lane of a row, the
+# running denominator one partial sum a lane (the row's sum is taken once,
+# on the last k-step)
 _STATE_LANES = 128
 
 
@@ -131,12 +145,32 @@ def _visible(qi, kj, block_q: int, block_k: int, causal: bool):
         else (qi >= 0)
 
 
+def _lanes(x, n: int):
+    """``x`` is ``[rows, 128]`` with a row's lanes all equal: the same rows
+    ``n`` lanes wide, by reusing the registers (no cross-lane move)."""
+    reps = -(-n // _STATE_LANES)
+    if reps > 1:
+        x = pltpu.repeat(x, reps, 1)
+    return x[:, :n]
+
+
+def _lane_partials(p):
+    """``[rows, cols]`` -> ``[rows, w]``: ``p``'s column groups of one
+    register's lanes (``w`` = 128, or what a narrower block has) added
+    elementwise.  Its sum over lanes is ``p``'s row sum."""
+    w = math.gcd(p.shape[-1], _STATE_LANES)
+    groups = [p[:, c:c + w] for c in range(0, p.shape[-1], w)]
+    return sum(groups[1:], groups[0])
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q: int,
                       block_k: int, causal: bool, return_lse: bool):
     """One (batch·head, q-block, k-step) cell.  Refs: q/o [block_q, d];
     k/v [block_k, d] (streamed); lse (when requested)
     [block_q, SCALAR_COLS]; scratch m/den [block_q, 128] and
-    acc [block_q, d], all fp32, persistent across k-steps."""
+    acc [block_q, d], all fp32, persistent across k-steps; m holds a
+    row's maximum in every lane and den lane partials (``_STATE_LANES``),
+    so the row maximum is a k-step's one cross-lane reduction."""
     if return_lse:
         lse_ref, m_ref, den_ref, acc_ref = rest
     else:
@@ -161,20 +195,21 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q: int,
             preferred_element_type=jnp.float32)            # [bq, bk]
         if causal:
             s = _causal_mask(s, qi, kj, block_q, block_k)
-        m_prev = m_ref[:, :1]                              # [bq, 1]
+        m_prev = m_ref[:]                                  # [bq, 128]
         m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)                    # [bq, 1]
-        den_new = den_ref[:, :1] * alpha + jnp.sum(p, -1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+        p = jnp.exp(s - _lanes(m_new, block_k))
+        alpha = jnp.exp(m_prev - m_new)                    # [bq, 128]
+        part = _lane_partials(p)                           # [bq, w]
+        w = part.shape[-1]
+        den_ref[:, :w] = den_ref[:, :w] * alpha[:, :w] + part
+        acc_ref[:] = acc_ref[:] * _lanes(alpha, d) + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        den_ref[:] = jnp.broadcast_to(den_new, den_ref.shape)
+        m_ref[:] = m_new
 
     @pl.when(kj == nk - 1)
     def _finalize():
-        den = den_ref[:, :1]
+        den = jnp.sum(den_ref[:], -1, keepdims=True)       # [bq, 1]
         o_ref[:] = (acc_ref[:] / den).astype(o_ref.dtype)
         if return_lse:
             lse_ref[:] = m_ref[:, :1] + jnp.log(den)

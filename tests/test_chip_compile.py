@@ -26,7 +26,7 @@ from jax.sharding import PartitionSpec as P
 from stochastic_gradient_push_tpu.ops import gossip_kernel as gk
 from stochastic_gradient_push_tpu.ops.flash_attention import (
     default_block, flash_attention, flash_attention_backward,
-    fused_backward_fits)
+    flash_attention_forward, fused_backward_fits)
 from stochastic_gradient_push_tpu.ops.ring_flash import ring_flash_attention
 from stochastic_gradient_push_tpu.parallel import (
     GOSSIP_AXIS, collectives, make_gossip_mesh, wire)
@@ -143,6 +143,27 @@ def test_flash_forward_and_backward_compile(one_chip, on_tpu, shape,
         x, x, x).compile().as_text()
     assert text.count("tpu_custom_call") == 1 + len(backward)
     assert _kernel_names(text) == {names.KERNEL_FLASH_FWD} | backward
+
+
+@pytest.mark.parametrize("shape,dtype,return_lse", [
+    ((1, 16, 8192, 64), jnp.bfloat16, True),     # gpt2m_sgp_w1_t8192's call
+    ((1, 16, 8192, 64), jnp.bfloat16, False),
+    ((4, 16, 1024, 64), jnp.float32, True),
+    ((1, 8, 2048, 128), jnp.bfloat16, True),     # alpha over a whole register
+    ((1, 4, 2048, 256), jnp.bfloat16, True),     # ... repeated over two
+    ((2, 4, 64, 64), jnp.bfloat16, True),        # a block under 128 lanes
+], ids=["t8192_lse", "t8192", "t1024_fp32", "d128", "d256", "t64"])
+def test_flash_forward_compiles(one_chip, shape, dtype, return_lse):
+    """The forward alone at the auto block, with and without the ``lse``
+    output: its running maximum repeated over a 512-wide ``s`` and its
+    ``alpha`` cut or repeated to the head's width are register moves the
+    interpreter cannot refuse and Mosaic can."""
+    block = default_block(shape[2])
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    text = jax.jit(lambda q, k, v: flash_attention_forward(
+        q, k, v, causal=True, block_q=block, block_k=block,
+        return_lse=return_lse)).lower(x, x, x).compile().as_text()
+    assert _kernel_names(text) == {names.KERNEL_FLASH_FWD}
 
 
 @pytest.mark.parametrize("d", [64, 128])

@@ -266,3 +266,152 @@ def test_the_benchmark_reads_the_fused_backward_by_its_name():
     for other in (names.KERNEL_FLASH_FWD, names.KERNEL_FLASH_DQ,
                   names.KERNEL_FLASH_DKV, names.KERNEL_FLASH_BWD + "_x.1"):
         assert not pattern.search(other)
+
+
+# -- one k-sweep crossing every kind of tile ---------------------------------
+#
+# The forward's running state (the maximum in every lane, the denominator
+# as lane partials) is handed from tile to tile; these shapes make one
+# call hold tiles wholly under the diagonal, tiles the diagonal crosses
+# and tiles above it, so a row's state passes through unmasked and masked
+# bodies both, first tile to last.
+
+T4 = 128    # 4 x 4 blocks of 32
+
+
+@pytest.fixture(scope="module")
+def qkv4():
+    rng = np.random.default_rng(11)
+    return [jnp.asarray(rng.normal(size=(1, H, T4, D)), jnp.float32)
+            for _ in range(4)]
+
+
+def _tile_kinds(t, block_q, block_k):
+    """(full, diagonal, skipped) tiles of a causal call, counted on the
+    mask itself."""
+    mask = np.tril(np.ones((t, t), bool)).reshape(
+        t // block_q, block_q, t // block_k, block_k)
+    some, every = mask.any((1, 3)), mask.all((1, 3))
+    return int(every.sum()), int((some & ~every).sum()), int((~some).sum())
+
+
+def _lse_oracle(q, k, causal):
+    s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
+                   k.astype(jnp.float32)) * (q.shape[-1] ** -0.5)
+    if causal:
+        t = q.shape[2]
+        s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+    return jax.scipy.special.logsumexp(s, axis=-1)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["fp32", "bf16"])
+@pytest.mark.parametrize("block_q,block_k", [(32, 32), (16, 32), (32, 16)])
+def test_all_tile_kinds_in_one_call_match_the_oracle(qkv4, dtype, block_q,
+                                                     block_k, backward):
+    """Forward, ``lse`` and all three gradients where one call holds
+    full, diagonal and skipped tiles (at least 4 x 4 blocks), at both
+    dtypes, through the fused backward and the pair."""
+    full, diagonal, skipped = _tile_kinds(T4, block_q, block_k)
+    assert full and diagonal and skipped
+    assert T4 // block_q >= 4 and T4 // block_k >= 4
+    q, k, v, do = (x.astype(dtype) for x in qkv4)
+    out, lse = flash_attention_forward(q, k, v, causal=True,
+                                       block_q=block_q, block_k=block_k,
+                                       interpret=True, return_lse=True)
+    dq, dk, dv = flash_attention_backward(
+        q, k, v, out, lse, do, causal=True, block_q=block_q,
+        block_k=block_k, interpret=True)
+    want, vjp = jax.vjp(
+        lambda q, k, v: blockwise_attention(q, k, v, 16, causal=True),
+        q, k, v)
+    tol = 2e-5 if dtype == jnp.float32 else 3e-2
+    assert out.dtype == dtype and lse.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol)
+    np.testing.assert_allclose(np.asarray(lse),
+                               np.asarray(_lse_oracle(q, k, True)),
+                               rtol=1e-5, atol=1e-5)
+    for got, ref in zip((dq, dk, dv), vjp(do)):
+        assert got.dtype == dtype
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(ref, np.float32),
+                                   rtol=10 * tol, atol=10 * tol)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["fp32", "bf16"])
+def test_a_row_that_starts_full_and_ends_on_the_diagonal(qkv, dtype):
+    """Two blocks a side: the second q-block's sweep opens on a tile with
+    no masked pair and closes on the diagonal's, so its denominator is
+    the sum of an unmasked and a masked tile's partials."""
+    assert _tile_kinds(T, 32, 32) == (1, 2, 1)
+    q, k, v = (x.astype(dtype) for x in qkv)
+    out, lse = flash_attention_forward(q, k, v, causal=True, block_q=32,
+                                       block_k=32, interpret=True,
+                                       return_lse=True)
+    want = blockwise_attention(q, k, v, 32, causal=True)
+    tol = 2e-5 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32)[:, :, 32:],
+                               np.asarray(want, np.float32)[:, :, 32:],
+                               rtol=tol, atol=tol)
+    np.testing.assert_allclose(np.asarray(lse)[:, :, 32:],
+                               np.asarray(_lse_oracle(q, k, True))[:, :, 32:],
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("t,block_q,block_k", [
+    (8192, 512, 512), (4096, 512, 512), (1024, 512, 512),
+    (1024, 256, 512), (1024, 512, 256), (64, 16, 32), (64, 32, 16)])
+def test_visible_tiles_are_the_masks_own(t, block_q, block_k):
+    """``_visible`` skips a tile exactly where the mask leaves no pair —
+    at the benchmark's three shapes (120, 28 and 1 tiles a head skipped,
+    as many wholly under the diagonal) and at unequal blocks both ways."""
+    full, diagonal, skipped = _tile_kinds(t, block_q, block_k)
+    seen = sum(bool(fa._visible(qi, kj, block_q, block_k, True))
+               for qi in range(t // block_q) for kj in range(t // block_k))
+    assert seen == full + diagonal
+    if block_q == block_k == 512:
+        n = t // 512
+        assert (full, diagonal, skipped) == (n * (n - 1) // 2, n,
+                                             n * (n - 1) // 2)
+    assert all(fa._visible(qi, kj, block_q, block_k, False)
+               for qi in range(t // block_q) for kj in range(t // block_k))
+
+
+@pytest.mark.parametrize("cols,width", [(512, 128), (128, 128), (256, 128),
+                                        (64, 64), (16, 16), (192, 64)])
+def test_lane_partials_sum_to_the_row_sum(cols, width):
+    """The denominator's lane partials: one register's lanes wide (or a
+    narrower block's own width), their sum over lanes the row sum."""
+    p = jnp.asarray(np.random.default_rng(cols).uniform(size=(8, cols)),
+                    jnp.float32)
+    part = fa._lane_partials(p)
+    assert part.shape == (8, width)
+    np.testing.assert_allclose(
+        np.asarray(part),
+        np.asarray(p).reshape(8, cols // width, width).sum(1), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(part.sum(-1)),
+                               np.asarray(p.sum(-1)), rtol=1e-6)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("block_q,block_k,d", [(128, 256, 16), (64, 128, 16),
+                                               (32, 32, 256)])
+def test_forward_state_wider_than_one_register(causal, block_q, block_k, d):
+    """Blocks of two registers' lanes (the running maximum is repeated
+    across them, the partials fold two column groups) and a head of two
+    (``alpha`` repeated over the accumulator)."""
+    rng = np.random.default_rng(13)
+    q, k, v = (jnp.asarray(rng.normal(size=(1, 2, 512, d)), jnp.float32)
+               for _ in range(3))
+    out, lse = flash_attention_forward(q, k, v, causal=causal,
+                                       block_q=block_q, block_k=block_k,
+                                       interpret=True, return_lse=True)
+    want = blockwise_attention(q, k, v, 32, causal=causal)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse),
+                               np.asarray(_lse_oracle(q, k, causal)),
+                               rtol=1e-5, atol=1e-5)
