@@ -1,37 +1,10 @@
-"""Headline benchmark: ResNet-50 SGP train-step throughput on TPU.
+"""Four CPU-side comparison modes; no chip measurement lives here.
 
-Prints ONE JSON line:
-    {"metric": ..., "value": N, "unit": ..., "vs_baseline": N, ...}
+Speed on the chip is measured by the benchmark (``BENCHMARK.json``,
+``python benchmark/run.py --workload <cell>``; PERF.md).  ``python
+bench.py`` with no mode says so and exits 2.
 
-The reference's headline benchmark family is ResNet-50/ImageNet
-time-per-iteration and derived images/sec (BASELINE.md; reference
-visualization/plotting.py:315-345).
-
-The headline mode is ONE process on the TPU: no probe child, no CPU
-number, no cached capture, no retry.  A run that finds no TPU exits
-non-zero saying so; a number from any other backend is not a speed
-number and is never printed under this metric's name.
-
-Extra diagnostics beyond the headline number:
-
-* ``mfu``       — model FLOP utilization, from XLA's compiled cost
-                  analysis over the device's peak bf16 FLOP/s.
-* ``fwd_ms``    — forward-only latency (inference step), so perf loss can
-                  be localized between forward, backward+opt, and gossip.
-* ``step_ms``   — full train-step latency (fwd, bwd, torch-semantics SGD,
-                  push-sum gossip round, metrics).
-
-This measures the *full* SGP train step — on a single chip the gossip
-collective degenerates to identity but stays in the program, so the
-compiled step is structurally identical to the multi-chip one.
-
-Env knobs: BENCH_BATCH, BENCH_IMAGE, BENCH_WARMUP, BENCH_STEPS,
-BENCH_SCAN (steps fused per dispatch), BENCH_AR=0 to skip the AllReduce
-comparison, BENCH_PHASES=0 to skip the forward-only breakdown.  The
-peak-FLOP/s table is keyed by ``device_kind``; a device it does not
-know is an error, not an assumed peak.
-
-Secondary mode — ``python bench.py --gossip-vs-ar`` (ROADMAP's
+First mode — ``python bench.py --gossip-vs-ar`` (ROADMAP's
 ``--global_avg_every`` wall-clock item): times gossip + periodic exact
 averaging against AllReduce-every-step on a world-8 virtual CPU mesh,
 instrumented through the telemetry span tracer, and writes a BENCH-style
@@ -55,7 +28,7 @@ differ.  On the CPU test backend the kernel runs through the Pallas
 interpreter, so its step time there is a correctness artifact, not a
 measurement.
 
-Third mode — ``python bench.py --synth-vs-registry``: model-only
+Second mode — ``python bench.py --synth-vs-registry``: model-only
 artifact for the planner's schedule *synthesizer* (planner/
 synthesize.py).  Runs the seeded beam search at world 12 and 48 on the
 16:1 DCN-dominant fabric plus a uniform-fabric control, and stamps the
@@ -71,12 +44,17 @@ fabric), and the world-48 case stamps the Spearman rank correlation
 between modeled priced cost and simulated seconds per consensus e-fold
 across the full candidate grid — gated at >= 0.8.
 
-Fourth mode — ``python bench.py --sim-scale``: consensus-vs-simulated-
+Third mode — ``python bench.py --sim-scale``: consensus-vs-simulated-
 wall-clock curves at pod worlds (256/1024/4096 x ring/exponential/
 npeer-exponential) on the 16:1 DCN fabric, from the sim/ package's
 exact engine.  Artifact: artifacts/bench_sim_scale.json (knobs
 BENCH_SIM_TOPOLOGIES/WORLDS/STEPS/OUT).  With ``--selftest``, gates
 curve coverage and the exponential-beats-ring wall-clock ordering.
+
+Fourth mode — ``python bench.py --overlap-vs-sync``: step time of the
+overlap phase schedule against synchronous gossip on the world-8 CPU
+mesh (``run_overlap_vs_sync`` lists the knobs); ``--selftest`` gates
+parity.
 """
 
 import json
@@ -84,272 +62,6 @@ import os
 import subprocess
 import sys
 import time
-
-REFERENCE_IMAGES_PER_SEC_PER_WORKER = 300.0  # see BASELINE.md
-
-# peak dense bf16 TFLOP/s per chip, by device_kind substring (public specs)
-PEAK_BF16_TFLOPS = (
-    ("v6 lite", 918.0),   # Trillium / v6e
-    ("v6e", 918.0),
-    ("v5 lite", 197.0),   # v5e
-    ("v5e", 197.0),
-    ("v5p", 459.0),
-    ("v5", 459.0),
-    ("v4", 275.0),
-    ("v3", 123.0),
-    ("v2", 45.0),
-)
-
-BATCH = int(os.environ.get("BENCH_BATCH", "128"))  # flagship config:
-# the batch BASELINE.md's sweep picked; plain `python bench.py` measures it
-IMAGE = int(os.environ.get("BENCH_IMAGE", "224"))
-# at least one warmup call (compile) and one timed step, whatever the env says
-WARMUP = max(1, int(os.environ.get("BENCH_WARMUP", "5")))
-STEPS = max(1, int(os.environ.get("BENCH_STEPS", "20")))
-SCAN = int(os.environ.get("BENCH_SCAN", "5"))
-
-
-def peak_tflops(device_kind: str) -> float:
-    kind = device_kind.lower()
-    for sub, tf in PEAK_BF16_TFLOPS:
-        if sub in kind:
-            return tf
-    raise ValueError(
-        f"no peak bf16 TFLOP/s known for device_kind {device_kind!r}: add "
-        "it to PEAK_BF16_TFLOPS with its source (an assumed peak is a "
-        "made-up MFU)")
-
-
-def _flops_of(compiled) -> float:
-    """Total-program FLOPs from XLA's cost analysis."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0]
-    return float(ca["flops"])
-
-
-def run_measurement() -> dict:
-    """The headline benchmark, in this process, on the TPU."""
-    from stochastic_gradient_push_tpu.utils.compile_cache import (
-        place_compile_cache)
-
-    place_compile_cache()
-
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    platform = jax.default_backend()
-    if platform != "tpu":
-        raise SystemExit(
-            f"bench.py: no TPU (backend is {platform!r}); the headline "
-            "benchmark measures the chip and has no other path")
-
-    from stochastic_gradient_push_tpu.algorithms import sgp
-    from stochastic_gradient_push_tpu.data import synthetic_classification
-    from stochastic_gradient_push_tpu.models import resnet50
-    from stochastic_gradient_push_tpu.parallel import (
-        GOSSIP_AXIS, make_gossip_mesh)
-    from stochastic_gradient_push_tpu.topology import (
-        NPeerDynamicDirectedExponentialGraph, RingGraph, build_schedule)
-    from stochastic_gradient_push_tpu.train import (
-        LRSchedule, build_train_step, init_train_state, replicate_state,
-        sgd, shard_scanned_train_step, shard_train_step)
-
-    world = jax.device_count()
-    device_kind = jax.devices()[0].device_kind
-    peak = peak_tflops(device_kind)
-    mesh = make_gossip_mesh(world)
-
-    # BENCH_S2D=1: the space-to-depth stem (models/resnet.py; equivalent
-    # math, denser MXU tiling) — sweepable on chip next to the default
-    stem_s2d = os.environ.get("BENCH_S2D", "0") == "1"
-    # BENCH_NORM: bn (default) | bn16 (compute-dtype batch stats) |
-    # folded (running-stats-only attribution probe) — the MFU backward
-    # experiments from docs/MFU_ANALYSIS.md
-    norm_variant = os.environ.get("BENCH_NORM", "bn")
-    model = resnet50(num_classes=1000, dtype=jnp.bfloat16,
-                     stem_s2d=stem_s2d, norm_variant=norm_variant)
-    graph_cls = (NPeerDynamicDirectedExponentialGraph if world > 2
-                 else RingGraph)
-    graph = graph_cls(world, peers_per_itr=1) if world > 1 else \
-        NPeerDynamicDirectedExponentialGraph(1, peers_per_itr=1)
-    schedule = build_schedule(graph)
-    alg = sgp(schedule, GOSSIP_AXIS)
-    tx = sgd(momentum=0.9, weight_decay=1e-4, nesterov=True)
-    # "folded" freezes every BN to its running stats — an ATTRIBUTION
-    # probe (docs/MFU_ANALYSIS.md): the step-time delta vs "bn" measures
-    # the BN reduction passes.  An unnormalized ResNet-50 is not
-    # trainable, so run it at lr=0 (identical compute per step; params
-    # stay at init, keeping the loss finite for the validity guard)
-    attribution_only = norm_variant == "folded"
-    lr_sched = LRSchedule(ref_lr=0.0 if attribution_only else 0.1,
-                          batch_size=BATCH, world_size=world,
-                          warmup=True)
-    step = build_train_step(model, alg, tx, lr_sched, itr_per_epoch=1000,
-                            num_classes=1000)
-    if SCAN > 1:
-        train_fn = shard_scanned_train_step(step, mesh, n_steps=SCAN)
-    else:
-        train_fn = shard_train_step(step, mesh)
-
-    state = replicate_state(
-        init_train_state(model, jax.random.PRNGKey(0),
-                         jnp.zeros((BATCH, IMAGE, IMAGE, 3), jnp.float32),
-                         tx, alg),
-        world)
-
-    images, labels = synthetic_classification(
-        world * BATCH, num_classes=1000, image_size=IMAGE, seed=0)
-    x = images.reshape(world, BATCH, IMAGE, IMAGE, 3)
-    y = labels.reshape(world, BATCH)
-    if SCAN > 1:
-        x = np.broadcast_to(x[None], (SCAN,) + x.shape).copy()
-        y = np.broadcast_to(y[None], (SCAN,) + y.shape).copy()
-
-    # pin the batch on device once: the benchmark measures the train step,
-    # not the ~190 MB/call host->device transfer
-    from jax.sharding import NamedSharding
-    from jax.sharding import PartitionSpec as P
-    spec = P(None, GOSSIP_AXIS) if SCAN > 1 else P(GOSSIP_AXIS)
-    x = jax.device_put(x, NamedSharding(mesh, spec))
-    y = jax.device_put(y, NamedSharding(mesh, spec))
-
-    # FLOPs for MFU: compile ahead-of-time so the cost analysis and the
-    # timed executions share one executable (no double compile)
-    compiled = train_fn.lower(state, x, y).compile()
-    # XLA's cost analysis counts a lax.scan body ONCE regardless of
-    # trip count (verified empirically), so the scanned program's flops
-    # already equal one iteration's flops — no division by SCAN
-    flops_per_itr = _flops_of(compiled)
-
-    def fence(state, metrics):
-        """Completion fence: ``block_until_ready`` on the state plus a
-        host readback of a value that depends on the whole step."""
-        jax.block_until_ready(state)
-        return float(np.min(np.asarray(jax.device_get(metrics["loss"]))))
-
-    def time_step(step_fn, st, warmup):
-        """Shared measurement discipline: warm up, fence, run STEPS timed
-        iterations, fence; returns (final state, loss, seconds)."""
-        m = None
-        for _ in range(warmup):
-            st, m = step_fn(st, x, y)
-        fence(st, m)
-        t0 = time.perf_counter()
-        for _ in range(STEPS):
-            st, m = step_fn(st, x, y)
-        loss = fence(st, m)
-        return st, loss, time.perf_counter() - t0
-
-    state, loss, dt = time_step(compiled, state, WARMUP)
-    if not np.isfinite(loss):
-        raise RuntimeError(f"non-finite loss {loss} — benchmark invalid")
-
-    time_per_itr = dt / (STEPS * SCAN)
-    images_per_sec = world * BATCH / time_per_itr
-    per_chip = images_per_sec / world
-
-    out = {
-        "metric": "resnet50_sgp_images_per_sec_per_chip",
-        "value": round(per_chip, 2),
-        "unit": "images/sec/chip",
-        "scan": SCAN,
-        "batch": BATCH,
-        **({"stem_s2d": True} if stem_s2d else {}),
-        **({"norm": norm_variant} if norm_variant != "bn" else {}),
-        **({"attribution_only": True} if attribution_only else {}),
-        "platform": platform,
-        "device": device_kind,
-        "step_ms": round(time_per_itr * 1e3, 3),
-        "vs_baseline": round(
-            per_chip / REFERENCE_IMAGES_PER_SEC_PER_WORKER, 3),
-    }
-
-    mfu = (flops_per_itr / time_per_itr) / (peak * 1e12 * world)
-    out["mfu"] = round(mfu, 4)
-    out["tflops_per_itr"] = round(flops_per_itr / 1e12, 3)
-
-    if os.environ.get("BENCH_AR", "1") == "1":
-        # secondary metric (BASELINE.json): SGP-vs-AR step latency — the
-        # same step with exact AllReduce in place of the gossip round
-        from stochastic_gradient_push_tpu.algorithms import all_reduce
-
-        ar_step = build_train_step(model, all_reduce(GOSSIP_AXIS), tx,
-                                   lr_sched, itr_per_epoch=1000,
-                                   num_classes=1000)
-        if SCAN > 1:
-            ar_fn = shard_scanned_train_step(ar_step, mesh, n_steps=SCAN)
-        else:
-            ar_fn = shard_train_step(ar_step, mesh)
-        ar_state = replicate_state(
-            init_train_state(model, jax.random.PRNGKey(0),
-                             jnp.zeros((BATCH, IMAGE, IMAGE, 3),
-                                       jnp.float32),
-                             tx, all_reduce(GOSSIP_AXIS)),
-            world)
-        _, _, ar_dt = time_step(ar_fn, ar_state, max(2, WARMUP // 2))
-        ar_ms = ar_dt / (STEPS * SCAN) * 1e3
-        out["ar_step_ms"] = round(ar_ms, 3)
-        out["gossip_overhead_ms"] = round(time_per_itr * 1e3 - ar_ms, 3)
-
-    if os.environ.get("BENCH_PHASES", "1") == "1":
-        # forward-only latency on de-biased params: localizes perf between
-        # forward, backward+opt, and gossip
-        def fwd(state, x):
-            z = alg.eval_params(
-                jax.tree.map(lambda a: a[0], state.params),
-                jax.tree.map(lambda a: a[0], state.gossip))
-            bstats = jax.tree.map(lambda a: a[0], state.batch_stats)
-            return model.apply({"params": z, "batch_stats": bstats},
-                               x[0] if SCAN == 1 else x[0, 0],
-                               train=False)
-
-        fwd_j = jax.jit(fwd)
-        _ = np.asarray(jax.device_get(fwd_j(state, x)))[0, 0]
-        t0 = time.perf_counter()
-        for _ in range(STEPS):
-            r = fwd_j(state, x)
-        _ = np.asarray(jax.device_get(r))[0, 0]  # completion fence
-        out["fwd_ms"] = round((time.perf_counter() - t0) / STEPS * 1e3, 3)
-
-        # forward+backward (training-mode BN, same loss as the step, no
-        # optimizer/gossip): with fwd_ms and step_ms this decomposes the
-        # step into fwd / bwd / optimizer+gossip — the round-3 verdict's
-        # open question (backward+optimizer was ~75% of the step at
-        # batch 128 with no attribution)
-        from stochastic_gradient_push_tpu.train.metrics import (
-            kl_div_loss, one_hot)
-
-        def fwdbwd(state, x, y):
-            z = alg.eval_params(
-                jax.tree.map(lambda a: a[0], state.params),
-                jax.tree.map(lambda a: a[0], state.gossip))
-            bstats = jax.tree.map(lambda a: a[0], state.batch_stats)
-            xx = x[0] if SCAN == 1 else x[0, 0]
-            yy = y[0] if SCAN == 1 else y[0, 0]
-
-            def loss_fn(p):
-                out_, _ = model.apply(
-                    {"params": p, "batch_stats": bstats}, xx,
-                    train=True, mutable=["batch_stats"])
-                return kl_div_loss(out_, one_hot(yy, 1000))
-
-            return jax.grad(loss_fn)(z)
-
-        bwd_j = jax.jit(fwdbwd)
-        g = bwd_j(state, x, y)
-        jax.block_until_ready(g)
-        t0 = time.perf_counter()
-        for _ in range(STEPS):
-            g = bwd_j(state, x, y)
-        jax.block_until_ready(g)
-        _ = float(np.asarray(jax.device_get(
-            jax.tree.leaves(g)[0])).ravel()[0])  # completion fence
-        out["fwdbwd_ms"] = round(
-            (time.perf_counter() - t0) / STEPS * 1e3, 3)
-
-    return out
 
 
 def _resolve_bench_kernel():
@@ -1191,8 +903,11 @@ def _child_env(base: dict) -> dict:
 
 
 def main() -> int:
-    print(json.dumps(run_measurement()), flush=True)
-    return 0
+    print("bench.py has no default mode.  CPU comparison modes: "
+          "--gossip-vs-ar, --overlap-vs-sync, --synth-vs-registry, "
+          "--sim-scale.  Chip measurements: python benchmark/run.py "
+          "--workload <cell> (BENCHMARK.json, PERF.md)", file=sys.stderr)
+    return 2
 
 
 if __name__ == "__main__":

@@ -176,10 +176,7 @@ def selftest() -> int:
     import jax.numpy as jnp
     from jax.sharding import Mesh
 
-    from stochastic_gradient_push_tpu.algorithms import sgp
-    from stochastic_gradient_push_tpu.models.transformer import (
-        TransformerConfig, TransformerLM)
-    from stochastic_gradient_push_tpu.parallel import GOSSIP_AXIS
+    from stochastic_gradient_push_tpu.run import gossip_lm
     from stochastic_gradient_push_tpu.serve.bench import (
         run_bench, synthetic_requests, write_artifact)
     from stochastic_gradient_push_tpu.serve.engine import (
@@ -191,12 +188,7 @@ def selftest() -> int:
     from stochastic_gradient_push_tpu.supervise.reshard import (
         reshard_state)
     from stochastic_gradient_push_tpu.telemetry import make_run_telemetry
-    from stochastic_gradient_push_tpu.topology import (
-        DynamicDirectedExponentialGraph, build_schedule)
-    from stochastic_gradient_push_tpu.train import LRSchedule, sgd
-    from stochastic_gradient_push_tpu.train.lm import (
-        build_lm_train_step, init_lm_state, make_dp_sp_mesh,
-        shard_lm_train_step)
+    from stochastic_gradient_push_tpu.utils import make_logger
     from stochastic_gradient_push_tpu.utils.checkpoint import (
         CheckpointManager)
 
@@ -212,21 +204,13 @@ def selftest() -> int:
     #    per-rank different data (the consensus is a real mixture)
     WORLD, BATCH, SEQ, VOCAB, HEADS = 4, 2, 16, 64, 4
     EPOCHS, ITR = 2, 4
-    mesh = make_dp_sp_mesh(WORLD, 1)
-    model = TransformerLM(TransformerConfig(
-        vocab_size=VOCAB, d_model=32, n_layers=2, n_heads=HEADS,
-        d_ff=64, max_len=32, attn_impl="full"))
-    alg = sgp(build_schedule(
-        DynamicDirectedExponentialGraph(WORLD, peers_per_itr=1)),
-        GOSSIP_AXIS)
-    tx = sgd(momentum=0.9, weight_decay=0.0)
-    lrs = LRSchedule(ref_lr=0.1, batch_size=BATCH * WORLD,
-                     world_size=WORLD, decay_schedule={}, warmup=False)
-    step = build_lm_train_step(model, alg, tx, lrs, itr_per_epoch=ITR,
-                               seq_axis=None)
-    train_fn = shard_lm_train_step(step, mesh, seq_axis=None)
-    state = init_lm_state(model, mesh, alg, tx, dp=WORLD, sp=1,
-                          batch_size=BATCH, block_len=SEQ, seq_axis=None)
+    job = gossip_lm.build_training(gossip_lm.parse_args([
+        "--world_size", str(WORLD), "--graph_type", "0",
+        "--vocab_size", str(VOCAB), "--d_model", "32", "--n_layers", "2",
+        "--n_heads", str(HEADS), "--d_ff", "64", "--seq_len", str(SEQ),
+        "--attn", "full", "--batch_size", str(BATCH), "--lr", "0.4",
+        "--seed", "0"]), make_logger("serve-selftest", False))
+    model, train_fn, state = job.model, job.train_fn, job.state
     rng = np.random.default_rng(0)
     loss = float("nan")
     for _ in range(EPOCHS * ITR):

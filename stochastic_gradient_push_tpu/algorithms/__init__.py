@@ -2,6 +2,7 @@
 
 from .api import GossipAlgorithm, GossipState
 from .algorithms import (
+    GOSSIP_MODES,
     AllReduce,
     BilateralGossip,
     PushPullGossip,
@@ -11,6 +12,8 @@ from .algorithms import (
     dpsgd,
     drain_in_flight,
     drain_state,
+    gossip_algorithm,
+    gossip_mode,
     osgp,
     sgp,
 )
@@ -27,6 +30,9 @@ __all__ = [
     "osgp",
     "dpsgd",
     "adpsgd",
+    "GOSSIP_MODES",
+    "gossip_mode",
+    "gossip_algorithm",
     "drain_in_flight",
     "drain_state",
 ]

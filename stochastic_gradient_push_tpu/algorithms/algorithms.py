@@ -24,6 +24,7 @@ from ..topology.schedule import GossipSchedule
 from .api import GossipAlgorithm, GossipState, Params
 
 __all__ = ["all_reduce", "sgp", "osgp", "dpsgd", "adpsgd",
+           "GOSSIP_MODES", "gossip_mode", "gossip_algorithm",
            "drain_in_flight", "drain_state",
            "AllReduce", "PushSumGossip", "PushPullGossip", "BilateralGossip"]
 
@@ -714,3 +715,97 @@ def dpsgd(schedule: GossipSchedule, axis_name: str,
 
 def adpsgd(pairing: np.ndarray, axis_name: str) -> BilateralGossip:
     return BilateralGossip(pairing, axis_name)
+
+
+# -- from a job's gossip settings to its algorithm ---------------------------
+
+# also the ``algorithm`` stamp of the harnesses' run_meta events
+GOSSIP_MODES = ("all_reduce", "adpsgd", "bilat_async", "sgp", "dpsgd")
+
+
+def gossip_mode(*, all_reduce: bool, push_sum: bool, bilat: bool = False,
+                bilat_async: bool = False) -> str:
+    """The selection flags of the module docstring's table as one name."""
+    if all_reduce:
+        return "all_reduce"
+    if bilat_async:
+        return "bilat_async"
+    if bilat:
+        return "adpsgd"
+    return "sgp" if push_sum else "dpsgd"
+
+
+def gossip_algorithm(mode: str, axis_name: str, *, world: int,
+                     graph_class=None, peers_per_itr: int = 1, mixing=None,
+                     overlap: bool = False, staleness: int = 1,
+                     gossip_every: int = 1, wire_dtype: str | None = None,
+                     wire_block: int = 64, error_feedback: bool = False,
+                     global_avg_every: int = 0,
+                     inject_faults: str | None = None,
+                     gossip_kernel="xla", gossip_buckets: int = 1,
+                     log=None) -> GossipAlgorithm:
+    """The algorithm a job's gossip settings describe — the one place
+    both harnesses (``train/loop.py::Trainer``, ``run/gossip_lm.py``)
+    turn settings into a :class:`GossipAlgorithm`.
+
+    ``mode`` is one of :data:`GOSSIP_MODES`; ``graph_class(world,
+    peers_per_itr=...)`` builds the communication graph and ``mixing``
+    (a ``MixingStrategy`` or None = uniform) its weights;
+    ``inject_faults`` is a fault spec (resilience/faults.py grammar)
+    compiled here against the schedule it will run on.  A knob the mode
+    does not have is a ``ValueError``, never silently dropped.
+    """
+    from ..parallel.wire import get_codec
+    from ..topology import build_pairing_schedule, build_schedule
+
+    if mode not in GOSSIP_MODES:
+        raise ValueError(f"unknown gossip mode {mode!r}; one of "
+                         f"{GOSSIP_MODES}")
+    codec = get_codec(wire_dtype, wire_block)
+    if mode != "sgp":
+        if codec is not None and codec.lossy:
+            raise ValueError("wire compression (wire_dtype) applies to "
+                             "the push-sum family only")
+        if error_feedback:
+            raise ValueError(
+                "error_feedback rides the push-sum gossip wire; "
+                "all_reduce/bilateral/D-PSGD modes have none")
+        if gossip_every != 1:
+            raise ValueError("gossip_every is a push-sum knob")
+    if mode not in ("sgp", "dpsgd"):
+        if global_avg_every:
+            raise ValueError(
+                "global_avg_every applies to the push-sum/D-PSGD gossip "
+                "family (all_reduce is already exact every step)")
+        if inject_faults:
+            raise ValueError(
+                "inject_faults breaks gossip edges; all_reduce/bilateral "
+                "modes have none (use push-sum gossip)")
+    if mode == "all_reduce":
+        return all_reduce(axis_name)
+    if mode == "bilat_async":
+        # no collective in the compiled step: the bilateral averaging
+        # runs host-side (train/async_bilat.py); pure local SGD here
+        return GossipAlgorithm()
+    graph = graph_class(world, peers_per_itr=peers_per_itr)
+    if mode == "adpsgd":
+        return adpsgd(build_pairing_schedule(graph), axis_name)
+    schedule = build_schedule(graph, mixing)
+    faults = None
+    if inject_faults:
+        # compiled against THIS schedule: masks are per-(phase, edge), so
+        # a peers_per_itr change rebuilds them
+        from ..resilience import parse_fault_spec
+
+        fault_plan = parse_fault_spec(inject_faults)
+        faults = fault_plan.build_masks(schedule, gossip_every=gossip_every)
+        if log is not None:
+            log.warning("gossip faults: %s", fault_plan.summary())
+    common = dict(overlap=overlap, staleness=staleness,
+                  global_avg_every=global_avg_every, faults=faults,
+                  gossip_kernel=gossip_kernel,
+                  gossip_buckets=gossip_buckets)
+    if mode == "sgp":
+        return sgp(schedule, axis_name, gossip_every=gossip_every,
+                   wire=codec, error_feedback=error_feedback, **common)
+    return dpsgd(schedule, axis_name, **common)

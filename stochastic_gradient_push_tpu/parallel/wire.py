@@ -125,9 +125,8 @@ class F32Codec(WireCodec):
 
 class BF16Codec(WireCodec):
     """Truncate payloads to bfloat16 on the wire (half the bytes,
-    ~1e-3 relative quantization error per round).  Reproduces the legacy
-    ``gossip_comm_dtype=bf16`` cast exactly: same astype down before the
-    ppermute, same astype back up at the receiver."""
+    ~1e-3 relative quantization error per round): an astype down before
+    the ppermute, an astype back up at the receiver."""
 
     name = "bf16"
     lossy = True

@@ -14,106 +14,51 @@ Example (virtual 8-device CPU mesh, 4 replicas × 2 sequence shards):
       python -m stochastic_gradient_push_tpu.run.gossip_lm \\
       --world_size 8 --sp 2 --seq_len 64 --d_model 64 --n_layers 2 \\
       --num_steps 100 --checkpoint_dir /tmp/lm/
+
+``main`` is parse → validate → telemetry → :func:`build_training` → resume
+→ data → :func:`train_loop`.  ``build_training`` is the one place the flags
+become a job (mesh, model, algorithm, step, state); a caller that wants
+the job without the run — a benchmark, a serving selftest — asks it.
 """
 
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import os
 import time
+import typing
 
 from ..topology import GRAPH_TOPOLOGIES, TOPOLOGY_NAMES
-from .gossip_sgd import (add_fleet_flags, add_kernel_flag,
-                         add_profile_flags, add_staleness_flag,
-                         add_synth_flags, add_wire_flags,
-                         reject_push_sum_wire_knobs,
-                         resolve_fleet_flags, resolve_kernel_flag,
-                         resolve_profile_flags, resolve_staleness_flag,
-                         resolve_wire_flags, synth_plan_config,
-                         wire_plan_config)
+# the resolve_* / *_plan_config names are read from this module by callers
+# that assemble a job flag by flag (benchmark/builders/)
+from .gossip_sgd import (_str_bool as sb, add_shared_flags,  # noqa: F401
+                         plan_gossip, reject_push_sum_wire_knobs,
+                         resolve_kernel_flag, resolve_shared_flags,
+                         resolve_staleness_flag, resolve_wire_flags,
+                         synth_plan_config, wire_plan_config)
 
-__all__ = ["main", "build_parser"]
+__all__ = ["main", "build_parser", "parse_args", "Training",
+           "build_training", "train_loop"]
 
 
 def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(description="Gossip LM on TPU")
-    # algorithm (same registry/flags as gossip_sgd where applicable)
-    p.add_argument("--all_reduce", default="False", type=str)
-    p.add_argument("--push_sum", default="True", type=str)
-    p.add_argument("--overlap", default="False", type=str)
-    add_staleness_flag(p)
+    # algorithm, planner, resilience, run basics (same as gossip_sgd)
+    add_shared_flags(p)
     p.add_argument("--bilat", default="False", type=str,
                    help="AD-PSGD: bilateral perfect-matching averaging "
                         "(synchronous formulation; see algorithms.py)")
-    p.add_argument("--graph_type", default=5, type=int,
-                   choices=list(GRAPH_TOPOLOGIES))
-    p.add_argument("--topology", default=None,
-                   choices=["auto"] + sorted(TOPOLOGY_NAMES),
-                   help="named topology: 'auto' lets the planner pick "
-                        "the gossip graph for the replica count; "
-                        "'synth' searches a hybrid psum/ppermute "
-                        "schedule against the priced fabric (registry "
-                        "fallback when not beaten); a name forces it "
-                        "(overriding --graph_type) with a below-floor "
-                        "warning when its gap is too small")
-    add_synth_flags(p)
-    p.add_argument("--gap_floor", default=0.01, type=float,
-                   help="minimum acceptable rotation-cycle spectral gap "
-                        "for the gossip graph (planner policy)")
-    p.add_argument("--global_avg_every", default=None, type=int,
-                   help="exact global average every k steps; unset = "
-                        "the planner decides (enabled when no gossip "
-                        "graph clears the gap floor), 0 = explicitly "
-                        "off, k = force every-k averaging")
-    p.add_argument("--slice_size", default=None, type=int,
-                   help="gossip replicas per ICI slice on a multi-slice "
-                        "pod: the planner prices intra-slice edges at "
-                        "torus-hop ICI cost and cross-slice edges at the "
-                        "DCN weight, and a planned/forced 'hierarchical' "
-                        "topology adopts this slice decomposition; "
-                        "unset = uniform fabric")
-    p.add_argument("--dcn_cost", default=None, type=float,
-                   help="relative per-byte cost of one inter-slice (DCN) "
-                        "message (ICI hop = 1.0; default 16 when any "
-                        "fabric flag is set)")
-    p.add_argument("--ici_cost", default=None, type=float,
-                   help="relative per-byte cost of one intra-slice ICI "
-                        "torus hop (default 1.0)")
-    p.add_argument("--mixing_alpha", default=None, type=str,
-                   help="SelfWeightedMixing self-mass: 'auto' co-"
-                        "optimizes alpha against the chosen topology "
-                        "(planner scalar search); a float in (0,1) "
-                        "forces it (with a warning when co-optimization "
-                        "would recover >10%% of the gap); unset = "
-                        "uniform mixing")
-    p.add_argument("--inject_faults", default=None, type=str,
-                   help="deterministic fault injection at the gossip "
-                        "boundary (resilience/faults.py grammar, e.g. "
-                        "'drop:0->1@10:40;straggler:3@20:30;seed:7'); "
-                        "mass-conserving drop semantics, push-sum "
-                        "synchronous mode only")
     p.add_argument("--health_every", default=0, type=int,
                    help="emit a structured 'gossip health:' line every k "
                         "steps; excursions arm the recovery policy "
                         "(immediate exact global average); flat dp/sp "
                         "meshes only; 0 disables")
-    p.add_argument("--residual_floor", default=0.01, type=float,
-                   help="consensus-residual level above which recovery "
-                        "fires an immediate exact global average "
-                        "(requires --health_every > 0)")
     p.add_argument("--peers_per_itr", default=1, type=int)
-    p.add_argument("--gossip_every", default=1, type=int,
-                   help="gossip on every k-th step (communication thinning)")
-    add_wire_flags(p)
-    add_kernel_flag(p)
-    add_fleet_flags(p)
     # optimization
     p.add_argument("--lr", default=0.5, type=float)
-    p.add_argument("--momentum", default=0.9, type=float)
     p.add_argument("--weight_decay", default=0.0, type=float)
-    p.add_argument("--nesterov", default="False", type=str)
-    p.add_argument("--warmup", default="False", type=str)
     p.add_argument("--warmup_steps", default=None, type=int,
                    help="linear warmup horizon (default: num_steps // 10)")
     # model
@@ -174,18 +119,14 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--batch_size", default=8, type=int,
                    help="sequences per replica per step")
     p.add_argument("--num_steps", default=1000, type=int)
-    p.add_argument("--print_freq", default=10, type=int)
-    p.add_argument("--seed", default=47, type=int)
     p.add_argument("--corpus_tokens", default=500_000, type=int)
     p.add_argument("--corpus_file", default=None,
                    help="real corpus: .npy/.npz pre-tokenized int array, "
                         "or any file read as raw bytes (byte-level LM, "
                         "vocab_size >= 256); default: synthetic Markov")
-    p.add_argument("--checkpoint_dir", default="./checkpoints", type=str)
     p.add_argument("--tag", default="lm_", type=str)
     p.add_argument("--ckpt_every", default=0, type=int,
                    help="checkpoint every N steps (0 = only at the end)")
-    p.add_argument("--resume", default="False", type=str)
     p.add_argument("--ckpt_backend", default="msgpack",
                    choices=["msgpack", "orbax"],
                    help="checkpoint backend (same as gossip_sgd): "
@@ -194,12 +135,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "native checkpoint).  ep/tp/pp multihost meshes "
                         "force orbax regardless — their state shards on "
                         "non-leading dims")
-    p.add_argument("--heartbeat_timeout", default=300, type=int,
-                   help="log an error if a blocking metrics fetch stalls "
-                        "longer than this many seconds (a dead peer host "
-                        "shows up as a hung collective; ≙ the 300s "
-                        "gossip-flag timeout, distributed.py:36); 0 "
-                        "disables")
     p.add_argument("--val_frac", default=0.0, type=float,
                    help="hold out this fraction of the corpus tail for "
                         "validation (0 = off); val_loss/val_ppl columns "
@@ -210,26 +145,11 @@ def build_parser() -> argparse.ArgumentParser:
                         "rows ride the CSV print cadence")
     p.add_argument("--val_batches", default=8, type=int,
                    help="validation batches per evaluation")
-    add_profile_flags(p)
-    p.add_argument("--trace_dir", default=None, type=str,
-                   help="run telemetry directory (telemetry/): "
-                        "trace.json host spans + events.jsonl typed "
-                        "plan/health/recovery/comm events; analyze with "
-                        "scripts/obsreport.py.  Unset = telemetry off")
     p.add_argument("--metrics_every", default=0, type=int,
                    help="emit a step_stats + comm telemetry event every "
                         "k steps (rides the --print_freq metrics fetch "
                         "cadence; 0 = only the final comm snapshot); "
                         "requires --trace_dir")
-    # multi-host (same surface as gossip_sgd)
-    p.add_argument("--multihost", default="auto",
-                   choices=["auto", "True", "False"],
-                   help="True/False/auto: join a jax.distributed cluster "
-                        "(auto = when SLURM/coordinator env vars are "
-                        "present or on a TPU pod slice)")
-    p.add_argument("--coordinator_address", default=None, type=str)
-    p.add_argument("--num_processes", default=None, type=int)
-    p.add_argument("--process_id", default=None, type=int)
     return p
 
 
@@ -295,7 +215,7 @@ def model_from_args(args, attn: str, seq_axis=None, ep_axis=None):
 
     from ..models.transformer import (TransformerConfig, TransformerLM,
                                       config_from_source)
-    from .gossip_sgd import _str_bool as sb
+
 
     runtime = dict(
         max_len=args.seq_len,
@@ -321,46 +241,14 @@ def model_from_args(args, attn: str, seq_axis=None, ep_axis=None):
     return TransformerLM(cfg)
 
 
-def main(argv=None):
-    from ..utils.compile_cache import place_compile_cache
-
-    place_compile_cache()
-    args = build_parser().parse_args(argv)
+def validate_args(args) -> None:
+    """Resolve and check the parsed flags in place — everything that
+    needs no device: the model file, the mesh factors, the shared gossip
+    flags (``gossip_sgd.resolve_shared_flags``: one set of error texts)
+    and the LM loop's cadences."""
     resolve_model_json(args)
-
-    import jax
-    import numpy as np
-
-    from ..algorithms import all_reduce, dpsgd, sgp
-    from ..data.lm import lm_batches, synthetic_lm_corpus
-    from ..parallel import GOSSIP_AXIS
-    from ..topology import build_schedule
-    from ..train import LRSchedule, sgd
-    from ..train.lm import (EP_AXIS, SEQ_AXIS, build_lm_train_step,
-                            ep_state_specs, init_lm_state,
-                            init_lm_state_ep, make_dp_ep_mesh,
-                            make_dp_ep_sp_mesh, make_dp_sp_mesh,
-                            make_dp_sp_tp_mesh, make_dp_tp_mesh,
-                            shard_lm_train_step)
-    from ..train.lr import WARMUP_EPOCHS
-    from ..utils import Meter, make_logger
-    from .gossip_sgd import (_multihost_env, _parse_mixing_alpha,
-                             _str_bool as sb)
-
-    want_mh = args.multihost
-    if want_mh == "True" or (want_mh == "auto" and _multihost_env()):
-        from ..parallel.discovery import initialize_multihost
-
-        initialize_multihost(args.coordinator_address, args.num_processes,
-                             args.process_id)
-
-    proc_count = jax.process_count()
-    proc_index = jax.process_index()
-    log = make_logger(f"lm p{proc_index}" if proc_count > 1 else "lm", True)
-
-    world = args.world_size or jax.device_count()
-    sp, tp, ep, pp = args.sp, args.tp, args.ep, args.pp
-    if sp < 1 or tp < 1 or ep < 1 or pp < 1:
+    sp, tp_, ep, pp = args.sp, args.tp, args.ep, args.pp
+    if sp < 1 or tp_ < 1 or ep < 1 or pp < 1:
         raise SystemExit("--sp, --tp, --ep and --pp must be >= 1")
     if pp > 1:
         # pipeline composes with gossip DP and — since round 3 — with
@@ -372,7 +260,7 @@ def main(argv=None):
         # parallelism (the MoE all_to_all dispatches token slots over ep
         # inside each tick), and with the full 4-D pp × ep × sp mesh.
         # Only tp stays fenced (ARCHITECTURE.md matrix).
-        if tp > 1:
+        if tp_ > 1:
             raise SystemExit("--pp composes with gossip DP, --sp, "
                              "--moe_experts and --ep only (not --tp)")
         if ep > 1 and not args.moe_experts:
@@ -399,59 +287,16 @@ def main(argv=None):
     if args.moe_experts and args.moe_experts % ep:
         raise SystemExit(
             f"moe_experts {args.moe_experts} not divisible by ep {ep}")
-    if world % (sp * tp * ep * pp):
-        raise SystemExit(
-            f"world_size {world} not divisible by sp*tp*ep*pp "
-            f"{sp * tp * ep * pp}")
-    dp = world // (sp * tp * ep * pp)
     if args.seq_len % sp:
         raise SystemExit(f"seq_len {args.seq_len} not divisible by sp {sp}")
-
-    # resilience/mixing flag validation (same error text as gossip_sgd,
-    # fail before any device work)
-    resolve_wire_flags(args)
-    resolve_kernel_flag(args)
-    resolve_staleness_flag(args, sb(args.overlap))
-    args.mixing_alpha = _parse_mixing_alpha(args.mixing_alpha)
-    if args.mixing_alpha is not None and (
-            sb(args.all_reduce) or not sb(args.push_sum)):
-        raise SystemExit("--mixing_alpha needs push-sum gossip: AllReduce "
-                         "doesn't mix, and D-PSGD requires a regular "
-                         "(doubly-stochastic) schedule")
-    fabric_flags = (args.slice_size is not None
-                    or args.dcn_cost is not None
-                    or args.ici_cost is not None)
-    if (args.mixing_alpha is not None or fabric_flags) \
-            and (sb(args.bilat) or sb(args.all_reduce) or dp < 2):
-        raise SystemExit("--topology auto / --mixing_alpha / fabric "
-                         "flags (--slice_size/--dcn_cost/--ici_cost) "
-                         "plan gossip schedules; they do not apply to "
-                         "all_reduce/bilateral modes or a "
-                         "single-rank world")
-    if args.inject_faults:
-        if sb(args.all_reduce) or sb(args.bilat) \
-                or not sb(args.push_sum):
-            raise SystemExit("--inject_faults needs push-sum gossip: only "
-                             "push-sum's mass accounting keeps the mean "
-                             "exact under dropped edges")
-        # overlap composes with faults (masks are keyed on the launch
-        # tick, resilience/faults.py)
-        from ..resilience import parse_fault_spec
-
-        fault_plan = parse_fault_spec(args.inject_faults)
-    else:
-        fault_plan = None
-    if args.metrics_every < 0:
-        raise SystemExit("--metrics_every must be >= 0")
-    if args.metrics_every and not args.trace_dir:
-        raise SystemExit("--metrics_every needs --trace_dir (telemetry "
-                         "events have nowhere to go without it)")
-    resolve_fleet_flags(args)
-    resolve_profile_flags(args)
-    if args.health_every < 0:
-        raise SystemExit("--health_every must be >= 0")
+    resolve_shared_flags(args)
+    if args.topology is not None and (sb(args.all_reduce)
+                                      or sb(args.bilat)):
+        raise SystemExit("--topology selects a push-sum/D-PSGD gossip "
+                         "graph; it does not apply to all_reduce/bilat "
+                         "modes")
     if args.health_every:
-        if ep > 1 or tp > 1 or pp > 1:
+        if ep > 1 or tp_ > 1 or pp > 1:
             # ep shards hold different expert slices (health signals
             # would vary over ep and break metrics replication); tp's
             # auto axis and pp's staged step are likewise health-opaque
@@ -462,86 +307,136 @@ def main(argv=None):
                 f"--health_every {args.health_every} must be a multiple "
                 f"of --print_freq {args.print_freq} (health signals ride "
                 "the metrics fetch cadence)")
+    if args.grad_accum > 1 and pp > 1:
+        raise SystemExit("--grad_accum composes with the flat meshes; "
+                         "pipeline runs control microbatching with "
+                         "--n_micro")
+    if args.grad_accum > 1 and args.batch_size % args.grad_accum:
+        raise SystemExit(
+            f"--batch_size {args.batch_size} not divisible by "
+            f"--grad_accum {args.grad_accum}")
+    if args.val_frac > 0 and args.val_every \
+            and args.val_every % args.print_freq:
+        raise SystemExit(
+            f"--val_every {args.val_every} must be a multiple of "
+            f"--print_freq {args.print_freq} (validation rows ride the "
+            "CSV print cadence)")
 
-    # run telemetry BEFORE planning so the plan event and the loop share
-    # one events.jsonl (the zero-overhead null bundle without --trace_dir)
-    from ..telemetry import make_run_telemetry
 
-    rt = make_run_telemetry(args.trace_dir, rank=proc_index, log=log,
-                            metrics_every=args.metrics_every)
+def parse_args(argv=None) -> argparse.Namespace:
+    """``argv`` → the validated namespace :func:`build_training` takes."""
+    args = build_parser().parse_args(argv)
+    validate_args(args)
+    return args
+
+
+class Training(typing.NamedTuple):
+    """What the flags describe, assembled (:func:`build_training`)."""
+
+    mesh: typing.Any
+    dp: int
+    sp: int
+    tp: int
+    ep: int
+    pp: int
+    attn: str                   # resolve_attention's answer
+    model: typing.Any
+    mode: str                   # one of algorithms.GOSSIP_MODES
+    algorithm: typing.Any           # GossipAlgorithm
+    plan: typing.Any                # planner.Plan, None when nothing to plan
+    interconnect: typing.Any        # the fabric model the plan priced on
+    lr_schedule: typing.Any
+    itr_per_epoch: int
+    step: typing.Callable           # the per-rank step, before sharding
+    train_fn: typing.Callable       # (state, tokens, targets) -> state, metrics
+    state: typing.Any
+    eval_fn: typing.Callable | None   # only with --val_frac > 0
+
+    @property
+    def world(self) -> int:
+        return self.dp * self.sp * self.tp * self.ep * self.pp
+
+    @property
+    def ring(self) -> bool:
+        """Sequence-sharded (ring-family) attention: batches carry a
+        ``seq`` dim."""
+        return self.attn in ("ring", "ring_flash")
+
+
+def _make_mesh(dp: int, sp: int, tp_: int, ep: int, pp: int):
+    """The mesh for the parallelism factors: every composition the CLI
+    accepts (ARCHITECTURE.md matrix)."""
+    from ..train import lm, pp as pipe
+
+    if pp > 1:
+        if sp > 1 and ep > 1:
+            return pipe.make_dp_pp_ep_sp_mesh(dp, pp, ep, sp)
+        if sp > 1:
+            return pipe.make_dp_pp_sp_mesh(dp, pp, sp)
+        if ep > 1:
+            return pipe.make_dp_pp_ep_mesh(dp, pp, ep)
+        return pipe.make_dp_pp_mesh(dp, pp)
+    if ep > 1 and sp > 1 and tp_ > 1:
+        return lm.make_dp_ep_sp_tp_mesh(dp, ep, sp, tp_)
+    if ep > 1 and sp > 1:
+        return lm.make_dp_ep_sp_mesh(dp, ep, sp)
+    if ep > 1 and tp_ > 1:
+        return lm.make_dp_ep_tp_mesh(dp, ep, tp_)
+    if ep > 1:
+        return lm.make_dp_ep_mesh(dp, ep)
+    if sp > 1 and tp_ > 1:
+        return lm.make_dp_sp_tp_mesh(dp, sp, tp_)
+    if tp_ > 1:
+        return lm.make_dp_tp_mesh(dp, tp_)
+    return lm.make_dp_sp_mesh(dp, sp)
+
+
+def build_training(args, log, registry=None) -> Training:
+    """From the validated flags (:func:`parse_args`) to the job they
+    describe, on every mesh the CLI accepts: plan, mesh, model,
+    algorithm, LR schedule, step, sharded train function, state and
+    (with ``--val_frac``) the eval function.  No I/O beyond log lines and
+    the plan event (``registry``): checkpoints, corpus, CSV, watchdog and
+    profiler are the caller's."""
+    import jax
+    import numpy as np
+
+    from ..algorithms import gossip_algorithm, gossip_mode
+    from ..parallel import GOSSIP_AXIS
+    from ..train import LRSchedule, sgd
+    from ..train.lm import (EP_AXIS, SEQ_AXIS, build_lm_train_step,
+                            ep_state_specs, init_lm_state,
+                            init_lm_state_ep, shard_lm_train_step)
+    from ..train.lr import WARMUP_EPOCHS
+
+    world = args.world_size or jax.device_count()
+    sp, tp_, ep, pp = args.sp, args.tp, args.ep, args.pp
+    if world % (sp * tp_ * ep * pp):
+        raise SystemExit(
+            f"world_size {world} not divisible by sp*tp*ep*pp "
+            f"{sp * tp_ * ep * pp}")
+    dp = world // (sp * tp_ * ep * pp)
 
     # launch-time topology policy BEFORE any mesh/device work (planning is
     # pure numpy, and a below-floor warning must reach the user even when
     # the launch subsequently fails): the gossip world for the LM is the
     # data-parallel replica count, not raw devices
-    plan = None
-    interconnect = None
-    synth = synth_plan_config(args)   # rejects stray --synth_* knobs
-    if not sb(args.all_reduce) and not sb(args.bilat) and dp > 1:
-        from ..planner import make_interconnect, resolve_topology
-
-        interconnect = make_interconnect(args.slice_size, args.dcn_cost,
-                                         args.ici_cost)
-        plan = resolve_topology(
-            dp, ppi=args.peers_per_itr, topology=args.topology,
-            graph_class=GRAPH_TOPOLOGIES[args.graph_type],
-            floor=args.gap_floor,
-            algorithm="sgp" if sb(args.push_sum) else "dpsgd",
-            self_weighted=(True if args.mixing_alpha == "auto"
-                           else (args.mixing_alpha or False)),
-            global_avg_every=args.global_avg_every,  # None = policy
-            interconnect=interconnect,
-            overlap=sb(args.overlap), faults=bool(args.inject_faults),
-            wire=wire_plan_config(args), synth=synth,
-            log=log, registry=rt.registry)
-    elif args.topology is not None and (sb(args.all_reduce)
-                                        or sb(args.bilat)):
-        raise SystemExit("--topology selects a push-sum/D-PSGD gossip "
-                         "graph; it does not apply to all_reduce/bilat "
-                         "modes")
-    elif args.topology in ("auto", "synth"):
-        raise SystemExit(f"--topology {args.topology} plans gossip "
-                         "schedules; it does not apply to a "
-                         "single-replica mesh")
-    if pp > 1:
-        from ..train.pp import (build_pp_train_step, init_pp_state,
-                                make_dp_pp_ep_mesh, make_dp_pp_ep_sp_mesh,
-                                make_dp_pp_mesh, make_dp_pp_sp_mesh,
-                                pp_state_specs, shard_pp_train_step)
-        if sp > 1 and ep > 1:
-            mesh = make_dp_pp_ep_sp_mesh(dp, pp, ep, sp)
-        elif sp > 1:
-            mesh = make_dp_pp_sp_mesh(dp, pp, sp)
-        elif ep > 1:
-            mesh = make_dp_pp_ep_mesh(dp, pp, ep)
-        else:
-            mesh = make_dp_pp_mesh(dp, pp)
-    elif ep > 1 and sp > 1 and tp > 1:
-        from ..train.lm import make_dp_ep_sp_tp_mesh
-        mesh = make_dp_ep_sp_tp_mesh(dp, ep, sp, tp)
-    elif ep > 1 and sp > 1:
-        mesh = make_dp_ep_sp_mesh(dp, ep, sp)
-    elif ep > 1 and tp > 1:
-        from ..train.lm import make_dp_ep_tp_mesh
-        mesh = make_dp_ep_tp_mesh(dp, ep, tp)
-    elif ep > 1:
-        mesh = make_dp_ep_mesh(dp, ep)
-    elif sp > 1 and tp > 1:
-        mesh = make_dp_sp_tp_mesh(dp, sp, tp)
-    elif tp > 1:
-        mesh = make_dp_tp_mesh(dp, tp)
-    else:
-        mesh = make_dp_sp_mesh(dp, sp)
-
-    if proc_count > 1:
+    mode = gossip_mode(all_reduce=sb(args.all_reduce),
+                       push_sum=sb(args.push_sum), bilat=sb(args.bilat))
+    plan, interconnect = plan_gossip(
+        args, dp, mode=mode, ppi=args.peers_per_itr,
+        graph_class=GRAPH_TOPOLOGIES[args.graph_type],
+        overlap=sb(args.overlap), log=log, registry=registry)
+    mesh = _make_mesh(dp, sp, tp_, ep, pp)
+    if jax.process_count() > 1:
         # per-process feeding works on every mesh; checkpoints need a
         # layout that can hold arbitrary shardings.  dp/dp×sp states
         # slice cleanly into per-process rank-row msgpack files; ep/tp/pp
         # states shard on non-leading dims (or via GSPMD), so those
         # meshes use the orbax global-state backend instead (one shared
         # root, each process writes its own shards).
-        log.info(f"process {proc_index}/{proc_count}: multihost LM over "
-                 f"{mesh}")
+        log.info(f"process {jax.process_index()}/{jax.process_count()}: "
+                 f"multihost LM over {mesh}")
 
     attn = resolve_attention(args.attn, args.seq_len, sp,
                              jax.default_backend(), log)
@@ -555,7 +450,7 @@ def main(argv=None):
                 f"--attn ring_flash needs the per-shard length "
                 f"(seq_len/sp = {shard}) divisible by "
                 f"{min(128, shard)}; pad seq_len or use --attn ring")
-    if tp > 1 and sp == 1 and ring_family:
+    if tp_ > 1 and sp == 1 and ring_family:
         raise SystemExit(
             "--tp with ring attention requires --sp > 1 (3-D mesh)")
     if ep > 1 and ring_family and sp == 1:
@@ -565,67 +460,29 @@ def main(argv=None):
     if pp > 1 and ring_family and sp == 1:
         raise SystemExit("--pp with ring attention needs --sp > 1 "
                          "(the 3-D gossip × pipe × seq mesh)")
-    if args.grad_accum > 1 and pp > 1:
-        raise SystemExit("--grad_accum composes with the flat meshes; "
-                         "pipeline runs control microbatching with "
-                         "--n_micro")
-    if args.grad_accum > 1 and args.batch_size % args.grad_accum:
-        raise SystemExit(
-            f"--batch_size {args.batch_size} not divisible by "
-            f"--grad_accum {args.grad_accum}")
+    seq_axis = SEQ_AXIS if ring_family else None
+    ep_axis = EP_AXIS if ep > 1 else None
+    model = model_from_args(args, attn, seq_axis=seq_axis, ep_axis=ep_axis)
 
-    model = model_from_args(args, attn,
-                            seq_axis=SEQ_AXIS if ring_family else None,
-                            ep_axis=EP_AXIS if ep > 1 else None)
-
-    if sb(args.all_reduce):
-        reject_push_sum_wire_knobs(args)
-        alg = all_reduce(GOSSIP_AXIS)
-    elif sb(args.bilat):
-        # AD-PSGD (synchronous matching formulation), as in gossip_sgd
-        from ..algorithms import adpsgd
-        from ..topology import build_pairing_schedule
-
-        reject_push_sum_wire_knobs(args)
-        graph = GRAPH_TOPOLOGIES[args.graph_type](
-            dp, peers_per_itr=args.peers_per_itr)
-        alg = adpsgd(build_pairing_schedule(graph), GOSSIP_AXIS)
+    if plan is not None:
+        graph_class = plan.graph_class
+    elif args.topology:  # forced name on a dp==1 mesh (plan skipped)
+        graph_class = TOPOLOGY_NAMES[args.topology]
     else:
-        if plan is not None:
-            graph_cls = plan.graph_class
-        elif args.topology:  # forced name on a dp==1 mesh (plan skipped)
-            graph_cls = TOPOLOGY_NAMES[args.topology]
-        else:
-            graph_cls = GRAPH_TOPOLOGIES[args.graph_type]
-        graph = graph_cls(dp, peers_per_itr=args.peers_per_itr)
-        schedule = build_schedule(
-            graph, plan.mixing_strategy() if plan is not None else None)
-        gae = plan.global_avg_every if plan is not None \
-            else (args.global_avg_every or 0)
-        faults = None
-        if fault_plan is not None:
-            # compiled against THIS schedule: masks are per-(phase, edge)
-            faults = fault_plan.build_masks(
-                schedule, gossip_every=args.gossip_every)
-            log.warning("gossip faults: %s", fault_plan.summary())
-        if sb(args.push_sum):
-            from ..parallel.wire import get_codec
-
-            alg = sgp(schedule, GOSSIP_AXIS, overlap=sb(args.overlap),
-                      staleness=max(1, args.staleness),
-                      gossip_every=args.gossip_every,
-                      wire=get_codec(args.wire_dtype, args.wire_block),
-                      error_feedback=bool(args.error_feedback),
-                      global_avg_every=gae, faults=faults,
-                      gossip_kernel=args.gossip_kernel,
-                      gossip_buckets=args.gossip_buckets)
-        else:
-            reject_push_sum_wire_knobs(args)
-            alg = dpsgd(schedule, GOSSIP_AXIS, overlap=sb(args.overlap),
-                        staleness=max(1, args.staleness),
-                        global_avg_every=gae, faults=faults,
-                        gossip_kernel=args.gossip_kernel,
-                        gossip_buckets=args.gossip_buckets)
+        graph_class = GRAPH_TOPOLOGIES[args.graph_type]
+    alg = gossip_algorithm(
+        mode, GOSSIP_AXIS, world=dp, graph_class=graph_class,
+        peers_per_itr=args.peers_per_itr,
+        mixing=plan.mixing_strategy() if plan is not None else None,
+        overlap=sb(args.overlap), staleness=max(1, args.staleness),
+        gossip_every=args.gossip_every, wire_dtype=args.wire_dtype,
+        wire_block=args.wire_block,
+        error_feedback=bool(args.error_feedback),
+        global_avg_every=(plan.global_avg_every if plan is not None
+                          else (args.global_avg_every or 0)),
+        inject_faults=args.inject_faults,
+        gossip_kernel=args.gossip_kernel,
+        gossip_buckets=args.gossip_buckets, log=log)
 
     tx = sgd(momentum=args.momentum, weight_decay=args.weight_decay,
              nesterov=sb(args.nesterov))
@@ -640,8 +497,11 @@ def main(argv=None):
     lrs = LRSchedule(ref_lr=args.lr, batch_size=args.batch_size,
                      world_size=dp * ep, decay_schedule={},
                      warmup=sb(args.warmup))
-    ring = ring_family
     if pp > 1:
+        from ..train.pp import (build_pp_eval_step, build_pp_train_step,
+                                init_pp_state, pp_state_specs,
+                                shard_pp_eval_step, shard_pp_train_step)
+
         step = build_pp_train_step(model, alg, tx, lrs,
                                    itr_per_epoch=itr_per_epoch)
         state = init_pp_state(model, mesh, alg, tx, dp=dp, pp=pp,
@@ -649,15 +509,13 @@ def main(argv=None):
                               micro_batch=args.batch_size // args.n_micro,
                               seq_len=args.seq_len, seed=args.seed, sp=sp,
                               ep=ep)
-        pp_ep = EP_AXIS if ep > 1 else None
-        train_fn = shard_pp_train_step(
-            step, mesh, pp_state_specs(state, ep_axis=pp_ep),
-            seq_axis=SEQ_AXIS if ring else None, ep_axis=pp_ep)
+        specs = pp_state_specs(state, ep_axis=ep_axis)
+        train_fn = shard_pp_train_step(step, mesh, specs,
+                                       seq_axis=seq_axis, ep_axis=ep_axis)
     else:
         step = build_lm_train_step(
             model, alg, tx, lrs, itr_per_epoch=itr_per_epoch,
-            seq_axis=SEQ_AXIS if ring_family else None,
-            ep_axis=EP_AXIS if ep > 1 else None,
+            seq_axis=seq_axis, ep_axis=ep_axis,
             grad_accum=args.grad_accum,
             health_axis=GOSSIP_AXIS if args.health_every > 0 else None)
         if ep > 1:
@@ -666,10 +524,10 @@ def main(argv=None):
                                      seq_len=args.seq_len, seed=args.seed,
                                      sp=sp)
             train_fn = shard_lm_train_step(
-                step, mesh, seq_axis=SEQ_AXIS if ring else None,
+                step, mesh, seq_axis=seq_axis,
                 state_specs=ep_state_specs(state), ep_axis=EP_AXIS,
-                tp=tp > 1)
-        elif tp > 1 and not ring:
+                tp=tp_ > 1)
+        elif tp_ > 1 and not ring_family:
             from ..train.lm import init_lm_state_tp
 
             state = init_lm_state_tp(model, mesh, alg, tx, dp=dp,
@@ -681,54 +539,59 @@ def main(argv=None):
             state = init_lm_state(
                 model, mesh, alg, tx, dp=dp, sp=sp,
                 batch_size=args.batch_size,
-                block_len=args.seq_len // sp if ring else args.seq_len,
-                seed=args.seed, seq_axis=SEQ_AXIS if ring else None)
+                block_len=(args.seq_len // sp if ring_family
+                           else args.seq_len),
+                seed=args.seed, seq_axis=seq_axis)
             train_fn = shard_lm_train_step(
-                step, mesh, seq_axis=SEQ_AXIS if ring else None, tp=tp > 1)
+                step, mesh, seq_axis=seq_axis, tp=tp_ > 1)
 
-    val_on = args.val_frac > 0
-    if val_on and args.val_every and args.val_every % args.print_freq:
-        raise SystemExit(
-            f"--val_every {args.val_every} must be a multiple of "
-            f"--print_freq {args.print_freq} (validation rows ride the "
-            "CSV print cadence)")
     eval_fn = None
-    if val_on and pp > 1:
-        from ..train.pp import build_pp_eval_step, shard_pp_eval_step
-
-        pp_ep = EP_AXIS if ep > 1 else None
-        ev = build_pp_eval_step(model, alg)
+    if args.val_frac > 0 and pp > 1:
         eval_fn = shard_pp_eval_step(
-            ev, mesh, pp_state_specs(state, ep_axis=pp_ep),
-            seq_axis=SEQ_AXIS if ring else None, ep_axis=pp_ep)
-    elif val_on:
+            build_pp_eval_step(model, alg), mesh, specs,
+            seq_axis=seq_axis, ep_axis=ep_axis)
+    elif args.val_frac > 0:
         from ..train.lm import build_lm_eval_step, shard_lm_eval_step
 
-        ev = build_lm_eval_step(model, alg,
-                                seq_axis=SEQ_AXIS if ring else None,
-                                ep_axis=EP_AXIS if ep > 1 else None)
         eval_fn = shard_lm_eval_step(
-            ev, mesh, seq_axis=SEQ_AXIS if ring else None, tp=tp > 1,
+            build_lm_eval_step(model, alg, seq_axis=seq_axis,
+                               ep_axis=ep_axis),
+            mesh, seq_axis=seq_axis, tp=tp_ > 1,
             state_specs=ep_state_specs(state) if ep > 1 else None,
-            ep_axis=EP_AXIS if ep > 1 else None)
+            ep_axis=ep_axis)
 
     n_params = sum(int(np.prod(np.shape(l)))
                    for l in jax.tree.leaves(
                        jax.tree.map(lambda a: a[0], state.params)))
     log.info(f"mesh {mesh}; {n_params/1e6:.2f}M params; attn={attn}")
+    return Training(mesh=mesh, dp=dp, sp=sp, tp=tp_, ep=ep, pp=pp,
+                    attn=attn, model=model, mode=mode, algorithm=alg,
+                    plan=plan, interconnect=interconnect, lr_schedule=lrs,
+                    itr_per_epoch=itr_per_epoch, step=step,
+                    train_fn=train_fn, state=state, eval_fn=eval_fn)
 
+
+def _attach_accounting(args, t: Training, rt) -> None:
+    """Comm-volume accounting and the ``run_meta`` event (telemetry/);
+    a no-op on the null bundle."""
+    import jax
+
+    if not rt.enabled:
+        return
+    dp, sp, tp_, ep, pp = t.dp, t.sp, t.tp, t.ep, t.pp
+    alg, state = t.algorithm, t.state
     # comm-volume accounting (telemetry/): flat dp / dp×sp meshes only —
     # ep/tp/pp shard params on non-leading dims, so the per-rank payload
     # arithmetic would be wrong there (same fence as --health_every)
-    if rt.enabled and pp == 1 and ep == 1 and tp == 1:
+    if pp == 1 and ep == 1 and tp_ == 1:
         from ..parallel.wire import get_codec
         from ..telemetry import (CommModel, encoded_payload_bytes,
                                  tree_payload_bytes)
 
         exact = tree_payload_bytes(state.params, dp)
-        if sb(args.all_reduce):
+        if t.mode == "all_reduce":
             comm_model = CommModel.for_allreduce(dp, exact)
-        elif sb(args.bilat):
+        elif t.mode == "adpsgd":
             comm_model = CommModel.for_bilat(dp, exact)
         else:
             # price the ENCODED payload (codec dtype + int8 scale lane;
@@ -740,7 +603,7 @@ def main(argv=None):
                 gossip_every=alg.gossip_every,
                 global_avg_every=alg.global_avg_every,
                 faults=alg.faults, ps_weight=sb(args.push_sum),
-                interconnect=interconnect, codec=codec,
+                interconnect=t.interconnect, codec=codec,
                 error_feedback=bool(args.error_feedback),
                 overlap=getattr(alg, "overlap", False),
                 staleness=getattr(alg, "staleness", 1),
@@ -748,59 +611,54 @@ def main(argv=None):
                                       "xla"),
                 gossip_buckets=getattr(alg, "gossip_buckets", 1))
         rt.attach_comm(comm_model)
-    if rt.enabled:
-        run_meta = {
-            "world": world, "dp": dp, "sp": sp, "tp": tp, "ep": ep,
-            "pp": pp,
-            "algorithm": ("all_reduce" if sb(args.all_reduce) else
-                          "adpsgd" if sb(args.bilat) else
-                          "sgp" if sb(args.push_sum) else "dpsgd"),
-            "gossip_every": args.gossip_every,
-            "batch_size": args.batch_size,
-            "num_steps": args.num_steps,
-            "comm_model": (rt.comm.model.to_dict()
-                           if rt.comm is not None else None)}
-        if args.profile_dir:
-            # where the XPlane dump lands + the captured step window,
-            # discoverable from the run directory (obsreport/fleetmon)
-            run_meta["profile_dir"] = args.profile_dir
-            run_meta["profile_window"] = [
-                args.profile_start_step,
-                args.profile_start_step + args.profile_steps]
-        if args.fleet:
-            run_meta["fleet"] = True
-            run_meta["host_id"] = (args.host_id
-                                   if args.host_id is not None
-                                   else proc_index)
-        rt.registry.emit("run_meta", run_meta)
+    run_meta = {
+        "world": t.world, "dp": dp, "sp": sp, "tp": tp_, "ep": ep,
+        "pp": pp, "algorithm": t.mode,
+        "gossip_every": args.gossip_every,
+        "batch_size": args.batch_size,
+        "num_steps": args.num_steps,
+        "comm_model": (rt.comm.model.to_dict()
+                       if rt.comm is not None else None)}
+    if args.profile_dir:
+        # where the XPlane dump lands + the captured step window,
+        # discoverable from the run directory (obsreport/fleetmon)
+        run_meta["profile_dir"] = args.profile_dir
+        run_meta["profile_window"] = [
+            args.profile_start_step,
+            args.profile_start_step + args.profile_steps]
+    if args.fleet:
+        run_meta["fleet"] = True
+        run_meta["host_id"] = (args.host_id
+                               if args.host_id is not None
+                               else jax.process_index())
+    rt.registry.emit("run_meta", run_meta)
 
-    # checkpoint/resume: state and step counter in one atomic msgpack
-    # payload (same manager as the image harness); restored leaves are
-    # device_put back into the live state's shardings.  On a pod each
-    # process saves/restores its own rank rows (per-process files), and
-    # the cluster resumes from the minimum step any process holds.
-    from ..parallel.multihost import (consensus_resume_point,
-                                      global_state_from_local,
-                                      host_local_slice, to_host)
-    from ..utils.checkpoint import (REQUEUE_EXIT_CODE, CheckpointManager,
-                                    ClusterManager)
 
+def _checkpointing(args, t: Training):
+    """``(ckpt, cluster, use_orbax)``: the checkpoint manager and the
+    preemption handler around it.  State and step counter go in one
+    atomic payload (same managers as the image harness); on a pod each
+    process saves/restores its own rank rows (per-process files)."""
+    import jax
+
+    from ..utils.checkpoint import CheckpointManager, ClusterManager
+
+    proc_count, proc_index = jax.process_count(), jax.process_index()
     # ep/tp/pp multihost states shard on non-leading dims — the rank-row
     # msgpack slicing cannot represent them, but orbax's global-state mode
     # holds any sharding (every process writes its own shards of ONE
     # logical checkpoint).  --ckpt_backend orbax selects the same backend
     # voluntarily (async saves + retention GC single-process)
     use_orbax = (args.ckpt_backend == "orbax"
-                 or (proc_count > 1 and (ep > 1 or tp > 1 or pp > 1)))
-    orbax_global = use_orbax and proc_count > 1
+                 or (proc_count > 1 and (t.ep > 1 or t.tp > 1 or t.pp > 1)))
     if use_orbax:
         from ..utils.orbax_ckpt import OrbaxCheckpointManager
 
         ckpt = OrbaxCheckpointManager(args.checkpoint_dir, tag=args.tag,
-                                      rank=proc_index, world_size=world)
+                                      rank=proc_index, world_size=t.world)
     else:
         ckpt = CheckpointManager(args.checkpoint_dir, tag=args.tag,
-                                 rank=proc_index, world_size=world,
+                                 rank=proc_index, world_size=t.world,
                                  all_workers=proc_count > 1)
     # preemption handling (≙ the image harness): SIGUSR1/SIGTERM raise a
     # flag; the step loop below finishes the in-flight step, checkpoints,
@@ -808,9 +666,28 @@ def main(argv=None):
     # the supervisor (supervise/) keys on.  No requeue command: the LM
     # harness leaves relaunching to the supervisor/launch layer
     cluster = ClusterManager(ckpt, rank=proc_index, requeue_command=None)
-    if sb(args.resume) and not use_orbax and not ckpt.exists() \
-            and pp == ep == tp == 1 and sp == 1 and proc_count == 1 \
-            and not args.fleet:
+    return ckpt, cluster, use_orbax
+
+
+def _resume(args, t: Training, ckpt, use_orbax: bool, log):
+    """``(state, start_step)`` under ``--resume``: restored leaves are
+    device_put back into the live state's shardings, and a pod resumes
+    from the minimum step any process holds."""
+    import jax
+    import numpy as np
+
+    from ..parallel import GOSSIP_AXIS
+    from ..parallel.multihost import (consensus_resume_point,
+                                      global_state_from_local,
+                                      host_local_slice)
+
+    state, mesh = t.state, t.mesh
+    proc_count = jax.process_count()
+    if not sb(args.resume):
+        return state, 0
+    if not use_orbax and not ckpt.exists() \
+            and t.pp == t.ep == t.tp == 1 and t.sp == 1 \
+            and proc_count == 1 and not args.fleet:
         # a resized relaunch: another world's checkpoint set may exist —
         # reshard it (exact-average consensus collapse) instead of
         # silently cold-starting.  Flat dp meshes only: sharded-dim
@@ -819,11 +696,11 @@ def main(argv=None):
         # assigned per-host shards — a local reshard would race them
         from ..supervise.reshard import maybe_cross_world_reshard
 
-        maybe_cross_world_reshard(args.checkpoint_dir, args.tag, world,
+        maybe_cross_world_reshard(args.checkpoint_dir, args.tag, t.world,
                                   log=log)
     shardings = jax.tree.map(lambda a: a.sharding, state)
     start_step = 0
-    if sb(args.resume) and proc_count > 1:
+    if proc_count > 1:
         # decide to resume COLLECTIVELY: gating the restore (and its
         # allgather) on a per-process exists() would hang the cluster when
         # one process's checkpoint is missing/torn — resume only when
@@ -833,7 +710,7 @@ def main(argv=None):
         all_have = int(np.min(np.asarray(multihost_utils.process_allgather(
             np.asarray([int(ckpt.exists())])))))
         if all_have:
-            if orbax_global:
+            if use_orbax:
                 # one shared logical checkpoint: the live sharded state is
                 # the restore template, every process reads its own shards
                 state, meta = ckpt.restore(state)
@@ -848,25 +725,168 @@ def main(argv=None):
         elif ckpt.exists():
             log.info("checkpoint present here but missing on a peer; "
                      "starting from step 0")
-    elif sb(args.resume) and ckpt.exists():
+    elif ckpt.exists():
         # the live state is only a structure template; restored host
         # values are device_put back into its shardings
         host_state, meta = ckpt.restore(state)
         state = jax.tree.map(jax.device_put, host_state, shardings)
         start_step = int(meta.get("step", 0))
         log.info(f"resumed from step {start_step}")
-    if start_step >= args.num_steps:
-        log.info(f"nothing to do: resumed at step {start_step} >= "
-                 f"num_steps {args.num_steps}")
-        rt.finish(step=start_step)
-        return {"final_loss": None, "avg_loss": None,
-                "tokens_per_sec": 0.0, "already_complete": True}
+    return state, start_step
+
+
+def _load_corpus(args, t: Training, log):
+    """``(corpus, val_corpus)``: the token stream the flags name, the
+    tail held out under ``--val_frac``."""
+    from ..data.lm import synthetic_lm_corpus
+
+    if args.corpus_file:
+        from ..data.lm import load_corpus
+
+        corpus = load_corpus(args.corpus_file, args.vocab_size)
+        log.info(f"corpus: {args.corpus_file} ({len(corpus):,} tokens)")
+    else:
+        corpus = synthetic_lm_corpus(args.corpus_tokens,
+                                     vocab_size=args.vocab_size,
+                                     seed=args.seed)
+    val_corpus = None
+    if args.val_frac > 0:
+        # hold out the corpus tail; at least one full validation batch
+        min_val = (args.seq_len + 1) * t.dp * t.ep * args.batch_size
+        n_val = max(int(len(corpus) * args.val_frac), min_val)
+        if n_val >= len(corpus) // 2:
+            raise SystemExit("--val_frac leaves too little training data")
+        corpus, val_corpus = corpus[:-n_val], corpus[-n_val:]
+    return corpus, val_corpus
+
+
+def _open_csv(args, world: int, start_step: int, log) -> str:
+    """Start (or, resuming, continue) this process's metrics CSV and
+    return its path."""
+    import jax
+
+    proc_count, proc_index = jax.process_count(), jax.process_index()
+    os.makedirs(args.checkpoint_dir, exist_ok=True)
+    out_fname = os.path.join(
+        args.checkpoint_dir,
+        f"{args.tag}out_n{world}.csv" if proc_count == 1
+        else f"{args.tag}out_p{proc_index}_n{world}.csv")
+    csv_header = ("step,loss,ppl,lr,tokens_per_sec,grad_norm"
+                  + (",moe_dropped" if args.moe_experts > 0 else "")
+                  + (",val_loss,val_ppl" if args.val_frac > 0 else ""))
+    if start_step and os.path.isfile(out_fname):
+        # appending to a pre-existing CSV: the schema has grown over time
+        # (grad_norm column), so a resume of an old run could silently
+        # misalign rows against the stale header — rewrite it in place
+        with open(out_fname) as f:
+            old_lines = f.read().splitlines()
+        if old_lines and old_lines[0] != csv_header:
+            log.warning(
+                "existing CSV header %r != current schema %r; remapping "
+                "old rows to the new schema (missing columns left empty)",
+                old_lines[0], csv_header)
+            old_cols = old_lines[0].split(",")
+            new_cols = csv_header.split(",")
+            # write-then-rename: a crash mid-rewrite must not destroy
+            # the run's accumulated loss history
+            tmp = out_fname + ".tmp"
+            with open(tmp, "w") as f:
+                print(csv_header, file=f)
+                for row in old_lines[1:]:
+                    # re-seat each value under its original column name so
+                    # e.g. val_loss never lands in a newly inserted
+                    # grad_norm slot
+                    vals = dict(zip(old_cols, row.split(",")))
+                    print(",".join(vals.get(c, "") for c in new_cols),
+                          file=f)
+            os.replace(tmp, out_fname)
+    else:
+        with open(out_fname, "w") as f:
+            print(csv_header, file=f)
+    return out_fname
+
+
+def _health_monitoring(args, t: Training, rt, log):
+    """``(monitor, policy, recovery)`` for ``--health_every`` (all None
+    when off): signals ride the metrics pytree every step and are
+    observed at the print cadence (the only points the LM loop fetches
+    metrics — dispatch stays asynchronous)."""
+    monitor = policy = recovery = None
+    if args.health_every > 0:
+        from ..resilience import (HealthMonitor, RecoveryPolicy,
+                                  make_recovery_fn)
+
+        monitor = HealthMonitor(health_every=args.health_every,
+                                residual_floor=args.residual_floor,
+                                log=log, registry=rt.registry)
+        # overlap runs recover too: the compiled recovery average folds
+        # the in-flight FIFO into Σx/Σw and drains it (recovery.py)
+        if t.dp > 1 and hasattr(t.algorithm, "global_average"):
+            plan = t.plan
+            policy = RecoveryPolicy(
+                world=t.dp, ppi=args.peers_per_itr, algorithm=t.mode,
+                topology=plan.topology if plan is not None else None,
+                residual_floor=args.residual_floor,
+                cooldown_steps=args.health_every, log=log,
+                registry=rt.registry, interconnect=t.interconnect,
+                faults=bool(args.inject_faults),
+                wire=wire_plan_config(args),
+                synth=plan.synth if plan is not None else None)
+            recovery = make_recovery_fn(t.algorithm, t.mesh)
+    return monitor, policy, recovery
+
+
+def _globalizer(t: Training):
+    """Host batch → what ``train_fn`` takes: the array itself in one
+    process, a global array over the mesh's batch dims on a pod."""
+    import jax
+
+    if jax.process_count() == 1:
+        return lambda arr: arr
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ..parallel import GOSSIP_AXIS
+    from ..train.lm import EP_AXIS, SEQ_AXIS
+
+    lead = (GOSSIP_AXIS,) + ((EP_AXIS,) if t.ep > 1 else ()) \
+        + ((SEQ_AXIS,) if t.ring else ())
+    bsharding = NamedSharding(t.mesh, P(*lead))
+
+    def globalize(arr):
+        # every process materializes the same (seed-deterministic
+        # synthetic) global batch and contributes only the shards its
+        # devices address; a real corpus would shard the stream
+        return jax.make_array_from_callback(
+            arr.shape, bsharding, lambda idx: arr[idx])
+
+    return globalize
+
+
+def train_loop(args, t: Training, state, start_step: int, corpus,
+               val_corpus, *, rt, log, ckpt, cluster, use_orbax: bool
+               ) -> dict:
+    """Run steps ``start_step .. --num_steps`` of the job ``t`` from
+    ``state``: data, dispatch, the print-cadence metrics fetch (CSV row,
+    health, recovery, validation), checkpoints and preemption — then the
+    final checkpoint and the result line."""
+    import jax
+    import numpy as np
+
+    from ..data.lm import lm_batches
+    from ..parallel.multihost import host_local_slice, to_host
+    from ..utils import Meter
+    from ..utils.checkpoint import REQUEUE_EXIT_CODE
+    from ..utils.profiling import ProfileWindow, StepWatchdog
+
+    mesh, dp, sp, ep, pp = t.mesh, t.dp, t.sp, t.ep, t.pp
+    alg, plan, ring = t.algorithm, t.plan, t.ring
+    train_fn, eval_fn = t.train_fn, t.eval_fn
+    proc_count, proc_index = jax.process_count(), jax.process_index()
+    moe_on, val_on = args.moe_experts > 0, args.val_frac > 0
 
     # step-indexed jax.profiler capture (shared with the image harness;
     # utils/profiling.py: a profiler start that hangs or fails is logged
     # as an error and the run continues untraced)
-    from ..utils.profiling import ProfileWindow
-
     pw = ProfileWindow(args.profile_dir,
                        start_step=args.profile_start_step,
                        num_steps=args.profile_steps)
@@ -900,104 +920,23 @@ def main(argv=None):
                           meta)
         return st
 
-    if args.corpus_file:
-        from ..data.lm import load_corpus
-
-        corpus = load_corpus(args.corpus_file, args.vocab_size)
-        log.info(f"corpus: {args.corpus_file} ({len(corpus):,} tokens)")
-    else:
-        corpus = synthetic_lm_corpus(args.corpus_tokens,
-                                     vocab_size=args.vocab_size,
-                                     seed=args.seed)
-    val_corpus = None
-    if val_on:
-        # hold out the corpus tail; at least one full validation batch
-        min_val = (args.seq_len + 1) * dp * ep * args.batch_size
-        n_val = max(int(len(corpus) * args.val_frac), min_val)
-        if n_val >= len(corpus) // 2:
-            raise SystemExit("--val_frac leaves too little training data")
-        corpus, val_corpus = corpus[:-n_val], corpus[-n_val:]
-    os.makedirs(args.checkpoint_dir, exist_ok=True)
-    out_fname = os.path.join(
-        args.checkpoint_dir,
-        f"{args.tag}out_n{world}.csv" if proc_count == 1
-        else f"{args.tag}out_p{proc_index}_n{world}.csv")
-    moe_on = args.moe_experts > 0
-    csv_header = ("step,loss,ppl,lr,tokens_per_sec,grad_norm"
-                  + (",moe_dropped" if moe_on else "")
-                  + (",val_loss,val_ppl" if val_on else ""))
-    if start_step and os.path.isfile(out_fname):
-        # appending to a pre-existing CSV: the schema has grown over time
-        # (grad_norm column), so a resume of an old run could silently
-        # misalign rows against the stale header — rewrite it in place
-        with open(out_fname) as f:
-            old_lines = f.read().splitlines()
-        if old_lines and old_lines[0] != csv_header:
-            log.warning(
-                "existing CSV header %r != current schema %r; remapping "
-                "old rows to the new schema (missing columns left empty)",
-                old_lines[0], csv_header)
-            old_cols = old_lines[0].split(",")
-            new_cols = csv_header.split(",")
-            # write-then-rename: a crash mid-rewrite must not destroy
-            # the run's accumulated loss history
-            tmp = out_fname + ".tmp"
-            with open(tmp, "w") as f:
-                print(csv_header, file=f)
-                for row in old_lines[1:]:
-                    # re-seat each value under its original column name so
-                    # e.g. val_loss never lands in a newly inserted
-                    # grad_norm slot
-                    vals = dict(zip(old_cols, row.split(",")))
-                    print(",".join(vals.get(c, "") for c in new_cols),
-                          file=f)
-            os.replace(tmp, out_fname)
-    else:
-        with open(out_fname, "w") as f:
-            print(csv_header, file=f)
+    out_fname = _open_csv(args, t.world, start_step, log)
 
     # heartbeat around the blocking metrics fetch (≙ the reference's 300s
     # gossip-flag timeout): a dead peer host shows up as a hung collective
     # at the next host readback, and silence is the worst failure mode.
     # Armed only from the second print point on — the first fetch drains
     # the queued compile, which can legitimately exceed any sane timeout.
-    import contextlib
-
-    from ..utils.profiling import StepWatchdog
     watchdog = (StepWatchdog(timeout=args.heartbeat_timeout,
                              rank=proc_index, registry=rt.registry)
                 if args.heartbeat_timeout > 0 else None)
     prints_done = 0
 
-    # runtime consensus health (resilience/): signals ride the metrics
-    # pytree every step and are observed at the print cadence (the only
-    # points the LM loop fetches metrics — dispatch stays asynchronous)
-    monitor = policy = recovery = None
-    if args.health_every > 0:
-        from ..resilience import (HealthMonitor, RecoveryPolicy,
-                                  make_recovery_fn)
-
-        monitor = HealthMonitor(health_every=args.health_every,
-                                residual_floor=args.residual_floor,
-                                log=log, registry=rt.registry)
-        # (fetch time, steps_done, val_time) at the previous metrics
-        # fetch — step-time samples are per-WINDOW deltas, so a straggler
-        # phase moves p99 instead of dissolving into the lifetime mean
-        health_window_start = None
-        # overlap runs recover too: the compiled recovery average folds
-        # the in-flight FIFO into Σx/Σw and drains it (recovery.py)
-        if dp > 1 and hasattr(alg, "global_average"):
-            policy = RecoveryPolicy(
-                world=dp, ppi=args.peers_per_itr,
-                algorithm="sgp" if sb(args.push_sum) else "dpsgd",
-                topology=plan.topology if plan is not None else None,
-                residual_floor=args.residual_floor,
-                cooldown_steps=args.health_every, log=log,
-                registry=rt.registry, interconnect=interconnect,
-                faults=bool(args.inject_faults),
-                wire=wire_plan_config(args),
-                synth=plan.synth if plan is not None else None)
-            recovery = make_recovery_fn(alg, mesh)
+    monitor, policy, recovery = _health_monitoring(args, t, rt, log)
+    # (fetch time, steps_done, val_time) at the previous metrics
+    # fetch — step-time samples are per-WINDOW deltas, so a straggler
+    # phase moves p99 instead of dissolving into the lifetime mean
+    health_window_start = None
 
     loss_meter = Meter(ptag="Loss")
     steps_done = start_step
@@ -1015,23 +954,7 @@ def main(argv=None):
     # fetch metrics only at print points so dispatch stays asynchronous
     serialize = jax.default_backend() == "cpu"
     metrics = None
-    if proc_count > 1:
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        if ep > 1:
-            bspec = (P(GOSSIP_AXIS, EP_AXIS, SEQ_AXIS) if ring
-                     else P(GOSSIP_AXIS, EP_AXIS))
-        else:
-            bspec = P(GOSSIP_AXIS, SEQ_AXIS) if ring else P(GOSSIP_AXIS)
-        bsharding = NamedSharding(mesh, bspec)
-
-        def globalize(arr):
-            # every process materializes the same (seed-deterministic
-            # synthetic) global batch and contributes only the shards its
-            # devices address; a real corpus would shard the stream
-            return jax.make_array_from_callback(
-                arr.shape, bsharding, lambda idx: arr[idx])
-    else:
-        globalize = lambda arr: arr
+    globalize = _globalizer(t)
 
     def host_metrics(m):
         # sharded metrics are not host-addressable on a pod: all-gather
@@ -1124,7 +1047,8 @@ def main(argv=None):
                     rt.comm.on_step(steps_done - 1)
                 if pw.active:
                     pw.maybe_stop(steps_done)
-                if steps_done % args.print_freq == 0                     or steps_done >= args.num_steps:
+                if steps_done % args.print_freq == 0 \
+                        or steps_done >= args.num_steps:
                     guard = (watchdog.step()
                              if watchdog is not None and prints_done >= 1
                              else contextlib.nullcontext())
@@ -1255,7 +1179,7 @@ def main(argv=None):
         # Trainer's fit() finally); finish() is idempotent
         rt.finish(step=steps_done)
 
-    result = {"attn": attn,
+    result = {"attn": t.attn,
               "final_loss": loss_meter.val, "avg_loss": loss_meter.avg,
               "tokens_per_sec": tokens_per_step
               * (steps_done - start_step)
@@ -1264,6 +1188,48 @@ def main(argv=None):
         result["val_loss"] = last_val
     log.info(json.dumps(result))
     return result
+
+
+def main(argv=None):
+    from ..utils.compile_cache import place_compile_cache
+
+    place_compile_cache()
+    args = parse_args(argv)
+
+    import jax
+
+    from ..telemetry import make_run_telemetry
+    from ..utils import make_logger
+    from .gossip_sgd import _multihost_env
+
+    want_mh = args.multihost
+    if want_mh == "True" or (want_mh == "auto" and _multihost_env()):
+        from ..parallel.discovery import initialize_multihost
+
+        initialize_multihost(args.coordinator_address, args.num_processes,
+                             args.process_id)
+    proc_count, proc_index = jax.process_count(), jax.process_index()
+    log = make_logger(f"lm p{proc_index}" if proc_count > 1 else "lm", True)
+
+    # run telemetry BEFORE planning so the plan event and the loop share
+    # one events.jsonl (the zero-overhead null bundle without --trace_dir)
+    rt = make_run_telemetry(args.trace_dir, rank=proc_index, log=log,
+                            metrics_every=args.metrics_every)
+    t = build_training(args, log, rt.registry)
+    _attach_accounting(args, t, rt)
+
+    ckpt, cluster, use_orbax = _checkpointing(args, t)
+    state, start_step = _resume(args, t, ckpt, use_orbax, log)
+    if start_step >= args.num_steps:
+        log.info(f"nothing to do: resumed at step {start_step} >= "
+                 f"num_steps {args.num_steps}")
+        rt.finish(step=start_step)
+        return {"final_loss": None, "avg_loss": None,
+                "tokens_per_sec": 0.0, "already_complete": True}
+    corpus, val_corpus = _load_corpus(args, t, log)
+    return train_loop(args, t, state, start_step, corpus, val_corpus,
+                      rt=rt, log=log, ckpt=ckpt, cluster=cluster,
+                      use_orbax=use_orbax)
 
 
 if __name__ == "__main__":

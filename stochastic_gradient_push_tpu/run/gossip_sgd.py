@@ -23,7 +23,6 @@ from __future__ import annotations
 
 import argparse
 import os
-import sys
 
 from ..topology import GRAPH_TOPOLOGIES, MIXING_STRATEGIES, TOPOLOGY_NAMES
 
@@ -36,8 +35,7 @@ def _str_bool(v: str) -> bool:
 
 def add_wire_flags(p: argparse.ArgumentParser) -> None:
     """Gossip wire-format flags, shared by both run CLIs (gossip_sgd and
-    gossip_lm): codec selection, int8 block size, error feedback, and
-    the deprecated pre-codec alias."""
+    gossip_lm): codec selection, int8 block size, error feedback."""
     p.add_argument("--wire_dtype", default=None,
                    choices=[None, "f32", "bf16", "int8"],
                    help="gossip wire codec (parallel/wire.py): f32 = "
@@ -57,26 +55,12 @@ def add_wire_flags(p: argparse.ArgumentParser) -> None:
                         "compression perturbs the network mean by a "
                         "bounded amount instead of a bias (needs a "
                         "lossy --wire_dtype; sync push-sum mode)")
-    p.add_argument("--gossip_comm_dtype", default=None,
-                   choices=[None, "bf16"],
-                   help="DEPRECATED alias for --wire_dtype bf16")
 
 
 def resolve_wire_flags(args) -> None:
-    """Normalize the wire flags in place: fold the deprecated
-    --gossip_comm_dtype alias into --wire_dtype, coerce --error_feedback
-    to bool, and fail fast on inconsistent combinations."""
+    """Normalize the wire flags in place: coerce --error_feedback to
+    bool, and fail fast on inconsistent combinations."""
     ef = _str_bool(args.error_feedback)
-    if args.gossip_comm_dtype:
-        if args.wire_dtype not in (None, "bf16"):
-            raise SystemExit(
-                "--gossip_comm_dtype is a deprecated alias for "
-                "--wire_dtype bf16 and conflicts with "
-                f"--wire_dtype {args.wire_dtype}")
-        print("warning: --gossip_comm_dtype is deprecated; use "
-              "--wire_dtype bf16", file=sys.stderr)
-        args.wire_dtype = "bf16"
-        args.gossip_comm_dtype = None
     if args.wire_block < 1:
         raise SystemExit("--wire_block must be >= 1")
     if ef and args.wire_dtype not in ("bf16", "int8"):
@@ -291,12 +275,10 @@ def reject_push_sum_wire_knobs(args) -> None:
     the push-sum gossip wire, which those modes don't have.  Call after
     :func:`resolve_wire_flags`."""
     wire_set = (args.wire_dtype not in (None, "f32")
-                or bool(getattr(args, "gossip_comm_dtype", None))
                 or _str_bool(str(args.error_feedback)))
     if args.gossip_every != 1 or wire_set:
         raise SystemExit(
-            "gossip_every/wire_dtype/error_feedback (and the deprecated "
-            "gossip_comm_dtype) are push-sum knobs")
+            "gossip_every/wire_dtype/error_feedback are push-sum knobs")
 
 
 def wire_plan_config(args) -> dict | None:
@@ -311,10 +293,206 @@ def wire_plan_config(args) -> dict | None:
     return cfg
 
 
+def add_shared_flags(p: argparse.ArgumentParser) -> None:
+    """Every flag both run CLIs declare alike (type, default, choices):
+    algorithm selection, the planner's inputs, resilience, the optimizer
+    and run-shape basics, the cluster rendezvous, and the flag groups
+    above.  A flag whose default differs between the CLIs (--lr,
+    --batch_size, --weight_decay, --tag) stays in its own parser."""
+    # algorithm (reference flag surface, gossip_sgd.py:72-159)
+    p.add_argument("--all_reduce", default="False", type=str)
+    p.add_argument("--push_sum", default="True", type=str)
+    p.add_argument("--overlap", default="False", type=str)
+    add_staleness_flag(p)
+    p.add_argument("--graph_type", default=5, type=int,
+                   choices=list(GRAPH_TOPOLOGIES))
+    p.add_argument("--gossip_every", default=1, type=int,
+                   help="gossip on every k-th step only (communication "
+                        "thinning; sync push-sum mode)")
+    add_wire_flags(p)
+    add_kernel_flag(p)
+    # launch-time topology policy (planner/)
+    p.add_argument("--topology", default=None,
+                   choices=["auto"] + sorted(TOPOLOGY_NAMES),
+                   help="named topology selection: 'auto' lets the "
+                        "planner pick (and tune) the gossip graph for "
+                        "the gossip world; 'synth' searches a hybrid "
+                        "psum/ppermute schedule against the priced "
+                        "fabric (falling back to the registry when not "
+                        "beaten); a name forces it (overriding "
+                        "--graph_type) with a below-floor warning when "
+                        "its spectral gap is too small")
+    add_synth_flags(p)
+    p.add_argument("--gap_floor", default=0.01, type=float,
+                   help="minimum acceptable rotation-cycle spectral gap; "
+                        "below it the planner auto-switches (or warns "
+                        "when the topology is user-forced)")
+    p.add_argument("--global_avg_every", default=None, type=int,
+                   help="exact global average (one allreduce) every k "
+                        "steps; unset = the planner decides (it enables "
+                        "periodic averaging when no gossip graph clears "
+                        "the gap floor), 0 = explicitly off even below "
+                        "the floor, k = force every-k averaging")
+    p.add_argument("--slice_size", default=None, type=int,
+                   help="gossip ranks per ICI slice (contiguous blocks) "
+                        "on a multi-slice pod: the planner prices "
+                        "intra-slice edges at torus-hop ICI cost and "
+                        "cross-slice edges at the DCN weight, and a "
+                        "planned/forced 'hierarchical' topology adopts "
+                        "this slice decomposition; unset = uniform fabric")
+    p.add_argument("--dcn_cost", default=None, type=float,
+                   help="relative per-byte cost of one inter-slice (DCN) "
+                        "message (ICI hop = 1.0; default 16 when any "
+                        "fabric flag is set); calibrate with bench.py "
+                        "--gossip-vs-ar on real slices")
+    p.add_argument("--ici_cost", default=None, type=float,
+                   help="relative per-byte cost of one intra-slice ICI "
+                        "torus hop (default 1.0)")
+    p.add_argument("--mixing_alpha", default=None, type=str,
+                   help="SelfWeightedMixing self-mass: 'auto' co-"
+                        "optimizes alpha against the chosen topology "
+                        "(planner scalar search); a float in (0,1) "
+                        "forces it (with a warning when co-optimization "
+                        "would recover >10%% of the gap); unset = "
+                        "uniform mixing")
+    # resilience
+    p.add_argument("--inject_faults", default=None, type=str,
+                   help="deterministic fault injection at the gossip "
+                        "boundary (resilience/faults.py grammar, e.g. "
+                        "'drop:0->1@10:40;straggler:3@20:30;seed:7'); "
+                        "mass-conserving drop semantics, push-sum "
+                        "synchronous mode only")
+    p.add_argument("--residual_floor", default=0.01, type=float,
+                   help="consensus-residual level above which recovery "
+                        "fires an immediate exact global average "
+                        "(requires --health_every > 0)")
+    p.add_argument("--heartbeat_timeout", default=300, type=int,
+                   help="seconds a blocking step or metrics fetch may "
+                        "take before the watchdog logs a stall (a dead "
+                        "peer host shows up as a hung collective; 0 "
+                        "disables; ≙ the gossip flag timeout, "
+                        "distributed.py:36)")
+    # optimizer and run shape
+    p.add_argument("--momentum", default=0.9, type=float)
+    p.add_argument("--nesterov", default="False", type=str)
+    p.add_argument("--warmup", default="False", type=str)
+    p.add_argument("--seed", default=47, type=int)
+    p.add_argument("--resume", default="False", type=str)
+    p.add_argument("--print_freq", default=10, type=int)
+    p.add_argument("--checkpoint_dir", type=str, default="./checkpoints")
+    p.add_argument("--trace_dir", default=None, type=str,
+                   help="run telemetry directory (telemetry/): writes "
+                        "trace.json (Chrome-trace host spans: data "
+                        "fetch, compiled step, checkpoint, eval, "
+                        "recovery averages) and events.jsonl (typed "
+                        "plan/health/recovery/comm events, one "
+                        "versioned schema); analyze with "
+                        "scripts/obsreport.py.  Unset = telemetry off "
+                        "(zero overhead)")
+    add_profile_flags(p)
+    add_fleet_flags(p)
+    # multi-host rendezvous
+    p.add_argument("--multihost", default="auto",
+                   choices=["auto", "True", "False"],
+                   help="join a multi-host cluster via "
+                        "jax.distributed.initialize; 'auto' joins when "
+                        "SLURM/coordinator env vars are present or on a "
+                        "TPU pod slice "
+                        "(≙ dist.init_process_group, gossip_sgd.py:671-673)")
+    p.add_argument("--coordinator_address", default=None, type=str,
+                   help="host:port of process 0 (multi-host rendezvous)")
+    p.add_argument("--num_processes", default=None, type=int)
+    p.add_argument("--process_id", default=None, type=int)
+
+
+def resolve_shared_flags(args) -> None:
+    """Normalize and check, in place, what :func:`add_shared_flags`
+    declares (plus --health_every/--metrics_every, which both parsers
+    have) — one set of error texts for both CLIs, before any device
+    work.  ``--bilat`` is the LM CLI's; the AD-PSGD image CLI selects
+    its mode after parsing."""
+    all_reduce = _str_bool(args.all_reduce)
+    not_push_sum = (all_reduce or _str_bool(getattr(args, "bilat", False))
+                    or not _str_bool(args.push_sum))
+    resolve_wire_flags(args)
+    resolve_kernel_flag(args)
+    resolve_staleness_flag(args, _str_bool(args.overlap))
+    if not_push_sum:
+        reject_push_sum_wire_knobs(args)
+    args.mixing_alpha = _parse_mixing_alpha(args.mixing_alpha)
+    if args.mixing_alpha is not None and (
+            all_reduce or not _str_bool(args.push_sum)):
+        raise SystemExit("--mixing_alpha needs push-sum gossip: AllReduce "
+                         "doesn't mix, and D-PSGD requires a regular "
+                         "(doubly-stochastic) schedule")
+    if args.inject_faults:
+        if not_push_sum:
+            raise SystemExit("--inject_faults needs push-sum gossip: only "
+                             "push-sum's mass accounting keeps the mean "
+                             "exact under dropped edges")
+        # overlap composes with faults (masks are keyed on the LAUNCH
+        # tick); fail bad specs at parse time, not at first compiled step
+        from ..resilience import parse_fault_spec
+
+        parse_fault_spec(args.inject_faults)
+    if args.health_every < 0:
+        raise SystemExit("--health_every must be >= 0")
+    if args.metrics_every < 0:
+        raise SystemExit("--metrics_every must be >= 0")
+    if args.metrics_every and not args.trace_dir:
+        raise SystemExit("--metrics_every needs --trace_dir (telemetry "
+                         "events have nowhere to go without it)")
+    resolve_fleet_flags(args)
+    resolve_profile_flags(args)
+
+
+def plan_gossip(args, gossip_world: int, *, mode: str, ppi: int,
+                graph_class, overlap: bool, log, registry=None):
+    """The launch-time topology policy (planner/) for either CLI:
+    ``(plan, interconnect)``, both None when ``mode`` (one of
+    ``algorithms.GOSSIP_MODES``) or a single-rank world has no gossip
+    schedule to plan — planner flags are then an error, not ignored.
+
+    Auto mode picks (and tunes) the graph; forced mode measures the
+    user's choice and warns loudly when its gap is below the floor.  The
+    chosen plan is logged as one JSON line (via the telemetry registry
+    when one exists).  Pure numpy: runs before any mesh/device work.
+    """
+    fabric_flags = (args.slice_size is not None
+                    or args.dcn_cost is not None
+                    or args.ici_cost is not None)
+    synth = synth_plan_config(args)   # rejects stray --synth_* knobs
+    if mode not in ("sgp", "dpsgd") or gossip_world < 2:
+        if args.topology in ("auto", "synth") \
+                or args.mixing_alpha is not None or fabric_flags \
+                or synth is not None:
+            raise SystemExit("--topology auto/synth / --mixing_alpha / "
+                             "fabric flags (--slice_size/--dcn_cost/"
+                             "--ici_cost) plan gossip schedules; they do "
+                             "not apply to all_reduce/bilateral modes or "
+                             "a single-rank world")
+        return None, None
+    from ..planner import make_interconnect, resolve_topology
+
+    interconnect = make_interconnect(args.slice_size, args.dcn_cost,
+                                     args.ici_cost)
+    plan = resolve_topology(
+        gossip_world, ppi=ppi, topology=args.topology,
+        graph_class=graph_class, floor=args.gap_floor, algorithm=mode,
+        self_weighted=(True if args.mixing_alpha == "auto"
+                       else (args.mixing_alpha or False)),
+        global_avg_every=args.global_avg_every,  # None = policy decides
+        interconnect=interconnect,
+        overlap=overlap, faults=bool(args.inject_faults),
+        wire=wire_plan_config(args), synth=synth,
+        log=log, registry=registry)
+    return plan, interconnect
+
+
 def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(description="Gossip SGD on TPU")
+    add_shared_flags(p)
     # reference flag surface (gossip_sgd.py:72-159)
-    p.add_argument("--all_reduce", default="False", type=str)
     p.add_argument("--batch_size", default=32, type=int,
                    help="per-agent batch size")
     p.add_argument("--lr", default=0.1, type=float,
@@ -343,109 +521,39 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--num_epochs", default=90, type=int)
     p.add_argument("--num_iterations_per_training_epoch", default=None,
                    type=int, help="early exit for testing")
-    p.add_argument("--momentum", default=0.9, type=float)
     p.add_argument("--weight_decay", default=1e-4, type=float)
-    p.add_argument("--nesterov", default="False", type=str)
-    p.add_argument("--push_sum", default="True", type=str)
-    p.add_argument("--graph_type", default=5, type=int,
-                   choices=list(GRAPH_TOPOLOGIES))
-    p.add_argument("--topology", default=None,
-                   choices=["auto"] + sorted(TOPOLOGY_NAMES),
-                   help="named topology selection: 'auto' lets the "
-                        "planner pick (and tune) the gossip graph for "
-                        "the world size; 'synth' searches a hybrid "
-                        "psum/ppermute schedule against the priced "
-                        "fabric (falling back to the registry when not "
-                        "beaten); a name forces it (overriding "
-                        "--graph_type) with a below-floor warning when "
-                        "its spectral gap is too small")
-    add_synth_flags(p)
-    p.add_argument("--gap_floor", default=0.01, type=float,
-                   help="minimum acceptable rotation-cycle spectral gap; "
-                        "below it the planner auto-switches (or warns "
-                        "when the topology is user-forced)")
-    p.add_argument("--global_avg_every", default=None, type=int,
-                   help="exact global average (one allreduce) every k "
-                        "steps; unset = the planner decides (it enables "
-                        "periodic averaging when no gossip graph clears "
-                        "the gap floor), 0 = explicitly off even below "
-                        "the floor, k = force every-k averaging")
-    p.add_argument("--slice_size", default=None, type=int,
-                   help="ranks per ICI slice (contiguous blocks) on a "
-                        "multi-slice pod: the planner prices intra-slice "
-                        "edges at torus-hop ICI cost and cross-slice "
-                        "edges at the DCN weight, and a planned/forced "
-                        "'hierarchical' topology adopts this slice "
-                        "decomposition; unset = uniform fabric")
-    p.add_argument("--dcn_cost", default=None, type=float,
-                   help="relative per-byte cost of one inter-slice (DCN) "
-                        "message (ICI hop = 1.0; default 16 when any "
-                        "fabric flag is set); calibrate with bench.py "
-                        "--gossip-vs-ar on real slices")
-    p.add_argument("--ici_cost", default=None, type=float,
-                   help="relative per-byte cost of one intra-slice ICI "
-                        "torus hop (default 1.0)")
-    p.add_argument("--mixing_alpha", default=None, type=str,
-                   help="SelfWeightedMixing self-mass: 'auto' co-"
-                        "optimizes alpha against the chosen topology "
-                        "(planner scalar search); a float in (0,1) "
-                        "forces it (with a warning when co-optimization "
-                        "would recover >10%% of the gap); unset = "
-                        "uniform mixing")
-    p.add_argument("--inject_faults", default=None, type=str,
-                   help="deterministic fault injection at the gossip "
-                        "boundary (resilience/faults.py grammar, e.g. "
-                        "'drop:0->1@10:40;straggler:3@20:30;seed:7'); "
-                        "mass-conserving drop semantics, push-sum "
-                        "synchronous mode only")
     p.add_argument("--health_every", default=0, type=int,
                    help="emit a structured 'gossip health:' line every k "
                         "steps (ps-weight drift, push-sum mass error, "
                         "NaN guards, consensus residual, step-time "
                         "p50/p99); excursions log immediately and arm "
                         "the recovery policy; 0 disables")
-    p.add_argument("--residual_floor", default=0.01, type=float,
-                   help="consensus-residual level above which recovery "
-                        "fires an immediate exact global average "
-                        "(requires --health_every > 0)")
     p.add_argument("--mixing_strategy", default=0, type=int,
                    choices=list(MIXING_STRATEGIES))
     p.add_argument("--schedule", nargs="+", default=[30, 0.1, 60, 0.1, 80, 0.1],
                    type=float, help="lr schedule as epoch value pairs")
     p.add_argument("--peers_per_itr_schedule", nargs="+", type=int,
                    default=None)
-    p.add_argument("--overlap", default="False", type=str)
     p.add_argument("--synch_freq", default=0, type=int,
                    help="overlap-mode staleness bound: in-flight gossip is "
                         "consumed synch_freq+1 steps after launch "
                         "(reference semantics: up to N non-blocking polls, "
                         "distributed.py:127-129)")
-    add_staleness_flag(p)
-    p.add_argument("--gossip_every", default=1, type=int,
-                   help="gossip on every k-th step only (communication "
-                        "thinning; sync push-sum mode)")
     p.add_argument("--cosine_lr", default="False", type=str,
                    help="cosine LR decay instead of the step schedule")
     p.add_argument("--label_smoothing", default=0.0, type=float)
     p.add_argument("--grad_accum", default=1, type=int,
                    help="microbatches accumulated per optimizer step")
-    add_wire_flags(p)
-    add_kernel_flag(p)
-    p.add_argument("--warmup", default="False", type=str)
-    p.add_argument("--seed", default=47, type=int)
-    p.add_argument("--resume", default="False", type=str)
     p.add_argument("--backend", default="xla",
                    choices=["xla", "nccl", "gloo", "mpi"],
                    help="accepted for compatibility; comm is XLA/ICI")
     p.add_argument("--tag", default="", type=str)
-    p.add_argument("--print_freq", default=10, type=int)
     p.add_argument("--verbose", default="True", type=str)
     p.add_argument("--train_fast", default="False", type=str)
     p.add_argument("--checkpoint_all", default="True", type=str)
     p.add_argument("--overwrite_checkpoints", default="True", type=str)
     p.add_argument("--master_port", default="40100", type=str,
                    help="accepted for compatibility; unused")
-    p.add_argument("--checkpoint_dir", type=str, default="./checkpoints")
     p.add_argument("--network_interface_type", default="infiniband",
                    choices=["infiniband", "ethernet"],
                    help="accepted for compatibility; unused")
@@ -475,38 +583,13 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--per_rank_csv", default="False", type=str,
                    help="emit one CSV per gossip rank (reference parity) "
                         "instead of a single rank-averaged file")
-    p.add_argument("--multihost", default="auto",
-                   choices=["auto", "True", "False"],
-                   help="join a multi-host cluster via "
-                        "jax.distributed.initialize; 'auto' joins when "
-                        "SLURM/coordinator env vars are present "
-                        "(≙ dist.init_process_group, gossip_sgd.py:671-673)")
-    p.add_argument("--coordinator_address", default=None, type=str,
-                   help="host:port of process 0 (multi-host rendezvous)")
-    p.add_argument("--num_processes", default=None, type=int)
-    p.add_argument("--process_id", default=None, type=int)
-    p.add_argument("--heartbeat_timeout", default=300, type=int,
-                   help="seconds a blocking step may take before the "
-                        "watchdog logs a stall (0 disables; ≙ the gossip "
-                        "flag timeout, distributed.py:36)")
     p.add_argument("--ckpt_backend", default="msgpack",
                    choices=["msgpack", "orbax"],
                    help="checkpoint serialization backend")
-    p.add_argument("--trace_dir", default=None, type=str,
-                   help="run telemetry directory (telemetry/): writes "
-                        "trace.json (Chrome-trace host spans: data "
-                        "fetch, compiled step, checkpoint, eval, "
-                        "recovery averages) and events.jsonl (typed "
-                        "plan/health/recovery/comm events, one "
-                        "versioned schema); analyze with "
-                        "scripts/obsreport.py.  Unset = telemetry off "
-                        "(zero overhead)")
     p.add_argument("--metrics_every", default=0, type=int,
                    help="emit a step_stats + comm telemetry event "
                         "every k steps (0 = only the final comm "
                         "snapshot); requires --trace_dir")
-    add_profile_flags(p)
-    add_fleet_flags(p)
     return p
 
 
@@ -532,12 +615,7 @@ def parse_config(argv=None):
     if 0 not in ppi_schedule:
         raise SystemExit("peers_per_itr_schedule must include epoch 0")
     all_reduce = _str_bool(args.all_reduce)
-    resolve_wire_flags(args)
-    resolve_kernel_flag(args)
-    resolve_staleness_flag(args, _str_bool(args.overlap))
-    if all_reduce or not _str_bool(args.push_sum):
-        # fail at parse time with the same text as the LM CLI's branches
-        reject_push_sum_wire_knobs(args)
+    resolve_shared_flags(args)
     if all_reduce and args.graph_type != -1:
         raise SystemExit("--all_reduce True requires --graph_type -1")
     if all_reduce and args.topology is not None:
@@ -547,31 +625,6 @@ def parse_config(argv=None):
             and GRAPH_TOPOLOGIES[args.graph_type] is None:
         raise SystemExit("gossip training requires a graph_type >= 0 "
                          "(or --topology)")
-    args.mixing_alpha = _parse_mixing_alpha(args.mixing_alpha)
-    if args.mixing_alpha is not None and (
-            all_reduce or not _str_bool(args.push_sum)):
-        raise SystemExit("--mixing_alpha needs push-sum gossip: AllReduce "
-                         "doesn't mix, and D-PSGD requires a regular "
-                         "(doubly-stochastic) schedule")
-    if args.inject_faults:
-        if all_reduce or not _str_bool(args.push_sum):
-            raise SystemExit("--inject_faults needs push-sum gossip: only "
-                             "push-sum's mass accounting keeps the mean "
-                             "exact under dropped edges")
-        # overlap composes with faults (masks are keyed on the LAUNCH
-        # tick); fail bad specs at parse time, not at first compiled step
-        from ..resilience import parse_fault_spec
-
-        parse_fault_spec(args.inject_faults)
-    if args.health_every < 0:
-        raise SystemExit("--health_every must be >= 0")
-    if args.metrics_every < 0:
-        raise SystemExit("--metrics_every must be >= 0")
-    if args.metrics_every and not args.trace_dir:
-        raise SystemExit("--metrics_every needs --trace_dir (telemetry "
-                         "events have nowhere to go without it)")
-    resolve_fleet_flags(args)
-    resolve_profile_flags(args)
     # a forced name overrides the integer registry; 'auto' is resolved in
     # main() once the world size is known (planner.resolve_topology)
     graph_class = GRAPH_TOPOLOGIES[args.graph_type]
@@ -655,50 +708,23 @@ def _parse_mixing_alpha(v):
 
 
 def _resolve_plan(cfg, args, gossip_world: int, log, registry=None):
-    """Apply the launch-time topology policy (planner/) to ``cfg``.
-
-    Auto mode picks (and tunes) the graph; forced mode measures the
-    user's choice and warns loudly when its gap is below the floor.  The
-    chosen plan is logged as one JSON line (via the telemetry registry
-    when one exists) and stamped into ``cfg.plan`` (and from there into
-    checkpoint metadata).
-    """
-    fabric_flags = (args.slice_size is not None
-                    or args.dcn_cost is not None
-                    or args.ici_cost is not None)
-    synth = synth_plan_config(args)   # rejects stray --synth_* knobs
-    if cfg.all_reduce or cfg.bilat or cfg.bilat_async or gossip_world < 2:
-        if args.topology in ("auto", "synth") \
-                or args.mixing_alpha is not None or fabric_flags \
-                or synth is not None:
-            raise SystemExit("--topology auto/synth / --mixing_alpha / "
-                             "fabric flags (--slice_size/--dcn_cost/"
-                             "--ici_cost) plan gossip schedules; they do "
-                             "not apply to all_reduce/bilateral modes or "
-                             "a single-rank world")
-        return
-    from ..planner import make_interconnect, resolve_topology
+    """Apply the launch-time topology policy (:func:`plan_gossip`) to
+    ``cfg``: the planned graph, mixing and periodic averaging replace the
+    flags' own, and the plan is stamped into ``cfg.plan`` (and from there
+    into checkpoint metadata)."""
+    from ..algorithms import gossip_mode
     from ..train.lr import ppi_at_epoch
-
-    interconnect = make_interconnect(args.slice_size, args.dcn_cost,
-                                     args.ici_cost)
 
     # plan for the epoch-0 peers_per_itr (a ppi schedule can change it
     # later; the stamped plan records which value was planned for)
-    plan = resolve_topology(
-        gossip_world,
-        ppi=ppi_at_epoch(cfg.ppi_schedule, 0),
-        topology=args.topology,
-        graph_class=cfg.graph_class,
-        floor=args.gap_floor,
-        algorithm="sgp" if cfg.push_sum else "dpsgd",
-        self_weighted=(True if args.mixing_alpha == "auto"
-                       else (args.mixing_alpha or False)),
-        global_avg_every=args.global_avg_every,  # None = policy decides
-        interconnect=interconnect,
-        overlap=cfg.overlap, faults=bool(cfg.inject_faults),
-        wire=wire_plan_config(args), synth=synth,
-        log=log, registry=registry)
+    plan, _ = plan_gossip(
+        args, gossip_world,
+        mode=gossip_mode(all_reduce=cfg.all_reduce, push_sum=cfg.push_sum,
+                         bilat=cfg.bilat, bilat_async=cfg.bilat_async),
+        ppi=ppi_at_epoch(cfg.ppi_schedule, 0), graph_class=cfg.graph_class,
+        overlap=cfg.overlap, log=log, registry=registry)
+    if plan is None:
+        return
     cfg.graph_class = plan.graph_class
     if plan.alpha is not None:
         from ..topology import SelfWeightedMixing

@@ -20,7 +20,7 @@ import numpy as np
 
 from jax.sharding import PartitionSpec as P
 
-from ..algorithms import GossipAlgorithm, adpsgd, all_reduce, dpsgd, sgp
+from ..algorithms import GossipAlgorithm, gossip_algorithm, gossip_mode
 from ..parallel.mesh import GOSSIP_AXIS, LOCAL_AXIS, NODE_AXIS
 from ..parallel.multihost import (
     global_state_from_local,
@@ -29,7 +29,7 @@ from ..parallel.multihost import (
     owned_ranks,
     to_host,
 )
-from ..topology import build_pairing_schedule, build_schedule
+from ..topology import build_pairing_schedule
 from ..utils import Meter, make_logger
 from ..utils.checkpoint import REQUEUE_EXIT_CODE, ClusterManager
 from ..utils.profiling import ProfileWindow, StepWatchdog
@@ -84,9 +84,6 @@ class TrainerConfig:
     # quantization error into round t+1's send so compression noise is a
     # bounded perturbation, not a bias (requires a lossy wire_dtype)
     error_feedback: bool = False
-    # DEPRECATED alias for wire_dtype="bf16" (the pre-codec knob); kept
-    # so existing launch scripts and library callers keep working
-    gossip_comm_dtype: str | None = None
     # gossip transport lane (ops/gossip_kernel.py): "pallas" fuses each
     # edge exchange into one remote-DMA kernel (in-VMEM wire decode +
     # mixing axpy; TPU only — a typed KernelBackendError elsewhere),
@@ -272,7 +269,14 @@ class Trainer:
         self.tx = sgd(momentum=config.momentum,
                       weight_decay=config.weight_decay,
                       nesterov=config.nesterov)
-        self.lr_schedule_obj = None  # built per-fit (needs itr_per_epoch)
+        # depends on the config and the world only: a constructed Trainer
+        # can compile its step (_train_fn) without fit() having run
+        schedule = dict(ref_lr=config.lr, batch_size=config.batch_size,
+                        world_size=self.world_size, warmup=config.warmup)
+        self.lr_schedule_obj = (
+            CosineLRSchedule(total_epochs=config.num_epochs, **schedule)
+            if config.cosine_lr else
+            LRSchedule(decay_schedule=config.lr_schedule, **schedule))
         self._step_cache: dict[tuple, tp.Callable] = {}
         # (step key, shapes) call counts: the first call compiles, and the
         # second can recompile again because donation turns the host-numpy
@@ -293,6 +297,7 @@ class Trainer:
                                      start_step=config.profile_start_step,
                                      num_steps=config.profile_steps)
         self._async_bilat = None  # built per-fit when cfg.bilat_async
+        self._logged_faults = False
         self._warned_prefetch = False
 
         # runtime consensus health (resilience/): monitor sees, policy
@@ -307,8 +312,7 @@ class Trainer:
                 health_every=config.health_every,
                 residual_floor=config.residual_floor, log=self.log,
                 registry=self.telemetry.registry)
-            if not (config.all_reduce or config.bilat
-                    or config.bilat_async):
+            if self._mode() in ("sgp", "dpsgd"):
                 # overlap runs recover too: the reactive average folds
                 # the in-flight FIFO into Σx/Σw and drains it, so
                 # nothing is double-counted (resilience/recovery.py)
@@ -321,8 +325,7 @@ class Trainer:
                 self.recovery_policy = RecoveryPolicy(
                     world=self.gossip_world,
                     ppi=ppi_at_epoch(config.ppi_schedule, 0),
-                    algorithm="sgp" if config.push_sum else "dpsgd",
-                    topology=topo,
+                    algorithm=self._mode(), topology=topo,
                     residual_floor=config.residual_floor,
                     cooldown_steps=config.health_every, log=self.log,
                     registry=self.telemetry.registry,
@@ -353,27 +356,11 @@ class Trainer:
         return None
 
     def _wire_codec(self):
-        """Resolve the wire codec from the config (wire_dtype, with the
-        deprecated gossip_comm_dtype alias); reject unknown values rather
-        than silently running uncompressed."""
-        from ..parallel import wire as wire_mod
+        """The wire codec the config names (None = unset); an unknown
+        ``wire_dtype`` is a ``ValueError``, never an uncompressed run."""
+        from ..parallel.wire import get_codec
 
-        cfg = self.cfg
-        if cfg.wire_dtype is not None:
-            if cfg.gossip_comm_dtype is not None \
-                    and cfg.wire_dtype != "bf16":
-                raise ValueError(
-                    "gossip_comm_dtype is a deprecated alias for "
-                    "wire_dtype=bf16 and conflicts with "
-                    f"wire_dtype={cfg.wire_dtype!r}")
-            return wire_mod.get_codec(cfg.wire_dtype, cfg.wire_block)
-        if cfg.gossip_comm_dtype is None:
-            return None
-        if cfg.gossip_comm_dtype != "bf16":
-            raise ValueError(f"unknown gossip_comm_dtype "
-                             f"{cfg.gossip_comm_dtype!r}; use 'bf16' "
-                             "(or the wire_dtype knob)")
-        return wire_mod.BF16
+        return get_codec(self.cfg.wire_dtype, self.cfg.wire_block)
 
     def wire_config(self) -> dict | None:
         """JSON-safe wire stamp ({"dtype", "block", "error_feedback"}),
@@ -410,75 +397,32 @@ class Trainer:
             return 1
         return staleness
 
+    def _mode(self) -> str:
+        cfg = self.cfg
+        return gossip_mode(all_reduce=cfg.all_reduce, push_sum=cfg.push_sum,
+                           bilat=cfg.bilat, bilat_async=cfg.bilat_async)
+
     def make_algorithm(self, ppi: int) -> GossipAlgorithm:
         cfg = self.cfg
-        axis = self.gossip_axis
-        codec = self._wire_codec()
-        if codec is not None and codec.lossy \
-                and (cfg.all_reduce or cfg.bilat or not cfg.push_sum):
-            raise ValueError(
-                "wire compression (wire_dtype / the deprecated "
-                "gossip_comm_dtype) applies to the push-sum family only")
-        if cfg.error_feedback and (cfg.all_reduce or cfg.bilat
-                                   or not cfg.push_sum):
-            raise ValueError(
-                "error_feedback rides the push-sum gossip wire; "
-                "all_reduce/bilateral/D-PSGD modes have none")
-        if cfg.global_avg_every and (cfg.all_reduce or cfg.bilat
-                                     or cfg.bilat_async):
-            raise ValueError(
-                "global_avg_every applies to the push-sum/D-PSGD gossip "
-                "family (all_reduce is already exact every step)")
-        if cfg.inject_faults and (cfg.all_reduce or cfg.bilat
-                                  or cfg.bilat_async):
-            raise ValueError(
-                "inject_faults breaks gossip edges; all_reduce/bilateral "
-                "modes have none (use push-sum gossip)")
-        if cfg.all_reduce:
-            return all_reduce(axis)
-        if cfg.bilat_async:
-            # no collective in the compiled step: the bilateral averaging
-            # runs host-side (train/async_bilat.py); pure local SGD here
-            return GossipAlgorithm()
-        graph = cfg.graph_class(self.gossip_world, peers_per_itr=ppi)
-        if cfg.bilat:
-            return adpsgd(build_pairing_schedule(graph), axis)
-        mixing = cfg.mixing_class() if cfg.mixing_class else None
-        schedule = build_schedule(graph, mixing)
-        faults = None
-        if cfg.inject_faults:
-            # compile the fault plan against THIS schedule: masks are
-            # per-(phase, edge), so a ppi schedule change rebuilds them
-            from ..resilience import parse_fault_spec
-
-            plan = parse_fault_spec(cfg.inject_faults)
-            faults = plan.build_masks(
-                schedule,
-                gossip_every=cfg.gossip_every if cfg.push_sum else 1)
-            if not getattr(self, "_logged_faults", False):
-                # make_algorithm runs once per compiled variant; one
-                # banner per run is enough
-                self.log.warning("gossip faults: %s", plan.summary())
-                self._logged_faults = True
-        staleness = self._resolve_staleness()
-        if cfg.push_sum:
-            return sgp(schedule, axis, overlap=cfg.overlap,
-                       gossip_every=cfg.gossip_every,
-                       wire=codec,
-                       error_feedback=cfg.error_feedback,
-                       staleness=staleness,
-                       global_avg_every=cfg.global_avg_every,
-                       faults=faults,
-                       gossip_kernel=cfg.gossip_kernel,
-                       gossip_buckets=cfg.gossip_buckets)
-        if cfg.gossip_every != 1:
-            raise ValueError("gossip_every is a push-sum knob")
-        return dpsgd(schedule, axis, overlap=cfg.overlap,
-                     staleness=staleness,
-                     global_avg_every=cfg.global_avg_every,
-                     faults=faults,
-                     gossip_kernel=cfg.gossip_kernel,
-                     gossip_buckets=cfg.gossip_buckets)
+        mode = self._mode()
+        log = None
+        if cfg.inject_faults and not self._logged_faults:
+            # make_algorithm runs once per compiled variant; one fault
+            # banner per run is enough
+            log, self._logged_faults = self.log, True
+        return gossip_algorithm(
+            mode, self.gossip_axis, world=self.gossip_world,
+            graph_class=cfg.graph_class, peers_per_itr=ppi,
+            mixing=cfg.mixing_class() if cfg.mixing_class else None,
+            overlap=cfg.overlap,
+            staleness=(self._resolve_staleness()
+                       if mode in ("sgp", "dpsgd") else 1),
+            gossip_every=cfg.gossip_every, wire_dtype=cfg.wire_dtype,
+            wire_block=cfg.wire_block, error_feedback=cfg.error_feedback,
+            global_avg_every=cfg.global_avg_every,
+            inject_faults=cfg.inject_faults,
+            gossip_kernel=cfg.gossip_kernel,
+            gossip_buckets=cfg.gossip_buckets, log=log)
 
     def _train_fn(self, ppi: int, itr_per_epoch: int, scan: int = 1):
         """Compiled step for a peers-per-itr value; each distinct
@@ -519,14 +463,12 @@ class Trainer:
 
         cfg = self.cfg
         exact = tree_payload_bytes(state.params, self.gossip_world)
-        if cfg.all_reduce:
-            alg_name = "all_reduce"
+        alg_name = self._mode()
+        if alg_name == "all_reduce":
             model = CommModel.for_allreduce(self.gossip_world, exact)
-        elif cfg.bilat or cfg.bilat_async:
-            alg_name = "bilat_async" if cfg.bilat_async else "adpsgd"
+        elif alg_name in ("adpsgd", "bilat_async"):
             model = CommModel.for_bilat(self.gossip_world, exact)
         else:
-            alg_name = "sgp" if cfg.push_sum else "dpsgd"
             # the epoch-0 compiled variant's own algorithm object: its
             # schedule/faults are exactly what the wire will run (the
             # cache entry is reused by the epoch loop, so this costs no
@@ -653,16 +595,6 @@ class Trainer:
         cap = cfg.num_iterations_per_training_epoch
         if cap not in (None, -1):
             itr_per_epoch = min(itr_per_epoch, cap)
-        if cfg.cosine_lr:
-            self.lr_schedule_obj = CosineLRSchedule(
-                ref_lr=cfg.lr, batch_size=cfg.batch_size,
-                world_size=self.world_size, total_epochs=cfg.num_epochs,
-                warmup=cfg.warmup)
-        else:
-            self.lr_schedule_obj = LRSchedule(
-                ref_lr=cfg.lr, batch_size=cfg.batch_size,
-                world_size=self.world_size, decay_schedule=cfg.lr_schedule,
-                warmup=cfg.warmup)
         self._init_csv()
 
         batch_meter = Meter(ptag="Time")

@@ -1,13 +1,6 @@
-"""Shared utilities: metering, logging, flattening, profiling."""
+"""Shared utilities: metering, logging, profiling."""
 
-from .flatten import (
-    communicate,
-    flatten_tensors,
-    global_norm,
-    group_by_dtype,
-    is_power_of,
-    unflatten_tensors,
-)
+from .flatten import global_norm
 from .logging import make_logger, reset_logger
 from .meter import Meter, PercentileMeter
 from .profiling import HEARTBEAT_TIMEOUT, StepWatchdog, trace
@@ -17,12 +10,7 @@ __all__ = [
     "PercentileMeter",
     "make_logger",
     "reset_logger",
-    "flatten_tensors",
-    "unflatten_tensors",
-    "group_by_dtype",
-    "communicate",
     "global_norm",
-    "is_power_of",
     "StepWatchdog",
     "trace",
     "HEARTBEAT_TIMEOUT",

@@ -1,7 +1,7 @@
 """Where the persistent XLA compile cache lives.
 
 Every entry point that compiles a train step (both training CLIs,
-``bench.py``'s measuring path, ``chip_smoke.py``) calls
+``chip_smoke.py``) calls
 :func:`place_compile_cache` before its first compile, so a second process
 in the same checkout — or a second call on a machine that keeps its disk —
 starts from compiled programs instead of minutes of ResNet-50 / 12-layer LM

@@ -1,67 +1,17 @@
-"""Pytree flattening utilities (C1 parity: gossip/utils/helpers.py:21-88).
+"""Pytree reductions over a whole parameter tree.
 
 The reference flattens parameter lists into one contiguous 1-D buffer per
-dtype so each gossip round is a single NCCL message (``flatten_tensors``,
-``unflatten_tensors``, ``group_by_dtype``).  On TPU the collective layer
-mixes pytrees leaf-by-leaf and XLA coalesces the transfers, so flattening
-is *not* needed on the hot path — these helpers exist for API parity and
-for the places where a single flat view is genuinely convenient
-(checkpoint hashing, norm computation, debugging parity with reference
-buffers).
+dtype so each gossip round is a single NCCL message (gossip/utils/
+helpers.py:21-88).  On TPU the collective layer mixes pytrees leaf by leaf
+and XLA coalesces the transfers, so nothing here flattens.
 """
 
 from __future__ import annotations
 
-import collections
-import math
-import typing as tp
-
 import jax
 import jax.numpy as jnp
-from jax.flatten_util import ravel_pytree
 
-__all__ = ["flatten_tensors", "unflatten_tensors", "group_by_dtype",
-           "communicate", "global_norm", "is_power_of"]
-
-
-def flatten_tensors(tree) -> tuple[jnp.ndarray, tp.Callable]:
-    """Flatten a pytree into one 1-D buffer.
-
-    Returns ``(flat, unravel)`` — unlike the reference (which re-derives
-    shapes from a template list), the unravel closure carries the
-    structure, so round-trips can't misalign.
-    """
-    return ravel_pytree(tree)
-
-
-def unflatten_tensors(flat: jnp.ndarray, unravel: tp.Callable):
-    """Inverse of :func:`flatten_tensors`."""
-    return unravel(flat)
-
-
-def group_by_dtype(tree) -> dict:
-    """Group leaves by dtype: {dtype: list of leaves} with a matching
-    treedef per dtype (≙ helpers.py:60-70)."""
-    groups = collections.defaultdict(list)
-    for leaf in jax.tree.leaves(tree):
-        groups[jnp.asarray(leaf).dtype].append(leaf)
-    return dict(groups)
-
-
-def communicate(tree, communication_op):
-    """Apply a collective to a pytree via one flat buffer per dtype
-    (≙ helpers.py:73-88).  ``communication_op`` maps array → array."""
-    leaves, treedef = jax.tree.flatten(tree)
-    by_dtype = collections.defaultdict(list)
-    for idx, leaf in enumerate(leaves):
-        by_dtype[jnp.asarray(leaf).dtype].append(idx)
-    new_leaves = list(leaves)
-    for dtype, idxs in by_dtype.items():
-        flat, unravel = ravel_pytree([leaves[i] for i in idxs])
-        result = unravel(communication_op(flat))
-        for i, r in zip(idxs, result):
-            new_leaves[i] = r
-    return jax.tree.unflatten(treedef, new_leaves)
+__all__ = ["global_norm"]
 
 
 def global_norm(tree) -> jnp.ndarray:
@@ -75,12 +25,3 @@ def global_norm(tree) -> jnp.ndarray:
         return jnp.float32(0.0)
     return jnp.sqrt(sum(jnp.sum(jnp.square(l.astype(jnp.float32)))
                         for l in leaves))
-
-
-def is_power_of(n: int, k: int) -> bool:
-    """Whether ``n`` is a power of ``k`` (≙ helpers.py:117-128)."""
-    if not (isinstance(n, int) and isinstance(k, int)) or k < 0 or n <= 0:
-        raise ValueError("n must be a positive int, k a non-negative int")
-    if k <= 1:
-        return n == 1
-    return k ** int(round(math.log(n, k))) == n

@@ -1,4 +1,4 @@
-"""Utility-layer tests: flatten/communicate, watchdog, discovery parsing."""
+"""Utility-layer tests: global norm, watchdog, discovery parsing."""
 
 import time
 
@@ -11,45 +11,7 @@ from stochastic_gradient_push_tpu.parallel.discovery import (
     _first_slurm_host,
     discover,
 )
-from stochastic_gradient_push_tpu.utils import (
-    StepWatchdog,
-    communicate,
-    flatten_tensors,
-    global_norm,
-    group_by_dtype,
-    unflatten_tensors,
-)
-
-
-def _tree():
-    return {"a": jnp.arange(6.0).reshape(2, 3),
-            "b": [jnp.ones((4,), jnp.float32),
-                  jnp.asarray([1, 2, 3], jnp.int32)]}
-
-
-def test_flatten_roundtrip():
-    tree = _tree()
-    flat, unravel = flatten_tensors(tree)
-    assert flat.ndim == 1
-    restored = unflatten_tensors(flat, unravel)
-    for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(restored)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_group_by_dtype():
-    groups = group_by_dtype(_tree())
-    assert set(groups) == {np.dtype(np.float32), np.dtype(np.int32)}
-    assert len(groups[np.dtype(np.float32)]) == 2
-    assert len(groups[np.dtype(np.int32)]) == 1
-
-
-def test_communicate_applies_op_per_dtype():
-    tree = {"x": jnp.ones((3,)), "y": jnp.full((2, 2), 2.0)}
-    out = communicate(tree, lambda flat: flat * 10)
-    np.testing.assert_allclose(np.asarray(out["x"]), 10 * np.ones(3))
-    np.testing.assert_allclose(np.asarray(out["y"]), 20 * np.ones((2, 2)))
-    # structure preserved
-    assert jax.tree.structure(out) == jax.tree.structure(tree)
+from stochastic_gradient_push_tpu.utils import StepWatchdog, global_norm
 
 
 def test_global_norm():

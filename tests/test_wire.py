@@ -500,19 +500,6 @@ def test_sgd_cli_wire_flags_thread_into_config():
     assert cfg.error_feedback is True
 
 
-def test_sgd_cli_gossip_comm_dtype_is_deprecated_alias(capsys):
-    from stochastic_gradient_push_tpu.run.gossip_sgd import parse_config
-
-    cfg, args = parse_config(
-        ["--dataset", "synthetic", "--gossip_comm_dtype", "bf16"])
-    assert cfg.wire_dtype == "bf16"
-    assert "deprecated" in capsys.readouterr().err
-    with pytest.raises(SystemExit, match="deprecated alias"):
-        parse_config(["--dataset", "synthetic",
-                      "--gossip_comm_dtype", "bf16",
-                      "--wire_dtype", "int8"])
-
-
 def test_sgd_cli_rejects_wire_knobs_outside_push_sum():
     from stochastic_gradient_push_tpu.run.gossip_sgd import parse_config
 
@@ -554,14 +541,13 @@ def test_trainer_config_wire_codec_resolution():
     codec = Trainer._wire_codec(
         type("T", (), {"cfg": cfg})())  # resolve without a mesh
     assert isinstance(codec, wire.Int8Codec) and codec.block == 32
-    # deprecated library-user spelling still resolves
-    cfg2 = TrainerConfig(gossip_comm_dtype="bf16")
+    cfg2 = TrainerConfig(wire_dtype="bf16")
     assert Trainer._wire_codec(
         type("T", (), {"cfg": cfg2})()) is wire.BF16
-    with pytest.raises(ValueError, match="deprecated alias"):
+    # an unknown name never runs uncompressed in silence
+    with pytest.raises(ValueError, match="unknown wire_dtype"):
         Trainer._wire_codec(type("T", (), {
-            "cfg": TrainerConfig(wire_dtype="int8",
-                                 gossip_comm_dtype="bf16")})())
+            "cfg": TrainerConfig(wire_dtype="fp8")})())
 
 
 def test_sgd_cli_int8_ef_end_to_end(tmp_path):
