@@ -82,14 +82,16 @@ class Mamba2Mixer(nn.Module):
             dt = jax.nn.softplus(dt.astype(f32) + dt_bias)
 
             lead = x.shape[:2]
-            x = x.reshape(lead + (h, p))
             with jax.named_scope(names.SCOPE_SSD):
                 y = ssd_chunked(
-                    x, dt, -jnp.exp(a_log),
+                    x.reshape(lead + (h, p)), dt, -jnp.exp(a_log),
                     b.reshape(lead + (ssm.n_groups, ssm.d_state)),
                     c.reshape(lead + (ssm.n_groups, ssm.d_state)),
                     ssm.chunk_size, operand_dtype=self.dtype)
-            y = (y + skip[:, None] * x).reshape(lead + (inner,))
+            # the skip on [B, T, H * P], the layout the projections and
+            # the scan's kernels share: a [B, T, H, P] view is, tiled for
+            # the TPU, another array
+            y = y.reshape(lead + (inner,)) + jnp.repeat(skip, p) * x
 
             y = y * jax.nn.silu(z.astype(f32))
             y = nn.RMSNorm(epsilon=self.norm_eps, dtype=f32, name="norm")(y)

@@ -42,6 +42,8 @@ KERNEL_FLASH_FWD = "flash_fwd"
 KERNEL_FLASH_BWD = "flash_bwd"            # the fused backward
 KERNEL_FLASH_DQ = "flash_dq"              # the pair it gives way to
 KERNEL_FLASH_DKV = "flash_dkv"            # beyond its VMEM budget
+KERNEL_SSD_FWD = "ssd_fwd"                # the scan inside a chunk
+KERNEL_SSD_BWD = "ssd_bwd"                # (ops/ssd.py), and its backward
 KERNEL_GOSSIP_START = "gossip_edge_start"
 KERNEL_GOSSIP_WAIT = "gossip_edge_wait"
 KERNEL_PAGED_ATTENTION = "paged_attention"

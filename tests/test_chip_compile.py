@@ -28,6 +28,7 @@ from stochastic_gradient_push_tpu.ops.flash_attention import (
     default_block, flash_attention, flash_attention_backward,
     flash_attention_forward, fused_backward_fits)
 from stochastic_gradient_push_tpu.ops.ring_flash import ring_flash_attention
+from stochastic_gradient_push_tpu.ops.ssd import kernel_fits, ssd_chunked
 from stochastic_gradient_push_tpu.parallel import (
     GOSSIP_AXIS, collectives, make_gossip_mesh, wire)
 from stochastic_gradient_push_tpu.serve.engine import ServeConfig
@@ -182,6 +183,43 @@ def test_fused_backward_compiles_at_the_budget_in_fp32(one_chip, d):
 
     text = jax.jit(bwd).lower(x, x, x, x, lse, x).compile().as_text()
     assert _kernel_names(text) == {names.KERNEL_FLASH_BWD}
+
+
+@pytest.mark.parametrize("dtype,groups", [
+    (jnp.bfloat16, 1), (jnp.float32, 1), (jnp.bfloat16, 8)],
+    ids=["bf16", "fp32", "groups8"])
+def test_scan_kernel_pair_compiles(one_chip, on_tpu, dtype, groups):
+    """The state-space scan at the published sizes (4096 steps in chunks
+    of 256, 64 heads of 64 over a state of 128) through ``jax.grad`` of
+    ``ssd_chunked``, under the mixer's scope as in the program: the rule
+    takes the kernels, and the compiled text holds one ``ssd_fwd`` and one
+    ``ssd_bwd``; in float32, and with the eight groups of the family's
+    larger models (a group's scores over one head block a chunk)."""
+    t, h, p, n, chunk = 4096, 64, 64, 128, 256
+    assert kernel_fits("tpu", chunk, n, p, h, groups,
+                       jnp.dtype(dtype).itemsize)
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                              sharding=one_chip)
+
+    def loss(x, dt, a, b, c):
+        with jax.named_scope(names.SCOPE_FORWARD), \
+                jax.named_scope(names.SCOPE_SSD):
+            return ssd_chunked(x, dt, a, b, c, chunk,
+                               operand_dtype=dtype).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        f32(1, t, h, p), f32(1, t, h), f32(h), f32(1, t, groups, n),
+        f32(1, t, groups, n)).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert _kernel_names(text) == {names.KERNEL_SSD_FWD,
+                                   names.KERNEL_SSD_BWD}
+    # the compiled calls carry the scan's scope (``ssd_ms`` finds them by
+    # it), through the jitted wrappers that share one trace among layers
+    for call, where in (("ssd_fwd", "jvp("), ("ssd_bwd", "transpose(jvp(")):
+        assert re.search(
+            rf'op_name="[^"]*/{re.escape(where)}{names.SCOPE_FORWARD}\)+/'
+            rf'{re.escape(names.SCOPE_SSD)}/jit\(\w+\)/{call}/pallas_call"',
+            text), call
 
 
 def test_push_sum_round_is_a_collective_permute(mesh):

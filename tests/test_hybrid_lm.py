@@ -409,9 +409,9 @@ def _locations(text: str) -> str:
     return "\n".join(re.findall(r'^#loc\d+ = loc\((.*)\)$', text, re.M))
 
 
-def test_the_lowered_step_holds_the_mixers_scopes():
+def _lowered_step_locations(model, seq: int) -> str:
+    """The op names in the lowered text of one SGP step of ``model``."""
     mesh = make_dp_sp_mesh(1, 1)
-    model = _model(remat=True)
     alg = sgp(build_schedule(NPeerDynamicDirectedExponentialGraph(
         1, peers_per_itr=1)), GOSSIP_AXIS)
     tx = sgd(momentum=0.9, weight_decay=0.0)
@@ -420,11 +420,14 @@ def test_the_lowered_step_holds_the_mixers_scopes():
     step = build_lm_train_step(model, alg, tx, lrs, itr_per_epoch=10,
                                seq_axis=None)
     state = init_lm_state(model, mesh, alg, tx, dp=1, sp=1, batch_size=2,
-                          block_len=SEQ, seq_axis=None)
-    tokens = jnp.zeros((1, 2, SEQ), jnp.int32)
-    text = shard_lm_train_step(step, mesh, seq_axis=None).lower(
-        state, tokens, tokens).as_text(debug_info=True)
-    where = _locations(text)
+                          block_len=seq, seq_axis=None)
+    tokens = jnp.zeros((1, 2, seq), jnp.int32)
+    return _locations(shard_lm_train_step(step, mesh, seq_axis=None).lower(
+        state, tokens, tokens).as_text(debug_info=True))
+
+
+def test_the_lowered_step_holds_the_mixers_scopes():
+    where = _lowered_step_locations(_model(remat=True), SEQ)
     for scope in (names.SCOPE_SSM_MIXER, names.SCOPE_SSD,
                   names.SCOPE_CONV1D):
         assert re.search(rf'[("/]{re.escape(scope)}[)/"]', where), scope
@@ -436,3 +439,38 @@ def test_the_lowered_step_holds_the_mixers_scopes():
         where)
     assert re.search(rf"{re.escape(names.SCOPE_SSM_MIXER)}.*"
                      rf"{re.escape(names.SCOPE_CONV1D)}", where)
+    # on this backend the scan is XLA operations: no kernel of its own
+    assert names.KERNEL_SSD_FWD not in where
+
+
+def test_the_lowered_step_holds_the_scan_kernels_under_the_scans_scope(
+        monkeypatch):
+    """At the smallest sizes the kernel pair tiles, with its rule answered
+    for it and the kernels interpreted: ``ssd_fwd`` in the forward pass
+    and ``ssd_bwd`` in its transpose, each inside ``lm.ssd`` inside the
+    mixer's scope inside the step's forward scope, which is where
+    ``ssd_ms``, ``ssm_mixer_ms``, ``fwd_ms`` and ``bwd_ms`` look for
+    them."""
+    ssd = importlib.import_module("stochastic_gradient_push_tpu.ops.ssd")
+    ssm = importlib.import_module("stochastic_gradient_push_tpu.models.ssm")
+    monkeypatch.setattr(ssd, "kernel_fits", lambda *args: True)
+    monkeypatch.setattr(ssd, "chunks_kernel", functools.partial(
+        ssd.chunks_kernel, interpret=True))
+    assert ssm.ssd_chunked is ssd.ssd_chunked
+    # an interpreted kernel's scratch is not typed varying over the mesh
+    # (jax 0.9): the step's shard_map lowers without that check here, as
+    # the gossip kernel's interpreted lane does; compiled kernels keep it
+    monkeypatch.setattr(jax, "shard_map", functools.partial(
+        jax.shard_map, check_vma=False))
+    model = _model(remat=True, hidden_size=64, mamba_n_heads=2,
+                   mamba_d_head=64, mamba_d_state=128, mamba_chunk_size=128)
+    where = _lowered_step_locations(model, 128)
+    # the kernels' wrappers are jitted (one trace and lowering for every
+    # layer): the call carries the scopes, the callee the kernel's name
+    under = (rf"{re.escape(names.SCOPE_FORWARD)}\)+/.*"
+             rf"{re.escape(names.SCOPE_SSM_MIXER)}/"
+             rf"{re.escape(names.SCOPE_SSD)}/")
+    assert re.search(rf'/jvp\({under}jit\(ssd_forward\)', where)
+    assert re.search(rf'/transpose\(jvp\({under}jit\(ssd_backward\)', where)
+    for kernel in (names.KERNEL_SSD_FWD, names.KERNEL_SSD_BWD):
+        assert f'"{kernel}/pallas_call"' in where
