@@ -1,6 +1,7 @@
 """The quickest proof that the trainers still start on the chip.
 
-    python chip_smoke.py             # one chip: ResNet-50 SGP, dense LM
+    python chip_smoke.py             # one chip: ResNet-50 SGP, dense LM,
+                                     # the experts' grouped kernels
     python chip_smoke.py --chips 4   # four chips: SGP vs AR, placement,
                                      # the Pallas gossip lane
 
@@ -22,6 +23,7 @@ from __future__ import annotations
 import argparse
 import csv
 import faulthandler
+import functools
 import json
 import math
 import os
@@ -55,6 +57,14 @@ SIZES = {
         # the auto rule's answer on a TPU, and the shape at which the
         # kernel must be IN the compiled program (None skips the check)
         "attn": "flash", "kernel_shape": (8, 12, 1024, 64),
+    },
+    # the top-k expert layer's products at the published sizes (8192 tokens
+    # send 32768 pairs to 16 held experts; 2048 to gate | up of 3584)
+    "grouped_kernels": {
+        "rows": 32768, "width": 2048, "out": 3584, "experts": 16,
+        "dtype": "bfloat16", "interpret": False,
+        # both sides round a float32 sum to bf16; the sums' order differs
+        "tolerance": 2.0 ** -6,
     },
     # the four-chip phases share one configuration: SGP, what the paper
     # compares it with (AR), and SGP again on the Pallas transport
@@ -238,6 +248,86 @@ def lm_dense_flash(sizes: dict, cache_dir: str) -> dict:
     return observe("lm_dense_flash", t0, len(losses), losses, cache_dir,
                    attn=result["attn"], flash_custom_calls=calls,
                    run_dir=out)
+
+
+def grouped_splits(rows: int, experts: int, tile: int) -> dict:
+    """Splits a router can give the held experts: half of the rows evenly,
+    an uneven draw with groups that share tiles, the held rows ending on a
+    tile's boundary with empty experts behind them (an empty expert's one
+    visit then lands on a tile no product wrote), and every row held."""
+    import numpy as np
+
+    rng = np.random.default_rng(0)
+    half, some = rows // 2, max(experts // 3, 1)
+    return {
+        "even": np.full(experts, half // experts),
+        "uneven": rng.multinomial(
+            half - tile // 3, rng.dirichlet(np.full(experts, 0.5))),
+        "trailing_empty_on_a_boundary": np.r_[
+            rng.multinomial(half // tile * tile, np.full(some, 1 / some)),
+            np.zeros(experts - some, np.int64)],
+        "every_row": rng.multinomial(rows, np.full(experts, 1 / experts)),
+    }
+
+
+def grouped_kernels(sizes: dict, cache_dir: str) -> dict:
+    """The expert layer's three kernels (``ops/grouped_matmul.py``),
+    compiled, against ``lax.ragged_dot`` at the published sizes: the
+    output, the rows' gradient and the blocks' gradient over
+    ``grouped_splits``, with NaN in the rows and in the output's gradient
+    past the held rows (what no product wrote is whatever was in memory).
+    The benchmark's ``correct`` runs the layer forward only; this is what
+    holds the two backward kernels on the chip."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import lax
+
+    from stochastic_gradient_push_tpu.ops import grouped_matmul as gm
+
+    t0 = time.time()
+    m, k, n, g = (sizes[key] for key in ("rows", "width", "out", "experts"))
+    dtype, interpret = jnp.dtype(sizes["dtype"]), sizes["interpret"]
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(2), 3)
+    x = jax.random.normal(k1, (m, k), dtype)
+    w = (jax.random.normal(k2, (g, k, n)) * k ** -0.5).astype(dtype)
+    d_out = jax.random.normal(k3, (m, n), dtype)
+    if not interpret and not gm.kernel_fits(jax.default_backend(), x, w):
+        raise RuntimeError(
+            f"grouped_kernels: kernel_fits refuses {x.shape} by {w.shape} "
+            f"on {jax.default_backend()}: the layer would take ragged_dot")
+
+    @functools.partial(jax.jit, static_argnames="kernels")
+    def products(x, w, d_out, split, kernels):
+        held = (jnp.arange(m) < split.sum())[:, None]
+        clean = lambda a: jnp.where(held, a, 0)
+        dot = (lambda x, w: gm.grouped_matmul(x, w, split, interpret)) \
+            if kernels else (lambda x, w: lax.ragged_dot(x, w, split))
+        fill = jnp.nan if kernels else 0
+        out, vjp = jax.vjp(dot, jnp.where(held, x, fill), w)
+        d_rows, d_blocks = vjp(jnp.where(held, d_out, fill))
+        return clean(out), clean(d_rows), d_blocks
+
+    worst = {}
+    for name, split in grouped_splits(m, g, gm.ROW_TILE).items():
+        split = jnp.asarray(split, jnp.int32)
+        pairs = zip(("out", "d_rows", "d_blocks"),
+                    products(x, w, d_out, split, True),
+                    products(x, w, d_out, split, False))
+        for what, got, want in pairs:
+            got, want = (np.asarray(t, np.float32) for t in (got, want))
+            error = float(np.abs(got - want).max() / np.abs(want).max())
+            if not error <= sizes["tolerance"]:     # NaN fails too
+                raise RuntimeError(
+                    f"grouped_kernels: {what} over the split {name!r} is "
+                    f"{error} of the largest value from lax.ragged_dot's "
+                    f"(tolerance {sizes['tolerance']})")
+            worst[what] = max(worst.get(what, 0.0), error)
+    return {"phase": "grouped_kernels", "ok": True,
+            "seconds": round(time.time() - t0, 1),
+            "worst_share_of_largest": worst,
+            "compile_cache_dir": cache_dir,
+            "compile_cache_entries": cache_entries(cache_dir)}
 
 
 # -- four chips -------------------------------------------------------------
@@ -507,7 +597,8 @@ def run(chips: int) -> int:
 
     if chips == 1:
         phases = [(resnet50_sgp, "resnet50_sgp"),
-                  (lm_dense_flash, "lm_dense_flash")]
+                  (lm_dense_flash, "lm_dense_flash"),
+                  (grouped_kernels, "grouped_kernels")]
     else:
         phases = [(placement_w4, "w4"), (sgp_w4, "w4"), (ar_w4, "w4"),
                   (sgp_w4_pallas, "w4")]

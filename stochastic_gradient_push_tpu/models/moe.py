@@ -16,15 +16,33 @@ shard_map-based step structure as everything else:
 sharded over ``ep`` via ``in_specs=P("ep")``).  With ``ep_axis=None`` the
 same code runs single-shard with all experts — the numerical reference the
 tests pin the sharded version against.
+
+Beside it, the layer of the published sparse-expert LMs
+(:func:`topk_moe_ffn`): sigmoid scores, ``per_token`` experts a token
+chosen with a selection bias, **no capacity and no token dropped**,
+SiLU-gated experts in the compute dtype.  The (token, choice) pairs are
+sorted by expert and each expert's rows go through grouped matrix
+products (``ops/grouped_matmul.py``: Pallas kernels on a TPU,
+``lax.ragged_dot`` elsewhere), at shapes that do not depend on the
+split and at the cost of the rows held.  The layer is told which experts
+it holds: it routes over all of them and computes its own experts' part
+of the result — what expert parallelism asks of a shard, here without
+the exchange.
 """
 
 from __future__ import annotations
+
+import typing as tp
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-__all__ = ["switch_moe_ffn", "moe_capacity"]
+from ..ops.grouped_matmul import grouped_dot
+from ..telemetry import names
+
+__all__ = ["switch_moe_ffn", "moe_capacity", "ExpertsConfig",
+           "topk_moe_ffn"]
 
 
 def moe_capacity(num_tokens: int, num_experts: int,
@@ -107,4 +125,155 @@ def switch_moe_ffn(x, router_w, w1, w2, ep_axis: str | None = None,
         "load_balance_loss": e_total * jnp.sum(frac * mean_prob),
         "dropped_fraction": 1.0 - jnp.mean(kept.astype(jnp.float32)),
     }
+    return y.astype(x.dtype), aux
+
+
+class ExpertsConfig(tp.NamedTuple):
+    """Sizes of the top-k expert layer, as the source's ``config.json``
+    gives them.  ``held`` is the half-open range of experts whose weights
+    live here (``None``: all of them); the router is ``n_experts`` wide
+    whatever is held."""
+
+    n_experts: int = 32
+    per_token: int = 4
+    d_ff: int = 1792
+    held: tuple[int, int] | None = None
+
+    @property
+    def first(self) -> int:
+        return self.held[0] if self.held else 0
+
+    @property
+    def n_held(self) -> int:
+        return self.held[1] - self.held[0] if self.held else self.n_experts
+
+    def check(self) -> None:
+        first, end = self.held or (0, self.n_experts)
+        if not 0 <= first < end <= self.n_experts:
+            raise ValueError(f"experts held [{first}, {end}) are no part "
+                             f"of the router's {self.n_experts}")
+        if not 1 <= self.per_token <= self.n_experts:
+            raise ValueError(f"{self.per_token} experts a token of "
+                             f"{self.n_experts}")
+
+
+# The two moves of rows between token order and expert order.  Each is a
+# gather, and so is its transpose: ``order`` lists the pairs by expert,
+# ``place`` is its inverse, and autodiff's scatter-add never appears.
+# Pairs are numbered choice-major (pair ``j * T + t`` is token ``t``'s
+# ``j``-th choice): ``[k * T, D]`` then splits into ``[k, T, D]`` on its
+# leading dimension, which a tiled layout gives for nothing, where
+# ``[T, k, D]`` would be another array.
+
+def _take(rows, index):
+    return jnp.take(rows, index, axis=0, mode="clip")
+
+
+@jax.custom_vjp
+def _rows_to_experts(x, order, place, held):
+    """``x`` ``[T, D]`` to ``[k * T, D]``: row ``i`` is the token of pair
+    ``order[i]``.  Rows of pairs not held come last and are read by no
+    product."""
+    return _take(x, order % x.shape[0])
+
+
+def _to_experts_fwd(x, order, place, held):
+    return _rows_to_experts(x, order, place, held), (place, held, x.shape[0])
+
+
+def _to_experts_bwd(res, d_rows):
+    place, held, t = res
+    back = jnp.where(held[:, None], _take(d_rows, place), 0)
+    d_x = back.reshape(-1, t, back.shape[-1]).astype(jnp.float32).sum(0)
+    return d_x.astype(d_rows.dtype), None, None, None
+
+
+_rows_to_experts.defvjp(_to_experts_fwd, _to_experts_bwd)
+
+
+@jax.custom_vjp
+def _rows_from_experts(out, order, place, held):
+    """``out`` ``[k * T, D]`` in expert order back to pair order, zero for
+    the pairs not held."""
+    return jnp.where(held[:, None], _take(out, place), 0)
+
+
+def _from_experts_fwd(out, order, place, held):
+    return _rows_from_experts(out, order, place, held), (order, held)
+
+
+def _from_experts_bwd(res, d_back):
+    order, held = res
+    # in expert order the held pairs come first
+    live = (jnp.arange(held.shape[0]) < held.sum())[:, None]
+    return jnp.where(live, _take(d_back, order), 0), None, None, None
+
+
+_rows_from_experts.defvjp(_from_experts_fwd, _from_experts_bwd)
+
+
+def topk_moe_ffn(x, router_w, bias, w_gate_up, w_down, *, per_token: int,
+                 first: int = 0, dtype=None):
+    """Top-k expert feed-forward with no token dropped, over the experts
+    held here.
+
+    Args:
+      x: ``[T, D]`` tokens, float32 (the norm's output).
+      router_w: ``[D, E]`` float32, ``E`` the published router width.
+      bias: ``[E]`` selection bias (it chooses, it does not weigh; no
+        gradient).
+      w_gate_up: ``[n, D, 2 F]`` the held experts' gate and up
+        projections side by side; ``w_down``: ``[n, F, D]``.  Experts
+        ``[first, first + n)`` of the ``E``.
+      dtype: the products' operand dtype (``None``: ``x``'s).
+
+    ``s = sigmoid(x W_g)``, ``S = top_k(s + b)``, ``g_e = s_e / (sum_S s
+    + 1e-6)``; ``y = sum_{e in S, held} g_e W_down^e
+    (silu(W_gate^e x) * W_up^e x)``.  Scores, selection and weights are
+    float32; ``S`` and the normalisation are over all ``E`` experts, and
+    what the experts not held would add is left out.  Every shape is
+    static: ``T * per_token`` rows whatever the split, the held pairs
+    first, grouped by expert; rows beyond them belong to no group, are
+    masked, and on the kernels' path cost no product.
+
+    Returns ``(y [T, D] in x's dtype, aux)``; ``aux`` holds ``selection``
+    ``[T, per_token]``, ``expert_rows`` ``[n]`` (rows each held expert
+    received) and ``pairs_not_held``.
+    """
+    t, d = x.shape
+    n, k = w_gate_up.shape[0], per_token
+    dtype = dtype or x.dtype
+    f32 = jnp.float32
+    with jax.named_scope(names.SCOPE_MOE_ROUTE):
+        scores = jax.nn.sigmoid(jnp.dot(
+            x.astype(f32), router_w.astype(f32),
+            precision=lax.Precision.HIGHEST))                  # [T, E]
+        _, selection = lax.top_k(
+            scores + lax.stop_gradient(bias.astype(f32)), k)   # [T, k]
+        # the chosen experts' own scores, by a 0/1 product: its transpose
+        # is a product too, where a gather's would be a scatter
+        chosen = selection[..., None] == jnp.arange(scores.shape[-1])
+        gates = (scores[:, None, :] * chosen).sum(-1)
+        gates = gates / (gates.sum(-1, keepdims=True) + 1e-6)
+        local = selection.T.reshape(-1) - first                # [k * T]
+        held = (local >= 0) & (local < n)
+        expert = jnp.where(held, local, n)
+        # pairs by expert; those not held last
+        order = jnp.argsort(expert, stable=True)
+        place = jnp.argsort(order)         # its inverse: where each went
+        sizes = (expert[:, None] == jnp.arange(n)[None]).sum(
+            0, dtype=jnp.int32)                                # [n]
+        rows = _rows_to_experts(x.astype(dtype), order, place, held)
+    with jax.named_scope(names.SCOPE_MOE_EXPERTS):
+        live = (jnp.arange(t * k) < sizes.sum())[:, None]
+        gate, up = jnp.split(grouped_dot(
+            rows, w_gate_up.astype(dtype), sizes), 2, axis=-1)
+        act = jnp.where(live, jax.nn.silu(gate) * up, 0)
+        out = grouped_dot(act, w_down.astype(dtype), sizes)
+    with jax.named_scope(names.SCOPE_MOE_ROUTE):
+        back = _rows_from_experts(out, order, place, held)
+        y = (back.reshape(k, t, d) * gates.T[..., None]).sum(0)
+    aux = {"selection": selection,
+           "expert_rows": sizes.astype(f32),
+           "pairs_not_held": (t * k - sizes.sum()).astype(f32)}
     return y.astype(x.dtype), aux

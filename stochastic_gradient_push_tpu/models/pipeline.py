@@ -43,8 +43,8 @@ class _ScanBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions):
-        use_moe = self.cfg.moe_experts > 0
-        return _Block(self.cfg, use_moe=use_moe,
+        ffn_type = "switch" if self.cfg.moe_experts > 0 else "dense"
+        return _Block(self.cfg, ffn_type=ffn_type,
                       name="block")(x, positions), None
 
 

@@ -34,7 +34,7 @@ class SSMConfig(tp.NamedTuple):
     conv_bias: bool = True
 
 
-def _causal_depthwise_conv(x, kernel, bias):
+def causal_depthwise_conv(x, kernel, bias):
     """``y_t = sum_k kernel[k] * x_{t - (K-1) + k} (+ bias)`` per channel,
     with zeros before the sequence.  ``x`` ``[B, T, C]``, ``kernel``
     ``[K, C]``: ``K`` shifted multiply-adds, which the compiler fuses."""
@@ -69,7 +69,7 @@ class Mamba2Mixer(nn.Module):
                                     (conv_dim,), f32)
                          if ssm.conv_bias else None)
             with jax.named_scope(names.SCOPE_CONV1D):
-                xbc = jax.nn.silu(_causal_depthwise_conv(
+                xbc = jax.nn.silu(causal_depthwise_conv(
                     xbc.astype(f32), kernel, conv_bias))
             x, b, c = jnp.split(xbc, [inner, inner + bc], -1)
 

@@ -23,6 +23,11 @@ first-class in-repo model family, built TPU-first:
   SiLU-gated MLP, no positions, a tied head, the four multipliers — are
   fields of the one config; ``config_from_source`` fills them from a
   published ``config.json``.  The defaults are the GPT-2-shaped model
+* the ``lfm2_moe`` family's parts: a ``conv`` mixer (the gated short
+  convolution, models/shortconv.py), a feed-forward *pattern*
+  (``ffn_types``: dense, the switch layer, or the top-k expert layer of
+  models/moe.py over the experts held here), per-head RMSNorm of q and k
+  and a configurable rotary base
 """
 
 from __future__ import annotations
@@ -35,11 +40,15 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..parallel.ring_attention import blockwise_attention, ring_attention
+from ..telemetry import names
+from .moe import ExpertsConfig, topk_moe_ffn
+from .shortconv import ShortConvMixer
 from .ssm import Mamba2Mixer, SSMConfig
 
 __all__ = ["TransformerLM", "TransformerConfig", "config_from_source"]
 
-LAYER_TYPES = ("attention", "mamba")
+LAYER_TYPES = ("attention", "mamba", "conv")
+FFN_TYPES = ("dense", "switch", "experts")
 
 
 def _rope(x: jnp.ndarray, positions: jnp.ndarray,
@@ -94,43 +103,63 @@ class TransformerConfig(tp.NamedTuple):
     # the softmax scale (None: head_dim ** -0.5)
     attention_multiplier: float | None = None
     logits_scaling: float = 1.0       # logits are divided by it
+    # -- the lfm2_moe family's parts ------------------------------------
+    # one feed-forward a layer, from FFN_TYPES (None: the switch layer
+    # every moe_every-th block where moe_experts > 0, else dense)
+    ffn_types: tuple[str, ...] | None = None
+    experts: ExpertsConfig | None = None   # the "experts" layers' sizes
+    conv_taps: int = 3                # the "conv" mixer's taps
+    qk_norm: bool = False             # RMSNorm of q and k per head
+    rope_theta: float = 10000.0
 
     def layer_type(self, i: int) -> str:
         return self.layer_types[i] if self.layer_types else "attention"
 
+    def ffn_type(self, i: int) -> str:
+        if self.ffn_types:
+            return self.ffn_types[i]
+        switch = (self.moe_experts > 0
+                  and i % self.moe_every == self.moe_every - 1)
+        return "switch" if switch else "dense"
+
     def check_pattern(self) -> None:
         """Raises ``ValueError`` for a pattern this config cannot build."""
-        types = self.layer_types
-        if types is None:
-            return
-        if len(types) != self.n_layers:
-            raise ValueError(f"layer_types names {len(types)} layers, "
-                             f"n_layers is {self.n_layers}")
-        unknown = sorted(set(types) - set(LAYER_TYPES))
-        if unknown:
-            raise ValueError(f"layer types {unknown} are none of "
-                             f"{LAYER_TYPES}")
-        if "mamba" in types and self.ssm is None:
+        for what, types, known in (
+                ("layer_types", self.layer_types, LAYER_TYPES),
+                ("ffn_types", self.ffn_types, FFN_TYPES)):
+            if types is None:
+                continue
+            if len(types) != self.n_layers:
+                raise ValueError(f"{what} names {len(types)} layers, "
+                                 f"n_layers is {self.n_layers}")
+            unknown = sorted(set(types) - set(known))
+            if unknown:
+                raise ValueError(f"{what} {unknown} are none of {known}")
+        if "mamba" in (self.layer_types or ()) and self.ssm is None:
             raise ValueError("a 'mamba' layer needs the mixer's sizes "
                              "(TransformerConfig.ssm)")
+        if "experts" in (self.ffn_types or ()):
+            if self.experts is None:
+                raise ValueError("an 'experts' feed-forward needs the "
+                                 "layer's sizes (TransformerConfig.experts)")
+            self.experts.check()
 
 
-def config_from_source(src: dict, **runtime) -> TransformerConfig:
-    """The config of a model described by its source's ``config.json``
-    keys (the ``granitemoehybrid`` family, dense: Mamba-2 and
-    grouped-query attention layers, each followed by a SiLU-gated MLP).
-    ``runtime`` gives what no source states (``dtype``, ``attn_impl``,
-    ``remat``, ...).  Keys beside the source's own are ignored; a value
-    the model code does not compute raises ``ValueError``."""
-    wanted = {"model_type": ("granitemoehybrid",), "hidden_act": ("silu",),
-              "normalization_function": ("rmsnorm",),
-              "position_embedding_type": ("nope", "rope"),
-              "num_local_experts": (0,), "attention_bias": (False,),
-              "mamba_proj_bias": (False,)}
+def _check_source(src: dict, wanted: dict) -> None:
     for key, values in wanted.items():
         if src.get(key, values[0]) not in values:
             raise ValueError(f"source config {key}={src[key]!r}: the model "
                              f"computes {key} in {values} only")
+
+
+def _granitemoehybrid_config(src: dict, **runtime) -> TransformerConfig:
+    """The ``granitemoehybrid`` family, dense: Mamba-2 and grouped-query
+    attention layers, each followed by a SiLU-gated MLP."""
+    _check_source(src, {
+        "hidden_act": ("silu",), "normalization_function": ("rmsnorm",),
+        "position_embedding_type": ("nope", "rope"),
+        "num_local_experts": (0,), "attention_bias": (False,),
+        "mamba_proj_bias": (False,)})
     d_model = src["hidden_size"]
     ssm = SSMConfig(
         n_heads=src["mamba_n_heads"], d_head=src["mamba_d_head"],
@@ -142,7 +171,7 @@ def config_from_source(src: dict, **runtime) -> TransformerConfig:
             f"mamba_n_heads * mamba_d_head = {ssm.n_heads * ssm.d_head} "
             f"is not mamba_expand * hidden_size = "
             f"{src['mamba_expand'] * d_model}")
-    cfg = TransformerConfig(
+    return TransformerConfig(
         vocab_size=src["vocab_size"], d_model=d_model,
         n_layers=src["num_hidden_layers"],
         n_heads=src["num_attention_heads"],
@@ -157,6 +186,69 @@ def config_from_source(src: dict, **runtime) -> TransformerConfig:
         residual_multiplier=src["residual_multiplier"],
         attention_multiplier=src["attention_multiplier"],
         logits_scaling=src["logits_scaling"], **runtime)
+
+
+def _lfm2_moe_config(src: dict, **runtime) -> TransformerConfig:
+    """The ``lfm2_moe`` family: gated short-convolution and grouped-query
+    attention layers (q and k normed per head, rotary), ``num_dense_layers``
+    SiLU-gated MLPs and then top-k experts chosen by biased sigmoid
+    scores; one tied table.  Two keys of a cut file, not of the source:
+    ``experts_held`` — ``[first, end)`` — says which of the experts live
+    here (none given: all of them), and ``experts_routed`` is the router's
+    width where it is not ``num_experts``."""
+    mixers = {"conv": "conv", "full_attention": "attention"}
+    unknown = sorted(set(src["layer_types"]) - set(mixers))
+    if unknown:
+        raise ValueError(f"source config layer_types {unknown} are none of "
+                         f"{tuple(mixers)}")
+    _check_source(src, {
+        "conv_bias": (False,), "norm_topk_prob": (True,),
+        "use_expert_bias": (True,), "routed_scaling_factor": (1, 1.0)})
+    n_layers, dense = src["num_hidden_layers"], src["num_dense_layers"]
+    held = src.get("experts_held")
+    experts = ExpertsConfig(
+        n_experts=src.get("experts_routed", src["num_experts"]),
+        per_token=src["num_experts_per_tok"],
+        d_ff=src["moe_intermediate_size"],
+        held=tuple(held) if held is not None else None)
+    return TransformerConfig(
+        vocab_size=src["vocab_size"], d_model=src["hidden_size"],
+        n_layers=n_layers, n_heads=src["num_attention_heads"],
+        n_kv_heads=src["num_key_value_heads"], d_ff=src["intermediate_size"],
+        layer_types=tuple(mixers[k] for k in src["layer_types"]),
+        ffn_types=("dense",) * dense + ("experts",) * (n_layers - dense),
+        experts=experts, conv_taps=src["conv_L_cache"], qk_norm=True,
+        rope_theta=float(src["rope_theta"]), norm="rmsnorm",
+        norm_eps=src["norm_eps"], mlp="swiglu", tie_embeddings=True,
+        **runtime)
+
+
+# model_type -> (the family's config, the source's key of the dense width)
+SOURCE_FAMILIES = {
+    "granitemoehybrid": (_granitemoehybrid_config,
+                         "shared_intermediate_size"),
+    "lfm2_moe": (_lfm2_moe_config, "intermediate_size"),
+}
+
+
+def source_family(src: dict):
+    """``SOURCE_FAMILIES``' entry for a source's ``model_type`` (a file
+    that names none is the first family's, as before there were two)."""
+    kind = src.get("model_type", "granitemoehybrid")
+    if kind not in SOURCE_FAMILIES:
+        raise ValueError(f"source config model_type={kind!r}: the model "
+                         f"computes model_type in {tuple(SOURCE_FAMILIES)} "
+                         "only")
+    return SOURCE_FAMILIES[kind]
+
+
+def config_from_source(src: dict, **runtime) -> TransformerConfig:
+    """The config of a model described by its source's ``config.json``
+    keys, by its ``model_type`` (``SOURCE_FAMILIES``).  ``runtime`` gives
+    what no source states (``dtype``, ``attn_impl``, ``remat``, ...).
+    Keys beside the source's own are ignored; a value the model code does
+    not compute raises ``ValueError``."""
+    cfg = source_family(src)[0](src, **runtime)
     cfg.check_pattern()
     return cfg
 
@@ -197,9 +289,16 @@ class _Attention(nn.Module):
                 0, 2, 1, 3)
 
         q, k, v = split(q), split(k), split(v)
+        if cfg.qk_norm:
+            # one learned weight over the head's width for q, one for k,
+            # before the rotation
+            per_head = lambda name, t: nn.RMSNorm(
+                epsilon=cfg.norm_eps, dtype=jnp.float32,
+                name=name)(t).astype(cfg.dtype)
+            q, k = per_head("q_norm", q), per_head("k_norm", k)
         if cfg.positions == "rotary":
-            q = _rope(q, positions)
-            k = _rope(k, positions)
+            q = _rope(q, positions, cfg.rope_theta)
+            k = _rope(k, positions, cfg.rope_theta)
         elif cfg.positions != "none":
             raise ValueError(f"unknown positions {cfg.positions}")
         if cfg.attention_multiplier is not None:
@@ -299,9 +398,43 @@ class _MoEFFN(nn.Module):
         return y.reshape(b, t, d)
 
 
+class _ExpertsFFN(nn.Module):
+    """The top-k expert feed-forward (models/moe.py::topk_moe_ffn) over
+    the experts ``cfg.experts`` says are held here.  The selection bias is
+    a leaf no gradient reaches; the layer's counters are sown under
+    ``moe_metrics`` and its selection under ``moe_selection`` (a
+    collection only a comparison asks for)."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg, ex = self.cfg, self.cfg.experts
+        per_expert = nn.initializers.lecun_normal(batch_axis=(0,))
+        router = self.param("router", nn.initializers.normal(0.02),
+                            (cfg.d_model, ex.n_experts), jnp.float32)
+        bias = self.param("expert_bias", nn.initializers.normal(0.02),
+                          (ex.n_experts,), jnp.float32)
+        gate_up = self.param("experts_gate_up", per_expert,
+                             (ex.n_held, cfg.d_model, 2 * ex.d_ff),
+                             jnp.float32)
+        down = self.param("experts_down", per_expert,
+                          (ex.n_held, ex.d_ff, cfg.d_model), jnp.float32)
+        b, t, d = x.shape
+        with jax.named_scope(names.SCOPE_MOE):
+            y, aux = topk_moe_ffn(
+                x.reshape(b * t, d), router, bias, gate_up, down,
+                per_token=ex.per_token, first=ex.first, dtype=cfg.dtype)
+        self.sow("moe_metrics", "expert_rows", aux["expert_rows"])
+        self.sow("moe_metrics", "pairs_not_held", aux["pairs_not_held"])
+        self.sow("moe_selection", "experts",
+                 aux["selection"].reshape(b, t, ex.per_token))
+        return y.reshape(b, t, d)
+
+
 class _Block(nn.Module):
     cfg: TransformerConfig
-    use_moe: bool = False
+    ffn_type: str = "dense"           # the feed-forward, from FFN_TYPES
     layer_type: str = "attention"     # the mixer, from LAYER_TYPES
 
     @nn.compact
@@ -312,14 +445,19 @@ class _Block(nn.Module):
         if self.layer_type == "mamba":
             mixed = Mamba2Mixer(cfg.ssm, cfg.d_model, dtype=cfg.dtype,
                                 norm_eps=cfg.norm_eps, name="ssm")(h)
+        elif self.layer_type == "conv":
+            mixed = ShortConvMixer(cfg.d_model, cfg.conv_taps,
+                                   dtype=cfg.dtype, name="conv")(h)
         else:
             mixed = _Attention(cfg, name="attn")(h, positions)
         x = x + _scaled(mixed, res)
         h = _norm(cfg, "ln2")(x)
-        if self.use_moe:
+        if self.ffn_type == "switch":
             # dropped (over-capacity) tokens contribute zero here and ride
             # the residual connection through unchanged
             return x + _scaled(_MoEFFN(cfg, name="moe")(h), res)
+        if self.ffn_type == "experts":
+            return x + _scaled(_ExpertsFFN(cfg, name="moe")(h), res)
         if cfg.mlp == "swiglu":
             # one product for gate and up, as the source's input_linear
             gate, up = jnp.split(nn.Dense(
@@ -368,9 +506,8 @@ class TransformerLM(nn.Module):
         if cfg.remat:
             block = nn.remat(_Block)
         for i in range(cfg.n_layers):
-            use_moe = (cfg.moe_experts > 0
-                       and i % cfg.moe_every == cfg.moe_every - 1)
-            x = block(cfg, use_moe=use_moe, layer_type=cfg.layer_type(i),
+            x = block(cfg, ffn_type=cfg.ffn_type(i),
+                      layer_type=cfg.layer_type(i),
                       name=f"block_{i}")(x, positions)
         x = _norm(cfg, "ln_f")(x)
         if cfg.tie_embeddings:

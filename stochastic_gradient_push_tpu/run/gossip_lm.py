@@ -194,13 +194,19 @@ def resolve_model_json(args) -> None:
         raise SystemExit("--model_json builds a layer pattern, which "
                          "composes with the flat data-parallel mesh only "
                          "(not --sp/--tp/--ep/--pp > 1 or --moe_experts)")
+    from ..models.transformer import source_family
+
     with open(args.model_json) as f:
         src = args.model_source = json.load(f)
+    try:
+        width_key = source_family(src)[1]
+    except ValueError as e:
+        raise SystemExit(f"--model_json {args.model_json}: {e}")
     for flag, key in (("vocab_size", "vocab_size"),
                       ("d_model", "hidden_size"),
                       ("n_layers", "num_hidden_layers"),
                       ("n_heads", "num_attention_heads"),
-                      ("d_ff", "shared_intermediate_size")):
+                      ("d_ff", width_key)):
         if key not in src:
             raise SystemExit(f"--model_json {args.model_json}: no {key!r}")
         setattr(args, flag, src[key])
@@ -1115,6 +1121,15 @@ def train_loop(args, t: Training, state, start_step: int, corpus,
                            f"{float(np.mean(mh['grad_norm'])):.4f}")
                     if moe_on:
                         row += (",%.4f" % float(np.mean(mh['moe_dropped'])))
+                    if "moe_expert_rows" in mh:
+                        # the top-k layer drops nothing: what it reports is
+                        # the load, summed over its layers, mean over ranks
+                        rows = np.mean(mh["moe_expert_rows"], axis=0)
+                        log.info(
+                            f"moe: rows a held expert min {rows.min():.0f} "
+                            f"mean {rows.mean():.0f} max {rows.max():.0f}; "
+                            "pairs not held "
+                            f"{float(np.mean(mh['moe_pairs_not_held'])):.0f}")
                     if rt.enabled and rt.metrics_every and \
                             steps_done - last_stats_emit >= rt.metrics_every:
                         # step_stats ride the print-cadence metrics fetch —

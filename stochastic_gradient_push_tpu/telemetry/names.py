@@ -29,6 +29,14 @@ STEP_SCOPES = (SCOPE_PRE_STEP, SCOPE_FORWARD, SCOPE_REDUCE_GRADS,
 SCOPE_SSM_MIXER = "lm.ssm_mixer"         # the whole Mamba-2 mixer
 SCOPE_CONV1D = "lm.conv1d"               # nested: causal depthwise conv
 SCOPE_SSD = "lm.ssd"                     # nested: the chunked scan alone
+# -- the gated short-convolution mixer (models/shortconv.py; its
+# convolution is under SCOPE_CONV1D too) and the top-k expert layer
+# (models/moe.py::topk_moe_ffn) ------------------------------------------
+SCOPE_CONV_MIXER = "lm.conv_mixer"       # the whole mixer, projections in
+SCOPE_MOE = "lm.moe"                     # router to combine
+SCOPE_MOE_ROUTE = "lm.moe.route"         # nested: scores, top-k, sort,
+#                                          the rows' gather and scatter
+SCOPE_MOE_EXPERTS = "lm.moe.experts"     # nested: the grouped products
 
 # -- the jitted steps' names: the compiled module is ``jit_<name>`` on the
 # trace's "XLA Modules" line, which tells the step from set-up's programs
@@ -44,6 +52,8 @@ KERNEL_FLASH_DQ = "flash_dq"              # the pair it gives way to
 KERNEL_FLASH_DKV = "flash_dkv"            # beyond its VMEM budget
 KERNEL_SSD_FWD = "ssd_fwd"                # the scan inside a chunk
 KERNEL_SSD_BWD = "ssd_bwd"                # (ops/ssd.py), and its backward
+KERNEL_GROUPED_MATMUL = "grouped_matmul"        # the expert layer's
+KERNEL_GROUPED_MATMUL_DW = "grouped_matmul_dw"  # products (ops/grouped_matmul.py)
 KERNEL_GOSSIP_START = "gossip_edge_start"
 KERNEL_GOSSIP_WAIT = "gossip_edge_wait"
 KERNEL_PAGED_ATTENTION = "paged_attention"

@@ -288,6 +288,13 @@ def lm_loss(logits: jnp.ndarray, targets: jnp.ndarray) -> jnp.ndarray:
     return jnp.mean(lse - tgt)
 
 
+def _sown(collection, name: str) -> list:
+    """The values a model's layers sowed under ``name``, in layer order."""
+    return [leaf for path, leaf
+            in jax.tree_util.tree_leaves_with_path(collection)
+            if any(getattr(k, "key", None) == name for k in path)]
+
+
 def build_lm_train_step(model, algorithm: GossipAlgorithm, tx, lr_schedule,
                         itr_per_epoch: int,
                         seq_axis: str | None = SEQ_AXIS,
@@ -335,13 +342,21 @@ def build_lm_train_step(model, algorithm: GossipAlgorithm, tx, lr_schedule,
                 if sown:
                     loss = loss + moe_loss_coef * sum(
                         jnp.mean(l) for l in sown) / len(sown)
-                dropped = jax.tree.leaves(mutated.get("moe_metrics", {}))
-                dropped = (sum(jnp.mean(d) for d in dropped) / len(dropped)
-                           if dropped else jnp.float32(0.0))
-            return loss, (ce, dropped)
+                counters = mutated.get("moe_metrics", {})
+                dropped = _sown(counters, "dropped_fraction")
+                moe = {"moe_dropped": (
+                    sum(jnp.mean(d) for d in dropped) / len(dropped)
+                    if dropped else jnp.float32(0.0))}
+                rows = _sown(counters, "expert_rows")
+                if rows:
+                    # the top-k layer's counters, summed over its layers
+                    moe["moe_expert_rows"] = sum(rows)
+                    moe["moe_pairs_not_held"] = sum(
+                        _sown(counters, "pairs_not_held"))
+            return loss, (ce, moe)
 
         if grad_accum == 1:
-            (loss, (ce, dropped)), grads = jax.value_and_grad(
+            (loss, (ce, moe)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(z, tokens, targets)
         else:
             b = tokens.shape[0]
@@ -353,25 +368,31 @@ def build_lm_train_step(model, algorithm: GossipAlgorithm, tx, lr_schedule,
             ys = targets.reshape((grad_accum, micro) + targets.shape[1:])
 
             def accum(carry, xy):
-                g_sum, loss_sum, ce_sum, drop_sum = carry
+                g_sum, loss_sum, ce_sum, moe_sum = carry
                 toks, tgts = xy
-                (l, (c, d)), g = jax.value_and_grad(
+                (l, (c, m)), g = jax.value_and_grad(
                     loss_fn, has_aux=True)(z, toks, tgts)
                 with jax.named_scope(names.SCOPE_REDUCE_GRADS):
                     return (jax.tree.map(jnp.add, g_sum, g), loss_sum + l,
-                            ce_sum + c, drop_sum + d), None
+                            ce_sum + c,
+                            jax.tree.map(jnp.add, moe_sum, m)), None
 
             zero_g = jax.tree.map(jnp.zeros_like, z)
             # scalar accumulators derive from the (device-varying) tokens
             # so the scan carry type matches the body outputs (vma rules)
             zero_s = jnp.sum(tokens * 0.0).astype(jnp.float32)
-            (g_sum, loss, ce, dropped), _ = lax.scan(
-                accum, (zero_g, zero_s, zero_s, zero_s), (xs, ys))
+            zero_moe = jax.tree.map(
+                lambda a: zero_s + jnp.zeros(a.shape, a.dtype),
+                jax.eval_shape(loss_fn, z, xs[0], ys[0])[1][1])
+            (g_sum, loss, ce, moe), _ = lax.scan(
+                accum, (zero_g, zero_s, zero_s, zero_moe), (xs, ys))
             with jax.named_scope(names.SCOPE_REDUCE_GRADS):
                 grads = jax.tree.map(lambda g: g / grad_accum, g_sum)
                 loss = loss / grad_accum
                 ce = ce / grad_accum
-                dropped = dropped / grad_accum
+                # the dropped share is a mean; the counters stay the
+                # step's sums over its microbatches
+                moe["moe_dropped"] = moe["moe_dropped"] / grad_accum
 
         with jax.named_scope(names.SCOPE_REDUCE_GRADS):
             if seq_axis is not None:
@@ -381,7 +402,7 @@ def build_lm_train_step(model, algorithm: GossipAlgorithm, tx, lr_schedule,
                 grads = jax.tree.map(lambda g: g / n_seq, grads)
                 loss = lax.pmean(loss, seq_axis)
                 ce = lax.pmean(ce, seq_axis)
-                dropped = lax.pmean(dropped, seq_axis)
+                moe["moe_dropped"] = lax.pmean(moe["moe_dropped"], seq_axis)
             if ep_axis is not None:
                 # the objective is the MEAN over ep shards of per-shard
                 # loss.  Replicated params are ep-invariant → autodiff psums
@@ -397,7 +418,7 @@ def build_lm_train_step(model, algorithm: GossipAlgorithm, tx, lr_schedule,
                 grads = jax.tree.map(lambda g: g / n_ep, grads)
                 loss = lax.pmean(loss, ep_axis)
                 ce = lax.pmean(ce, ep_axis)
-                dropped = lax.pmean(dropped, ep_axis)
+                moe["moe_dropped"] = lax.pmean(moe["moe_dropped"], ep_axis)
             grads = algorithm.reduce_grads(grads)
 
         with jax.named_scope(names.SCOPE_OPTIMIZER):
@@ -424,7 +445,7 @@ def build_lm_train_step(model, algorithm: GossipAlgorithm, tx, lr_schedule,
                 if ax is not None:
                     gn = lax.pmean(gn, ax)
             metrics = {"loss": loss, "ppl": jnp.exp(ce), "lr": lr,
-                       "moe_dropped": dropped, "grad_norm": gn}
+                       "grad_norm": gn, **moe}
             if health_axis is not None:
                 # consensus health AFTER the gossip round (resilience/):
                 # each signal is a collective over the gossip axis and — on
@@ -583,9 +604,9 @@ def init_lm_state(model, mesh, algorithm, tx, dp: int, sp: int,
     ring = seq_axis is not None
     batch_spec = P(gossip_axis, seq_axis) if ring else P(gossip_axis)
 
-    def init_fn(toks):
+    def init_fn(toks, key):
         t = toks[0, 0] if ring else toks[0]
-        variables = model.init(jax.random.PRNGKey(seed), t)
+        variables = model.init(key, t)
         return jax.tree.map(lambda a: a[None], variables["params"])
 
     has_tp = TP_AXIS in mesh.axis_names
@@ -593,13 +614,13 @@ def init_lm_state(model, mesh, algorithm, tx, dp: int, sp: int,
     if has_tp:
         kwargs["axis_names"] = {gossip_axis} | (
             {seq_axis} if ring else set())
-    sm_init = jax.shard_map(init_fn, mesh=mesh, in_specs=(batch_spec,),
+    sm_init = jax.shard_map(init_fn, mesh=mesh, in_specs=(batch_spec, P()),
                             out_specs=P(gossip_axis), **kwargs)
     dummy_shape = ((dp, sp, batch_size, block_len) if ring
                    else (dp, batch_size, block_len))
 
-    def build(dummy):
-        params = sm_init(dummy)
+    def build(dummy, key):
+        params = sm_init(dummy, key)
         one = lambda t: jax.tree.map(lambda a: a[0], t)
         return TrainState(
             step=jnp.zeros((dp,), jnp.int32), params=params,
@@ -608,13 +629,16 @@ def init_lm_state(model, mesh, algorithm, tx, dp: int, sp: int,
             gossip=replicate_state(algorithm.init(one(params)), dp))
 
     dummy = np.zeros(dummy_shape, np.int32)
+    # the key is an argument of the program, not a constant in it: every
+    # seed runs the one compiled initialisation
+    key = jax.random.PRNGKey(seed)
     if has_tp:
         # materialize straight into the tensor-parallel layout: momentum
         # and gossip buffers are created sharded, never full-size
-        shapes = jax.eval_shape(build, dummy)
+        shapes = jax.eval_shape(build, dummy, key)
         return jax.jit(build, out_shardings=tp_sharding_tree(
-            shapes, mesh))(dummy)
-    return jax.jit(build)(dummy)
+            shapes, mesh))(dummy, key)
+    return jax.jit(build)(dummy, key)
 
 
 def init_lm_state_ep(model, mesh, algorithm, tx, dp: int, ep: int,

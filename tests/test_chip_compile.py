@@ -23,7 +23,9 @@ import pytest
 from jax.sharding import NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
+from stochastic_gradient_push_tpu.models.moe import topk_moe_ffn
 from stochastic_gradient_push_tpu.ops import gossip_kernel as gk
+from stochastic_gradient_push_tpu.ops import grouped_matmul as gm
 from stochastic_gradient_push_tpu.ops.flash_attention import (
     default_block, flash_attention, flash_attention_backward,
     flash_attention_forward, fused_backward_fits)
@@ -220,6 +222,42 @@ def test_scan_kernel_pair_compiles(one_chip, on_tpu, dtype, groups):
             rf'op_name="[^"]*/{re.escape(where)}{names.SCOPE_FORWARD}\)+/'
             rf'{re.escape(names.SCOPE_SSD)}/jit\(\w+\)/{call}/pallas_call"',
             text), call
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "fp32"])
+def test_expert_layer_compiles_with_the_grouped_kernels(one_chip, on_tpu,
+                                                        dtype):
+    """The top-k expert layer at the published sizes (8192 tokens, 4 of 32
+    experts a token, 16 held, 2048 wide, experts of 1792) through
+    ``jax.grad``, under its scopes as in the program: the rule takes the
+    kernels, and the compiled text holds the forward's two grouped
+    products, the rows' gradients' two, and the blocks' gradients' two."""
+    t, d, f, held, routed = 8192, 2048, 1792, 16, 32
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32,
+                                            sharding=one_chip)
+    rows = jax.ShapeDtypeStruct((t * 4, d), dtype)
+    assert gm.kernel_fits("tpu", rows, jax.ShapeDtypeStruct(
+        (held, d, 2 * f), dtype))
+
+    def loss(x, router, bias, gate_up, down):
+        with jax.named_scope(names.SCOPE_FORWARD), \
+                jax.named_scope(names.SCOPE_MOE):
+            return topk_moe_ffn(x, router, bias, gate_up, down, per_token=4,
+                                first=0, dtype=dtype)[0].sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 3, 4))).lower(
+        shape(t, d), shape(d, routed), shape(routed),
+        shape(held, d, 2 * f), shape(held, f, d)).compile().as_text()
+    assert text.count("tpu_custom_call") == 6
+    assert _kernel_names(text) == {names.KERNEL_GROUPED_MATMUL,
+                                   names.KERNEL_GROUPED_MATMUL_DW}
+    # the compiled calls carry the experts' scope (``moe_experts_ms`` finds
+    # them by it), through the jitted wrappers the layers share
+    assert re.search(
+        rf'op_name="[^"]*{re.escape(names.SCOPE_MOE_EXPERTS)}/jit\(\w+\)/'
+        rf'{names.KERNEL_GROUPED_MATMUL}/pallas_call"', text)
+    assert "ragged" not in text
 
 
 def test_push_sum_round_is_a_collective_permute(mesh):

@@ -92,6 +92,10 @@ def test_size_table_is_the_issue_s_configuration(smoke):
             lm["batch_size"], lm["vocab_size"]) == (
         768, 12, 12, 1024, 8, 32768)
     assert lm["attn"] == "flash" and lm["kernel_shape"] == (8, 12, 1024, 64)
+    # the lfm2 cell's expert layer: 2 x 4096 tokens x 4 choices, 16 held
+    gk = smoke.SIZES["grouped_kernels"]
+    assert (gk["rows"], gk["width"], gk["out"], gk["experts"], gk["dtype"],
+            gk["interpret"]) == (32768, 2048, 2 * 1792, 16, "bfloat16", False)
     w4 = smoke.SIZES["w4"]
     assert w4["world_size"] == 4 and w4["gossip_kernel"] == "pallas"
     assert {k: w4[k] for k in smoke.RESNET} == smoke.RESNET
@@ -115,6 +119,38 @@ def test_one_chip_lm_phase_at_toy_size(smoke, cache_dir):
     # phase must say so, not pass
     with pytest.raises(RuntimeError, match="resolved attention to 'full'"):
         smoke.lm_dense_flash(dict(TOY_LM, attn="flash"), cache_dir)
+
+
+TOY_GROUPED = {"rows": 2048, "width": 128, "out": 256, "experts": 6,
+               "dtype": "float32", "interpret": True, "tolerance": 1e-5}
+
+
+def test_grouped_kernels_phase_at_toy_size(smoke, cache_dir):
+    """Interpreted here; the splits are the chip's, at four tiles."""
+    splits = smoke.grouped_splits(2048, 6, 512)
+    assert all(s.sum() <= 2048 and len(s) == 6 for s in splits.values())
+    assert splits["every_row"].sum() == 2048
+    boundary = splits["trailing_empty_on_a_boundary"]
+    assert boundary.sum() % 512 == 0 and 0 < boundary.sum() < 2048 \
+        and boundary[-1] == 0
+    with jax.default_matmul_precision("highest"):
+        line = smoke.grouped_kernels(TOY_GROUPED, cache_dir)
+    assert line["ok"] and set(line["worst_share_of_largest"]) == {
+        "out", "d_rows", "d_blocks"}
+    # compiled, the rule has to take the kernels: off the chip it does not
+    with pytest.raises(RuntimeError, match="kernel_fits refuses"):
+        smoke.grouped_kernels(dict(TOY_GROUPED, interpret=False), cache_dir)
+
+
+def test_grouped_kernels_phase_fails_on_a_wrong_product(smoke, cache_dir,
+                                                        monkeypatch):
+    from stochastic_gradient_push_tpu.ops import grouped_matmul as gm
+
+    real = gm._tgmm
+    monkeypatch.setattr(gm, "_tgmm", lambda *a, **k: real(*a, **k) * 1.01)
+    with jax.default_matmul_precision("highest"), \
+            pytest.raises(RuntimeError, match="d_blocks over the split"):
+        smoke.grouped_kernels(TOY_GROUPED, cache_dir)
 
 
 def test_flash_kernel_check_fails_off_the_chip(smoke):
