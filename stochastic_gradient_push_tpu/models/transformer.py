@@ -510,10 +510,13 @@ class TransformerLM(nn.Module):
                       layer_type=cfg.layer_type(i),
                       name=f"block_{i}")(x, positions)
         x = _norm(cfg, "ln_f")(x)
-        if cfg.tie_embeddings:
-            logits = embed.attend(x)
-        else:
-            logits = nn.Dense(cfg.vocab_size, use_bias=False,
-                              dtype=cfg.dtype, name="lm_head")(x)
-        return _scaled(jnp.asarray(logits, jnp.float32),
-                       1.0 / cfg.logits_scaling)
+        # the loss (train/lm.py::lm_loss) carries the same scope: between
+        # them they hold every pass over an array of the logits' size
+        with jax.named_scope(names.SCOPE_LM_HEAD):
+            if cfg.tie_embeddings:
+                logits = embed.attend(x)
+            else:
+                logits = nn.Dense(cfg.vocab_size, use_bias=False,
+                                  dtype=cfg.dtype, name="lm_head")(x)
+            return _scaled(jnp.asarray(logits, jnp.float32),
+                           1.0 / cfg.logits_scaling)

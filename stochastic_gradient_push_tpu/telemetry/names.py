@@ -37,6 +37,9 @@ SCOPE_MOE = "lm.moe"                     # router to combine
 SCOPE_MOE_ROUTE = "lm.moe.route"         # nested: scores, top-k, sort,
 #                                          the rows' gather and scatter
 SCOPE_MOE_EXPERTS = "lm.moe.experts"     # nested: the grouped products
+# -- the head (models/transformer.py) and the loss (train/lm.py::lm_loss):
+# every operation over an array of the logits' size ----------------------
+SCOPE_LM_HEAD = "lm.head"                # the head's product, the loss
 
 # -- the jitted steps' names: the compiled module is ``jit_<name>`` on the
 # trace's "XLA Modules" line, which tells the step from set-up's programs

@@ -281,11 +281,27 @@ def lm_loss(logits: jnp.ndarray, targets: jnp.ndarray) -> jnp.ndarray:
     full ``[B, T, vocab]`` float32 residual (~1 GB at the bench shape
     b8 t1024 v32k), pure HBM traffic XLA instead re-derives from the
     saved logits inside the fused backward.
+
+    The target's logit is a masked sum over the vocabulary axis, not a
+    ``take_along_axis``: the sum of one logit and zeros is that logit,
+    bit for bit, and its transpose is a ``select`` where a gather's is a
+    scatter-add of ``B * T`` numbers into a float32 array of the logits'
+    size.  XLA then forms ``softmax - onehot`` inside the operand fusions
+    of the head's two backward products and the step holds the logits
+    once, in bf16.  At ``[1, 8192, 50257]`` (50257 is no multiple of 128,
+    so the scatter's flat operand was no bitcast) the gather cost two
+    ``dynamic-update-slice`` loops re-laying 1.65 GB there and back and
+    five more passes over float32 arrays of that size: 12.9 GB of the
+    step's 169.5 GB of traffic by XLA's count for a v5e, and on the chip
+    55 ms of GPT-2 medium's 397 ms step (PERF.md §6, PR 34).
     """
-    logits = jnp.asarray(logits, jnp.float32)
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    return jnp.mean(lse - tgt)
+    with jax.named_scope(names.SCOPE_LM_HEAD):
+        logits = jnp.asarray(logits, jnp.float32)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        ids = lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
+        tgt = jnp.sum(jnp.where(ids == targets[..., None], logits, 0.0),
+                      axis=-1)
+        return jnp.mean(lse - tgt)
 
 
 def _sown(collection, name: str) -> list:

@@ -24,6 +24,8 @@ from jax.sharding import NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
 from stochastic_gradient_push_tpu.models.moe import topk_moe_ffn
+from stochastic_gradient_push_tpu.models.transformer import (
+    TransformerConfig, TransformerLM)
 from stochastic_gradient_push_tpu.ops import gossip_kernel as gk
 from stochastic_gradient_push_tpu.ops import grouped_matmul as gm
 from stochastic_gradient_push_tpu.ops.flash_attention import (
@@ -39,6 +41,7 @@ from stochastic_gradient_push_tpu.serve.paged_attention import (
 from stochastic_gradient_push_tpu.telemetry import names
 from stochastic_gradient_push_tpu.topology import (
     NPeerDynamicDirectedExponentialGraph, build_schedule)
+from stochastic_gradient_push_tpu.train.lm import lm_loss
 
 WORLD = 4
 # ResNet-50's parameter count: the flat payload one gossip round moves
@@ -258,6 +261,86 @@ def test_expert_layer_compiles_with_the_grouped_kernels(one_chip, on_tpu,
         rf'op_name="[^"]*{re.escape(names.SCOPE_MOE_EXPERTS)}/jit\(\w+\)/'
         rf'{names.KERNEL_GROUPED_MATMUL}/pallas_call"', text)
     assert "ragged" not in text
+
+
+def _arrays_outside_fusions(compiled_text: str, elements: int) -> list:
+    """``(opcode, dtype)`` of every instruction that writes an array of
+    ``elements`` elements to memory: those of the entry computation and of
+    the loops' bodies, a fusion by its outputs; what is inside a fused
+    computation stays in registers and is not counted."""
+    fused = set(re.findall(r"fusion\([^\n]*?calls=%?([\w.\-]+)",
+                           compiled_text))
+    found, counted = [], False
+    for line in compiled_text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            counted = head.group(1) not in fused
+            continue
+        made = counted and re.match(
+            r"\s+(?:ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(", line)
+        if not made or made.group(2) in (
+                "parameter", "tuple", "get-tuple-element", "bitcast",
+                "while"):
+            continue            # these write nothing of their own
+        for dtype, dims in re.findall(r"\b(pred|[a-z]+\d+)\[([\d,]+)\]",
+                                      made.group(1)):
+            if np.prod([int(d) for d in dims.split(",")]) == elements:
+                found.append((made.group(2), dtype))
+    return found
+
+
+@pytest.mark.parametrize("batch,seq,temp_limit", [
+    (1, 8192, 3.0e9), (4, 1024, 2.0e9)], ids=["t8192", "t1024"])
+def test_the_lm_step_holds_the_logits_once_in_bf16(one_chip, on_tpu, batch,
+                                                   seq, temp_limit):
+    """GPT-2 medium's width, one block, the 50257-wide head, ``lm_loss``
+    and a momentum update at the two GPT-2 cells' tokens.  The loss takes
+    the target's logit by comparison, so the step keeps one array of the
+    logits' size: the head's product rounded to bf16, read again by the
+    sum of exponentials and by the two products of its backward.  With
+    ``take_along_axis`` (to PR 33) its transpose scattered 8192 numbers
+    into a float32 array of that size, which at t8192 — 50257 is no
+    multiple of 128 — two loops of ``dynamic-update-slice`` re-laid flat
+    and back: 5.34 GB of temporaries where this reads 1.42 GB (t1024:
+    a float32 copy of the logits beside the bf16 one, 1.41 against 0.63)."""
+    vocab = 50257
+    model = TransformerLM(TransformerConfig(
+        vocab_size=vocab, d_model=1024, n_layers=1, n_heads=16, d_ff=4096,
+        max_len=seq, dtype=jnp.bfloat16, attn_impl="flash"))
+    tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: model.init(
+            jax.random.PRNGKey(0), jnp.zeros((batch, seq), jnp.int32))
+        )["params"])
+
+    def step(params, momenta, tokens, targets):
+        def loss_fn(p):
+            with jax.named_scope(names.SCOPE_FORWARD):
+                return lm_loss(model.apply({"params": p}, tokens), targets)
+
+        loss, grads = jax.value_and_grad(loss_fn)(params)
+        momenta = jax.tree.map(lambda m, g: 0.9 * m + g, momenta, grads)
+        return jax.tree.map(lambda p, m: p - 0.1 * m, params,
+                            momenta), momenta, loss
+
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        params, params, tokens, tokens).compile()
+    text = compiled.as_text()
+    assert _kernel_names(text) == {names.KERNEL_FLASH_FWD,
+                                   names.KERNEL_FLASH_BWD}
+    # written once, by the head's product, in bf16: no float32 array of
+    # that size (flat or not), no copy, no scatter and no
+    # dynamic-update-slice over one
+    assert _arrays_outside_fusions(text, batch * seq * vocab) == [
+        ("fusion", "bf16")]
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_limit
+    # the head's products and the loss's passes carry the head's scope
+    for under in ("jvp(", "transpose(jvp("):
+        assert re.search(
+            rf'op_name="[^"]*/{re.escape(under)}{names.SCOPE_FORWARD}\)+/'
+            rf'TransformerLM/{re.escape(names.SCOPE_LM_HEAD)}/lm_head/'
+            rf'dot_general"', text), under
 
 
 def test_push_sum_round_is_a_collective_permute(mesh):

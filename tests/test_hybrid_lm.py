@@ -8,6 +8,7 @@ the ``--model_json`` way in; the scopes in the lowered step."""
 import functools
 import importlib
 import json
+import os
 import re
 
 import jax
@@ -441,6 +442,44 @@ def test_the_lowered_step_holds_the_mixers_scopes():
                      rf"{re.escape(names.SCOPE_CONV1D)}", where)
     # on this backend the scan is XLA operations: no kernel of its own
     assert names.KERNEL_SSD_FWD not in where
+
+
+@pytest.mark.parametrize("tied", [False, True], ids=["dense", "tied_hybrid"])
+def test_the_lowered_step_holds_the_heads_scope(tied):
+    """``lm.head`` is one name of the vocabulary, around the head's
+    product (an ``lm_head`` kernel, or the tied table's ``attend``) and
+    around the loss, each inside the step's forward scope and in its
+    transpose: where ``lm_head_ms`` looks for them."""
+    assert [v for k, v in vars(names).items() if k.startswith("SCOPE_")
+            ].count(names.SCOPE_LM_HEAD) == 1
+    with open(os.path.join(os.path.dirname(plain.__file__), os.pardir,
+                           "layer_metrics", "lm_head_ms.json")) as f:
+        assert re.fullmatch(json.load(f)["params"]["pattern"],
+                            names.SCOPE_LM_HEAD)
+    if tied:
+        model, product = _model(remat=True), "embed.attend"
+    else:
+        model, product = TransformerLM(TransformerConfig(
+            vocab_size=96, d_model=32, n_layers=2, n_heads=4, d_ff=64,
+            max_len=SEQ)), "lm_head"
+    assert model.cfg.tie_embeddings == tied
+    where = _lowered_step_locations(model, SEQ)
+    head = re.escape(names.SCOPE_LM_HEAD)
+    # the loss: the sum of exponentials and the target's masked sum, and
+    # in the transpose the select that the comparison turns into
+    for way, of_the_loss in ((r"/jvp\(", "reduce_sum"),
+                             (r"/transpose\(jvp\(", "select_n")):
+        under = rf"{way}{re.escape(names.SCOPE_FORWARD)}\)+/"
+        assert re.search(
+            rf"{under}TransformerLM/{head}/{re.escape(product)}/dot_general",
+            where), (way, product)
+        assert re.search(rf"{under}{head}/{of_the_loss}", where), way
+    # under the scope nothing scatters, and nothing lies outside the
+    # step's forward scope
+    for line in where.splitlines():
+        if f"/{names.SCOPE_LM_HEAD}/" in line:
+            assert "scatter" not in line, line
+            assert names.SCOPE_FORWARD in line, line
 
 
 def test_the_lowered_step_holds_the_scan_kernels_under_the_scans_scope(

@@ -361,7 +361,7 @@ def test_the_step_reports_the_layers_counters_and_takes_no_balance_loss(
         debug_info=True)
     for scope in (names.SCOPE_CONV_MIXER, names.SCOPE_MOE,
                   names.SCOPE_MOE_ROUTE, names.SCOPE_MOE_EXPERTS,
-                  names.SCOPE_CONV1D):
+                  names.SCOPE_CONV1D, names.SCOPE_LM_HEAD):
         assert scope in text, scope
     with jax.default_matmul_precision("default"):
         state, metrics = train_fn(state, tokens[None], targets[None])
@@ -376,18 +376,23 @@ def test_the_step_reports_the_layers_counters_and_takes_no_balance_loss(
         float(jnp.log(metrics["ppl"][0])), rel=1e-6)
 
 
-# sha256 of ``lower(...).as_text()`` of one SGP step on the CPU, taken from
-# the parent commit (6451a40) by this very function: the models that were
-# there before this family lower to the same text, byte for byte.  A PR
-# that means to change their step re-takes them the same way.
+# sha256 of ``lower(...).as_text()`` of one SGP step on the CPU, taken by
+# this very function: the models that were there before a family lower to
+# the same text, byte for byte.  A PR that means to change their step
+# re-takes them the same way.  Last taken from PR 34's tree, which changed
+# every LM's step (``lm_loss`` takes the target's logit by comparison, and
+# the head and the loss carry the ``lm.head`` scope); PR 33 had held them
+# to its parent (6451a40).
 PARENTS_STEPS = {
     "gpt2_shaped":
-        "b3859270d8120827d664473ef937e191f80e6fcead375466069e8bdb42ff3e5d",
+        "1142cd33953d735832f5040e321bd14d081bc96ff6ae6fa1b902d483b2d9b222",
     "granite_shaped":
-        "b46c77f551c7d15012908e73fea9aaee24c64419737c16092a0651c1e546f2d3",
+        "6e336a913c43bc6f37f4710c62e663c66ac7a9599d6009c5fc89371710b0b1f5",
     "switch_moe":
-        "fbb5ddce063c0e54f13186e18e03fca784793e30458b25ffffc282ec781912a9",
+        "c3e36ca44469e3ef49f90daa9741cddd8992f163b05bf171e59251b114d9a036",
 }
+
+
 def _earlier_model(name):
     dense = dict(vocab_size=64, d_model=32, n_layers=2, n_heads=4, d_ff=64,
                  max_len=24)

@@ -195,6 +195,69 @@ def test_lm_loss_matches_manual_ce():
     np.testing.assert_allclose(got, want, rtol=1e-6)
 
 
+def _lm_loss_by_gather(logits, targets):
+    """``lm_loss`` as it read the target's logit up to PR 33: the
+    reference the comparison is held to.  Its transpose is a scatter-add
+    into an array of the logits' size; the values are the same."""
+    logits = jnp.asarray(logits, jnp.float32)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+    return jnp.mean(lse - tgt)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["fp32", "bf16"])
+@pytest.mark.parametrize("vocab", [256, 257])
+def test_lm_loss_by_comparison_is_the_gathers_loss(vocab, dtype):
+    """A vocabulary that is a multiple of 128 lanes and one that is not
+    (as 50257), targets at both ends of the axis: the value bit for bit,
+    the logits' gradient to 1e-6."""
+    rng = np.random.default_rng(vocab)
+    logits = jnp.asarray(4 * rng.normal(size=(2, 9, vocab)), dtype)
+    targets = rng.integers(0, vocab, size=(2, 9)).astype(np.int32)
+    targets[0, 0], targets[1, -1] = 0, vocab - 1
+    targets = jnp.asarray(targets)
+    got, dgot = jax.value_and_grad(lm_loss)(logits, targets)
+    want, dwant = jax.value_and_grad(_lm_loss_by_gather)(logits, targets)
+    assert got.dtype == want.dtype == jnp.float32
+    assert float(got) == float(want)
+    assert dgot.dtype == dtype
+    np.testing.assert_allclose(np.asarray(dgot, np.float32),
+                               np.asarray(dwant, np.float32), atol=1e-6)
+    # nothing whose transpose scatters: the backward holds no scatter
+    text = jax.jit(jax.grad(lm_loss)).lower(logits, targets).as_text()
+    assert "scatter" not in text and "gather" not in text
+
+
+@pytest.mark.parametrize("scaling", [1.0, 8.0])
+@pytest.mark.parametrize("tied", [False, True], ids=["untied", "tied"])
+def test_lm_loss_by_comparison_through_the_model(tied, scaling):
+    """Through ``jax.value_and_grad`` of a two-block model, its head tied
+    and not, its logits divided and not: the parameters' gradients."""
+    cfg = small_cfg()._replace(vocab_size=257, dtype=jnp.bfloat16,
+                               tie_embeddings=tied, logits_scaling=scaling)
+    model = TransformerLM(cfg)
+    rng = np.random.default_rng(1)
+    tokens = jnp.asarray(rng.integers(0, 257, size=(2, SEQ)), jnp.int32)
+    targets = jnp.roll(tokens, -1, axis=1)
+    params = model.init(jax.random.PRNGKey(0), tokens)["params"]
+    assert ("lm_head" in params) == (not tied)
+
+    def run(loss):
+        return jax.value_and_grad(lambda p: loss(
+            model.apply({"params": p}, tokens), targets))(params)
+
+    got, ggot = run(lm_loss)
+    want, gwant = run(_lm_loss_by_gather)
+    assert float(got) == float(want)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(ggot),
+                            jax.tree.leaves(gwant)):
+        scale = float(jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), atol=1e-5 * scale,
+            err_msg=jax.tree_util.keystr(path))
+
+
 def test_lm_cli_checkpoint_and_resume(tmp_path):
     """LM CLI saves its state+step atomically and resumes from it."""
     import numpy as np
