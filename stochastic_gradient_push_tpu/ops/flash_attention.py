@@ -1,16 +1,25 @@
 """Pallas TPU flash-attention kernels: fused forward AND backward.
 
 The transformer path's compute hot spot.  Every kernel has one schedule
-shape: a 3-D grid over batch·head (``parallel``), an output block, and a
-MINOR dimension that walks the streamed axis with ``arbitrary`` semantics
-— so Pallas double-buffers the streamed k/v (or q/do) block fetches behind
-the matmuls instead of parking whole ``[seq, d]`` operands in VMEM per
-cell (the round-3 design, whose dk/dv kernel lost to XLA 122.8 ms vs
-68.6 ms at t=4096 in an earlier installation's capture).  Running state
-lives in fp32 VMEM scratch that persists across grid steps: the forward
-carries the online-softmax ``(m, den, acc)`` triple, the backward its
-gradient accumulators, and outputs are written once, on the last step
-that adds to them.
+shape: a 2-D grid over batch·head (``parallel``) and a list of tile
+VISITS (``arbitrary``).  :func:`tile_visits` makes the list at trace time
+from the sequence, the blocks and the mask — the (q-block, k-block) pairs
+that hold an unmasked pair, a row of the major axis after another — and
+the kernels take it by scalar prefetch
+(``pltpu.PrefetchScalarGridSpec``): index maps read a visit's blocks, so
+Pallas double-buffers the streamed k/v (or q/do) block fetches behind the
+matmuls instead of parking whole ``[seq, d]`` operands in VMEM per cell
+(the round-3 design, whose dk/dv kernel lost to XLA 122.8 ms vs 68.6 ms
+at t=4096 in an earlier installation's capture), and the kernels find a
+row's first and last visit by comparing with the list's neighbouring
+entry.  A causal call therefore takes no grid step without a tile body
+(136 visits a head at t8192 in blocks of 512, where the rectangle has
+256; TPU v5e, PERF.md §6, PR 37), a call with no mask walks the whole
+rectangle as a list, and a window or a document mask is another list.
+Running state lives in fp32 VMEM scratch that persists across grid steps:
+the forward carries the online-softmax ``(m, den, acc)`` triple, the
+backward its gradient accumulators, and outputs are written once, on the
+last visit that adds to them.
 
 The forward's tile body keeps its per-row state off the cross-lane unit,
 which — not the MXU, not the mask, not the operands' casts — set its pace
@@ -25,30 +34,29 @@ tile by fp32 rounding of a reordered sum, nothing more.
 Backward (``jax.custom_vjp``) is flash-attention-2's, in ONE kernel
 wherever :func:`fused_backward_fits`:
 
-* Fused kernel, grid ``(bh, k-block, q-step)``: per visible tile
+* Fused kernel, visits k-block-major: per tile
   ``s = q·kᵀ``, the mask, ``p = exp(s - lse)``, ``dp = do·vᵀ`` and
   ``ds = p·(dp - delta)`` are computed once and feed all three products:
   ``dv += pᵀ·do`` and ``dk += dsᵀ·q`` into ``[block_k, d]`` accumulators
   written on the k-block's last q-step, ``dq[q-block rows] += ds·k`` into
   a ``[seq, d]`` accumulator that lives for the whole sweep of one bh and
-  is written once (both inner grid dims are ``arbitrary`` for its sake).
+  is written once.
   Five products a tile, every operand read once.  VMEM is O(block²)
   plus dq for one sequence, which is what the shape rule budgets.
 * Beyond the budget (``seq`` over 8192, heads wider than 128) the
-  dq + dk/dv PAIR: a dQ kernel, grid ``(bh, q-block, k-step)``, streams
-  k/v and accumulates ``dq``; a dK/dV kernel on the fused kernel's grid
-  accumulates ``dk`` and ``dv``.  Each recomputes ``p`` and ``ds`` — seven
-  products a tile, operands read twice — but VMEM stays O(block²) at any
-  length.  Same operands, same casts, same order of accumulation: the
-  two give the same bits.
+  dq + dk/dv PAIR: a dQ kernel, visits q-block-major as the forward's,
+  streams k/v and accumulates ``dq``; a dK/dV kernel on the fused
+  kernel's visits accumulates ``dk`` and ``dv``.  Each recomputes ``p``
+  and ``ds`` — seven products a tile, operands read twice — but VMEM
+  stays O(block²) at any length; what grows with the length is the
+  visit list in SMEM (:func:`tile_visits` gives the limit).  Same
+  operands, same casts, same order of accumulation: the two give the
+  same bits.
 
 The per-row residuals travel in compact ``[rows, 1]`` layouts: the
 forward's logsumexp and ``delta = rowsum(do · o)``, the latter computed
 once outside the kernels (a fused XLA elementwise-reduce) so ``o`` is not
-an operand of any backward kernel.  Causal runs skip the empty
-triangle two ways: masked minor steps are compute-gated with ``pl.when``,
-and their index maps clamp into the visible range so no new block is ever
-fetched for a skipped step.
+an operand of any backward kernel.
 
 On non-TPU backends ``flash_attention`` transparently falls back to the
 pure-JAX blockwise implementation
@@ -63,6 +71,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -70,7 +79,8 @@ from ..parallel.ring_attention import blockwise_attention
 from ..telemetry import names
 
 __all__ = ["flash_attention", "flash_attention_forward",
-           "flash_attention_backward", "fused_backward_fits"]
+           "flash_attention_backward", "fused_backward_fits",
+           "tile_visits"]
 
 NEG_INF = -1e30
 
@@ -96,7 +106,7 @@ def default_block(t: int) -> int:
     at 64.0 ms vs 82.7 (block 256) vs 127.5 (block 128) at t=1024, and
     block 512 also won the kernel-level fenced sweeps at t=2048 and
     t=4096 (docs/tpu_runs/20260731T071733_retry/flashblocks.txt).
-    The 3-D-grid schedule keeps VMEM at O(block^2), so 512 is safe; the
+    The streamed schedule keeps VMEM at O(block^2), so 512 is safe; the
     chip's compiler accepts it at t=1024 and t=4096
     (tests/test_chip_compile.py)."""
     for b in (512, 256, 128):
@@ -120,14 +130,97 @@ def _sds(shape, dtype, *like):
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
-def _compiler_params(interpret: bool, middle: str = "parallel"):
-    """Minor grid dim walks the streamed axis: revisited outputs/scratch
-    require ``arbitrary``; batch·head is parallel, and so is the middle
-    dim unless state is carried across it (the fused backward's dq)."""
-    if interpret:
-        return None
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", middle, "arbitrary"))
+@functools.lru_cache(maxsize=None)
+def tile_visits(t: int, block_q: int, block_k: int, causal: bool,
+                major: str):
+    """The tiles a kernel visits, in the order it visits them: the
+    q-block and the k-block of each visit, two read-only ``int32`` arrays
+    of one length.  A tile is visited where the mask leaves it any pair
+    — causal: its last query row reaches its first key column; no mask:
+    every tile.  ``major`` ``"q"`` walks q-blocks with each one's k-blocks
+    ascending to the diagonal (forward, dq); ``"k"`` walks k-blocks with
+    each one's q-blocks ascending from the diagonal (dk/dv, the fused
+    backward).  Every block of the major axis has a visit.  Made at trace
+    time, once a shape: a step's trace asks 72 times.  The kernels hold
+    the two arrays in SMEM, 1 MiB on a v5e, so a call may make just under
+    2**17 visits: 32768 tokens in blocks of 128 with no mask (65536
+    visits) compile, 65536 do not (tests/test_chip_compile.py); at the
+    auto block of 512 the limit lies beyond 131072 tokens."""
+    keep = np.ones((t // block_q, t // block_k), bool)
+    if causal:
+        last_row = np.arange(t // block_q) * block_q + block_q - 1
+        keep = last_row[:, None] >= np.arange(t // block_k) * block_k
+    if major == "q":
+        q_blocks, k_blocks = np.nonzero(keep)
+    else:
+        k_blocks, q_blocks = np.nonzero(keep.T)
+    visits = q_blocks.astype(np.int32), k_blocks.astype(np.int32)
+    for blocks in visits:
+        blocks.flags.writeable = False
+    return visits
+
+
+def _visit(i, q_blocks, k_blocks, rows):
+    """Grid step ``i``'s visit: its q-block, its k-block, and whether it
+    is the first and the last visit of its row of ``rows`` (the major
+    axis's array of the two), by the neighbouring entries."""
+    n = rows.shape[0]
+    row = rows[i]
+    first = (i == 0) | (rows[jnp.maximum(i - 1, 0)] != row)
+    last = (i == n - 1) | (rows[jnp.minimum(i + 1, n - 1)] != row)
+    return q_blocks[i], k_blocks[i], first, last
+
+
+def _visit_call(kernel, name: str, visits, operands, in_specs, out_specs,
+                out_shape, scratch_shapes, interpret: bool):
+    """``kernel`` over ``operands`` ``[bh, ...]`` on grid (batch·head,
+    visit): the two visit arrays go first, by scalar prefetch, so every
+    index map is ``(bh, i, q_blocks, k_blocks)`` and the kernel takes the
+    visit's number ``i``, then the arrays, then its refs.  Batch·head is
+    ``parallel``; the visits revisit outputs and scratch, which requires
+    ``arbitrary``.  The arrays are constants beside operands that vary
+    over the mesh's axes, and the compiled call takes the mix inside a
+    vma-checked ``shard_map`` (tests/test_chip_compile.py).  The
+    INTERPRETER evaluates the kernel's equations on the enclosing trace's
+    vma-typed values, where a kernel's literals and fresh scratch carry no
+    axes and ``mul`` refuses them beside a varying block (casting the
+    arrays to vary moves the refusal into the index maps'
+    ``dynamic_slice``: PERF.md §6, PR 37); a ``cond``'s branches are not
+    typed, so an interpreted body runs in one that is always taken — what
+    the rectangle's gate on the whole tile body did unnoticed."""
+    def cell(*refs):
+        i = pl.program_id(1)
+        pl.when(i >= 0 if interpret else True)(lambda: kernel(i, *refs))
+
+    visits = [jnp.asarray(blocks) for blocks in visits]
+    return pl.pallas_call(
+        cell,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(operands[0].shape[0], visits[0].shape[0]),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=scratch_shapes),
+        out_shape=out_shape,
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name=name)(*visits, *operands)
+
+
+def _row_specs(d: int, block_q: int, block_k: int):
+    """The three block specs every kernel's operands take: ``[block_q, d]``
+    and the per-row scalars' ``[block_q, SCALAR_COLS]`` following the
+    visit's q-block, ``[block_k, d]`` following its k-block."""
+    def q_row(bh, i, q_blocks, k_blocks):
+        return (bh, q_blocks[i], 0)
+
+    def k_row(bh, i, q_blocks, k_blocks):
+        return (bh, k_blocks[i], 0)
+
+    return (pl.BlockSpec((None, block_q, d), q_row),
+            pl.BlockSpec((None, block_k, d), k_row),
+            pl.BlockSpec((None, block_q, SCALAR_COLS), q_row))
 
 
 def _causal_mask(s, qi, kj, block_q: int, block_k: int):
@@ -136,13 +229,6 @@ def _causal_mask(s, qi, kj, block_q: int, block_k: int):
     k_pos = kj * block_k + jax.lax.broadcasted_iota(
         jnp.int32, s.shape, 1)
     return jnp.where(q_pos >= k_pos, s, NEG_INF)
-
-
-def _visible(qi, kj, block_q: int, block_k: int, causal: bool):
-    """Whether tile (q-block ``qi``, k-block ``kj``) holds any unmasked
-    pair."""
-    return (qi * block_q + block_q - 1 >= kj * block_k) if causal \
-        else (qi >= 0)
 
 
 def _lanes(x, n: int):
@@ -163,51 +249,50 @@ def _lane_partials(p):
     return sum(groups[1:], groups[0])
 
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q: int,
-                      block_k: int, causal: bool, return_lse: bool):
-    """One (batch·head, q-block, k-step) cell.  Refs: q/o [block_q, d];
-    k/v [block_k, d] (streamed); lse (when requested)
+def _flash_fwd_kernel(i, q_blocks, k_blocks, q_ref, k_ref, v_ref, o_ref,
+                      *rest, block_q: int, block_k: int, causal: bool,
+                      return_lse: bool):
+    """One (batch·head, visit) cell, visits q-block-major.  Refs: q/o
+    [block_q, d]; k/v [block_k, d] (streamed); lse (when requested)
     [block_q, SCALAR_COLS]; scratch m/den [block_q, 128] and
-    acc [block_q, d], all fp32, persistent across k-steps; m holds a
-    row's maximum in every lane and den lane partials (``_STATE_LANES``),
-    so the row maximum is a k-step's one cross-lane reduction."""
+    acc [block_q, d], all fp32, persistent across a q-block's visits; m
+    holds a row's maximum in every lane and den lane partials
+    (``_STATE_LANES``), so the row maximum is a visit's one cross-lane
+    reduction."""
     if return_lse:
         lse_ref, m_ref, den_ref, acc_ref = rest
     else:
         m_ref, den_ref, acc_ref = rest
-    qi, kj = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
+    qi, kj, first, last = _visit(i, q_blocks, k_blocks, q_blocks)
 
-    @pl.when(kj == 0)
+    @pl.when(first)
     def _init():
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         den_ref[:] = jnp.zeros_like(den_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    @pl.when(_visible(qi, kj, block_q, block_k, causal))
-    def _compute():
-        d = q_ref.shape[-1]
-        q = q_ref[:].astype(jnp.float32) * (d ** -0.5)
-        k = k_ref[:].astype(jnp.float32)
-        v = v_ref[:].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [bq, bk]
-        if causal:
-            s = _causal_mask(s, qi, kj, block_q, block_k)
-        m_prev = m_ref[:]                                  # [bq, 128]
-        m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
-        p = jnp.exp(s - _lanes(m_new, block_k))
-        alpha = jnp.exp(m_prev - m_new)                    # [bq, 128]
-        part = _lane_partials(p)                           # [bq, w]
-        w = part.shape[-1]
-        den_ref[:, :w] = den_ref[:, :w] * alpha[:, :w] + part
-        acc_ref[:] = acc_ref[:] * _lanes(alpha, d) + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[:] = m_new
+    d = q_ref.shape[-1]
+    q = q_ref[:].astype(jnp.float32) * (d ** -0.5)
+    k = k_ref[:].astype(jnp.float32)
+    v = v_ref[:].astype(jnp.float32)
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)                # [bq, bk]
+    if causal:
+        s = _causal_mask(s, qi, kj, block_q, block_k)
+    m_prev = m_ref[:]                                      # [bq, 128]
+    m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
+    p = jnp.exp(s - _lanes(m_new, block_k))
+    alpha = jnp.exp(m_prev - m_new)                        # [bq, 128]
+    part = _lane_partials(p)                               # [bq, w]
+    w = part.shape[-1]
+    den_ref[:, :w] = den_ref[:, :w] * alpha[:, :w] + part
+    acc_ref[:] = acc_ref[:] * _lanes(alpha, d) + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_ref[:] = m_new
 
-    @pl.when(kj == nk - 1)
+    @pl.when(last)
     def _finalize():
         den = jnp.sum(den_ref[:], -1, keepdims=True)       # [bq, 1]
         o_ref[:] = (acc_ref[:] / den).astype(o_ref.dtype)
@@ -235,34 +320,20 @@ def flash_attention_forward(q, k, v, causal: bool = False,
     kf = k.reshape(b * h, t, d)
     vf = v.reshape(b * h, t, d)
 
-    def kv_map(bh, qi, kj):
-        if causal:
-            # masked steps re-point at the last visible block: same index
-            # as the previous step ⇒ Pallas skips the fetch entirely
-            kj = jnp.minimum(kj, (qi * block_q + block_q - 1) // block_k)
-        return (bh, kj, 0)
-
     kernel = functools.partial(
         _flash_fwd_kernel, block_q=block_q, block_k=block_k,
         causal=causal, return_lse=return_lse)
-    out_specs = [
-        pl.BlockSpec((None, block_q, d), lambda bh, qi, kj: (bh, qi, 0)),
-    ]
+    q_row, k_row, s_row = _row_specs(d, block_q, block_k)
+    out_specs = [q_row]
     out_shape = [_sds((b * h, t, d), q.dtype, qf)]
     if return_lse:
-        out_specs.append(pl.BlockSpec((None, block_q, SCALAR_COLS),
-                                      lambda bh, qi, kj: (bh, qi, 0)))
+        out_specs.append(s_row)
         out_shape.append(_sds((b * h, t, SCALAR_COLS), jnp.float32,
                               qf))
-    results = pl.pallas_call(
-        kernel,
-        grid=(b * h, t // block_q, t // block_k),
-        in_specs=[
-            pl.BlockSpec((None, block_q, d),
-                         lambda bh, qi, kj: (bh, qi, 0)),
-            pl.BlockSpec((None, block_k, d), kv_map),
-            pl.BlockSpec((None, block_k, d), kv_map),
-        ],
+    results = _visit_call(
+        kernel, names.KERNEL_FLASH_FWD,
+        tile_visits(t, block_q, block_k, causal, "q"), (qf, kf, vf),
+        in_specs=[q_row, k_row, k_row],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
@@ -270,10 +341,7 @@ def flash_attention_forward(q, k, v, causal: bool = False,
             pltpu.VMEM((block_q, _STATE_LANES), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=_compiler_params(interpret),
-        interpret=interpret,
-        name=names.KERNEL_FLASH_FWD,
-    )(qf, kf, vf)
+        interpret=interpret)
     if return_lse:
         out, lse = results
         return out.reshape(b, h, t, d), lse[..., 0].reshape(b, h, t)
@@ -283,7 +351,7 @@ def flash_attention_forward(q, k, v, causal: bool = False,
 
 def _tile_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, kj,
                block_q: int, block_k: int, causal: bool):
-    """What every backward product of one visible tile is made from:
+    """What every backward product of one visited tile is made from:
     ``p = exp(s - lse)`` and ``ds = p * (dp - delta)``, both
     ``[block_q, block_k]`` fp32, with the fp32 operands they came from
     (``q`` already scaled by ``d ** -0.5``)."""
@@ -322,100 +390,93 @@ def _dkv_terms(p, ds, q, do):
     return dk, dv
 
 
-def _flash_bwd_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, *dq_scratch,
-                      block_q: int, block_k: int, causal: bool):
-    """Fused backward cell (bh, k-block, q-step).  Refs: k/v/dk/dv
-    [block_k, d]; q/do [block_q, d] (streamed); lse/delta
+def _flash_bwd_kernel(i, q_blocks, k_blocks, k_ref, v_ref, q_ref, do_ref,
+                      lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dk_acc,
+                      dv_acc, *dq_scratch, block_q: int, block_k: int,
+                      causal: bool):
+    """Fused backward cell (bh, visit), visits k-block-major.  Refs:
+    k/v/dk/dv [block_k, d]; q/do [block_q, d] (streamed); lse/delta
     [block_q, SCALAR_COLS]; dq [t, d], one block a bh.  Scratch, fp32:
-    dk/dv accumulators [block_k, d], alive over one k-block's q-steps, and
-    the dq accumulator [t, d], alive over the whole (k-block, q-step)
-    sweep of one bh — the dq block itself where dq is fp32, which is
-    resident for just that sweep."""
-    kj, qi = pl.program_id(1), pl.program_id(2)
-    nk, nq = pl.num_programs(1), pl.num_programs(2)
+    dk/dv accumulators [block_k, d], alive over one k-block's visits, and
+    the dq accumulator [t, d], alive over all the visits of one bh — the
+    dq block itself where dq is fp32, which is resident for just that
+    sweep."""
+    qi, kj, first, last = _visit(i, q_blocks, k_blocks, k_blocks)
     dq_acc = dq_scratch[0] if dq_scratch else dq_ref
 
-    @pl.when((kj == 0) & (qi == 0))
+    @pl.when(i == 0)
     def _init_dq():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    @pl.when(qi == 0)
+    @pl.when(first)
     def _init_dkv():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    @pl.when(_visible(qi, kj, block_q, block_k, causal))
-    def _compute():
-        q, k, do, p, ds = _tile_p_ds(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, kj,
-            block_q, block_k, causal)
-        dk, dv = _dkv_terms(p, ds, q, do)
-        dv_acc[:] += dv
-        dk_acc[:] += dk
-        rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
-        dq_acc[rows, :] += _dq_term(ds, k)
+    q, k, do, p, ds = _tile_p_ds(
+        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, kj,
+        block_q, block_k, causal)
+    dk, dv = _dkv_terms(p, ds, q, do)
+    dv_acc[:] += dv
+    dk_acc[:] += dk
+    rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+    dq_acc[rows, :] += _dq_term(ds, k)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(last)
     def _finalize_dkv():
         dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
 
     if dq_scratch:
-        @pl.when((kj == nk - 1) & (qi == nq - 1))
+        @pl.when(i == k_blocks.shape[0] - 1)
         def _finalize_dq():
             dq_ref[:] = dq_acc[:].astype(dq_ref.dtype)
 
 
-def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                     dq_ref, acc_ref, *, block_q: int, block_k: int,
-                     causal: bool):
-    """dQ cell (bh, q-block, k-step).  Refs: q/do/dq [block_q, d];
-    k/v [block_k, d] (streamed); lse/delta [block_q, SCALAR_COLS];
-    scratch acc [block_q, d] fp32."""
-    qi, kj = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
+def _flash_dq_kernel(i, q_blocks, k_blocks, q_ref, k_ref, v_ref, do_ref,
+                     lse_ref, delta_ref, dq_ref, acc_ref, *, block_q: int,
+                     block_k: int, causal: bool):
+    """dQ cell (bh, visit), visits q-block-major.  Refs: q/do/dq
+    [block_q, d]; k/v [block_k, d] (streamed); lse/delta
+    [block_q, SCALAR_COLS]; scratch acc [block_q, d] fp32."""
+    qi, kj, first, last = _visit(i, q_blocks, k_blocks, q_blocks)
 
-    @pl.when(kj == 0)
+    @pl.when(first)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    @pl.when(_visible(qi, kj, block_q, block_k, causal))
-    def _compute():
-        _, k, _, _, ds = _tile_p_ds(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, kj,
-            block_q, block_k, causal)
-        acc_ref[:] += _dq_term(ds, k)
+    _, k, _, _, ds = _tile_p_ds(
+        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, kj,
+        block_q, block_k, causal)
+    acc_ref[:] += _dq_term(ds, k)
 
-    @pl.when(kj == nk - 1)
+    @pl.when(last)
     def _finalize():
         dq_ref[:] = acc_ref[:].astype(dq_ref.dtype)
 
 
-def _flash_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
-                      dk_ref, dv_ref, dk_acc, dv_acc, *, block_q: int,
-                      block_k: int, causal: bool):
-    """dK/dV cell (bh, k-block, q-step).  Refs: k/v/dk/dv [block_k, d];
-    q/do [block_q, d] (streamed); lse/delta [block_q, SCALAR_COLS];
-    scratch dk/dv accumulators [block_k, d] fp32."""
-    kj, qi = pl.program_id(1), pl.program_id(2)
-    nq = pl.num_programs(2)
+def _flash_dkv_kernel(i, q_blocks, k_blocks, k_ref, v_ref, q_ref, do_ref,
+                      lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
+                      block_q: int, block_k: int, causal: bool):
+    """dK/dV cell (bh, visit), visits k-block-major.  Refs: k/v/dk/dv
+    [block_k, d]; q/do [block_q, d] (streamed); lse/delta
+    [block_q, SCALAR_COLS]; scratch dk/dv accumulators [block_k, d]
+    fp32."""
+    qi, kj, first, last = _visit(i, q_blocks, k_blocks, k_blocks)
 
-    @pl.when(qi == 0)
+    @pl.when(first)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    @pl.when(_visible(qi, kj, block_q, block_k, causal))
-    def _compute():
-        q, _, do, p, ds = _tile_p_ds(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, kj,
-            block_q, block_k, causal)
-        dk, dv = _dkv_terms(p, ds, q, do)
-        dv_acc[:] += dv
-        dk_acc[:] += dk
+    q, _, do, p, ds = _tile_p_ds(
+        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, kj,
+        block_q, block_k, causal)
+    dk, dv = _dkv_terms(p, ds, q, do)
+    dv_acc[:] += dv
+    dk_acc[:] += dk
 
-    @pl.when(qi == nq - 1)
+    @pl.when(last)
     def _finalize():
         dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
@@ -468,47 +529,24 @@ def flash_attention_backward(q, k, v, out, lse, do, causal: bool = False,
             dv.reshape(b, h, t, d))
 
 
-def _dkv_specs(t: int, d: int, block_q: int, block_k: int, causal: bool):
-    """Grid and operand specs of the (bh, k-block, q-step) schedule, for
-    operands in the order k, v, q, do, lse, delta; and the k-block spec
-    dk and dv are written through."""
-    def q_map(bh, kj, qi):
-        if causal:
-            # the first visible q-step for this k-block; earlier (masked)
-            # steps alias it so no block is fetched for them
-            qi = jnp.maximum(qi, (kj * block_k) // block_q)
-        return (bh, qi, 0)
-
-    k_col = pl.BlockSpec((None, block_k, d),
-                         lambda bh, kj, qi: (bh, kj, 0))
-    in_specs = [
-        k_col,                                              # k
-        k_col,                                              # v
-        pl.BlockSpec((None, block_q, d), q_map),            # q
-        pl.BlockSpec((None, block_q, d), q_map),            # do
-        pl.BlockSpec((None, block_q, SCALAR_COLS), q_map),  # lse
-        pl.BlockSpec((None, block_q, SCALAR_COLS), q_map),  # delta
-    ]
-    return (t // block_k, t // block_q), in_specs, k_col
-
-
 def _backward_fused(qf, kf, vf, dof, lsef, delta, causal, block_q,
                     block_k, interpret):
-    """One kernel over grid (bh, k-block, q-step): every visible tile's
+    """One kernel over the k-block-major visits: every visited tile's
     ``p`` and ``ds`` computed once, dk/dv written a k-block, dq carried in
-    VMEM over the whole sweep and written once a bh — so both inner dims
-    are ``arbitrary``."""
+    VMEM over all the visits of one bh and written once."""
     bh, t, d = qf.shape
-    inner, in_specs, k_col = _dkv_specs(t, d, block_q, block_k, causal)
-    return pl.pallas_call(
+    q_row, k_row, s_row = _row_specs(d, block_q, block_k)
+    return _visit_call(
         functools.partial(_flash_bwd_kernel, block_q=block_q,
                           block_k=block_k, causal=causal),
-        grid=(bh,) + inner,
-        in_specs=in_specs,
+        names.KERNEL_FLASH_BWD,
+        tile_visits(t, block_q, block_k, causal, "k"),
+        (kf, vf, qf, dof, lsef, delta),
+        in_specs=[k_row, k_row, q_row, q_row, s_row, s_row],
         out_specs=[
-            pl.BlockSpec((None, t, d), lambda bh, kj, qi: (bh, 0, 0)),
-            k_col,
-            k_col,
+            pl.BlockSpec((None, t, d), lambda bh, i, *visits: (bh, 0, 0)),
+            k_row,
+            k_row,
         ],
         out_shape=[
             _sds((bh, t, d), qf.dtype, qf),
@@ -521,55 +559,36 @@ def _backward_fused(qf, kf, vf, dof, lsef, delta, causal, block_q,
             # an fp32 dq accumulates in its own block: no scratch for it
         ] + ([] if qf.dtype == jnp.float32
              else [pltpu.VMEM((t, d), jnp.float32)]),
-        compiler_params=_compiler_params(interpret, middle="arbitrary"),
-        interpret=interpret,
-        name=names.KERNEL_FLASH_BWD,
-    )(kf, vf, qf, dof, lsef, delta)
+        interpret=interpret)
 
 
 def _backward_pair(qf, kf, vf, dof, lsef, delta, causal, block_q, block_k,
                    interpret):
-    """The dq kernel, grid (bh, q-block, k-step), then the dk/dv kernel,
-    grid (bh, k-block, q-step): VMEM O(block²) at any length, every
-    tile's ``p`` and ``ds`` computed twice."""
+    """The dq kernel over the q-block-major visits, then the dk/dv kernel
+    over the k-block-major ones: VMEM O(block²) at any length the visit
+    lists fit SMEM at, every tile's ``p`` and ``ds`` computed twice."""
     bh, t, d = qf.shape
-
-    def kv_map(bh, qi, kj):
-        if causal:
-            kj = jnp.minimum(kj, (qi * block_q + block_q - 1) // block_k)
-        return (bh, kj, 0)
-
-    q_row = pl.BlockSpec((None, block_q, d),
-                         lambda bh, qi, kj: (bh, qi, 0))
-    s_row = pl.BlockSpec((None, block_q, SCALAR_COLS),
-                         lambda bh, qi, kj: (bh, qi, 0))
-    dq = pl.pallas_call(
+    q_row, k_row, s_row = _row_specs(d, block_q, block_k)
+    dq = _visit_call(
         functools.partial(_flash_dq_kernel, block_q=block_q,
                           block_k=block_k, causal=causal),
-        grid=(bh, t // block_q, t // block_k),
-        in_specs=[
-            q_row,                                          # q
-            pl.BlockSpec((None, block_k, d), kv_map),       # k
-            pl.BlockSpec((None, block_k, d), kv_map),       # v
-            q_row,                                          # do
-            s_row,                                          # lse
-            s_row,                                          # delta
-        ],
+        names.KERNEL_FLASH_DQ,
+        tile_visits(t, block_q, block_k, causal, "q"),
+        (qf, kf, vf, dof, lsef, delta),
+        in_specs=[q_row, k_row, k_row, q_row, s_row, s_row],
         out_specs=q_row,
         out_shape=_sds((bh, t, d), qf.dtype, qf),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_compiler_params(interpret),
-        interpret=interpret,
-        name=names.KERNEL_FLASH_DQ,
-    )(qf, kf, vf, dof, lsef, delta)
+        interpret=interpret)
 
-    inner, in_specs, k_col = _dkv_specs(t, d, block_q, block_k, causal)
-    dk, dv = pl.pallas_call(
+    dk, dv = _visit_call(
         functools.partial(_flash_dkv_kernel, block_q=block_q,
                           block_k=block_k, causal=causal),
-        grid=(bh,) + inner,
-        in_specs=in_specs,
-        out_specs=[k_col, k_col],
+        names.KERNEL_FLASH_DKV,
+        tile_visits(t, block_q, block_k, causal, "k"),
+        (kf, vf, qf, dof, lsef, delta),
+        in_specs=[k_row, k_row, q_row, q_row, s_row, s_row],
+        out_specs=[k_row, k_row],
         out_shape=[
             _sds((bh, t, d), kf.dtype, kf),
             _sds((bh, t, d), vf.dtype, vf),
@@ -578,10 +597,7 @@ def _backward_pair(qf, kf, vf, dof, lsef, delta, causal, block_q, block_k,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=_compiler_params(interpret),
-        interpret=interpret,
-        name=names.KERNEL_FLASH_DKV,
-    )(kf, vf, qf, dof, lsef, delta)
+        interpret=interpret)
     return dq, dk, dv
 
 

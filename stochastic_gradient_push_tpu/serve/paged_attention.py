@@ -6,16 +6,16 @@ values live in the page pool ``[kv_heads, num_pages, page_size,
 head_dim]`` and each sequence names its pages through an int32
 ``page_indices`` row (padded with 0) plus a ``lengths`` scalar.
 
-The Pallas kernel reuses the flash-attention schedule shape
-(ops/flash_attention.py): a 3-D grid whose two major dims are parallel
+The Pallas kernel carries the flash-attention forward's state
+(ops/flash_attention.py) on a 3-D grid whose two major dims are parallel
 (batch, kv-head) and whose MINOR dim walks the sequence's pages with
-``arbitrary`` semantics, carrying the online-softmax ``(m, den, acc)``
-triple in fp32 VMEM scratch across page steps.  The page walk is the
-part flash attention cannot express: the k/v block fetched at minor
+``arbitrary`` semantics: the online-softmax ``(m, den, acc)`` triple in
+fp32 VMEM scratch across page steps.  The k/v block fetched at minor
 step ``j`` is ``pages[page_indices[b, j]]`` — a data-dependent block
-index, which is exactly what ``pltpu.PrefetchScalarGridSpec`` exists
-for (scalar operands land in SMEM before the grid starts, and the
-index maps read them to steer the double-buffered block fetches).
+index, which is what ``pltpu.PrefetchScalarGridSpec`` exists for
+(scalar operands land in SMEM before the grid starts, and the index
+maps read them to steer the double-buffered block fetches; the flash
+kernels take their tile visits the same way).
 Pages past a sequence's length are compute-gated with ``pl.when`` and
 their fetches are aliased back to the sequence's first page, so padded
 ``page_indices`` rows never cost bandwidth.
@@ -44,7 +44,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..ops.flash_attention import NEG_INF, _compiler_params, _sds
+from ..ops.flash_attention import NEG_INF, _sds
 from ..ops.gossip_kernel import resolve_use_pallas
 from ..telemetry import names
 
@@ -194,7 +194,8 @@ def _paged_decode_pallas(q, k_pages, v_pages, page_indices, lengths,
         functools.partial(_paged_decode_kernel, page_size=page),
         grid_spec=grid_spec,
         out_shape=_sds((b, hkv, group, d), q.dtype, qg),
-        compiler_params=_compiler_params(interpret),
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name=names.KERNEL_PAGED_ATTENTION,
     )(page_indices.astype(jnp.int32), lengths.astype(jnp.int32),

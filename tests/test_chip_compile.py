@@ -30,7 +30,7 @@ from stochastic_gradient_push_tpu.ops import gossip_kernel as gk
 from stochastic_gradient_push_tpu.ops import grouped_matmul as gm
 from stochastic_gradient_push_tpu.ops.flash_attention import (
     default_block, flash_attention, flash_attention_backward,
-    flash_attention_forward, fused_backward_fits)
+    flash_attention_forward, fused_backward_fits, tile_visits)
 from stochastic_gradient_push_tpu.ops.ring_flash import ring_flash_attention
 from stochastic_gradient_push_tpu.ops.ssd import kernel_fits, ssd_chunked
 from stochastic_gradient_push_tpu.parallel import (
@@ -170,6 +170,48 @@ def test_flash_forward_compiles(one_chip, shape, dtype, return_lse):
         q, k, v, causal=True, block_q=block, block_k=block,
         return_lse=return_lse)).lower(x, x, x).compile().as_text()
     assert _kernel_names(text) == {names.KERNEL_FLASH_FWD}
+
+
+def test_flash_compiles_inside_the_steps_shard_map(mesh, on_tpu):
+    """``gpt2m_sgp_w1_t8192``'s attention through ``jax.grad`` as the step
+    holds it: per rank inside a vma-checked ``shard_map``, where the
+    operands vary over the mesh's axis and the kernels' visit arrays are
+    constants with no axis: the compiled ``pallas_call`` takes the mix,
+    with no cast."""
+    def grads(q, k, v):
+        def loss(q, k, v):
+            with jax.named_scope(names.SCOPE_FORWARD):
+                return flash_attention(q, k, v, causal=True).astype(
+                    jnp.float32).sum()
+
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    x = _per_rank(mesh, (1, 16, 8192, 64), jnp.bfloat16)
+    text = _sharded(grads, mesh, 3).lower(x, x, x).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert _kernel_names(text) == {names.KERNEL_FLASH_FWD,
+                                   names.KERNEL_FLASH_BWD}
+
+
+def test_flash_compiles_at_the_longest_visit_lists(one_chip):
+    """What grows with the length is the two visit arrays in SMEM: 32768
+    tokens in blocks of 128 with no mask, a long ring-flash shard's tick
+    at the smallest block Mosaic tiles, make 65536 visits a list, and the
+    forward and the dq + dk/dv pair compile with them."""
+    t, block = 32768, 128
+    assert len(tile_visits(t, block, block, False, "q")[0]) == 2 ** 16
+    x = jax.ShapeDtypeStruct((1, 1, t, 64), jnp.bfloat16, sharding=one_chip)
+
+    def both(q, k, v):
+        out, lse = flash_attention_forward(
+            q, k, v, block_q=block, block_k=block, return_lse=True)
+        return flash_attention_backward(q, k, v, out, lse, out,
+                                        block_q=block, block_k=block)
+
+    text = jax.jit(both).lower(x, x, x).compile().as_text()
+    assert _kernel_names(text) == {
+        names.KERNEL_FLASH_FWD, names.KERNEL_FLASH_DQ,
+        names.KERNEL_FLASH_DKV}
 
 
 @pytest.mark.parametrize("d", [64, 128])

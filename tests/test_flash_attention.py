@@ -117,8 +117,9 @@ def test_default_block_rule():
 
 @pytest.mark.parametrize("block_q,block_k", [(16, 32), (32, 16)])
 def test_flash_kernel_mixed_block_sizes(qkv, block_q, block_k):
-    """Both aspect ratios exercise the causal index-map clamps (a
-    wrong floor in either direction reads the wrong streamed block)."""
+    """Both aspect ratios exercise the causal visit list at unequal
+    blocks (a wrong floor in either direction leaves a visible tile out
+    or reads a masked one)."""
     q, k, v = qkv
     got = flash_attention_forward(q, k, v, causal=True, block_q=block_q,
                                   block_k=block_k, interpret=True)
@@ -361,23 +362,114 @@ def test_a_row_that_starts_full_and_ends_on_the_diagonal(qkv, dtype):
                                rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("t,block_q,block_k", [
-    (8192, 512, 512), (4096, 512, 512), (1024, 512, 512),
-    (1024, 256, 512), (1024, 512, 256), (64, 16, 32), (64, 32, 16)])
-def test_visible_tiles_are_the_masks_own(t, block_q, block_k):
-    """``_visible`` skips a tile exactly where the mask leaves no pair —
-    at the benchmark's three shapes (120, 28 and 1 tiles a head skipped,
-    as many wholly under the diagonal) and at unequal blocks both ways."""
-    full, diagonal, skipped = _tile_kinds(t, block_q, block_k)
-    seen = sum(bool(fa._visible(qi, kj, block_q, block_k, True))
-               for qi in range(t // block_q) for kj in range(t // block_k))
-    assert seen == full + diagonal
-    if block_q == block_k == 512:
-        n = t // 512
-        assert (full, diagonal, skipped) == (n * (n - 1) // 2, n,
-                                             n * (n - 1) // 2)
-    assert all(fa._visible(qi, kj, block_q, block_k, False)
-               for qi in range(t // block_q) for kj in range(t // block_k))
+# -- the visit lists ---------------------------------------------------------
+
+VISIT_SHAPES = [(8192, 512, 512), (4096, 512, 512), (1024, 512, 512),
+                (1024, 256, 512), (1024, 512, 256), (64, 16, 32),
+                (64, 32, 16)]
+
+
+def _tiles_with_a_pair(t, block_q, block_k, causal):
+    """``[q-blocks, k-blocks]`` bools, counted on the mask itself."""
+    mask = np.tril(np.ones((t, t), bool)) if causal \
+        else np.ones((t, t), bool)
+    return mask.reshape(t // block_q, block_q, t // block_k,
+                        block_k).any((1, 3))
+
+
+@pytest.mark.parametrize("major", ["q", "k"])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("t,block_q,block_k", VISIT_SHAPES)
+def test_visits_are_the_tiles_the_mask_leaves(t, block_q, block_k, causal,
+                                              major):
+    """``tile_visits`` lists a tile exactly where the mask leaves it a
+    pair, once — at the benchmark's three shapes (136, 36 and 3 visits a
+    head where the rectangle has 256, 64 and 4) and at unequal blocks both
+    ways —, a row of the major axis after another with the other axis
+    ascending to (q-major) or from (k-major) the diagonal, no row without
+    a visit; and the kernels' comparison with the neighbouring entry finds
+    each row's first and last visit."""
+    q_blocks, k_blocks = fa.tile_visits(t, block_q, block_k, causal, major)
+    some = _tiles_with_a_pair(t, block_q, block_k, causal)
+    nq, nk = some.shape
+    # a step's trace asks 72 times: one list a shape, read-only for it
+    assert fa.tile_visits(t, block_q, block_k, causal, major)[0] is q_blocks
+    for blocks in (q_blocks, k_blocks):
+        assert blocks.dtype == np.int32 and blocks.ndim == 1
+        assert not blocks.flags.writeable
+    n = len(q_blocks)
+    assert len(k_blocks) == n == int(some.sum())
+    seen = np.zeros_like(some)
+    seen[q_blocks, k_blocks] = True
+    np.testing.assert_array_equal(seen, some)
+    if causal:
+        full, diagonal, skipped = _tile_kinds(t, block_q, block_k)
+        assert n == full + diagonal == nq * nk - skipped
+        if block_q == block_k == 512:
+            assert n == nq * (nq + 1) // 2
+            assert (t, n, nq * nk) in ((8192, 136, 256), (4096, 36, 64),
+                                       (1024, 3, 4))
+    else:
+        assert n == nq * nk
+
+    rows, steps, of_row = (q_blocks, k_blocks, some) if major == "q" \
+        else (k_blocks, q_blocks, some.T)
+    # as ``_visit`` decides them in the kernels
+    i = np.arange(n)
+    first = (i == 0) | (rows[np.maximum(i - 1, 0)] != rows)
+    last = (i == n - 1) | (rows[np.minimum(i + 1, n - 1)] != rows)
+    np.testing.assert_array_equal(rows[first], np.arange(len(of_row)))
+    np.testing.assert_array_equal(rows[last], rows[first])
+    assert (np.diff(rows) >= 0).all()
+    assert (np.diff(steps)[~last[:-1]] == 1).all()     # no gap in a row
+    np.testing.assert_array_equal(steps[first], of_row.argmax(1))
+    np.testing.assert_array_equal(
+        steps[last], of_row.shape[1] - 1 - of_row[:, ::-1].argmax(1))
+    if major == "q":
+        assert (steps[first] == 0).all()               # to the diagonal
+    else:
+        assert (steps[last] == nq - 1).all()           # from the diagonal
+
+
+def _grids(shape, dtype, causal=True):
+    """``{kernel name: grid}`` of the forward's and the backward's Pallas
+    calls at ``shape`` and the auto block (traced, not run)."""
+    x = jax.ShapeDtypeStruct(shape, dtype)
+    lse = jax.ShapeDtypeStruct(shape[:3], jnp.float32)
+    block = fa.default_block(shape[2])
+
+    def both(q, k, v, lse, do):
+        out = flash_attention_forward(q, k, v, causal=causal,
+                                      block_q=block, block_k=block)
+        return flash_attention_backward(q, k, v, out, lse, do,
+                                        causal=causal, block_q=block,
+                                        block_k=block)
+
+    jaxpr = jax.make_jaxpr(both)(x, x, x, lse, x)
+    return {e.params["name"]: tuple(e.params["grid_mapping"].grid)
+            for e in jaxpr.eqns if e.primitive.name == "pallas_call"}
+
+
+@pytest.mark.parametrize("shape,visits,rectangle", [
+    ((1, 16, 8192, 64), 136, 256),      # gpt2m_sgp_w1_t8192's call
+    ((1, 32, 4096, 64), 36, 64),        # the granite and lfm2 cells'
+    ((4, 16, 1024, 64), 3, 4),          # gpt2m_sgp_w1_t1024's
+    ((1, 4, 16384, 64), 528, 1024),     # beyond the budget: the pair
+], ids=["t8192", "t4096", "t1024", "t16384_pair"])
+def test_a_causal_call_takes_no_grid_step_without_a_tile(shape, visits,
+                                                         rectangle):
+    """The counter that says the walk engages is static: every kernel's
+    grid is (batch·head, visits), the visits those of the list and fewer
+    than the rectangle's steps; with no mask, the whole rectangle."""
+    bh = shape[0] * shape[1]
+    backward = [names.KERNEL_FLASH_BWD] if fused_backward_fits(
+        *shape[2:]) else [names.KERNEL_FLASH_DQ, names.KERNEL_FLASH_DKV]
+    grids = _grids(shape, jnp.bfloat16)
+    assert grids == {name: (bh, visits)
+                     for name in [names.KERNEL_FLASH_FWD] + backward}
+    assert visits < rectangle
+    assert set(_grids(shape, jnp.bfloat16, causal=False).values()) == {
+        (bh, rectangle)}
 
 
 @pytest.mark.parametrize("cols,width", [(512, 128), (128, 128), (256, 128),
