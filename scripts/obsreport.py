@@ -24,6 +24,7 @@ import argparse
 import json
 import os
 import sys
+import types
 
 # report building is pure host work; never pull in an accelerator
 # runtime just to read JSON (same pattern as plan.py)
@@ -40,6 +41,10 @@ from stochastic_gradient_push_tpu.telemetry import (  # noqa: E402
     TRACE_FILE,
     request_latency_meter,
     step_time_meter,
+)
+from stochastic_gradient_push_tpu.telemetry.setup_ledger import (  # noqa: E402
+    SetupLedger,
+    setup_line,
 )
 
 # -- loading ---------------------------------------------------------------
@@ -314,8 +319,25 @@ def build_report(run_dir: str) -> dict:
             "rejections_observed": rejects,
         }
 
+    # set-up, as the ledger reported it at the first step (telemetry/
+    # setup_ledger.py), and the programs built after it
+    setup_evs = by_kind.get("setup", [])
+    compile_evs = by_kind.get("compile", [])
+    rebuilt = [ev["data"] for ev in compile_evs
+               if ev.get("severity") == "warning"]
+
     report = {
         "run_dir": run_dir,
+        "setup": setup_evs[0]["data"] if setup_evs else None,
+        "compiles_after_setup": {
+            "count": len(compile_evs),
+            "seconds": round(sum(ev["data"].get("seconds", 0.0)
+                                 for ev in compile_evs), 6),
+            "built_again": [{"fun_name": d.get("fun_name"),
+                             "seconds": d.get("seconds"),
+                             "cache": d.get("cache"),
+                             "build": d.get("build")} for d in rebuilt],
+        },
         "trace_present": trace_present,
         "schema_problems": problems,
         "events": {k: len(v) for k, v in sorted(by_kind.items())},
@@ -388,6 +410,18 @@ def render(report: dict) -> str:
         lines.append("gossip-vs-compute: gossip rounds add "
                      f"{report['gossip_step_overhead_s']*1e3:.2f} ms "
                      "per gossiping step (vs thinned steps)")
+    su = report.get("setup")
+    if su:
+        # the line the loop logged at its first step, from the same totals
+        lines.append(setup_line(su))
+    ca = report.get("compiles_after_setup") or {}
+    if ca.get("count"):
+        lines.append(
+            f"built after set-up: {ca['count']} program(s), "
+            f"{ca['seconds']:.2f} s; built again: "
+            + (", ".join(f"{r['fun_name']} (build {r['build']}, "
+                         f"{r['seconds']:.2f} s, {r['cache']})"
+                         for r in ca["built_again"]) or "none"))
     if report["phase_totals_s"]:
         lines.append("host wall-clock by phase: " + ", ".join(
             f"{k} {v:.3f}s" for k, v in
@@ -565,6 +599,40 @@ def selftest() -> int:
                 {"steps": 1, "timed": t >= 2,
                  "gossip": int(model.gossip_fires(t)),
                  "global_avg": int(model.global_avg_fires(t))})
+        # set-up as the ledger reports it: two phases, an init program
+        # the cache held, a train step it did not (an inner jit traced
+        # inside the step's trace: a union, not a sum), then a program
+        # built again after set-up
+        from stochastic_gradient_push_tpu.telemetry import names
+        led = SetupLedger(clock=lambda: t0)
+        led.armed, led.t0 = True, t0 - 10.0
+        led.bind(rt)
+        led.phase("parse", t0 - 9.5, t0 - 9.0)
+        jit_step = f"jit({names.MODULE_TRAIN_STEP})"
+        for ev, a, b, fn in (
+                (names.JAX_TRACE_EVENT, -9.0, -8.0, "init"),
+                (names.JAX_LOWER_EVENT, -8.0, -7.5, "jit(init)"),
+                (names.JAX_CACHE_HIT_EVENT, None, None, None),
+                (names.JAX_BACKEND_EVENT, -7.5, -6.5, "jit(init)"),
+                (names.JAX_TRACE_EVENT, -5.5, -5.0, "kernel"),
+                (names.JAX_TRACE_EVENT, -6.0, -4.0, names.MODULE_TRAIN_STEP),
+                (names.JAX_LOWER_EVENT, -4.0, -3.0, jit_step),
+                (names.JAX_CACHE_MISS_EVENT, None, None, None),
+                (names.JAX_BACKEND_EVENT, -3.0, -1.0, jit_step)):
+            if a is None:
+                led.on_event(ev)
+            else:
+                led.on_time_span(ev, t0 + a, t0 + b, fun_name=fn)
+        led.phase("first_step", t0 - 6.0, t0 - 0.5)
+        setup_lines, rebuilt_lines = [], []
+        led.report(types.SimpleNamespace(info=setup_lines.append,
+                                         warning=rebuilt_lines.append),
+                   rt, step=1)
+        led.on_time_span(names.JAX_LOWER_EVENT, t0 + 0.02, t0 + 0.03,
+                         fun_name=jit_step)
+        led.on_time_span(names.JAX_BACKEND_EVENT, t0 + 0.03, t0 + 0.04,
+                         fun_name=jit_step)
+        led.unbind(rt)
         rt.registry.emit("health", {
             "step": 9, "consensus_residual": 0.5,
             "reasons": ["residual-above-floor"]}, step=9,
@@ -676,6 +744,27 @@ def selftest() -> int:
         expect(report["step_time"]["p50_s"] > 0, "p50 > 0")
         expect(report["step_time"]["p99_s"] >=
                report["step_time"]["p50_s"], "p99 >= p50")
+        su = report["setup"]
+        expect(su is not None and su["programs"] == 2
+               and su["cache_hits"] == 1 and su["cache_misses"] == 1
+               and su["step_program"]["seconds"] == 5.0,
+               f"setup event: {su}")
+        if su is not None:
+            # unions: the kernel's trace lies inside the step's
+            expect(su["trace_lower_s"] == 4.5 and su["compile_s"] == 2.0
+                   and su["cache_load_s"] == 1.0
+                   and su["total_s"] == 10.0 and su["other_s"] == 1.5,
+                   f"setup totals: {su}")
+            expect(setup_lines == [setup_line(su)]
+                   and setup_lines[0] in rendered,
+                   "the report renders the line the loop logged")
+        ca = report["compiles_after_setup"]
+        expect(ca["count"] == 1 and len(ca["built_again"]) == 1
+               and ca["built_again"][0]["build"] == 2
+               and len(rebuilt_lines) == 1,
+               f"compile events after set-up: {ca}")
+        expect(report["phase_totals_s"].get("setup") == 6.0,
+               "set-up's phases on trace.json's setup track")
         expect(report["health"]["excursions"] == 1, "one excursion")
         expect(report["recoveries"]["count"] == 1, "one recovery")
         expect(report["heartbeat_stalls"] == 1, "one stall")

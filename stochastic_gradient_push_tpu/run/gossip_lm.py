@@ -409,6 +409,7 @@ def build_training(args, log, registry=None) -> Training:
 
     from ..algorithms import gossip_algorithm, gossip_mode
     from ..parallel import GOSSIP_AXIS
+    from ..telemetry import setup_phase
     from ..train import LRSchedule, sgd
     from ..train.lm import (EP_AXIS, SEQ_AXIS, build_lm_train_step,
                             ep_state_specs, init_lm_state,
@@ -429,11 +430,13 @@ def build_training(args, log, registry=None) -> Training:
     # data-parallel replica count, not raw devices
     mode = gossip_mode(all_reduce=sb(args.all_reduce),
                        push_sum=sb(args.push_sum), bilat=sb(args.bilat))
-    plan, interconnect = plan_gossip(
-        args, dp, mode=mode, ppi=args.peers_per_itr,
-        graph_class=GRAPH_TOPOLOGIES[args.graph_type],
-        overlap=sb(args.overlap), log=log, registry=registry)
-    mesh = _make_mesh(dp, sp, tp_, ep, pp)
+    with setup_phase("plan"):
+        plan, interconnect = plan_gossip(
+            args, dp, mode=mode, ppi=args.peers_per_itr,
+            graph_class=GRAPH_TOPOLOGIES[args.graph_type],
+            overlap=sb(args.overlap), log=log, registry=registry)
+    with setup_phase("mesh"):
+        mesh = _make_mesh(dp, sp, tp_, ep, pp)
     if jax.process_count() > 1:
         # per-process feeding works on every mesh; checkpoints need a
         # layout that can hold arbitrary shardings.  dp/dp×sp states
@@ -468,7 +471,9 @@ def build_training(args, log, registry=None) -> Training:
                          "(the 3-D gossip × pipe × seq mesh)")
     seq_axis = SEQ_AXIS if ring_family else None
     ep_axis = EP_AXIS if ep > 1 else None
-    model = model_from_args(args, attn, seq_axis=seq_axis, ep_axis=ep_axis)
+    with setup_phase("model"):
+        model = model_from_args(args, attn, seq_axis=seq_axis,
+                                ep_axis=ep_axis)
 
     if plan is not None:
         graph_class = plan.graph_class
@@ -503,53 +508,56 @@ def build_training(args, log, registry=None) -> Training:
     lrs = LRSchedule(ref_lr=args.lr, batch_size=args.batch_size,
                      world_size=dp * ep, decay_schedule={},
                      warmup=sb(args.warmup))
-    if pp > 1:
-        from ..train.pp import (build_pp_eval_step, build_pp_train_step,
-                                init_pp_state, pp_state_specs,
-                                shard_pp_eval_step, shard_pp_train_step)
+    # the step is assembled and the state is made: the init program is
+    # built and run in here (the ledger's rows say which and how long)
+    with setup_phase("state_init"):
+        if pp > 1:
+            from ..train.pp import (build_pp_eval_step, build_pp_train_step,
+                                    init_pp_state, pp_state_specs,
+                                    shard_pp_eval_step, shard_pp_train_step)
 
-        step = build_pp_train_step(model, alg, tx, lrs,
-                                   itr_per_epoch=itr_per_epoch)
-        state = init_pp_state(model, mesh, alg, tx, dp=dp, pp=pp,
-                              n_micro=args.n_micro,
-                              micro_batch=args.batch_size // args.n_micro,
-                              seq_len=args.seq_len, seed=args.seed, sp=sp,
-                              ep=ep)
-        specs = pp_state_specs(state, ep_axis=ep_axis)
-        train_fn = shard_pp_train_step(step, mesh, specs,
-                                       seq_axis=seq_axis, ep_axis=ep_axis)
-    else:
-        step = build_lm_train_step(
-            model, alg, tx, lrs, itr_per_epoch=itr_per_epoch,
-            seq_axis=seq_axis, ep_axis=ep_axis,
-            grad_accum=args.grad_accum,
-            health_axis=GOSSIP_AXIS if args.health_every > 0 else None)
-        if ep > 1:
-            state = init_lm_state_ep(model, mesh, alg, tx, dp=dp, ep=ep,
-                                     batch_size=args.batch_size,
-                                     seq_len=args.seq_len, seed=args.seed,
-                                     sp=sp)
-            train_fn = shard_lm_train_step(
-                step, mesh, seq_axis=seq_axis,
-                state_specs=ep_state_specs(state), ep_axis=EP_AXIS,
-                tp=tp_ > 1)
-        elif tp_ > 1 and not ring_family:
-            from ..train.lm import init_lm_state_tp
-
-            state = init_lm_state_tp(model, mesh, alg, tx, dp=dp,
-                                     batch_size=args.batch_size,
-                                     seq_len=args.seq_len, seed=args.seed)
-            train_fn = shard_lm_train_step(step, mesh, seq_axis=None,
-                                           tp=True)
+            step = build_pp_train_step(model, alg, tx, lrs,
+                                       itr_per_epoch=itr_per_epoch)
+            state = init_pp_state(model, mesh, alg, tx, dp=dp, pp=pp,
+                                  n_micro=args.n_micro,
+                                  micro_batch=args.batch_size // args.n_micro,
+                                  seq_len=args.seq_len, seed=args.seed, sp=sp,
+                                  ep=ep)
+            specs = pp_state_specs(state, ep_axis=ep_axis)
+            train_fn = shard_pp_train_step(step, mesh, specs,
+                                           seq_axis=seq_axis, ep_axis=ep_axis)
         else:
-            state = init_lm_state(
-                model, mesh, alg, tx, dp=dp, sp=sp,
-                batch_size=args.batch_size,
-                block_len=(args.seq_len // sp if ring_family
-                           else args.seq_len),
-                seed=args.seed, seq_axis=seq_axis)
-            train_fn = shard_lm_train_step(
-                step, mesh, seq_axis=seq_axis, tp=tp_ > 1)
+            step = build_lm_train_step(
+                model, alg, tx, lrs, itr_per_epoch=itr_per_epoch,
+                seq_axis=seq_axis, ep_axis=ep_axis,
+                grad_accum=args.grad_accum,
+                health_axis=GOSSIP_AXIS if args.health_every > 0 else None)
+            if ep > 1:
+                state = init_lm_state_ep(model, mesh, alg, tx, dp=dp, ep=ep,
+                                         batch_size=args.batch_size,
+                                         seq_len=args.seq_len, seed=args.seed,
+                                         sp=sp)
+                train_fn = shard_lm_train_step(
+                    step, mesh, seq_axis=seq_axis,
+                    state_specs=ep_state_specs(state), ep_axis=EP_AXIS,
+                    tp=tp_ > 1)
+            elif tp_ > 1 and not ring_family:
+                from ..train.lm import init_lm_state_tp
+
+                state = init_lm_state_tp(model, mesh, alg, tx, dp=dp,
+                                         batch_size=args.batch_size,
+                                         seq_len=args.seq_len, seed=args.seed)
+                train_fn = shard_lm_train_step(step, mesh, seq_axis=None,
+                                               tp=True)
+            else:
+                state = init_lm_state(
+                    model, mesh, alg, tx, dp=dp, sp=sp,
+                    batch_size=args.batch_size,
+                    block_len=(args.seq_len // sp if ring_family
+                               else args.seq_len),
+                    seed=args.seed, seq_axis=seq_axis)
+                train_fn = shard_lm_train_step(
+                    step, mesh, seq_axis=seq_axis, tp=tp_ > 1)
 
     eval_fn = None
     if args.val_frac > 0 and pp > 1:
@@ -880,6 +888,7 @@ def train_loop(args, t: Training, state, start_step: int, corpus,
 
     from ..data.lm import lm_batches
     from ..parallel.multihost import host_local_slice, to_host
+    from ..telemetry.setup_ledger import first_step
     from ..utils import Meter
     from ..utils.checkpoint import REQUEUE_EXIT_CODE
     from ..utils.profiling import ProfileWindow, StepWatchdog
@@ -1034,8 +1043,11 @@ def train_loop(args, t: Training, state, start_step: int, corpus,
                 if pw.enabled:
                     pw.maybe_start(steps_done + 1)
                 # the loop's phases by name in a --profile_dir capture
-                # (telemetry/names.py); shared no-ops when none is active
-                with pw.step(steps_done + 1):
+                # (telemetry/names.py); shared no-ops when none is active.
+                # The process's first step is set-up's last phase and
+                # ends with set-up's report; a shared no-op from then on
+                with first_step(log, rt, steps_done + 1), \
+                        pw.step(steps_done + 1):
                     with pw.span("data_fetch"):
                         x = globalize(shape_batch(tokens))
                         y = globalize(shape_batch(targets))
@@ -1208,22 +1220,26 @@ def train_loop(args, t: Training, state, start_step: int, corpus,
 def main(argv=None):
     from ..utils.compile_cache import place_compile_cache
 
-    place_compile_cache()
-    args = parse_args(argv)
+    place_compile_cache()     # and arms the set-up ledger
+    from ..telemetry import make_run_telemetry, setup_phase
+
+    with setup_phase("parse"):
+        args = parse_args(argv)
 
     import jax
 
-    from ..telemetry import make_run_telemetry
     from ..utils import make_logger
     from .gossip_sgd import _multihost_env
 
-    want_mh = args.multihost
-    if want_mh == "True" or (want_mh == "auto" and _multihost_env()):
-        from ..parallel.discovery import initialize_multihost
+    # the devices come up here (the first question put to the backend)
+    with setup_phase("mesh"):
+        want_mh = args.multihost
+        if want_mh == "True" or (want_mh == "auto" and _multihost_env()):
+            from ..parallel.discovery import initialize_multihost
 
-        initialize_multihost(args.coordinator_address, args.num_processes,
-                             args.process_id)
-    proc_count, proc_index = jax.process_count(), jax.process_index()
+            initialize_multihost(args.coordinator_address,
+                                 args.num_processes, args.process_id)
+        proc_count, proc_index = jax.process_count(), jax.process_index()
     log = make_logger(f"lm p{proc_index}" if proc_count > 1 else "lm", True)
 
     # run telemetry BEFORE planning so the plan event and the loop share
@@ -1234,14 +1250,16 @@ def main(argv=None):
     _attach_accounting(args, t, rt)
 
     ckpt, cluster, use_orbax = _checkpointing(args, t)
-    state, start_step = _resume(args, t, ckpt, use_orbax, log)
+    with setup_phase("resume"):
+        state, start_step = _resume(args, t, ckpt, use_orbax, log)
     if start_step >= args.num_steps:
         log.info(f"nothing to do: resumed at step {start_step} >= "
                  f"num_steps {args.num_steps}")
         rt.finish(step=start_step)
         return {"final_loss": None, "avg_loss": None,
                 "tokens_per_sec": 0.0, "already_complete": True}
-    corpus, val_corpus = _load_corpus(args, t, log)
+    with setup_phase("data"):
+        corpus, val_corpus = _load_corpus(args, t, log)
     return train_loop(args, t, state, start_step, corpus, val_corpus,
                       rt=rt, log=log, ckpt=ckpt, cluster=cluster,
                       use_orbax=use_orbax)

@@ -737,13 +737,16 @@ def _resolve_plan(cfg, args, gossip_world: int, log, registry=None):
 def main(argv=None, config_transform=None, extra_args=None):
     from ..utils.compile_cache import place_compile_cache
 
-    place_compile_cache()
-    cfg, args = parse_config(argv)
-    if extra_args:
-        for k, v in extra_args.items():
-            setattr(args, k, v)
-    if config_transform is not None:
-        cfg = config_transform(cfg, args)
+    place_compile_cache()     # and arms the set-up ledger
+    from ..telemetry import make_run_telemetry, setup_phase
+
+    with setup_phase("parse"):
+        cfg, args = parse_config(argv)
+        if extra_args:
+            for k, v in extra_args.items():
+                setattr(args, k, v)
+        if config_transform is not None:
+            cfg = config_transform(cfg, args)
 
     import jax
 
@@ -753,8 +756,9 @@ def main(argv=None, config_transform=None, extra_args=None):
     if want_mh == "True" or (want_mh == "auto" and _multihost_env()):
         from ..parallel.discovery import initialize_multihost
 
-        initialize_multihost(args.coordinator_address, args.num_processes,
-                             args.process_id)
+        with setup_phase("mesh"):
+            initialize_multihost(args.coordinator_address,
+                                 args.num_processes, args.process_id)
 
     from ..data import (DistributedSampler, ShardedLoader,
                         StreamingImageFolder, synthetic_classification)
@@ -765,15 +769,15 @@ def main(argv=None, config_transform=None, extra_args=None):
     from ..utils.checkpoint import ClusterManager
 
     log = make_logger("main", cfg.verbose)
-    world = args.world_size or jax.device_count()
+    # the devices come up here (the first question put to the backend)
+    with setup_phase("mesh"):
+        world = args.world_size or jax.device_count()
+        proc_index = jax.process_index()
 
     # run telemetry BEFORE planning, so the planner's `plan` event and
     # the train loop share one events.jsonl (the null bundle when no
     # --trace_dir)
-    from ..telemetry import make_run_telemetry
-
-    telemetry = make_run_telemetry(cfg.trace_dir,
-                                   rank=jax.process_index(), log=log,
+    telemetry = make_run_telemetry(cfg.trace_dir, rank=proc_index, log=log,
                                    metrics_every=cfg.metrics_every)
 
     # launch-time topology policy BEFORE any mesh/device work: planning is
@@ -782,18 +786,19 @@ def main(argv=None, config_transform=None, extra_args=None):
     # of a hierarchical mesh, so that's the world the mixing analysis sees
     gossip_world = (world // args.nprocs_per_node
                     if args.nprocs_per_node > 1 else world)
-    _resolve_plan(cfg, args, gossip_world, log,
-                  registry=telemetry.registry)
+    with setup_phase("plan"):
+        _resolve_plan(cfg, args, gossip_world, log,
+                      registry=telemetry.registry)
 
-    if args.nprocs_per_node > 1:
-        cfg.nprocs_per_node = args.nprocs_per_node
-        mesh = make_hierarchical_mesh(args.nprocs_per_node, world)
-    else:
-        mesh = make_gossip_mesh(world)
+    with setup_phase("mesh"):
+        if args.nprocs_per_node > 1:
+            cfg.nprocs_per_node = args.nprocs_per_node
+            mesh = make_hierarchical_mesh(args.nprocs_per_node, world)
+        else:
+            mesh = make_gossip_mesh(world)
     log.info(f"mesh: {mesh}; devices: {world}")
 
     proc_count = jax.process_count()
-    proc_index = jax.process_index()
     if proc_count > 1:
         if not cfg.checkpoint_all:
             # every process holds *different* ranks; funnelling them into
@@ -815,48 +820,51 @@ def main(argv=None, config_transform=None, extra_args=None):
     import jax.numpy as jnp
 
     dtype = jnp.bfloat16 if args.precision == "bf16" else jnp.float32
-    if args.model in RESNETS:
-        model = RESNETS[args.model](num_classes=cfg.num_classes, dtype=dtype,
-                                    stem_s2d=_str_bool(args.stem_s2d))
-    elif args.model == "tiny_cnn":
-        model = TinyCNN(num_classes=cfg.num_classes, dtype=dtype)
-    else:
-        raise SystemExit(f"unknown model {args.model}")
+    with setup_phase("model"):
+        if args.model in RESNETS:
+            model = RESNETS[args.model](
+                num_classes=cfg.num_classes, dtype=dtype,
+                stem_s2d=_str_bool(args.stem_s2d))
+        elif args.model == "tiny_cnn":
+            model = TinyCNN(num_classes=cfg.num_classes, dtype=dtype)
+        else:
+            raise SystemExit(f"unknown model {args.model}")
 
-    if args.dataset == "synthetic":
-        n = args.synthetic_samples or world * cfg.batch_size * 8
-        n_val = max(world * cfg.batch_size, n // 8)
-        # one draw, then split: train and val share class structure
-        all_images, all_labels = synthetic_classification(
-            n + n_val, num_classes=cfg.num_classes,
-            image_size=args.image_size, seed=cfg.seed)
-        images, labels = all_images[:n], all_labels[:n]
-        val_images, val_labels = all_images[n:], all_labels[n:]
-        sampler = DistributedSampler(len(images), world)
-        loader = ShardedLoader(images, labels, cfg.batch_size, sampler,
-                               ranks=local_ranks)
-    else:
-        if not args.dataset_dir:
-            raise SystemExit("--dataset_dir required for imagefolder")
-        # both splits stream with background decode; val never needs the
-        # whole split resident in host memory
-        workers = args.num_dataloader_workers or 8
-        loader = StreamingImageFolder(
-            args.dataset_dir, "train", world, cfg.batch_size,
-            image_size=args.image_size, train=True,
-            num_workers=workers, seed=cfg.seed, ranks=local_ranks,
-            backend=args.data_backend, output=args.data_output)
-        sampler = loader  # owns set_epoch for both sampling and augment
-        val_loader = StreamingImageFolder(
-            args.dataset_dir, "val", world, cfg.batch_size,
-            image_size=args.image_size, train=False, num_workers=workers,
-            ranks=local_ranks, backend=args.data_backend,
-            output=args.data_output)
+    with setup_phase("data"):
+        if args.dataset == "synthetic":
+            n = args.synthetic_samples or world * cfg.batch_size * 8
+            n_val = max(world * cfg.batch_size, n // 8)
+            # one draw, then split: train and val share class structure
+            all_images, all_labels = synthetic_classification(
+                n + n_val, num_classes=cfg.num_classes,
+                image_size=args.image_size, seed=cfg.seed)
+            images, labels = all_images[:n], all_labels[:n]
+            val_images, val_labels = all_images[n:], all_labels[n:]
+            sampler = DistributedSampler(len(images), world)
+            loader = ShardedLoader(images, labels, cfg.batch_size, sampler,
+                                   ranks=local_ranks)
+        else:
+            if not args.dataset_dir:
+                raise SystemExit("--dataset_dir required for imagefolder")
+            # both splits stream with background decode; val never needs the
+            # whole split resident in host memory
+            workers = args.num_dataloader_workers or 8
+            loader = StreamingImageFolder(
+                args.dataset_dir, "train", world, cfg.batch_size,
+                image_size=args.image_size, train=True,
+                num_workers=workers, seed=cfg.seed, ranks=local_ranks,
+                backend=args.data_backend, output=args.data_output)
+            sampler = loader  # owns set_epoch for both sampling and augment
+            val_loader = StreamingImageFolder(
+                args.dataset_dir, "val", world, cfg.batch_size,
+                image_size=args.image_size, train=False, num_workers=workers,
+                ranks=local_ranks, backend=args.data_backend,
+                output=args.data_output)
 
-    if args.dataset == "synthetic":
-        val_sampler = DistributedSampler(len(val_images), world)
-        val_loader = ShardedLoader(val_images, val_labels, cfg.batch_size,
-                                   val_sampler, ranks=local_ranks)
+        if args.dataset == "synthetic":
+            val_sampler = DistributedSampler(len(val_images), world)
+            val_loader = ShardedLoader(val_images, val_labels, cfg.batch_size,
+                                       val_sampler, ranks=local_ranks)
 
     ckpt = _make_ckpt_manager(args, cfg, world, proc_index)
     cluster = ClusterManager(ckpt, rank=proc_index,

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import os
 
+from . import setup_ledger
 from .comm import (
     COMM_CATEGORIES,
     CommAccountant,
@@ -38,12 +39,13 @@ from .registry import (
     SCHEMA_VERSION,
     TelemetryRegistry,
 )
+from .setup_ledger import setup_phase
 from .sink import JsonlSink, LoggerCompatSink, MemorySink
 from .tracer import NULL_TRACER, SPAN_PHASES, NullTracer, SpanTracer
 from .tracer import _NULL_SPAN
 
 __all__ = [
-    "RunTelemetry", "make_run_telemetry", "NULL_TELEMETRY",
+    "RunTelemetry", "make_run_telemetry", "NULL_TELEMETRY", "setup_phase",
     "SpanTracer", "NullTracer", "NULL_TRACER", "SPAN_PHASES",
     "TelemetryRegistry", "SCHEMA_VERSION", "EVENT_KINDS",
     "LEGACY_PREFIXES", "JsonlSink", "LoggerCompatSink", "MemorySink",
@@ -108,6 +110,9 @@ class RunTelemetry:
         self.registry = TelemetryRegistry(rank=rank, sinks=sinks)
         self.comm: CommAccountant | None = None
         self._finished = False
+        # set-up's phases reach trace.json, and programs built after
+        # set-up become `compile` events, through the process's ledger
+        setup_ledger.LEDGER.bind(self)
 
     # -- tracer passthrough (the loop's hot-path surface) ------------------
 
@@ -137,6 +142,7 @@ class RunTelemetry:
         if self._finished:
             return
         self._finished = True
+        setup_ledger.LEDGER.unbind(self)
         self.emit_comm(step=step)
         self.tracer.write(os.path.join(
             self.trace_dir, _rank_file(TRACE_FILE, self.rank)))

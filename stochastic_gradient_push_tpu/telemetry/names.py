@@ -10,7 +10,14 @@ One clock: every name below lands in the profiler's XPlane — scopes and
 kernel names on the device planes (as the operations' ``op_name``), host
 spans and the step marker on the host plane — so an idle gap of the
 device can be put down to the host span it fell in.  ``SpanTracer``'s
-``trace.json`` (tracer.py) stays a separate, host-only operator's view.
+``trace.json`` (tracer.py) is the operator's whole-run view on the host's
+wall clock; the loops' spans reach it by calls of their own.  Set-up is the
+exception: ``telemetry.setup_phase`` (setup_ledger.py) takes two
+``time.time()`` readings and hands the same two to the set-up ledger and to
+``trace.json`` (phase ``setup``), and opens ``sgp:setup.<name>`` in
+whatever capture is running — one call, three sinks.  ``time.time()`` is
+also the clock of JAX's own build events (``JAX_*_EVENT`` below), so the
+ledger holds both sides on one clock with no conversion.
 """
 
 # -- device side: jax.named_scope around the phases of the train step ----
@@ -68,4 +75,23 @@ HOST_STEP = "sgp_step"
 HOST_SPANS = ("data_fetch", "dispatch", "fence", "metrics_fetch", "health",
               "async_bilat", "checkpoint_save", "validate",
               "recovery_global_average")
+# set-up's phases (telemetry.setup_phase): ``sgp:setup.<name>`` in a
+# capture, ``<name>`` on trace.json's ``setup`` track and in the ledger
+SETUP_SPAN_PREFIX = HOST_SPAN_PREFIX + "setup."
+SETUP_SPANS = ("parse", "mesh", "plan", "model", "state_init", "data",
+               "resume", "first_step")
+# the jitted steps by the name JAX's build events give them; the first one
+# built ends set-up (the ledger's cut)
+STEP_MODULES = (MODULE_TRAIN_STEP, MODULE_TRAIN_STEP_SCAN,
+                MODULE_LM_TRAIN_STEP, MODULE_LM_TRAIN_STEP_SCAN)
+
+# -- jax.monitoring events the set-up ledger listens to (jax 0.9): the
+# three time spans carry ``fun_name`` and [start, end] on time.time() ----
+JAX_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+JAX_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+JAX_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+JAX_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+JAX_CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"  # on a write
+JAX_CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+JAX_CACHE_SAVED_EVENT = "/jax/compilation_cache/compile_time_saved_sec"
 

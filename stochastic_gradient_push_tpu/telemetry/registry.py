@@ -50,6 +50,8 @@ EVENT_KINDS = frozenset({
     "serve",        # serving-stack lifecycle (reject/summary; serve/)
     "request",      # one completed serve request (typed-only; serve/)
     "alert",        # SLO rule firing (typed-only; telemetry.aggregate)
+    "setup",        # one per process: set-up's totals at the first step
+    "compile",      # a program built after set-up (telemetry.setup_ledger)
 })
 
 SEVERITIES = ("info", "warning", "error")

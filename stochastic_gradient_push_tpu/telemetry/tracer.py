@@ -39,7 +39,7 @@ __all__ = ["SpanTracer", "NullTracer", "NULL_TRACER", "SPAN_PHASES"]
 # the span vocabulary: every event lands on one of these phase tracks
 # (Chrome-trace tid); obsreport groups its per-phase totals by them
 SPAN_PHASES = ("data", "step", "gossip", "global_avg", "checkpoint",
-               "eval", "recovery", "bench", "serve", "request")
+               "eval", "recovery", "bench", "serve", "request", "setup")
 
 
 class _NullSpan:

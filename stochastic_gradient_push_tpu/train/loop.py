@@ -29,6 +29,7 @@ from ..parallel.multihost import (
     owned_ranks,
     to_host,
 )
+from ..telemetry.setup_ledger import first_step, setup_phase
 from ..topology import build_pairing_schedule
 from ..utils import Meter, make_logger
 from ..utils.checkpoint import REQUEUE_EXIT_CODE, ClusterManager
@@ -431,7 +432,9 @@ class Trainer:
         gossip_sgd.py:497-505)."""
         key = (ppi, itr_per_epoch, scan)
         if key not in self._step_cache:
-            alg = self.make_algorithm(ppi)
+            # the schedule and the algorithm of this variant
+            with setup_phase("plan"):
+                alg = self.make_algorithm(ppi)
             step = build_train_step(
                 self.model, alg, self.tx, self.lr_schedule_obj,
                 itr_per_epoch=itr_per_epoch, num_classes=self.cfg.num_classes,
@@ -564,22 +567,23 @@ class Trainer:
     # -- main entry points -------------------------------------------------
 
     def init_state(self):
-        import jax.numpy as jnp
-        alg = self.make_algorithm(ppi_at_epoch(self.cfg.ppi_schedule, 0))
-        state = init_train_state(
-            self.model, jax.random.PRNGKey(self.cfg.seed),
-            jnp.zeros(self.sample_input_shape), self.tx, alg)
-        if self.proc_count == 1:
-            return replicate_state(state, self.gossip_world)
-        # every rank starts identical (same seed, gossip_sgd.py:172-175);
-        # each process materializes only its local rows and assembles the
-        # global sharded state from them
-        local = jax.tree.map(
-            lambda a: np.broadcast_to(
-                np.asarray(a)[None],
-                (len(self.local_ranks),) + np.shape(a)).copy(),
-            state)
-        return global_state_from_local(self.mesh, self.gossip_axis, local)
+        with setup_phase("state_init"):
+            import jax.numpy as jnp
+            alg = self.make_algorithm(ppi_at_epoch(self.cfg.ppi_schedule, 0))
+            state = init_train_state(
+                self.model, jax.random.PRNGKey(self.cfg.seed),
+                jnp.zeros(self.sample_input_shape), self.tx, alg)
+            if self.proc_count == 1:
+                return replicate_state(state, self.gossip_world)
+            # every rank starts identical (same seed, gossip_sgd.py:172-175);
+            # each process materializes only its local rows and assembles the
+            # global sharded state from them
+            local = jax.tree.map(
+                lambda a: np.broadcast_to(
+                    np.asarray(a)[None],
+                    (len(self.local_ranks),) + np.shape(a)).copy(),
+                state)
+            return global_state_from_local(self.mesh, self.gossip_axis, local)
 
     def fit(self, state, train_loader, sampler,
             val_loader=None) -> tuple[tp.Any, dict]:
@@ -625,7 +629,8 @@ class Trainer:
                               "peer; starting from epoch 0")
             have_ckpt = bool(all_have)
         if have_ckpt:
-            state, meta = self._restore(state)
+            with setup_phase("resume"):
+                state, meta = self._restore(state)
             start_epoch = meta.get("epoch", 0)
             start_itr = meta.get("itr", 0)
             if self.proc_count > 1:
@@ -1023,7 +1028,10 @@ class Trainer:
                 # a scanned chunk starts/stops around the whole program —
                 # the profiler cannot cut inside one compiled scan
                 prof.maybe_start(gstep)
-            with prof.step(gstep):
+            # the process's first step is set-up's last phase and ends
+            # with set-up's report; a shared no-op from then on
+            with first_step(self.log, self.telemetry, gstep), \
+                    prof.step(gstep):
                 with guard:
                     with prof.span("dispatch"):
                         state, metrics = train_fn(state, x, y)

@@ -7,6 +7,14 @@ in the same checkout — or a second call on a machine that keeps its disk —
 starts from compiled programs instead of minutes of ResNet-50 / 12-layer LM
 compilation.  It is *not* called at package import: importing the library
 changes no JAX configuration.
+
+The same call arms the set-up ledger (``telemetry/setup_ledger.py``): the
+entry points already make it before anything compiles, ``benchmark/run.py``
+among them, so every program the process builds lands in the ledger and no
+caller has a second thing to remember.  Package import would be earlier by
+a few hundredths of a second and would register listeners in every process
+that imports the library; this registers them where a train step is about
+to be built.
 """
 
 from __future__ import annotations
@@ -28,8 +36,12 @@ def place_compile_cache() -> str:
 
     With ``JAX_COMPILATION_CACHE_DIR`` set in the environment JAX reads it
     by itself, and no code of this repo sets another directory.  Otherwise
-    the cache is ``<checkout>/.jax_cache`` (git-ignored).
+    the cache is ``<checkout>/.jax_cache`` (git-ignored).  Arms the
+    set-up ledger on the way (a second call arms nothing).
     """
+    from ..telemetry import setup_ledger
+
+    setup_ledger.arm()
     placed = os.environ.get(CACHE_DIR_ENV)
     if placed:
         return placed
