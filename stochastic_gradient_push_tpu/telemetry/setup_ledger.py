@@ -39,6 +39,9 @@ from .tracer import _NULL_SPAN
 __all__ = ["LEDGER", "SetupLedger", "arm", "disarm", "setup_phase",
            "first_step", "setup_line"]
 
+# a row's cache: the persistent cache's hit, the step store's load
+_LOADED = ("hit", "stored")
+
 _SPAN_KINDS = {names.JAX_TRACE_EVENT: "trace",
                names.JAX_LOWER_EVENT: "lower",
                names.JAX_BACKEND_EVENT: "backend"}
@@ -110,6 +113,7 @@ class SetupLedger:
         self._pending: dict = {}    # (kind, fun_name) -> span no row took
         self._cache: dict = {}      # what the cache said since a row closed
         self._builds: dict = {}     # program name -> times built
+        self.store_notes: list[str] = []   # the step store's, until reported
 
     @property
     def closed(self) -> bool:
@@ -140,17 +144,35 @@ class SetupLedger:
 
     def _close_row(self, fun_name, start, end) -> None:
         name = _program_name(fun_name)
-        built = self._builds[name] = self._builds.get(name, 0) + 1
         row = {"fun_name": name,
                "trace": self._pending.get(("trace", name)),
                "lower": self._pending.get(("lower", fun_name)),
                "backend": (start, end), "cache": "uncached",
-               "retrieval_s": 0.0, "saved_s": 0.0, "build": built,
-               **self._cache}
+               "retrieval_s": 0.0, "saved_s": 0.0, **self._cache}
         # whatever else was pending belonged to no program: a trace for
         # shapes alone, a cached trace looked up again
         self._pending.clear()
         self._cache = {}
+        self._add_row(row)
+
+    def stored(self, name, start, end) -> None:
+        """A train step the step store (``utils/step_store.py``) loaded:
+        never traced nor lowered, its key, read and load from ``start`` to
+        ``end`` as its backend interval."""
+        if self.armed:
+            self._add_row({"fun_name": name, "trace": None, "lower": None,
+                           "backend": (start, end), "cache": "stored",
+                           "retrieval_s": 0.0, "saved_s": 0.0})
+
+    def note_store(self, text: str) -> None:
+        """What the step store did with a step built before the report:
+        ``stored (hit)``, ``miss, written``, ``refused: <why>``..."""
+        if not self.reported:
+            self.store_notes.append(text)
+
+    def _add_row(self, row) -> None:
+        name = row["fun_name"]
+        row["build"] = self._builds[name] = self._builds.get(name, 0) + 1
         self.rows.append(row)
         if not self.closed and name in names.STEP_MODULES:
             self.cut = len(self.rows) - 1
@@ -229,8 +251,9 @@ class SetupLedger:
 
         built = clipped(s[2:] for s in self.spans if s[0] != "phase")
         compiled = clipped(r["backend"] for r in rows
-                           if r["cache"] != "hit")
-        loaded = clipped(r["backend"] for r in rows if r["cache"] == "hit")
+                           if r["cache"] not in _LOADED)
+        loaded = clipped(r["backend"] for r in rows
+                         if r["cache"] in _LOADED)
         phases: dict[str, float] = {}
         phase_spans = []
         for kind, name, s, e in self.spans:
@@ -260,6 +283,8 @@ class SetupLedger:
             "cache_hits": sum(r["cache"] == "hit" for r in rows),
             "cache_misses": sum(r["cache"] == "miss" for r in rows),
             "uncached": sum(r["cache"] == "uncached" for r in rows),
+            "step_store_hits": sum(r["cache"] == "stored" for r in rows),
+            "step_store": self.store_notes[0] if self.store_notes else None,
             "step_program": _row_view(step) if step else None,
             "rows": [_row_view(r) for r in rows],
             "later_rows": [_row_view(r) for r in later],
@@ -297,7 +322,7 @@ def _row_view(row: dict) -> dict:
 def _row_text(view: dict) -> str:
     return (f"{view['seconds']:.1f} s (trace {view['trace_s']:.1f}, lower "
             f"{view['lower_s']:.1f}, "
-            f"{'load' if view['cache'] == 'hit' else 'compile'} "
+            f"{'load' if view['cache'] in _LOADED else 'compile'} "
             f"{view['backend_s']:.1f})")
 
 
@@ -419,6 +444,8 @@ def setup_line(s: dict) -> str:
     step = s["step_program"]
     if step:
         line += f"; step program {step['fun_name']} {_row_text(step)}"
+    if s.get("step_store"):
+        line += f"; step {s['step_store']}"
     if phases:
         line += f"; whole phases: {phases}"
     return line

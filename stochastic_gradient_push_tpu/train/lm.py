@@ -32,6 +32,7 @@ from ..algorithms.api import GossipAlgorithm
 from ..parallel.collectives import as_scalar
 from ..parallel.mesh import GOSSIP_AXIS
 from ..telemetry import names
+from ..utils import step_store
 from .state import TrainState
 from .step import restack
 
@@ -510,12 +511,12 @@ def shard_lm_train_step(step_fn, mesh, gossip_axis: str = GOSSIP_AXIS,
             | ({ep_axis} if ep_axis else set())
         kwargs["axis_names"] = manual
     state_spec = P(gossip_axis) if state_specs is None else state_specs
-    sharded = jax.shard_map(
-        wrapped, mesh=mesh,
-        in_specs=(state_spec, batch_spec, batch_spec),
-        out_specs=(state_spec, P(gossip_axis)), **kwargs)
+    specs = dict(in_specs=(state_spec, batch_spec, batch_spec),
+                 out_specs=(state_spec, P(gossip_axis)), **kwargs)
+    sharded = jax.shard_map(wrapped, mesh=mesh, **specs)
     sharded.__name__ = names.MODULE_LM_TRAIN_STEP
-    return jax.jit(sharded, donate_argnums=(0,))
+    return step_store.jit(sharded, material=(wrapped, mesh, specs),
+                          donate_argnums=(0,))
 
 
 def build_lm_eval_step(model, algorithm: GossipAlgorithm,
@@ -597,12 +598,12 @@ def shard_scanned_lm_step(step_fn, mesh, n_steps: int,
             body, jax.tree.map(lambda a: a[0], state),
             (sq(tokens), sq(targets))))
 
-    sharded = jax.shard_map(
-        wrapped, mesh=mesh,
-        in_specs=(P(gossip_axis), batch_spec, batch_spec),
-        out_specs=(P(gossip_axis), P(gossip_axis)))
+    specs = dict(in_specs=(P(gossip_axis), batch_spec, batch_spec),
+                 out_specs=(P(gossip_axis), P(gossip_axis)))
+    sharded = jax.shard_map(wrapped, mesh=mesh, **specs)
     sharded.__name__ = names.MODULE_LM_TRAIN_STEP_SCAN
-    return jax.jit(sharded, donate_argnums=(0,))
+    return step_store.jit(sharded, material=(wrapped, mesh, specs),
+                          donate_argnums=(0,))
 
 
 def init_lm_state(model, mesh, algorithm, tx, dp: int, sp: int,

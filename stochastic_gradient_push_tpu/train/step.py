@@ -36,6 +36,7 @@ from ..algorithms.api import GossipAlgorithm
 from ..parallel.collectives import as_scalar
 from ..parallel.mesh import GOSSIP_AXIS
 from ..telemetry import names
+from ..utils import step_store
 from .metrics import accuracy_topk, kl_div_loss, one_hot
 from .state import TrainState
 
@@ -287,12 +288,12 @@ def shard_train_step(step_fn, mesh, axis_name: str = GOSSIP_AXIS,
         return restack(*step_fn(
             squeeze(state), squeeze(images), squeeze(labels)))
 
-    sharded = jax.shard_map(
-        wrapped, mesh=mesh,
-        in_specs=(P(axis_name), batch_spec, batch_spec),
-        out_specs=(P(axis_name), P(axis_name)), check_vma=check_vma)
+    specs = dict(in_specs=(P(axis_name), batch_spec, batch_spec),
+                 out_specs=(P(axis_name), P(axis_name)), check_vma=check_vma)
+    sharded = jax.shard_map(wrapped, mesh=mesh, **specs)
     sharded.__name__ = names.MODULE_TRAIN_STEP
-    return jax.jit(sharded, donate_argnums=(0,))
+    return step_store.jit(sharded, material=(wrapped, mesh, specs),
+                          donate_argnums=(0,))
 
 
 def shard_scanned_train_step(step_fn, mesh, n_steps: int,
@@ -328,12 +329,12 @@ def shard_scanned_train_step(step_fn, mesh, n_steps: int,
 
         return restack(*lax.scan(body, squeeze(state), (images, labels)))
 
-    sharded = jax.shard_map(
-        wrapped, mesh=mesh,
-        in_specs=(P(axis_name), batch_spec, batch_spec),
-        out_specs=(P(axis_name), P(axis_name)), check_vma=check_vma)
+    specs = dict(in_specs=(P(axis_name), batch_spec, batch_spec),
+                 out_specs=(P(axis_name), P(axis_name)), check_vma=check_vma)
+    sharded = jax.shard_map(wrapped, mesh=mesh, **specs)
     sharded.__name__ = names.MODULE_TRAIN_STEP_SCAN
-    return jax.jit(sharded, donate_argnums=(0,))
+    return step_store.jit(sharded, material=(wrapped, mesh, specs),
+                          donate_argnums=(0,))
 
 
 def shard_eval_step(eval_fn, mesh, axis_name: str = GOSSIP_AXIS,

@@ -14,7 +14,9 @@ among them, so every program the process builds lands in the ledger and no
 caller has a second thing to remember.  Package import would be earlier by
 a few hundredths of a second and would register listeners in every process
 that imports the library; this registers them where a train step is about
-to be built.
+to be built.  It arms the step store (``utils/step_store.py``) too, in the
+cache's subdirectory ``step_store``: a library caller that never places the
+cache gets plain ``jax.jit`` steps.
 """
 
 from __future__ import annotations
@@ -37,16 +39,18 @@ def place_compile_cache() -> str:
     With ``JAX_COMPILATION_CACHE_DIR`` set in the environment JAX reads it
     by itself, and no code of this repo sets another directory.  Otherwise
     the cache is ``<checkout>/.jax_cache`` (git-ignored).  Arms the
-    set-up ledger on the way (a second call arms nothing).
+    set-up ledger (a second call arms nothing) and the step store on the
+    way.
     """
     from ..telemetry import setup_ledger
+    from . import step_store
 
     setup_ledger.arm()
-    placed = os.environ.get(CACHE_DIR_ENV)
-    if placed:
-        return placed
-    import jax
+    path = os.environ.get(CACHE_DIR_ENV)
+    if not path:
+        import jax
 
-    path = os.path.join(_CHECKOUT, ".jax_cache")
-    jax.config.update("jax_compilation_cache_dir", path)
+        path = os.path.join(_CHECKOUT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    step_store.arm(path)
     return path
