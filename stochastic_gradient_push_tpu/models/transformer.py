@@ -28,6 +28,11 @@ first-class in-repo model family, built TPU-first:
   (``ffn_types``: dense, the switch layer, or the top-k expert layer of
   models/moe.py over the experts held here), per-head RMSNorm of q and k
   and a configurable rotary base
+* the ``olmo_hybrid`` family's parts: a ``linear_attention`` mixer (the
+  gated delta rule, models/gated_deltanet.py), norms after each sublayer
+  in place of before it (``post_norm``: ``x + norm(f(x))``, Olmo 2's
+  order) and RMSNorm of q and k over the whole projection
+  (``qk_norm="projection"``)
 """
 
 from __future__ import annotations
@@ -41,13 +46,14 @@ from jax import lax
 
 from ..parallel.ring_attention import blockwise_attention, ring_attention
 from ..telemetry import names
+from .gated_deltanet import DeltaNetConfig, GatedDeltaNetMixer
 from .moe import ExpertsConfig, topk_moe_ffn
 from .shortconv import ShortConvMixer
 from .ssm import Mamba2Mixer, SSMConfig
 
 __all__ = ["TransformerLM", "TransformerConfig", "config_from_source"]
 
-LAYER_TYPES = ("attention", "mamba", "conv")
+LAYER_TYPES = ("attention", "mamba", "conv", "linear_attention")
 FFN_TYPES = ("dense", "switch", "experts")
 
 
@@ -109,8 +115,13 @@ class TransformerConfig(tp.NamedTuple):
     ffn_types: tuple[str, ...] | None = None
     experts: ExpertsConfig | None = None   # the "experts" layers' sizes
     conv_taps: int = 3                # the "conv" mixer's taps
-    qk_norm: bool = False             # RMSNorm of q and k per head
+    # RMSNorm of q and k: None, "head" (one weight of head_dim, lfm2) or
+    # "projection" (one of all heads' widths, before the split: Olmo 2)
+    qk_norm: str | None = None
     rope_theta: float = 10000.0
+    # -- the olmo_hybrid family's parts ---------------------------------
+    delta: DeltaNetConfig | None = None   # the "linear_attention" layers'
+    post_norm: bool = False           # x + norm(f(x)), no norm before f
 
     def layer_type(self, i: int) -> str:
         return self.layer_types[i] if self.layer_types else "attention"
@@ -138,6 +149,10 @@ class TransformerConfig(tp.NamedTuple):
         if "mamba" in (self.layer_types or ()) and self.ssm is None:
             raise ValueError("a 'mamba' layer needs the mixer's sizes "
                              "(TransformerConfig.ssm)")
+        if "linear_attention" in (self.layer_types or ()) \
+                and self.delta is None:
+            raise ValueError("a 'linear_attention' layer needs the mixer's "
+                             "sizes (TransformerConfig.delta)")
         if "experts" in (self.ffn_types or ()):
             if self.experts is None:
                 raise ValueError("an 'experts' feed-forward needs the "
@@ -217,10 +232,49 @@ def _lfm2_moe_config(src: dict, **runtime) -> TransformerConfig:
         n_kv_heads=src["num_key_value_heads"], d_ff=src["intermediate_size"],
         layer_types=tuple(mixers[k] for k in src["layer_types"]),
         ffn_types=("dense",) * dense + ("experts",) * (n_layers - dense),
-        experts=experts, conv_taps=src["conv_L_cache"], qk_norm=True,
+        experts=experts, conv_taps=src["conv_L_cache"], qk_norm="head",
         rope_theta=float(src["rope_theta"]), norm="rmsnorm",
         norm_eps=src["norm_eps"], mlp="swiglu", tie_embeddings=True,
         **runtime)
+
+
+def _olmo_hybrid_config(src: dict, **runtime) -> TransformerConfig:
+    """The ``olmo_hybrid`` family: gated delta-rule (``linear_attention``)
+    and full attention layers, each followed by a SiLU-gated MLP, every
+    sublayer's RMSNorm after it (Olmo 2), q and k normed over the whole
+    projection, no bias, an untied head, and no position term at all
+    (``rope_parameters.rope_theta`` null)."""
+    mixers = {"linear_attention": "linear_attention",
+              "full_attention": "attention"}
+    unknown = sorted(set(src["layer_types"]) - set(mixers))
+    if unknown:
+        raise ValueError(f"source config layer_types {unknown} are none of "
+                         f"{tuple(mixers)}")
+    _check_source(src, {"hidden_act": ("silu",), "attention_bias": (False,)})
+    if src["rope_parameters"]["rope_theta"] is not None:
+        raise ValueError("source config rope_parameters.rope_theta="
+                         f"{src['rope_parameters']['rope_theta']!r}: the "
+                         "model computes no positions for this family")
+    if src["linear_num_value_heads"] != src["linear_num_key_heads"]:
+        raise ValueError("source config linear_num_value_heads="
+                         f"{src['linear_num_value_heads']!r}: the model "
+                         "computes one value head a key head only")
+    delta = DeltaNetConfig(
+        n_heads=src["linear_num_key_heads"],
+        key_head_dim=src["linear_key_head_dim"],
+        value_head_dim=src["linear_value_head_dim"],
+        conv_kernel_dim=src["linear_conv_kernel_dim"],
+        allow_neg_eigval=src["linear_allow_neg_eigval"])
+    return TransformerConfig(
+        vocab_size=src["vocab_size"], d_model=src["hidden_size"],
+        n_layers=src["num_hidden_layers"],
+        n_heads=src["num_attention_heads"],
+        n_kv_heads=src["num_key_value_heads"], d_ff=src["intermediate_size"],
+        layer_types=tuple(mixers[k] for k in src["layer_types"]),
+        delta=delta, qk_norm="projection",
+        post_norm=True, norm="rmsnorm", norm_eps=src["rms_norm_eps"],
+        mlp="swiglu", tie_embeddings=src["tie_word_embeddings"],
+        positions="none", **runtime)
 
 
 # model_type -> (the family's config, the source's key of the dense width)
@@ -228,6 +282,7 @@ SOURCE_FAMILIES = {
     "granitemoehybrid": (_granitemoehybrid_config,
                          "shared_intermediate_size"),
     "lfm2_moe": (_lfm2_moe_config, "intermediate_size"),
+    "olmo_hybrid": (_olmo_hybrid_config, "intermediate_size"),
 }
 
 
@@ -288,14 +343,16 @@ class _Attention(nn.Module):
             return t.reshape(b, s, e // head_dim, head_dim).transpose(
                 0, 2, 1, 3)
 
+        # one learned weight for q, one for k, over the head's width or
+        # the whole projection's, before the rotation
+        qk_norm = lambda name, t: nn.RMSNorm(
+            epsilon=cfg.norm_eps, dtype=jnp.float32,
+            name=name)(t).astype(cfg.dtype)
+        if cfg.qk_norm == "projection":
+            q, k = qk_norm("q_norm", q), qk_norm("k_norm", k)
         q, k, v = split(q), split(k), split(v)
-        if cfg.qk_norm:
-            # one learned weight over the head's width for q, one for k,
-            # before the rotation
-            per_head = lambda name, t: nn.RMSNorm(
-                epsilon=cfg.norm_eps, dtype=jnp.float32,
-                name=name)(t).astype(cfg.dtype)
-            q, k = per_head("q_norm", q), per_head("k_norm", k)
+        if cfg.qk_norm == "head":
+            q, k = qk_norm("q_norm", q), qk_norm("k_norm", k)
         if cfg.positions == "rotary":
             q = _rope(q, positions, cfg.rope_theta)
             k = _rope(k, positions, cfg.rope_theta)
@@ -441,24 +498,32 @@ class _Block(nn.Module):
     def __call__(self, x, positions):
         cfg = self.cfg
         res = cfg.residual_multiplier
-        h = _norm(cfg, "ln1")(x)
+        # a sublayer's norm: before it (h = norm(x)), or after it, on its
+        # output (post_norm), with no norm in front
+        before = lambda name, t: t if cfg.post_norm else _norm(cfg, name)(t)
+        after = lambda name, t: _norm(cfg, name)(t) if cfg.post_norm else t
+        h = before("ln1", x)
         if self.layer_type == "mamba":
             mixed = Mamba2Mixer(cfg.ssm, cfg.d_model, dtype=cfg.dtype,
                                 norm_eps=cfg.norm_eps, name="ssm")(h)
         elif self.layer_type == "conv":
             mixed = ShortConvMixer(cfg.d_model, cfg.conv_taps,
                                    dtype=cfg.dtype, name="conv")(h)
+        elif self.layer_type == "linear_attention":
+            mixed = GatedDeltaNetMixer(cfg.delta, cfg.d_model,
+                                       dtype=cfg.dtype,
+                                       norm_eps=cfg.norm_eps, name="delta")(h)
         else:
             mixed = _Attention(cfg, name="attn")(h, positions)
-        x = x + _scaled(mixed, res)
-        h = _norm(cfg, "ln2")(x)
+        x = x + _scaled(after("ln1", mixed), res)
+        h = before("ln2", x)
         if self.ffn_type == "switch":
             # dropped (over-capacity) tokens contribute zero here and ride
             # the residual connection through unchanged
-            return x + _scaled(_MoEFFN(cfg, name="moe")(h), res)
-        if self.ffn_type == "experts":
-            return x + _scaled(_ExpertsFFN(cfg, name="moe")(h), res)
-        if cfg.mlp == "swiglu":
+            h = _MoEFFN(cfg, name="moe")(h)
+        elif self.ffn_type == "experts":
+            h = _ExpertsFFN(cfg, name="moe")(h)
+        elif cfg.mlp == "swiglu":
             # one product for gate and up, as the source's input_linear
             gate, up = jnp.split(nn.Dense(
                 2 * cfg.d_ff, use_bias=False, dtype=cfg.dtype,
@@ -471,7 +536,7 @@ class _Block(nn.Module):
             h = nn.Dense(cfg.d_model, dtype=cfg.dtype, name="down")(h)
         else:
             raise ValueError(f"unknown mlp {cfg.mlp}")
-        return x + _scaled(h, res)
+        return x + _scaled(after("ln2", h), res)
 
 
 class TransformerLM(nn.Module):

@@ -44,6 +44,10 @@ SCOPE_MOE = "lm.moe"                     # router to combine
 SCOPE_MOE_ROUTE = "lm.moe.route"         # nested: scores, top-k, sort,
 #                                          the rows' gather and scatter
 SCOPE_MOE_EXPERTS = "lm.moe.experts"     # nested: the grouped products
+# -- the gated delta-rule mixer (models/gated_deltanet.py; its convolution
+# is under SCOPE_CONV1D too) -----------------------------------------------
+SCOPE_DELTA_MIXER = "lm.delta_mixer"     # the whole mixer, projections in
+SCOPE_DELTA_RULE = "lm.delta_rule"       # nested: the chunked rule alone
 # -- the head (models/transformer.py) and the loss (train/lm.py::lm_loss):
 # every operation over an array of the logits' size ----------------------
 SCOPE_LM_HEAD = "lm.head"                # the head's product, the loss

@@ -28,6 +28,7 @@ from stochastic_gradient_push_tpu.models.transformer import (
     TransformerConfig, TransformerLM)
 from stochastic_gradient_push_tpu.ops import gossip_kernel as gk
 from stochastic_gradient_push_tpu.ops import grouped_matmul as gm
+from stochastic_gradient_push_tpu.ops.delta_rule import delta_rule_chunked
 from stochastic_gradient_push_tpu.ops.flash_attention import (
     default_block, flash_attention, flash_attention_backward,
     flash_attention_forward, fused_backward_fits, tile_visits)
@@ -124,14 +125,16 @@ def _kernel_names(compiled_text: str) -> set[str]:
     ((2, 8, 4096, 64), {names.KERNEL_FLASH_BWD}),
     ((1, 8, 8192, 64), {names.KERNEL_FLASH_BWD}),
     ((1, 4, 16384, 64), {names.KERNEL_FLASH_DQ, names.KERNEL_FLASH_DKV}),
-], ids=["t1024", "t4096", "t8192", "t16384_pair"])
+    ((1, 30, 4096, 128), {names.KERNEL_FLASH_BWD}),
+], ids=["t1024", "t4096", "t8192", "t16384_pair", "t4096_d128"])
 def test_flash_forward_and_backward_compile(one_chip, on_tpu, shape,
                                             backward):
     """The flagship LM's attention, the longest captured length and the
     longest the fused backward holds dq for (a 4 MB accumulator under a
     two-deep 2 MB block), at the auto block via ``jax.grad``: the forward
     kernel and ONE backward kernel; one length beyond the budget, where
-    the dq + dk/dv pair takes over."""
+    the dq + dk/dv pair takes over; and the Olmo hybrid cell's heads of
+    128, the widest the fused backward takes."""
     assert default_block(shape[2]) == 512
     assert fused_backward_fits(*shape[2:]) == (
         backward == {names.KERNEL_FLASH_BWD})
@@ -267,6 +270,32 @@ def test_scan_kernel_pair_compiles(one_chip, on_tpu, dtype, groups):
             rf'op_name="[^"]*/{re.escape(where)}{names.SCOPE_FORWARD}\)+/'
             rf'{re.escape(names.SCOPE_SSD)}/jit\(\w+\)/{call}/pallas_call"',
             text), call
+
+
+def test_the_delta_rule_compiles_at_the_cells_sizes(one_chip):
+    """The gated delta rule of the Olmo hybrid cell (4096 steps in chunks
+    of 64, 30 heads with keys of 96 and values of 192, bf16 products)
+    through ``jax.grad`` of ``delta_rule_chunked``: XLA products, a
+    batched triangular solve and a scan over the 64 chunks; no kernel."""
+    t, h, dk, dv = 4096, 30, 96, 192
+    arg = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+
+    def loss(q, k, v, log_alpha, beta):
+        with jax.named_scope(names.SCOPE_FORWARD), \
+                jax.named_scope(names.SCOPE_DELTA_RULE):
+            return delta_rule_chunked(q, k, v, log_alpha, beta, 64,
+                                      operand_dtype=jnp.bfloat16).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        arg(jnp.bfloat16, 1, t, h, dk), arg(jnp.bfloat16, 1, t, h, dk),
+        arg(jnp.bfloat16, 1, t, h, dv), arg(jnp.float32, 1, t, h),
+        arg(jnp.float32, 1, t, h)).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    # a layer's rule and its gradient: 2.05 GB of temporaries at jax 0.9
+    # (the states entering the chunks, [64, 30, 96, 192] float32, are
+    # 142 MB a copy; the [64, 30, 64, 64] blocks 31 MB each)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],

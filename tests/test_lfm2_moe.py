@@ -250,7 +250,7 @@ def test_config_from_source_reads_the_familys_keys():
     assert cfg.experts == ExpertsConfig(
         n_experts=8, per_token=4, d_ff=24, held=None)
     assert (cfg.d_ff, cfg.n_kv_heads, cfg.qk_norm, cfg.rope_theta) \
-        == (96, 2, True, 1e6)
+        == (96, 2, "head", 1e6)
     assert cfg.tie_embeddings and cfg.norm == "rmsnorm" \
         and cfg.mlp == "swiglu" and cfg.conv_taps == 3
     held = config_from_source(_share(4, 8)).experts
