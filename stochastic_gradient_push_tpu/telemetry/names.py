@@ -66,6 +66,8 @@ KERNEL_FLASH_DQ = "flash_dq"              # the pair it gives way to
 KERNEL_FLASH_DKV = "flash_dkv"            # beyond its VMEM budget
 KERNEL_SSD_FWD = "ssd_fwd"                # the scan inside a chunk
 KERNEL_SSD_BWD = "ssd_bwd"                # (ops/ssd.py), and its backward
+KERNEL_DELTA_FWD = "delta_fwd"            # the chunked delta rule
+KERNEL_DELTA_BWD = "delta_bwd"            # (ops/delta_rule.py), and back
 KERNEL_GROUPED_MATMUL = "grouped_matmul"        # the expert layer's
 KERNEL_GROUPED_MATMUL_DW = "grouped_matmul_dw"  # products (ops/grouped_matmul.py)
 KERNEL_GOSSIP_START = "gossip_edge_start"

@@ -29,6 +29,8 @@ from stochastic_gradient_push_tpu.models.transformer import (
 from stochastic_gradient_push_tpu.ops import gossip_kernel as gk
 from stochastic_gradient_push_tpu.ops import grouped_matmul as gm
 from stochastic_gradient_push_tpu.ops.delta_rule import delta_rule_chunked
+from stochastic_gradient_push_tpu.ops.delta_rule import (
+    kernel_fits as delta_kernel_fits)
 from stochastic_gradient_push_tpu.ops.flash_attention import (
     default_block, flash_attention, flash_attention_backward,
     flash_attention_forward, fused_backward_fits, tile_visits)
@@ -272,30 +274,64 @@ def test_scan_kernel_pair_compiles(one_chip, on_tpu, dtype, groups):
             text), call
 
 
-def test_the_delta_rule_compiles_at_the_cells_sizes(one_chip):
-    """The gated delta rule of the Olmo hybrid cell (4096 steps in chunks
-    of 64, 30 heads with keys of 96 and values of 192, bf16 products)
-    through ``jax.grad`` of ``delta_rule_chunked``: XLA products, a
-    batched triangular solve and a scan over the 64 chunks; no kernel."""
-    t, h, dk, dv = 4096, 30, 96, 192
-    arg = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype,
-                                                     sharding=one_chip)
+def _delta_rule_loss(q, k, v, log_alpha, beta):
+    """The Olmo hybrid cell's rule (chunks of 64, bf16 products) under the
+    mixer's scope inside the step's forward scope, as in the program."""
+    with jax.named_scope(names.SCOPE_FORWARD), \
+            jax.named_scope(names.SCOPE_DELTA_RULE):
+        return delta_rule_chunked(q, k, v, log_alpha, beta, 64,
+                                  operand_dtype=jnp.bfloat16).sum()
 
-    def loss(q, k, v, log_alpha, beta):
-        with jax.named_scope(names.SCOPE_FORWARD), \
-                jax.named_scope(names.SCOPE_DELTA_RULE):
-            return delta_rule_chunked(q, k, v, log_alpha, beta, 64,
-                                      operand_dtype=jnp.bfloat16).sum()
 
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
-        arg(jnp.bfloat16, 1, t, h, dk), arg(jnp.bfloat16, 1, t, h, dk),
-        arg(jnp.bfloat16, 1, t, h, dv), arg(jnp.float32, 1, t, h),
-        arg(jnp.float32, 1, t, h)).compile()
-    assert "tpu_custom_call" not in compiled.as_text()
-    # a layer's rule and its gradient: 2.05 GB of temporaries at jax 0.9
-    # (the states entering the chunks, [64, 30, 96, 192] float32, are
-    # 142 MB a copy; the [64, 30, 64, 64] blocks 31 MB each)
-    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+# the Olmo hybrid cell's rule: 4096 steps, 30 heads, keys of 96, values
+# of 192; q, k, v arrive in bf16, the gates in float32
+DELTA_SHAPES = [(jnp.bfloat16, (1, 4096, 30, 96)),
+                (jnp.bfloat16, (1, 4096, 30, 96)),
+                (jnp.bfloat16, (1, 4096, 30, 192)),
+                (jnp.float32, (1, 4096, 30)), (jnp.float32, (1, 4096, 30))]
+
+
+def test_the_delta_rule_compiles_at_the_cells_sizes(one_chip, on_tpu):
+    """The gated delta rule of the Olmo hybrid cell through ``jax.grad``
+    of ``delta_rule_chunked``: the rule takes the kernel pair, and the
+    compiled text holds one ``delta_fwd`` and one ``delta_bwd``, under the
+    rule's scope, and none of XLA's triangular-solve custom calls."""
+    assert delta_kernel_fits("tpu", 64, 96, 192)
+    compiled = jax.jit(jax.grad(_delta_rule_loss, argnums=(0, 1, 2, 3, 4))) \
+        .lower(*(jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+                 for dtype, shape in DELTA_SHAPES)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert _kernel_names(text) == {names.KERNEL_DELTA_FWD,
+                                   names.KERNEL_DELTA_BWD}
+    assert "InvertDiagBlocksLowerTriangular" not in text
+    # the compiled calls carry the rule's scope (``delta_rule_ms`` finds
+    # them by it), through the jitted wrappers that share one trace among
+    # layers
+    for call, where in (("delta_fwd", "jvp("),
+                        ("delta_bwd", "transpose(jvp(")):
+        assert re.search(
+            rf'op_name="[^"]*/{re.escape(where)}{names.SCOPE_FORWARD}\)+/'
+            rf'{re.escape(names.SCOPE_DELTA_RULE)}/jit\(\w+\)/{call}/'
+            rf'pallas_call"', text), call
+    # a layer's rule and its gradient: 0.47 GB of temporaries at jax 0.9
+    # (2.05 GB on the XLA path: its scan's states and [64, 30, 64, 64]
+    # blocks); the pair's residuals are the entering states, [30, 64, 96,
+    # 192] float32 = 142 MB, and each chunk's inverse
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
+def test_the_delta_rule_compiles_inside_the_steps_shard_map(mesh, on_tpu):
+    """The same rule through ``jax.grad`` per rank inside a vma-checked
+    ``shard_map``, as the step holds it: the pair's outputs vary over the
+    mesh's axis as their operands do."""
+    grads = jax.grad(_delta_rule_loss, argnums=(0, 1, 2, 3, 4))
+    text = _sharded(grads, mesh, 5).lower(
+        *(_per_rank(mesh, shape, dtype) for dtype, shape in DELTA_SHAPES)
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert _kernel_names(text) == {names.KERNEL_DELTA_FWD,
+                                   names.KERNEL_DELTA_BWD}
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
