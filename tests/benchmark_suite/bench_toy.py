@@ -22,6 +22,9 @@ TOY_LM_CUT = {**TOY_LM, "published": {"n_layer": 8, "vocab_size": 512},
               "deployment": "four pipeline stages of two layers, the "
                             "vocabulary split over eight chips"}
 TOY_LM_CUT_REDUCED = ["n_layer", "vocab_size"]
+TOY_TOKENS = {"kind": "tokens", "ranks": 1, "batch_per_rank": 8,
+              "seq_len": 32, "vocab": 64, "zipf_exponent": 1.1,
+              "hidden_states": 4, "stay": 0.9, "resident_batches": 4}
 
 
 def _write(path, obj):
@@ -54,10 +57,7 @@ def make_toy_root(root: str, cut_config: dict = TOY_LM_CUT,
     _write(os.path.join(data, "configs", "toy_lm_cut.json"), cut_config)
     _write(os.path.join(data, "workloads", TOY_CUT_CELL + ".json"),
            {"flags": ["--lr", "8.0"], "loss_n": 40})
-    _write(os.path.join(data, "traffic", "toy_tokens_w1.json"),
-           {"kind": "tokens", "ranks": 1, "batch_per_rank": 8,
-            "seq_len": 32, "vocab": 64, "zipf_exponent": 1.1,
-            "hidden_states": 4, "stay": 0.9, "resident_batches": 4})
+    _write(os.path.join(data, "traffic", "toy_tokens_w1.json"), TOY_TOKENS)
     _write(os.path.join(data, "workloads", TOY_LM_CELL + ".json"),
            {"flags": ["--lr", "8.0"], "loss_n": 40})
     _write(os.path.join(data, "layer_metrics", "toy_steps.json"),

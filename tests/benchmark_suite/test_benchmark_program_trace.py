@@ -237,12 +237,18 @@ PAIR = {"reader": "program_trace:kernel_ms",
 
 @pytest.mark.parametrize("kernel,metric,ms", [
     ("flash_fwd", None, 2e3), ("flash_bwd", None, 1.5e3),
-    ("flash_dq+flash_dkv", PAIR, 7e3)],
-    ids=["flash_fwd", "flash_bwd", "the-pair-inline"])
+    ("flash_dq+flash_dkv", PAIR, 7e3),
+    # every flash kernel, and no other kernel of the step
+    ("flash", None, 2e3 + 7e3 + 1.5e3),
+    ("delta_kernel", None, 3e3), ("ssd_kernel", None, 1.5e3)],
+    ids=["flash_fwd", "flash_bwd", "the-pair-inline", "flash_ms",
+         "delta_kernel_ms", "ssd_kernel_ms"])
 def test_the_three_flash_kernels_are_told_apart_by_name(kernel, metric, ms):
     """The metrics' own files give the kernel reader a pattern on the
     custom call's name: each kernel's calls and no other, and nothing
-    (not zero) for a program whose kernels carry no name yet."""
+    (not zero) for a program whose kernels carry no name yet. The step may
+    hold other kernels beside the flash ones (the delta rule's, the scan's,
+    the experts')."""
     metric = metric or _kernel_metric(kernel)
     assert metric["reader"] == "program_trace:kernel_ms"
     call = ('%{0} = bf16[64,1024,64]{{2,1,0}} custom-call(%q), '
@@ -255,6 +261,11 @@ def test_the_three_flash_kernels_are_told_apart_by_name(kernel, metric, ms):
         _event(call.format("attn.7"), 9.0, 10.0),
         _event(call.format("flash_fwd_tail.1"), 10.0, 11.0),
         _event(call.format("flash_bwd.31"), 11.0, 12.5),
+        _event(call.format("delta_fwd.4"), 12.5, 13.5),
+        _event(call.format("delta_bwd"), 13.5, 15.5),
+        _event(call.format("ssd_fwd.2"), 15.5, 16.0),
+        _event(call.format("ssd_bwd.9"), 16.0, 17.0),
+        _event(call.format("grouped_matmul.5"), 17.0, 18.0),
     ]
     reading = types.SimpleNamespace(
         trace=tr.Trace({0: events}, [], STEPS), traced_steps=1,

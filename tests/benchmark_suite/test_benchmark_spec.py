@@ -14,54 +14,62 @@ from benchmark import spec
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
-BENCH = spec.load_benchmark(REPO)
-CELLS = [w["name"] for w in BENCH["workloads"]]
-METRICS = BENCH["end_to_end"] + BENCH["per_layer"]
+CELLS = [w["name"] for w in spec.load_benchmark(REPO)["workloads"]]
 
 
-def test_benchmark_json_keeps_to_the_contracts_shape():
-    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
+def keeps_to_the_contracts_shape(root):
+    """The checks of this file, each a function of the root whose
+    ``BENCHMARK.json`` it reads: the tests hold the repo's to them, and
+    test_appending.py a copy with an entry and a cell appended."""
+    bench = spec.load_benchmark(root)
+    cells = [w["name"] for w in bench["workloads"]]
+    metrics = bench["end_to_end"] + bench["per_layer"]
+    assert set(bench) == {"command", "paths", "run_seconds", "configs",
                           "workloads", "end_to_end", "per_layer"}
-    assert 1 <= BENCH["run_seconds"] <= 51
+    assert 1 <= bench["run_seconds"] <= 51
     # a full check with 24 cells has to fit the driver's 43200 s
-    assert (2 + 14 * 24) * (BENCH["run_seconds"] + 60) + 24 * 180 + 1200 \
+    assert (2 + 14 * 24) * (bench["run_seconds"] + 60) + 24 * 180 + 1200 \
         <= 43200
-    for path in BENCH["paths"]:
-        assert os.path.isdir(os.path.join(REPO, path))
-    assert all(os.path.exists(os.path.join(REPO, w)) for w in
-               BENCH["command"] if "/" in w)
-    names = [m["name"] for m in METRICS]
-    assert len(set(names)) == len(names) and len(set(CELLS)) == len(CELLS)
-    for m in METRICS:
+    for path in bench["paths"]:
+        assert os.path.isdir(os.path.join(root, path))
+    assert all(os.path.exists(os.path.join(root, w)) for w in
+               bench["command"] if "/" in w)
+    names = [m["name"] for m in metrics]
+    assert len(set(names)) == len(names) and len(set(cells)) == len(cells)
+    for m in metrics:
         assert NAME.match(m["name"]) and UNIT.match(m["unit"])
         assert m["better"] in ("lower", "higher")
         assert m["source"] in SOURCES
-    for m in BENCH["end_to_end"]:
+    for m in bench["end_to_end"]:
         assert set(m) <= {"name", "unit", "better", "bound", "source",
                           "workloads"}
         assert m["source"] in ("host_clock", "device_trace")
         assert 0.01 <= m["bound"] <= 0.1
     assert "setup_s" in names
-    for m in BENCH["per_layer"]:
+    for m in bench["per_layer"]:
         assert set(m) <= {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
-    pairs = [(w["config"], w["traffic"]) for w in BENCH["workloads"]]
+    pairs = [(w["config"], w["traffic"]) for w in bench["workloads"]]
     assert len(set(pairs)) == len(pairs)
-    four = [w for w in BENCH["workloads"] if w["chips"] == 4]
-    assert all(w["chips"] in (1, 4) for w in BENCH["workloads"])
-    assert len(four) <= max(1, len(CELLS) // 4)
-    for w in BENCH["workloads"]:
+    four = [w for w in bench["workloads"] if w["chips"] == 4]
+    assert all(w["chips"] in (1, 4) for w in bench["workloads"])
+    assert len(four) <= max(1, len(cells) // 4)
+    for w in bench["workloads"]:
         assert NAME.match(w["name"]) and NAME.match(w["traffic"])
         assert 1 <= len(w["why"]) <= 200 and "\n" not in w["why"]
-    used = {w["config"] for w in BENCH["workloads"]}
-    assert used == {c["name"] for c in BENCH["configs"]}
-    for c in BENCH["configs"]:
-        assert c["file"].startswith(tuple(p + "/" for p in BENCH["paths"]))
+    used = {w["config"] for w in bench["workloads"]}
+    assert used == {c["name"] for c in bench["configs"]}
+    for c in bench["configs"]:
+        assert c["file"].startswith(tuple(p + "/" for p in bench["paths"]))
         assert 1 <= len(c["source"]) <= 200 and 1 <= len(c["why"]) <= 200
         # what is cut is listed, and the file keeps the contract for cuts
         assert len(c["reduced"]) <= 16 and all(
             NAME.match(k) for k in c["reduced"])
-        spec.check_cut(c, spec._load_json(os.path.join(REPO, c["file"])))
+        spec.check_cut(c, spec._load_json(os.path.join(root, c["file"])))
+
+
+def test_benchmark_json_keeps_to_the_contracts_shape():
+    keeps_to_the_contracts_shape(REPO)
 
 
 def _without(config, *keys):
@@ -120,12 +128,11 @@ def test_a_cut_may_shorten_a_layer_pattern_and_never_lengthen_it():
         spec.check_cut(entry, config)
 
 
-@pytest.mark.parametrize("cell", CELLS)
-def test_every_file_a_cell_names_loads_and_agrees(cell):
-    loaded = spec.load_cell(REPO, cell)
+def every_file_the_cell_names_loads_and_agrees(root, cell):
+    loaded = spec.load_cell(root, cell)
     assert loaded.traffic["ranks"] == loaded.chips
     assert loaded.loss_n >= 10
-    builder = spec.load_plugin(REPO, "builders", loaded.builder)
+    builder = spec.load_plugin(root, "builders", loaded.builder)
     argv = builder.argv_of(loaded, 2 ** 31 + 7)
     assert "--seed" in argv and all(isinstance(a, str) for a in argv)
     reported = {m["name"] for m in loaded.end_to_end}
@@ -133,29 +140,40 @@ def test_every_file_a_cell_names_loads_and_agrees(cell):
     for m in loaded.per_layer:
         # a layer metric is reported only where the metric it moves is
         assert m["moves"] in reported, m["name"]
-        assert callable(spec.load_reader(REPO, m))
+        assert callable(spec.load_reader(root, m))
 
 
-def test_every_name_refers_to_something_that_exists():
-    end_to_end = {m["name"] for m in BENCH["end_to_end"]}
-    for m in METRICS:
-        assert set(m.get("workloads", [])) <= set(CELLS), m["name"]
-    for m in BENCH["per_layer"]:
+@pytest.mark.parametrize("cell", CELLS)
+def test_every_file_a_cell_names_loads_and_agrees(cell):
+    every_file_the_cell_names_loads_and_agrees(REPO, cell)
+
+
+def every_name_refers_to_something_that_exists(root):
+    bench = spec.load_benchmark(root)
+    cells = {w["name"] for w in bench["workloads"]}
+    end_to_end = {m["name"] for m in bench["end_to_end"]}
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        assert set(m.get("workloads", [])) <= cells, m["name"]
+    for m in bench["per_layer"]:
         assert m["moves"] in end_to_end
-        assert os.path.isfile(spec.data_path(REPO, "layer_metrics",
+        assert os.path.isfile(spec.data_path(root, "layer_metrics",
                                              m["name"]))
     # no file without an entry: a metric or cell file nobody names
     for kind, names in (("layer_metrics", {m["name"]
-                                           for m in BENCH["per_layer"]}),
-                        ("workloads", set(CELLS)),
-                        ("configs", {c["name"] for c in BENCH["configs"]}),
+                                           for m in bench["per_layer"]}),
+                        ("workloads", cells),
+                        ("configs", {c["name"] for c in bench["configs"]}),
                         ("traffic", {w["traffic"]
-                                     for w in BENCH["workloads"]})):
+                                     for w in bench["workloads"]})):
         on_disk = {f[:-5] for f in os.listdir(
-            os.path.join(REPO, "benchmark", kind)) if f.endswith(".json")}
+            os.path.join(root, "benchmark", kind)) if f.endswith(".json")}
         assert on_disk == names, kind
     with pytest.raises(KeyError, match="no workload named"):
-        spec.load_cell(REPO, "no_such_cell")
+        spec.load_cell(root, "no_such_cell")
+
+
+def test_every_name_refers_to_something_that_exists():
+    every_name_refers_to_something_that_exists(REPO)
 
 
 def test_a_later_pr_adds_files_and_entries_and_edits_none(tmp_path):

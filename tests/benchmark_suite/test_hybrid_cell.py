@@ -20,8 +20,12 @@ from stochastic_gradient_push_tpu.models.transformer import (
 
 CELL = "granite4hm_sgp_w1_t4096"
 CONFIG = "granite_4_0_h_micro"
-NEW_METRICS = ("ssm_mixer_ms", "ssd_ms", "ssd_roofline_pct")
+# the scan's metrics: the mixer, the scan, its roofline share, its kernels
+NEW_METRICS = ("ssm_mixer_ms", "ssd_ms", "ssd_roofline_pct", "ssd_kernel_ms")
+# the lists of the other LM cells that this cell joins
+JOINED = ("lm_head_ms", "flash_fwd_ms", "flash_bwd_ms")
 # every phase metric carries no list of cells: due in a cell a later PR adds
+# (a later PR may give the cell more: the set is held from below)
 UNLISTED = {"dispatch_ms", "mfu_pct", "device_idle_pct", "fwd_ms", "bwd_ms",
             "optimizer_ms", "gossip_ms", "unscoped_ms"}
 # the language model's settings of ibm-granite/granite-4.0-h-micro's
@@ -60,30 +64,40 @@ TOY_HYBRID = {
     "reference": {"logit_tolerance": 1e-4, "loss_tolerance": 1e-4}}
 
 
-def _entry(kind, name):
-    return next(e for e in spec.load_benchmark(REPO)[kind]
+def _entry(kind, name, root=REPO):
+    return next(e for e in spec.load_benchmark(root)[kind]
                 if e["name"] == name)
 
 
-def test_the_cell_and_every_file_it_names_load():
-    cell = spec.load_cell(REPO, CELL)       # check_cut runs in here
+def the_cell_and_every_file_it_names_load(root):
+    """The cell in the ``BENCHMARK.json`` at ``root``: the repo's, or a copy
+    with entries appended (test_appending.py)."""
+    cell = spec.load_cell(root, CELL)       # check_cut runs in here
     assert cell.chips == 1 and cell.builder == "hybrid_lm_trainer"
     assert cell.flags == ["--remat", "True"] and cell.loss_n == 20
     assert cell.traffic == {
         "kind": "tokens", "ranks": 1, "batch_per_rank": 1, "seq_len": 4096,
         "vocab": 25088, "zipf_exponent": 1.1, "hidden_states": 8,
         "stay": 0.9, "resident_batches": 8}
-    assert {m["name"] for m in cell.per_layer} == UNLISTED | set(NEW_METRICS)
+    names = {m["name"] for m in cell.per_layer}
+    assert names >= UNLISTED | set(NEW_METRICS) | set(JOINED)
     for m in cell.per_layer:
-        assert callable(spec.load_reader(REPO, m)), m["name"]
-    builder = spec.load_plugin(REPO, "builders", cell.builder)
+        assert callable(spec.load_reader(root, m)), m["name"]
+    builder = spec.load_plugin(root, "builders", cell.builder)
     argv = builder.argv_of(cell, 2 ** 31 + 11)
     assert argv[:4] == ["--model_json", os.path.join(
-        REPO, _entry("configs", CONFIG)["file"]), "--precision", "bf16"]
+        root, _entry("configs", CONFIG, root)["file"]), "--precision", "bf16"]
     assert argv[-2:] == ["--remat", "True"]
-    for name in NEW_METRICS:
-        entry = _entry("per_layer", name)
-        assert entry["workloads"] == [CELL] and entry["moves"] == "step_ms"
+    for name in NEW_METRICS + JOINED:
+        entry = _entry("per_layer", name, root)
+        assert CELL in entry["workloads"] and entry["moves"] == "step_ms"
+    with open(spec.data_path(root, "layer_metrics", "ssd_kernel_ms")) as f:
+        assert json.load(f)["reader"] == "program_trace:kernel_ms"
+    assert _entry("per_layer", "ssd_kernel_ms", root)["layer"] == "Kernels"
+
+
+def test_the_cell_and_every_file_it_names_load():
+    the_cell_and_every_file_it_names_load(REPO)
 
 
 def test_the_configuration_is_the_sources_but_for_what_reduced_lists():
@@ -209,6 +223,17 @@ def toy_root(tmp_path_factory):
             m["workloads"].append(TOY_CELL)
     _write(os.path.join(root, "BENCHMARK.json"), bench)
     return root
+
+
+def test_the_scans_metrics_load_in_the_toy_cell(toy_root):
+    """A cell appended to the lists finds each file and its reader."""
+    cell = spec.load_cell(toy_root, TOY_CELL)
+    loaded = {m["name"]: m for m in cell.per_layer}
+    assert set(NEW_METRICS) <= set(loaded)
+    for name in NEW_METRICS:
+        with open(spec.data_path(REPO, "layer_metrics", name)) as f:
+            assert loaded[name].get("params") == json.load(f).get("params")
+        assert callable(spec.load_reader(toy_root, loaded[name])), name
 
 
 @pytest.mark.parametrize("trace", [False, True], ids=["untraced", "traced"])

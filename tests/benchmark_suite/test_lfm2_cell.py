@@ -24,6 +24,8 @@ CONFIG = "lfm2_8b_a1b"
 NEW_METRICS = ("moe_ms", "moe_route_ms", "moe_experts_ms",
                "shortconv_mixer_ms", "moe_experts_roofline_pct",
                "moe_load_max_over_mean")
+# the lists of the other LM cells that this cell joins
+JOINED = ("lm_head_ms", "flash_fwd_ms", "flash_bwd_ms")
 # every phase metric carries no list of cells: due in a cell a later PR adds
 # (a later PR may give the cell more: the set is held from below)
 UNLISTED = {"dispatch_ms", "mfu_pct", "device_idle_pct", "fwd_ms", "bwd_ms",
@@ -66,8 +68,8 @@ TOY_MOE = {
                   "selection_gap_tolerance": 1e-4}}
 
 
-def _entry(kind, name):
-    return next(e for e in spec.load_benchmark(REPO)[kind]
+def _entry(kind, name, root=REPO):
+    return next(e for e in spec.load_benchmark(root)[kind]
                 if e["name"] == name)
 
 
@@ -76,27 +78,36 @@ def _held():
         return json.load(f)
 
 
-def test_the_cell_and_every_file_it_names_load():
-    cell = spec.load_cell(REPO, CELL)       # check_cut runs in here
+def the_cell_and_every_file_it_names_load(root):
+    """The cell in the ``BENCHMARK.json`` at ``root``: the repo's, or a copy
+    with entries appended (test_appending.py)."""
+    cell = spec.load_cell(root, CELL)       # check_cut runs in here
     assert cell.chips == 1 and cell.builder == "moe_lm_trainer"
     assert cell.flags == ["--remat", "True"] and cell.loss_n == 20
     assert cell.traffic == {
         "kind": "tokens", "ranks": 1, "batch_per_rank": 2, "seq_len": 4096,
         "vocab": 16384, "zipf_exponent": 1.1, "hidden_states": 8,
         "stay": 0.9, "resident_batches": 8}
-    assert {m["name"] for m in cell.per_layer} >= UNLISTED | set(NEW_METRICS)
+    assert {m["name"] for m in cell.per_layer} \
+        >= UNLISTED | set(NEW_METRICS) | set(JOINED)
     for m in cell.per_layer:
-        assert callable(spec.load_reader(REPO, m)), m["name"]
-    builder = spec.load_plugin(REPO, "builders", cell.builder)
+        assert callable(spec.load_reader(root, m)), m["name"]
+    builder = spec.load_plugin(root, "builders", cell.builder)
     argv = builder.argv_of(cell, 2 ** 31 + 11)
     assert argv[:4] == ["--model_json", os.path.join(
-        REPO, _entry("configs", CONFIG)["file"]), "--precision", "bf16"]
+        root, _entry("configs", CONFIG, root)["file"]), "--precision", "bf16"]
     assert argv[-2:] == ["--remat", "True"]
     for name in NEW_METRICS:
-        entry = _entry("per_layer", name)
+        entry = _entry("per_layer", name, root)
         assert CELL in entry["workloads"] and entry["moves"] == "step_ms"
         assert entry["layer"] == "Models"
-    assert len(_entry("workloads", CELL)["why"]) <= 200
+    for name in JOINED:
+        assert CELL in _entry("per_layer", name, root)["workloads"]
+    assert len(_entry("workloads", CELL, root)["why"]) <= 200
+
+
+def test_the_cell_and_every_file_it_names_load():
+    the_cell_and_every_file_it_names_load(REPO)
 
 
 def test_the_configuration_is_the_sources_but_for_what_reduced_lists():

@@ -7,6 +7,7 @@ control on the CPU."""
 
 import json
 import os
+import re
 import time
 import types
 
@@ -42,11 +43,9 @@ PUBLISHED = {
     "rope_parameters": {"rope_theta": None}}
 
 # the rule's three metrics (two scopes by files alone and the rule's
-# roofline share): the files a later benchmark change adds with their
-# entries. Entries go at the end of ``per_layer``, where
-# test_setup_metrics.py holds the seven ``setup_*`` ones, so the toy root
-# alone carries them. The accepted metrics of the flash kernels and the
-# head are the ones whose lists the cell joins.
+# roofline share): the text of their files, each entry as ``_delta_entry``
+# makes it. The accepted metrics of the flash kernels and the head are the
+# ones whose lists the cell joins.
 DELTA_METRICS = {
     "delta_mixer_ms": {
         "reader": "program_trace:scope_ms",
@@ -83,6 +82,8 @@ def _delta_entry(name, cells):
 
 JOINED = ("flash_ms", "flash_roofline_pct", "flash_fwd_ms", "flash_bwd_ms",
           "lm_head_ms")
+# the rule's kernel pair by the calls' names, beside the flash kernels'
+KERNEL_METRIC = "delta_kernel_ms"
 
 TOY_CELL = "toy_olmohyb_sgp_w1"
 TOY_OLMO = {
@@ -105,8 +106,8 @@ TOY_TRAFFIC = {"kind": "tokens", "ranks": 1, "batch_per_rank": 8,
                "hidden_states": 4, "stay": 0.9, "resident_batches": 4}
 
 
-def _entry(kind, name):
-    return next(e for e in spec.load_benchmark(REPO)[kind]
+def _entry(kind, name, root=REPO):
+    return next(e for e in spec.load_benchmark(root)[kind]
                 if e["name"] == name)
 
 
@@ -115,8 +116,10 @@ def _held():
         return json.load(f)
 
 
-def test_the_cell_and_every_file_it_names_load():
-    cell = spec.load_cell(REPO, CELL)       # check_cut runs in here
+def the_cell_and_every_file_it_names_load(root):
+    """The cell in the ``BENCHMARK.json`` at ``root``: the repo's, or a copy
+    with entries appended (test_appending.py)."""
+    cell = spec.load_cell(root, CELL)       # check_cut runs in here
     assert cell.chips == 1 and cell.builder == "olmo_hybrid_trainer"
     assert cell.flags == ["--remat", "True"] and cell.loss_n == 20
     assert cell.traffic == {
@@ -124,32 +127,51 @@ def test_the_cell_and_every_file_it_names_load():
         "vocab": 12544, "zipf_exponent": 1.1, "hidden_states": 8,
         "stay": 0.9, "resident_batches": 8}
     names = {m["name"] for m in cell.per_layer}
-    assert names >= UNLISTED | set(JOINED)
-    assert not names & set(DELTA_METRICS)
+    assert names >= UNLISTED | set(JOINED) | set(DELTA_METRICS) \
+        | {KERNEL_METRIC}
     for m in cell.per_layer:
-        assert callable(spec.load_reader(REPO, m)), m["name"]
-    builder = spec.load_plugin(REPO, "builders", cell.builder)
+        assert callable(spec.load_reader(root, m)), m["name"]
+    builder = spec.load_plugin(root, "builders", cell.builder)
     argv = builder.argv_of(cell, 2 ** 31 + 11)
     assert argv[:4] == ["--model_json", os.path.join(
-        REPO, _entry("configs", CONFIG)["file"]), "--precision", "bf16"]
+        root, _entry("configs", CONFIG, root)["file"]), "--precision", "bf16"]
     assert argv[-2:] == ["--remat", "True"]
-    assert len(_entry("workloads", CELL)["why"]) <= 200
-    assert _entry("configs", CONFIG)["source"] == \
+    assert len(_entry("workloads", CELL, root)["why"]) <= 200
+    assert _entry("configs", CONFIG, root)["source"] == \
         "https://huggingface.co/allenai/Olmo-Hybrid-7B/blob/main/config.json"
 
 
-def test_the_cell_joins_five_lists_and_moves_no_entry():
-    """The accepted ``per_layer`` entries keep their places, the seven
-    ``setup_*`` ones last; the five lists the cell joins end in it; the
-    rule's metrics have neither entry nor file yet."""
-    per_layer = spec.load_benchmark(REPO)["per_layer"]
-    names = [m["name"] for m in per_layer]
-    assert names[-7:][0] == "setup_trace_lower_s"
-    assert not set(names) & set(DELTA_METRICS)
-    for name in DELTA_METRICS:
-        assert not os.path.exists(spec.data_path(REPO, "layer_metrics", name))
+def the_rules_entries_and_lists_hold(root):
+    """The five accepted lists the cell joined hold it; the rule's metrics
+    have their entries, with the cell, and their files."""
     for name in JOINED:
-        assert _entry("per_layer", name)["workloads"][-1] == CELL
+        assert CELL in _entry("per_layer", name, root)["workloads"]
+    for name, file in DELTA_METRICS.items():
+        entry = _entry("per_layer", name, root)
+        assert CELL in entry["workloads"]
+        assert entry == _delta_entry(name, entry["workloads"])
+        with open(spec.data_path(root, "layer_metrics", name)) as f:
+            assert json.load(f) == file
+    entry = _entry("per_layer", KERNEL_METRIC, root)
+    assert CELL in entry["workloads"]
+    assert entry == {**_delta_entry(KERNEL_METRIC, entry["workloads"]),
+                     "layer": "Kernels"}
+    with open(spec.data_path(root, "layer_metrics", KERNEL_METRIC)) as f:
+        file = json.load(f)
+    assert file["reader"] == "program_trace:kernel_ms" and file["what"]
+    pattern = re.compile(file["params"]["pattern"])
+    assert all(pattern.search(n) for n in ("delta_fwd", "delta_bwd.3"))
+    assert not any(pattern.search(n) for n in ("flash_fwd.2", "delta_fwd_x"))
+
+
+def test_the_cell_and_every_file_it_names_load():
+    the_cell_and_every_file_it_names_load(REPO)
+
+
+def test_the_cell_joins_five_lists_and_moves_no_entry():
+    """What the entries hold; not where they stand, since a later change
+    appends after them."""
+    the_rules_entries_and_lists_hold(REPO)
 
 
 def test_the_configuration_is_the_sources_but_for_what_reduced_lists():
@@ -287,8 +309,8 @@ def test_the_rules_roofline_share_from_shapes_and_the_measured_time():
 @pytest.fixture(scope="module")
 def toy_root(tmp_path_factory):
     """The toy root of the other tests plus an Olmo hybrid configuration
-    and cell, added the way the real ones are, and the rule's three metrics
-    with their files, listing the toy cell alone."""
+    and cell, added the way the real ones are, and appended to the lists of
+    the rule's four metrics."""
     root = make_toy_root(str(tmp_path_factory.mktemp("olmohyb")))
     data = os.path.join(root, "benchmark")
     _write(os.path.join(data, "configs", "toy_olmo_hybrid.json"), TOY_OLMO)
@@ -304,9 +326,9 @@ def toy_root(tmp_path_factory):
     bench["workloads"].append(
         {"name": TOY_CELL, "config": "toy_olmo_hybrid",
          "traffic": "toy_tokens_v512_w1", "chips": 1, "why": "toy"})
-    for name, file in DELTA_METRICS.items():
-        _write(os.path.join(data, "layer_metrics", name + ".json"), file)
-        bench["per_layer"].append(_delta_entry(name, [TOY_CELL]))
+    for m in bench["per_layer"]:
+        if m["name"] in (*DELTA_METRICS, KERNEL_METRIC):
+            m["workloads"].append(TOY_CELL)
     _write(os.path.join(root, "BENCHMARK.json"), bench)
     return root
 
@@ -314,9 +336,10 @@ def toy_root(tmp_path_factory):
 def test_the_rules_metrics_load_in_the_toy_cell(toy_root):
     cell = spec.load_cell(toy_root, TOY_CELL)
     loaded = {m["name"]: m for m in cell.per_layer}
-    assert set(DELTA_METRICS) <= set(loaded)
+    assert set(DELTA_METRICS) | {KERNEL_METRIC} <= set(loaded)
     for name, file in DELTA_METRICS.items():
         assert loaded[name]["params"] == file["params"]
+    for name in (*DELTA_METRICS, KERNEL_METRIC):
         assert callable(spec.load_reader(toy_root, loaded[name])), name
 
 
@@ -339,7 +362,8 @@ def test_toy_olmo_hybrid_cell_runs_through_the_harness(toy_root, trace):
         # no device plane, so the scopes' readers find nothing and the
         # line leaves their metrics out
         assert {"dispatch_ms", "mfu_pct"} <= set(result["metrics"])
-        assert not set(DELTA_METRICS) & set(result["metrics"])
+        assert not (set(DELTA_METRICS) | {KERNEL_METRIC}) \
+            & set(result["metrics"])
     else:
         assert set(result["metrics"]) == {"step_ms", "step_ms_p90",
                                           "loss_at_n", "setup_s"}

@@ -1,7 +1,7 @@
-"""The seven per-layer metrics under ``setup_s`` (PR 38): their entries and
-files, a traced toy run that reports all of them from the program's set-up
-ledger, and readers that find nothing to read wherever there is no ledger
-to read."""
+"""The per-layer metrics under ``setup_s`` read from the program's set-up
+ledger (seven totals and the step store's hits): their entries and files,
+a traced toy run that reports all of them, and readers that find nothing
+to read wherever there is no ledger to read."""
 
 import json
 import os
@@ -17,6 +17,7 @@ from benchmark import harness, spec
 from stochastic_gradient_push_tpu import telemetry
 from stochastic_gradient_push_tpu.telemetry import setup_ledger
 
+# the set-up ledger's seven, side by side in this order
 SETUP_METRICS = {
     "setup_trace_lower_s": ("s", "program_span"),
     "setup_compile_s": ("s", "program_span"),
@@ -26,14 +27,45 @@ SETUP_METRICS = {
     "setup_cache_misses": ("count", "program_counter"),
     "setup_unaccounted_s": ("s", "program_span"),
 }
+# the step store's hits: rows of the ledger that no JAX build made
+STORE_HITS = "setup_step_store_hits"
+LEDGER_METRICS = {**SETUP_METRICS, STORE_HITS: ("count", "program_counter")}
+# the cells each list holds at least: a later PR appends more
 CELLS = ["resnet50_sgp_w1", "gpt2m_sgp_w1_t1024", "gpt2m_sgp_w1_t8192",
-         "resnet50_sgp_w4", "lfm2moe_sgp_w1_t4096_b2"]
+         "resnet50_sgp_w4", "lfm2moe_sgp_w1_t4096_b2",
+         "granite4hm_sgp_w1_t4096", "olmohyb_sgp_w1_t4096"]
 TOY_STEPS = 45
 
 
-def _entries(root=REPO):
-    return {m["name"]: m for m in spec.load_benchmark(root)["per_layer"]
-            if m["name"] in SETUP_METRICS}
+def entries_hold(root):
+    """The ledger's metrics in the ``BENCHMARK.json`` at ``root``: one entry
+    each with these fields and a file naming a ledger reader; the seven side
+    by side in their order; every cell of ``CELLS`` in each list, and every
+    cell a list names loads the metric."""
+    per_layer = spec.load_benchmark(root)["per_layer"]
+    names = [m["name"] for m in per_layer]
+    assert all(names.count(name) == 1 for name in LEDGER_METRICS)
+    first = names.index(next(iter(SETUP_METRICS)))
+    assert names[first:first + len(SETUP_METRICS)] == list(SETUP_METRICS)
+    entries = {m["name"]: m for m in per_layer}
+    loaded = {}
+    for name, (unit, source) in LEDGER_METRICS.items():
+        m = entries[name]
+        assert (m["unit"], m["source"]) == (unit, source)
+        better = "higher" if name == STORE_HITS else "lower"
+        assert (m["layer"], m["moves"], m["better"]) == (
+            "Entry points", "setup_s", better)
+        assert set(CELLS) <= set(m["workloads"])
+        with open(spec.data_path(root, "layer_metrics", name)) as f:
+            file = json.load(f)
+        assert file["reader"].startswith("setup_ledger:") and file["what"]
+        for cell in m["workloads"]:
+            if cell not in loaded:
+                loaded[cell] = {x["name"] for x in
+                                spec.load_cell(root, cell).per_layer}
+            assert name in loaded[cell], (name, cell)
+    with open(spec.data_path(root, "layer_metrics", STORE_HITS)) as f:
+        assert json.load(f)["params"] == {"key": "step_store_hits"}
 
 
 @pytest.fixture
@@ -57,7 +89,7 @@ def toy_root(tmp_path_factory):
     with open(path) as f:
         bench = json.load(f)
     for m in bench["per_layer"]:
-        if m["name"] in SETUP_METRICS:
+        if m["name"] in LEDGER_METRICS:
             m["workloads"] += [TOY_CELL, TOY_LM_CELL]
     with open(path, "w") as f:
         json.dump(bench, f)
@@ -74,24 +106,8 @@ def _reading(name, **values):
 
 
 def test_the_seven_are_entry_points_metrics_under_setup_s_in_five_cells():
-    entries = _entries()
-    assert set(entries) == set(SETUP_METRICS)
-    last = [m["name"] for m in spec.load_benchmark(REPO)["per_layer"]][-7:]
-    assert set(last) == set(SETUP_METRICS)      # appended, nothing moved
-    for name, (unit, source) in SETUP_METRICS.items():
-        m = entries[name]
-        assert (m["unit"], m["source"]) == (unit, source)
-        assert (m["layer"], m["moves"], m["better"]) == (
-            "Entry points", "setup_s", "lower")
-        assert m["workloads"] == CELLS
-        with open(spec.data_path(REPO, "layer_metrics", name)) as f:
-            file = json.load(f)
-        assert file["reader"].startswith("setup_ledger:") and file["what"]
-    for cell in CELLS:
-        loaded = {m["name"] for m in spec.load_cell(REPO, cell).per_layer}
-        assert set(SETUP_METRICS) <= loaded
-    granite = spec.load_cell(REPO, "granite4hm_sgp_w1_t4096").per_layer
-    assert not set(SETUP_METRICS) & {m["name"] for m in granite}
+    """Every cell, and the step store's hits beside the seven."""
+    entries_hold(REPO)
 
 
 @pytest.mark.parametrize("cell", [TOY_CELL, TOY_LM_CELL])
@@ -100,11 +116,13 @@ def test_a_traced_toy_run_reports_all_seven(toy_root, ledger, cell, capsys):
                               time.time(), min_steps=TOY_STEPS)
     printed = capsys.readouterr().out
     metrics = {k: v["value"] for k, v in result["metrics"].items()}
-    assert set(SETUP_METRICS) <= set(metrics)
-    for name, (unit, _) in SETUP_METRICS.items():
+    assert set(LEDGER_METRICS) <= set(metrics)
+    for name, (unit, _) in LEDGER_METRICS.items():
         assert result["metrics"][name]["unit"] == unit
     built = int(re.search(r"(\d+) programs built in set-up", printed)[1])
-    assert metrics["setup_programs"] == built > 0
+    # the step store keeps to a TPU: on the CPU every program is built
+    assert metrics[STORE_HITS] == 0
+    assert metrics["setup_programs"] == built + metrics[STORE_HITS] > 0
     assert result["checks"]["compilations_in_window"] == 0
     assert result["correct"] is True, result["checks"]["verdicts"]
     setup_s = result["end_to_end_of_this_run"]["setup_s"]
@@ -128,7 +146,7 @@ def test_a_traced_toy_run_reports_all_seven(toy_root, ledger, cell, capsys):
     assert ("both" in later) == ("reference" in result["checks"])
 
 
-@pytest.mark.parametrize("name", sorted(SETUP_METRICS))
+@pytest.mark.parametrize("name", sorted(LEDGER_METRICS))
 def test_with_the_ledger_unarmed_every_reader_returns_none(name):
     was_armed = setup_ledger.LEDGER.armed
     setup_ledger.disarm()
@@ -143,7 +161,7 @@ def test_with_the_ledger_unarmed_every_reader_returns_none(name):
 def test_armed_with_no_train_step_built_there_is_no_cut_to_read_up_to(
         ledger):
     ledger.phase("mesh", time.time() - 1.0, time.time())
-    for name in SETUP_METRICS:
+    for name in LEDGER_METRICS:
         reader, reading = _reading(name)
         assert reader(reading) is None
 
@@ -153,6 +171,6 @@ def test_a_program_from_before_the_ledger_reads_nothing(monkeypatch, ledger):
     fails, the reader returns nothing and the line leaves the metric out."""
     monkeypatch.delattr(telemetry, "setup_ledger")
     monkeypatch.setitem(sys.modules, setup_ledger.__name__, None)
-    for name in SETUP_METRICS:
+    for name in LEDGER_METRICS:
         reader, reading = _reading(name)
         assert reader(reading) is None
