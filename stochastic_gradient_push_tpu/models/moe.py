@@ -27,7 +27,9 @@ products (``ops/grouped_matmul.py``: Pallas kernels on a TPU,
 split and at the cost of the rows held.  The layer is told which experts
 it holds: it routes over all of them and computes its own experts' part
 of the result — what expert parallelism asks of a shard, here without
-the exchange.
+the exchange.  DeepSeek-V3's form of it (arXiv:2412.19437 §2.1.2) adds
+a scale on the normalised weights and a shared expert every token
+passes through.
 """
 
 from __future__ import annotations
@@ -132,12 +134,16 @@ class ExpertsConfig(tp.NamedTuple):
     """Sizes of the top-k expert layer, as the source's ``config.json``
     gives them.  ``held`` is the half-open range of experts whose weights
     live here (``None``: all of them); the router is ``n_experts`` wide
-    whatever is held."""
+    whatever is held.  ``scale`` multiplies the normalised weights
+    (``routed_scaling_factor``); ``d_shared`` is the width of a shared
+    expert every token passes through (0: none)."""
 
     n_experts: int = 32
     per_token: int = 4
     d_ff: int = 1792
     held: tuple[int, int] | None = None
+    scale: float = 1.0
+    d_shared: int = 0
 
     @property
     def first(self) -> int:
@@ -213,7 +219,8 @@ _rows_from_experts.defvjp(_from_experts_fwd, _from_experts_bwd)
 
 
 def topk_moe_ffn(x, router_w, bias, w_gate_up, w_down, *, per_token: int,
-                 first: int = 0, dtype=None):
+                 first: int = 0, dtype=None, scale: float = 1.0,
+                 shared=None):
     """Top-k expert feed-forward with no token dropped, over the experts
     held here.
 
@@ -226,10 +233,14 @@ def topk_moe_ffn(x, router_w, bias, w_gate_up, w_down, *, per_token: int,
         projections side by side; ``w_down``: ``[n, F, D]``.  Experts
         ``[first, first + n)`` of the ``E``.
       dtype: the products' operand dtype (``None``: ``x``'s).
+      scale: multiplies the normalised weights.
+      shared: ``(w_gate_up [D, 2 F_s], w_down [F_s, D])``, an expert
+        every token passes through at weight 1, under its own scope; it
+        is computed alike by every shard of the experts.
 
-    ``s = sigmoid(x W_g)``, ``S = top_k(s + b)``, ``g_e = s_e / (sum_S s
-    + 1e-6)``; ``y = sum_{e in S, held} g_e W_down^e
-    (silu(W_gate^e x) * W_up^e x)``.  Scores, selection and weights are
+    ``s = sigmoid(x W_g)``, ``S = top_k(s + b)``, ``g_e = scale * s_e /
+    (sum_S s + 1e-6)``; ``y = sum_{e in S, held} g_e W_down^e
+    (silu(W_gate^e x) * W_up^e x)``, plus the shared expert's output.  Scores, selection and weights are
     float32; ``S`` and the normalisation are over all ``E`` experts, and
     what the experts not held would add is left out.  Every shape is
     static: ``T * per_token`` rows whatever the split, the held pairs
@@ -238,7 +249,7 @@ def topk_moe_ffn(x, router_w, bias, w_gate_up, w_down, *, per_token: int,
 
     Returns ``(y [T, D] in x's dtype, aux)``; ``aux`` holds ``selection``
     ``[T, per_token]``, ``expert_rows`` ``[n]`` (rows each held expert
-    received) and ``pairs_not_held``.
+    received), ``pairs_not_held`` and the router's ``scores`` ``[T, E]``.
     """
     t, d = x.shape
     n, k = w_gate_up.shape[0], per_token
@@ -255,6 +266,8 @@ def topk_moe_ffn(x, router_w, bias, w_gate_up, w_down, *, per_token: int,
         chosen = selection[..., None] == jnp.arange(scores.shape[-1])
         gates = (scores[:, None, :] * chosen).sum(-1)
         gates = gates / (gates.sum(-1, keepdims=True) + 1e-6)
+        if scale != 1.0:
+            gates = gates * scale
         local = selection.T.reshape(-1) - first                # [k * T]
         held = (local >= 0) & (local < n)
         expert = jnp.where(held, local, n)
@@ -273,7 +286,21 @@ def topk_moe_ffn(x, router_w, bias, w_gate_up, w_down, *, per_token: int,
     with jax.named_scope(names.SCOPE_MOE_ROUTE):
         back = _rows_from_experts(out, order, place, held)
         y = (back.reshape(k, t, d) * gates.T[..., None]).sum(0)
+    if shared is not None:
+        with jax.named_scope(names.SCOPE_MOE_SHARED):
+            y = y + _gated_mlp(x.astype(dtype), *shared, dtype)
     aux = {"selection": selection,
            "expert_rows": sizes.astype(f32),
-           "pairs_not_held": (t * k - sizes.sum()).astype(f32)}
+           "pairs_not_held": (t * k - sizes.sum()).astype(f32),
+           "scores": scores}
     return y.astype(x.dtype), aux
+
+
+def _gated_mlp(x, w_gate_up, w_down, dtype):
+    """``W_down(silu(W_gate x) * W_up x)``: operands in ``dtype``,
+    float32 accumulation and result."""
+    dot = lambda a, w: jnp.dot(a, w.astype(dtype),
+                               preferred_element_type=jnp.float32)
+    gate, up = jnp.split(dot(x, w_gate_up), 2, axis=-1)
+    return dot((jax.nn.silu(gate) * up).astype(dtype), w_down)
+

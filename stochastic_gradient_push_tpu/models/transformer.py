@@ -33,6 +33,13 @@ first-class in-repo model family, built TPU-first:
   in place of before it (``post_norm``: ``x + norm(f(x))``, Olmo 2's
   order) and RMSNorm of q and k over the whole projection
   (``qk_norm="projection"``)
+* the ``joyai_llm_flash`` family's parts, DeepSeek-V3's (arXiv:2412.19437
+  §2.1–2.2): a ``latent_attention`` mixer (queries and keys-values through
+  low-rank latents, q·k heads of 192 beside v heads of 128, one rotated
+  64-lane key head shared by all heads, rotary in the interleaved
+  convention), top-k experts with a scale on their weights and a shared
+  expert, and a multi-token-prediction module (``mtp_layers``) whose
+  logits for the token after next the loss (train/lm.py::mtp_loss) adds
 """
 
 from __future__ import annotations
@@ -53,7 +60,8 @@ from .ssm import Mamba2Mixer, SSMConfig
 
 __all__ = ["TransformerLM", "TransformerConfig", "config_from_source"]
 
-LAYER_TYPES = ("attention", "mamba", "conv", "linear_attention")
+LAYER_TYPES = ("attention", "mamba", "conv", "linear_attention",
+               "latent_attention")
 FFN_TYPES = ("dense", "switch", "experts")
 
 
@@ -70,6 +78,32 @@ def _rope(x: jnp.ndarray, positions: jnp.ndarray,
     rotated = jnp.concatenate(
         [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return rotated.astype(x.dtype)
+
+
+def _rope_interleaved(x: jnp.ndarray, positions: jnp.ndarray,
+                      base: float) -> jnp.ndarray:
+    """Rotary embeddings in the interleaved convention: lanes ``2i`` and
+    ``2i + 1`` are the pair rotated by ``positions * base ** (-2i / D)``.
+    As the source's code does it, the lanes are first laid out evens then
+    odds, and then rotated half-split: the output is in that order, for q
+    and k alike, which leaves every product ``q · k`` as the pairs'
+    rotation gives it."""
+    *lead, d = x.shape
+    x = x.reshape(*lead, d // 2, 2)
+    x = jnp.swapaxes(x, -1, -2).reshape(*lead, d)
+    return _rope(x, positions, base)
+
+
+class MLAConfig(tp.NamedTuple):
+    """Sizes of the latent-attention mixer, as the source's ``config.json``
+    names them: ``q_lora_rank``, ``kv_lora_rank``, ``qk_nope_head_dim``,
+    ``qk_rope_head_dim``, ``v_head_dim``."""
+
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_dim: int = 128
+    qk_rope_dim: int = 64
+    v_head_dim: int = 128
 
 
 class TransformerConfig(tp.NamedTuple):
@@ -122,6 +156,9 @@ class TransformerConfig(tp.NamedTuple):
     # -- the olmo_hybrid family's parts ---------------------------------
     delta: DeltaNetConfig | None = None   # the "linear_attention" layers'
     post_norm: bool = False           # x + norm(f(x)), no norm before f
+    # -- the joyai_llm_flash family's parts -----------------------------
+    mla: MLAConfig | None = None      # the "latent_attention" layers'
+    mtp_layers: int = 0               # multi-token-prediction modules
 
     def layer_type(self, i: int) -> str:
         return self.layer_types[i] if self.layer_types else "attention"
@@ -153,6 +190,21 @@ class TransformerConfig(tp.NamedTuple):
                 and self.delta is None:
             raise ValueError("a 'linear_attention' layer needs the mixer's "
                              "sizes (TransformerConfig.delta)")
+        if "latent_attention" in (self.layer_types or ()) \
+                and self.mla is None:
+            raise ValueError("a 'latent_attention' layer needs the mixer's "
+                             "sizes (TransformerConfig.mla)")
+        if self.mtp_layers not in (0, 1):
+            raise ValueError(f"{self.mtp_layers} multi-token-prediction "
+                             "modules: the model builds 0 or 1")
+        if self.mtp_layers and (self.seq_axis is not None or self.attn_impl
+                                in ("ring", "ring_flash")):
+            # each shard would read its own first token as its last row's
+            # next one, and drop a row the whole sequence does not
+            raise ValueError("the multi-token-prediction module takes the "
+                             "next token from the rows it holds: it runs "
+                             "on an unsharded sequence only, not under "
+                             "seq_axis or ring attention")
         if "experts" in (self.ffn_types or ()):
             if self.experts is None:
                 raise ValueError("an 'experts' feed-forward needs the "
@@ -277,12 +329,62 @@ def _olmo_hybrid_config(src: dict, **runtime) -> TransformerConfig:
         positions="none", **runtime)
 
 
+def _joyai_llm_flash_config(src: dict, **runtime) -> TransformerConfig:
+    """The ``joyai_llm_flash`` family, DeepSeek-V3's layers: latent
+    attention in every layer, ``first_k_dense_replace`` SiLU-gated MLPs
+    and then top-k experts chosen by sigmoid scores and a selection bias
+    (``noaux_tc``, one group), their weights normalised and scaled by
+    ``routed_scaling_factor``, beside ``n_shared_experts`` shared ones;
+    RMSNorm, an untied head and ``num_nextn_predict_layers``
+    multi-token-prediction modules.  ``experts_held`` and
+    ``experts_routed`` are the cut file's keys, as in ``lfm2_moe``."""
+    _check_source(src, {
+        "hidden_act": ("silu",), "attention_bias": (False,),
+        "scoring_func": ("sigmoid",), "topk_method": ("noaux_tc",),
+        "n_group": (1,), "topk_group": (1,), "norm_topk_prob": (True,),
+        "moe_layer_freq": (1,), "rope_scaling": (None,),
+        "num_nextn_predict_layers": (0, 1)})
+    heads = src["num_attention_heads"]
+    if src["num_key_value_heads"] != heads:
+        raise ValueError("source config num_key_value_heads="
+                         f"{src['num_key_value_heads']!r}: latent attention "
+                         "has one key-value head a query head")
+    mla = MLAConfig(
+        q_lora_rank=src["q_lora_rank"], kv_lora_rank=src["kv_lora_rank"],
+        qk_nope_dim=src["qk_nope_head_dim"],
+        qk_rope_dim=src["qk_rope_head_dim"], v_head_dim=src["v_head_dim"])
+    if src.get("qk_head_dim", mla.qk_nope_dim + mla.qk_rope_dim) \
+            != mla.qk_nope_dim + mla.qk_rope_dim:
+        raise ValueError(f"source config qk_head_dim={src['qk_head_dim']!r}"
+                         " is not qk_nope_head_dim + qk_rope_head_dim")
+    n_layers, dense = src["num_hidden_layers"], src["first_k_dense_replace"]
+    held = src.get("experts_held")
+    experts = ExpertsConfig(
+        n_experts=src.get("experts_routed", src["n_routed_experts"]),
+        per_token=src["num_experts_per_tok"],
+        d_ff=src["moe_intermediate_size"],
+        held=tuple(held) if held is not None else None,
+        scale=float(src["routed_scaling_factor"]),
+        d_shared=src["n_shared_experts"] * src["moe_intermediate_size"])
+    mtp = src.get("num_nextn_predict_layers", 0)
+    return TransformerConfig(
+        vocab_size=src["vocab_size"], d_model=src["hidden_size"],
+        n_layers=n_layers, n_heads=heads, d_ff=src["intermediate_size"],
+        layer_types=("latent_attention",) * n_layers,
+        ffn_types=("dense",) * dense + ("experts",) * (n_layers - dense),
+        experts=experts, mla=mla, rope_theta=float(src["rope_theta"]),
+        norm="rmsnorm", norm_eps=src["rms_norm_eps"], mlp="swiglu",
+        tie_embeddings=src["tie_word_embeddings"], mtp_layers=mtp,
+        **runtime)
+
+
 # model_type -> (the family's config, the source's key of the dense width)
 SOURCE_FAMILIES = {
     "granitemoehybrid": (_granitemoehybrid_config,
                          "shared_intermediate_size"),
     "lfm2_moe": (_lfm2_moe_config, "intermediate_size"),
     "olmo_hybrid": (_olmo_hybrid_config, "intermediate_size"),
+    "joyai_llm_flash": (_joyai_llm_flash_config, "intermediate_size"),
 }
 
 
@@ -319,6 +421,48 @@ def _norm(cfg: TransformerConfig, name: str):
 
 def _scaled(x, by: float):
     return x if by == 1.0 else x * by
+
+
+def _attend(cfg: TransformerConfig, q, k, v):
+    """Causal attention of ``q``, ``k`` ``[B, H, T, d_qk]`` and ``v``
+    ``[B, H, T, d_v]`` by ``cfg.attn_impl``, at the scale ``d_qk ** -0.5``;
+    ``[B, H, T, d_v]``."""
+    if cfg.attn_impl == "ring":
+        if cfg.seq_axis is None:
+            raise ValueError("ring attention requires seq_axis")
+        return ring_attention(q, k, v, cfg.seq_axis, causal=True)
+    if cfg.attn_impl == "ring_flash":
+        # flash-kernel ticks: O(attn_block_size²) memory per device
+        # regardless of shard length — the long-context production
+        # path (ops/ring_flash.py)
+        if cfg.seq_axis is None:
+            raise ValueError("ring attention requires seq_axis")
+        from ..ops.flash_attention import default_block
+        from ..ops.ring_flash import ring_flash_attention
+        return ring_flash_attention(
+            q, k, v, cfg.seq_axis, causal=True,
+            block=cfg.attn_block_size or default_block(q.shape[2]))
+    if cfg.attn_impl == "flash":
+        from ..ops.flash_attention import flash_attention
+        return flash_attention(
+            q, k, v, causal=True,
+            block_q=cfg.attn_block_size,
+            block_k=cfg.attn_block_k or cfg.attn_block_size)
+    if cfg.attn_impl == "blockwise":
+        return blockwise_attention(
+            q, k, v, min(cfg.attn_block_size or 128, q.shape[2]),
+            causal=True)
+    if cfg.attn_impl == "full":
+        t = q.shape[2]
+        mask = jnp.tril(jnp.ones((t, t), bool))[None, None]
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                       preferred_element_type=jnp.float32)
+        s = s * q.shape[-1] ** -0.5
+        s = jnp.where(mask, s, -1e30)
+        p = jax.nn.softmax(s, axis=-1)
+        return jnp.einsum("bhqk,bhkd->bhqd", p,
+                          v.astype(jnp.float32)).astype(cfg.dtype)
+    raise ValueError(f"unknown attn_impl {cfg.attn_impl}")
 
 
 class _Attention(nn.Module):
@@ -370,48 +514,63 @@ class _Attention(nn.Module):
             k = jnp.repeat(k, cfg.n_heads // kv_heads, axis=1)
             v = jnp.repeat(v, cfg.n_heads // kv_heads, axis=1)
 
-        if cfg.attn_impl == "ring":
-            if cfg.seq_axis is None:
-                raise ValueError("ring attention requires seq_axis")
-            out = ring_attention(q, k, v, cfg.seq_axis, causal=True)
-        elif cfg.attn_impl == "ring_flash":
-            # flash-kernel ticks: O(attn_block_size²) memory per device
-            # regardless of shard length — the long-context production
-            # path (ops/ring_flash.py)
-            if cfg.seq_axis is None:
-                raise ValueError("ring attention requires seq_axis")
-            from ..ops.flash_attention import default_block
-            from ..ops.ring_flash import ring_flash_attention
-            out = ring_flash_attention(
-                q, k, v, cfg.seq_axis, causal=True,
-                block=cfg.attn_block_size or default_block(q.shape[2]))
-        elif cfg.attn_impl == "flash":
-            from ..ops.flash_attention import flash_attention
-            out = flash_attention(
-                q, k, v, causal=True,
-                block_q=cfg.attn_block_size,
-                block_k=cfg.attn_block_k or cfg.attn_block_size)
-        elif cfg.attn_impl == "blockwise":
-            out = blockwise_attention(
-                q, k, v, min(cfg.attn_block_size or 128, q.shape[2]),
-                causal=True)
-        elif cfg.attn_impl == "full":
-            t = q.shape[2]
-            mask = jnp.tril(jnp.ones((t, t), bool))[None, None]
-            s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
-                           preferred_element_type=jnp.float32)
-            s = s * head_dim ** -0.5
-            s = jnp.where(mask, s, -1e30)
-            p = jax.nn.softmax(s, axis=-1)
-            out = jnp.einsum("bhqk,bhkd->bhqd", p,
-                             v.astype(jnp.float32)).astype(cfg.dtype)
-        else:
-            raise ValueError(f"unknown attn_impl {cfg.attn_impl}")
-
+        out = _attend(cfg, q, k, v)
         b, h, s, d = out.shape
         out = out.transpose(0, 2, 1, 3).reshape(b, s, h * d)
         return nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
                         name="o")(out)
+
+
+class _LatentAttention(nn.Module):
+    """DeepSeek-V3's multi-head latent attention (arXiv:2412.19437
+    §2.1.1), trained as the source computes it (no absorbed products):
+
+    * q: ``c_q = RMSNorm(x W_qa)``, ``q = c_q W_qb``, each head
+      ``q_nope`` | ``q_pe``;
+    * kv: ``[c_kv | k_pe] = x W_kva``, ``[k_nope | v] = RMSNorm(c_kv)
+      W_kvb`` a head; ``k_pe`` is one head, rotated and shared by all;
+    * rotary (interleaved) on the ``q_pe`` / ``k_pe`` lanes alone, causal
+      attention of ``[q_nope | q_pe]`` on ``[k_nope | k_pe]`` at
+      ``(nope + rope) ** -0.5`` over ``v``, and ``W_o``.
+
+    Norms float32; the products bf16 operands where ``cfg.dtype`` is."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, positions):
+        cfg, m = self.cfg, self.cfg.mla
+        heads, d_qk = cfg.n_heads, m.qk_nope_dim + m.qk_rope_dim
+        dense = lambda width, name: nn.Dense(
+            width, use_bias=False, dtype=cfg.dtype, name=name)
+        norm = lambda name, t: nn.RMSNorm(
+            epsilon=cfg.norm_eps, dtype=jnp.float32,
+            name=name)(t).astype(cfg.dtype)
+        b, t, _ = x.shape
+
+        def split(y):  # [B, T, heads·w] -> [B, heads, T, w]
+            return y.reshape(b, t, heads, -1).transpose(0, 2, 1, 3)
+
+        with jax.named_scope(names.SCOPE_MLA):
+            q = split(dense(heads * d_qk, "q_b")(
+                norm("q_a_norm", dense(m.q_lora_rank, "q_a")(x))))
+            c_kv, k_pe = jnp.split(
+                dense(m.kv_lora_rank + m.qk_rope_dim, "kv_a")(x),
+                [m.kv_lora_rank], axis=-1)
+            kv = split(dense(heads * (m.qk_nope_dim + m.v_head_dim),
+                             "kv_b")(norm("kv_a_norm", c_kv)))
+            k_nope, v = jnp.split(kv, [m.qk_nope_dim], axis=-1)
+            q_nope, q_pe = jnp.split(q, [m.qk_nope_dim], axis=-1)
+            q_pe = _rope_interleaved(q_pe, positions, cfg.rope_theta)
+            k_pe = _rope_interleaved(k_pe[:, None], positions,
+                                     cfg.rope_theta)
+            q = jnp.concatenate([q_nope, q_pe], axis=-1)
+            k = jnp.concatenate(
+                [k_nope, jnp.broadcast_to(k_pe, q_pe.shape)], axis=-1)
+            out = _attend(cfg, q, k, v)
+            out = out.transpose(0, 2, 1, 3).reshape(
+                b, t, heads * m.v_head_dim)
+            return dense(cfg.d_model, "o")(out)
 
 
 class _MoEFFN(nn.Module):
@@ -457,10 +616,11 @@ class _MoEFFN(nn.Module):
 
 class _ExpertsFFN(nn.Module):
     """The top-k expert feed-forward (models/moe.py::topk_moe_ffn) over
-    the experts ``cfg.experts`` says are held here.  The selection bias is
-    a leaf no gradient reaches; the layer's counters are sown under
-    ``moe_metrics`` and its selection under ``moe_selection`` (a
-    collection only a comparison asks for)."""
+    the experts ``cfg.experts`` says are held here, and its shared expert
+    where it has one.  The selection bias is a leaf no gradient reaches;
+    the layer's counters are sown under ``moe_metrics``, its selection
+    under ``moe_selection`` and the router's scores under ``moe_scores``
+    (collections only a comparison or the bias's balancing asks for)."""
 
     cfg: TransformerConfig
 
@@ -477,15 +637,24 @@ class _ExpertsFFN(nn.Module):
                              jnp.float32)
         down = self.param("experts_down", per_expert,
                           (ex.n_held, ex.d_ff, cfg.d_model), jnp.float32)
+        shared = None
+        if ex.d_shared:
+            shared = (self.param("shared_gate_up",
+                                 nn.initializers.lecun_normal(),
+                                 (cfg.d_model, 2 * ex.d_shared), jnp.float32),
+                      self.param("shared_down", nn.initializers.lecun_normal(),
+                                 (ex.d_shared, cfg.d_model), jnp.float32))
         b, t, d = x.shape
         with jax.named_scope(names.SCOPE_MOE):
             y, aux = topk_moe_ffn(
                 x.reshape(b * t, d), router, bias, gate_up, down,
-                per_token=ex.per_token, first=ex.first, dtype=cfg.dtype)
+                per_token=ex.per_token, first=ex.first, dtype=cfg.dtype,
+                scale=ex.scale, shared=shared)
         self.sow("moe_metrics", "expert_rows", aux["expert_rows"])
         self.sow("moe_metrics", "pairs_not_held", aux["pairs_not_held"])
         self.sow("moe_selection", "experts",
                  aux["selection"].reshape(b, t, ex.per_token))
+        self.sow("moe_scores", "scores", aux["scores"])
         return y.reshape(b, t, d)
 
 
@@ -513,6 +682,8 @@ class _Block(nn.Module):
             mixed = GatedDeltaNetMixer(cfg.delta, cfg.d_model,
                                        dtype=cfg.dtype,
                                        norm_eps=cfg.norm_eps, name="delta")(h)
+        elif self.layer_type == "latent_attention":
+            mixed = _LatentAttention(cfg, name="mla")(h, positions)
         else:
             mixed = _Attention(cfg, name="attn")(h, positions)
         x = x + _scaled(after("ln1", mixed), res)
@@ -545,6 +716,15 @@ class TransformerLM(nn.Module):
     Under sequence sharding (``attn_impl='ring'``), ``tokens`` is this
     rank's contiguous block and global positions are derived from the
     rank's position on the sequence axis.
+
+    With ``cfg.mtp_layers`` the multi-token-prediction module
+    (arXiv:2412.19437 eq. 21–25) runs after the blocks: ``h' = W_eh
+    [RMSNorm(Emb(t_{i+1})) ; RMSNorm(h_i)]`` with ``h_i`` the last block's
+    output before the final norm, one more block (the last layer's
+    kinds), its own RMSNorm, and the shared head; its logits for the token
+    after next are sown under ``mtp`` (``train/lm.py::mtp_loss``).  The
+    last row's ``t_{i+1}`` lies past the block: it reads the first token,
+    and the loss leaves that row out.
     """
 
     cfg: TransformerConfig
@@ -574,14 +754,29 @@ class TransformerLM(nn.Module):
             x = block(cfg, ffn_type=cfg.ffn_type(i),
                       layer_type=cfg.layer_type(i),
                       name=f"block_{i}")(x, positions)
-        x = _norm(cfg, "ln_f")(x)
-        # the loss (train/lm.py::lm_loss) carries the same scope: between
-        # them they hold every pass over an array of the logits' size
-        with jax.named_scope(names.SCOPE_LM_HEAD):
-            if cfg.tie_embeddings:
-                logits = embed.attend(x)
-            else:
-                logits = nn.Dense(cfg.vocab_size, use_bias=False,
-                                  dtype=cfg.dtype, name="lm_head")(x)
-            return _scaled(jnp.asarray(logits, jnp.float32),
-                           1.0 / cfg.logits_scaling)
+        head = embed.attend if cfg.tie_embeddings else nn.Dense(
+            cfg.vocab_size, use_bias=False, dtype=cfg.dtype, name="lm_head")
+
+        def logits_of(h):
+            # the loss (train/lm.py::lm_loss) carries the same scope:
+            # between them they hold every pass over an array of the
+            # logits' size
+            with jax.named_scope(names.SCOPE_LM_HEAD):
+                return _scaled(jnp.asarray(head(h), jnp.float32),
+                               1.0 / cfg.logits_scaling)
+
+        if cfg.mtp_layers:
+            last = cfg.n_layers - 1
+            with jax.named_scope(names.SCOPE_MTP):
+                ahead = _scaled(embed(jnp.roll(tokens, -1, axis=1)),
+                                cfg.embedding_multiplier)
+                h = nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
+                             name="eh_proj")(jnp.concatenate(
+                    [_norm(cfg, "mtp_enorm")(ahead),
+                     _norm(cfg, "mtp_hnorm")(x)], axis=-1))
+                h = block(cfg, ffn_type=cfg.ffn_type(last),
+                          layer_type=cfg.layer_type(last),
+                          name="mtp_block")(h, positions)
+                h = _norm(cfg, "mtp_norm")(h)
+            self.sow("mtp", "logits", logits_of(h))
+        return logits_of(_norm(cfg, "ln_f")(x))

@@ -53,6 +53,14 @@ wherever :func:`fused_backward_fits`:
   operands, same casts, same order of accumulation: the two give the
   same bits.
 
+Values may be narrower or wider than queries and keys (latent attention
+takes q·k heads of 192 beside v heads of 128): ``v``, ``out``, ``do`` and
+``dv`` are ``d_v`` wide, ``q``, ``k``, ``dq`` and ``dk`` ``d_qk``, and the
+softmax scale is ``d_qk ** -0.5``.  The fused backward takes one width
+only; at ``d_qk`` over 128 its shape rule hands the pair the call anyway.
+A call with ``d_v == d_qk`` builds the kernels it built before widths
+could differ.
+
 The per-row residuals travel in compact ``[rows, 1]`` layouts: the
 forward's logsumexp and ``delta = rowsum(do · o)``, the latter computed
 once outside the kernels (a fused XLA elementwise-reduce) so ``o`` is not
@@ -252,10 +260,10 @@ def _lane_partials(p):
 def _flash_fwd_kernel(i, q_blocks, k_blocks, q_ref, k_ref, v_ref, o_ref,
                       *rest, block_q: int, block_k: int, causal: bool,
                       return_lse: bool):
-    """One (batch·head, visit) cell, visits q-block-major.  Refs: q/o
-    [block_q, d]; k/v [block_k, d] (streamed); lse (when requested)
-    [block_q, SCALAR_COLS]; scratch m/den [block_q, 128] and
-    acc [block_q, d], all fp32, persistent across a q-block's visits; m
+    """One (batch·head, visit) cell, visits q-block-major.  Refs: q
+    [block_q, d_qk], o [block_q, d_v]; k [block_k, d_qk], v [block_k, d_v]
+    (streamed); lse (when requested) [block_q, SCALAR_COLS]; scratch
+    m/den [block_q, 128] and acc [block_q, d_v], all fp32, persistent across a q-block's visits; m
     holds a row's maximum in every lane and den lane partials
     (``_STATE_LANES``), so the row maximum is a visit's one cross-lane
     reduction."""
@@ -287,7 +295,8 @@ def _flash_fwd_kernel(i, q_blocks, k_blocks, q_ref, k_ref, v_ref, o_ref,
     part = _lane_partials(p)                               # [bq, w]
     w = part.shape[-1]
     den_ref[:, :w] = den_ref[:, :w] * alpha[:, :w] + part
-    acc_ref[:] = acc_ref[:] * _lanes(alpha, d) + jax.lax.dot_general(
+    acc_ref[:] = acc_ref[:] * _lanes(alpha, acc_ref.shape[-1]) \
+        + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     m_ref[:] = m_new
@@ -304,7 +313,8 @@ def flash_attention_forward(q, k, v, causal: bool = False,
                             block_q: int = 128, block_k: int = 128,
                             interpret: bool = False,
                             return_lse: bool = False):
-    """Pallas forward.  q/k/v: ``[batch, heads, seq, head_dim]``.
+    """Pallas forward.  q/k: ``[batch, heads, seq, d_qk]``, v
+    ``[batch, heads, seq, d_v]``; the output is ``d_v`` wide.
 
     With ``return_lse`` also returns the row logsumexp ``[b, h, seq]``
     (float32), the residual the fused backward kernels consume.
@@ -316,16 +326,18 @@ def flash_attention_forward(q, k, v, causal: bool = False,
         raise ValueError(f"block sizes ({block_q}, {block_k}) must divide "
                          f"seq {t}")
 
+    d_v = v.shape[-1]
     qf = q.reshape(b * h, t, d)
     kf = k.reshape(b * h, t, d)
-    vf = v.reshape(b * h, t, d)
+    vf = v.reshape(b * h, t, d_v)
 
     kernel = functools.partial(
         _flash_fwd_kernel, block_q=block_q, block_k=block_k,
         causal=causal, return_lse=return_lse)
     q_row, k_row, s_row = _row_specs(d, block_q, block_k)
-    out_specs = [q_row]
-    out_shape = [_sds((b * h, t, d), q.dtype, qf)]
+    o_row, v_row, _ = _row_specs(d_v, block_q, block_k)
+    out_specs = [o_row]
+    out_shape = [_sds((b * h, t, d_v), q.dtype, qf)]
     if return_lse:
         out_specs.append(s_row)
         out_shape.append(_sds((b * h, t, SCALAR_COLS), jnp.float32,
@@ -333,20 +345,20 @@ def flash_attention_forward(q, k, v, causal: bool = False,
     results = _visit_call(
         kernel, names.KERNEL_FLASH_FWD,
         tile_visits(t, block_q, block_k, causal, "q"), (qf, kf, vf),
-        in_specs=[q_row, k_row, k_row],
+        in_specs=[q_row, k_row, v_row],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((block_q, _STATE_LANES), jnp.float32),
             pltpu.VMEM((block_q, _STATE_LANES), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, d_v), jnp.float32),
         ],
         interpret=interpret)
     if return_lse:
         out, lse = results
-        return out.reshape(b, h, t, d), lse[..., 0].reshape(b, h, t)
+        return out.reshape(b, h, t, d_v), lse[..., 0].reshape(b, h, t)
     out, = results
-    return out.reshape(b, h, t, d)
+    return out.reshape(b, h, t, d_v)
 
 
 def _tile_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, kj,
@@ -436,9 +448,10 @@ def _flash_bwd_kernel(i, q_blocks, k_blocks, k_ref, v_ref, q_ref, do_ref,
 def _flash_dq_kernel(i, q_blocks, k_blocks, q_ref, k_ref, v_ref, do_ref,
                      lse_ref, delta_ref, dq_ref, acc_ref, *, block_q: int,
                      block_k: int, causal: bool):
-    """dQ cell (bh, visit), visits q-block-major.  Refs: q/do/dq
-    [block_q, d]; k/v [block_k, d] (streamed); lse/delta
-    [block_q, SCALAR_COLS]; scratch acc [block_q, d] fp32."""
+    """dQ cell (bh, visit), visits q-block-major.  Refs: q/dq
+    [block_q, d_qk], do [block_q, d_v]; k [block_k, d_qk], v
+    [block_k, d_v] (streamed); lse/delta [block_q, SCALAR_COLS]; scratch
+    acc [block_q, d_qk] fp32."""
     qi, kj, first, last = _visit(i, q_blocks, k_blocks, q_blocks)
 
     @pl.when(first)
@@ -458,10 +471,10 @@ def _flash_dq_kernel(i, q_blocks, k_blocks, q_ref, k_ref, v_ref, do_ref,
 def _flash_dkv_kernel(i, q_blocks, k_blocks, k_ref, v_ref, q_ref, do_ref,
                       lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
                       block_q: int, block_k: int, causal: bool):
-    """dK/dV cell (bh, visit), visits k-block-major.  Refs: k/v/dk/dv
-    [block_k, d]; q/do [block_q, d] (streamed); lse/delta
-    [block_q, SCALAR_COLS]; scratch dk/dv accumulators [block_k, d]
-    fp32."""
+    """dK/dV cell (bh, visit), visits k-block-major.  Refs: k/dk
+    [block_k, d_qk], v/dv [block_k, d_v]; q [block_q, d_qk], do
+    [block_q, d_v] (streamed); lse/delta [block_q, SCALAR_COLS]; scratch
+    dk/dv accumulators as wide as dk/dv, fp32."""
     qi, kj, first, last = _visit(i, q_blocks, k_blocks, k_blocks)
 
     @pl.when(first)
@@ -489,7 +502,8 @@ def fused_backward_fits(t: int, d: int) -> bool:
     up to 128, eight bytes a lane: an fp32 accumulator under a two-deep
     16-bit dq block, or a two-deep fp32 dq block accumulated in place.
     Wider heads stay with the pair: their tiles leave dq no such room
-    (t4096 / d256 fp32 is refused fused and compiles as the pair)."""
+    (t4096 / d256 fp32 is refused fused and compiles as the pair).  ``d``
+    is the q·k width."""
     return d <= 128 and t * 128 * 8 <= FUSED_BWD_VMEM_BUDGET
 
 
@@ -498,7 +512,9 @@ def flash_attention_backward(q, k, v, out, lse, do, causal: bool = False,
                              interpret: bool = False):
     """Pallas backward: returns ``(dq, dk, dv)``, from one fused kernel
     where :func:`fused_backward_fits` and from the dq + dk/dv pair where
-    the sequence is too long for it.
+    the sequence is too long or the heads too wide for it.  ``v``,
+    ``out`` and ``do`` may be narrower or wider than ``q`` and ``k``;
+    the fused kernel refuses that with ``ValueError``.
 
     ``lse`` is the forward's row logsumexp ``[b, h, seq]``; it and
     ``delta = rowsum(do · out)`` (computed here, once, as a fused XLA
@@ -513,10 +529,11 @@ def flash_attention_backward(q, k, v, out, lse, do, causal: bool = False,
         raise ValueError(f"block sizes ({block_q}, {block_k}) must divide "
                          f"seq {t}")
 
+    d_v = v.shape[-1]
     qf = q.reshape(b * h, t, d)
     kf = k.reshape(b * h, t, d)
-    vf = v.reshape(b * h, t, d)
-    dof = do.reshape(b * h, t, d)
+    vf = v.reshape(b * h, t, d_v)
+    dof = do.reshape(b * h, t, d_v)
     lsef = lse.reshape(b * h, t)[..., None]  # [b*h, t, SCALAR_COLS]
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1).reshape(b * h, t)[..., None]
@@ -526,7 +543,7 @@ def flash_attention_backward(q, k, v, out, lse, do, causal: bool = False,
     dq, dk, dv = backward(qf, kf, vf, dof, lsef, delta, causal, block_q,
                           block_k, interpret)
     return (dq.reshape(b, h, t, d), dk.reshape(b, h, t, d),
-            dv.reshape(b, h, t, d))
+            dv.reshape(b, h, t, d_v))
 
 
 def _backward_fused(qf, kf, vf, dof, lsef, delta, causal, block_q,
@@ -535,6 +552,9 @@ def _backward_fused(qf, kf, vf, dof, lsef, delta, causal, block_q,
     ``p`` and ``ds`` computed once, dk/dv written a k-block, dq carried in
     VMEM over all the visits of one bh and written once."""
     bh, t, d = qf.shape
+    if vf.shape[-1] != d:
+        raise ValueError(f"the fused backward takes one head width: q·k "
+                         f"{d}, v {vf.shape[-1]}")
     q_row, k_row, s_row = _row_specs(d, block_q, block_k)
     return _visit_call(
         functools.partial(_flash_bwd_kernel, block_q=block_q,
@@ -568,14 +588,16 @@ def _backward_pair(qf, kf, vf, dof, lsef, delta, causal, block_q, block_k,
     over the k-block-major ones: VMEM O(block²) at any length the visit
     lists fit SMEM at, every tile's ``p`` and ``ds`` computed twice."""
     bh, t, d = qf.shape
+    d_v = vf.shape[-1]
     q_row, k_row, s_row = _row_specs(d, block_q, block_k)
+    o_row, v_row, _ = _row_specs(d_v, block_q, block_k)
     dq = _visit_call(
         functools.partial(_flash_dq_kernel, block_q=block_q,
                           block_k=block_k, causal=causal),
         names.KERNEL_FLASH_DQ,
         tile_visits(t, block_q, block_k, causal, "q"),
         (qf, kf, vf, dof, lsef, delta),
-        in_specs=[q_row, k_row, k_row, q_row, s_row, s_row],
+        in_specs=[q_row, k_row, v_row, o_row, s_row, s_row],
         out_specs=q_row,
         out_shape=_sds((bh, t, d), qf.dtype, qf),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
@@ -587,15 +609,15 @@ def _backward_pair(qf, kf, vf, dof, lsef, delta, causal, block_q, block_k,
         names.KERNEL_FLASH_DKV,
         tile_visits(t, block_q, block_k, causal, "k"),
         (kf, vf, qf, dof, lsef, delta),
-        in_specs=[k_row, k_row, q_row, q_row, s_row, s_row],
-        out_specs=[k_row, k_row],
+        in_specs=[k_row, v_row, q_row, o_row, s_row, s_row],
+        out_specs=[k_row, v_row],
         out_shape=[
             _sds((bh, t, d), kf.dtype, kf),
-            _sds((bh, t, d), vf.dtype, vf),
+            _sds((bh, t, d_v), vf.dtype, vf),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d_v), jnp.float32),
         ],
         interpret=interpret)
     return dq, dk, dv

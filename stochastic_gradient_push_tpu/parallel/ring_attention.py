@@ -128,6 +128,8 @@ def blockwise_attention(q, k, v, block_size: int, causal: bool = False):
     no mesh): the local building block and the test oracle's counterpart.
 
     Shapes: ``[batch, heads, seq, head_dim]``; ``seq % block_size == 0``.
+    ``v`` may be narrower or wider than ``q`` and ``k``; the output is as
+    wide as ``v`` and the scale is ``q``'s width ``** -0.5``.
     """
     b, h, t, d = q.shape
     if t % block_size:
@@ -136,7 +138,7 @@ def blockwise_attention(q, k, v, block_size: int, causal: bool = False):
     qf = q.astype(jnp.float32)
 
     k_blocks = k.reshape(b, h, n_blocks, block_size, d)
-    v_blocks = v.reshape(b, h, n_blocks, block_size, d)
+    v_blocks = v.reshape(b, h, n_blocks, block_size, v.shape[-1])
 
     def body(state, blk_idx):
         k_blk = k_blocks[:, :, blk_idx]
@@ -152,6 +154,9 @@ def blockwise_attention(q, k, v, block_size: int, causal: bool = False):
         return _merge(state, m2, num2, den2), None
 
     zeros_bht = jnp.sum(qf * 0.0, axis=-1)
-    init = (zeros_bht + NEG_INF, jnp.zeros_like(qf), zeros_bht)
+    m = zeros_bht + NEG_INF
+    num = jnp.zeros_like(qf) if v.shape[-1] == d \
+        else zeros_bht[..., None] * jnp.zeros(v.shape[-1], jnp.float32)
+    init = (m, num, zeros_bht)
     (m, num, den), _ = lax.scan(body, init, jnp.arange(n_blocks))
     return (num / den[..., None]).astype(q.dtype)

@@ -44,10 +44,16 @@ SCOPE_MOE = "lm.moe"                     # router to combine
 SCOPE_MOE_ROUTE = "lm.moe.route"         # nested: scores, top-k, sort,
 #                                          the rows' gather and scatter
 SCOPE_MOE_EXPERTS = "lm.moe.experts"     # nested: the grouped products
+SCOPE_MOE_SHARED = "lm.moe.shared"       # nested: the shared expert
 # -- the gated delta-rule mixer (models/gated_deltanet.py; its convolution
 # is under SCOPE_CONV1D too) -----------------------------------------------
 SCOPE_DELTA_MIXER = "lm.delta_mixer"     # the whole mixer, projections in
 SCOPE_DELTA_RULE = "lm.delta_rule"       # nested: the chunked rule alone
+# -- latent attention (models/transformer.py::_LatentAttention) and the
+# multi-token-prediction module (its norms, eh_proj and block; its head
+# pass is under SCOPE_LM_HEAD) -------------------------------------------
+SCOPE_MLA = "lm.mla"                     # the whole mixer, projections in
+SCOPE_MTP = "lm.mtp"
 # -- the head (models/transformer.py) and the loss (train/lm.py::lm_loss):
 # every operation over an array of the logits' size ----------------------
 SCOPE_LM_HEAD = "lm.head"                # the head's product, the loss

@@ -46,7 +46,7 @@ __all__ = ["SEQ_AXIS", "TP_AXIS", "EP_AXIS", "make_dp_sp_mesh",
            "make_dp_ep_sp_tp_mesh",
            "build_lm_train_step", "shard_lm_train_step",
            "build_lm_eval_step", "shard_lm_eval_step",
-           "shard_scanned_lm_step", "lm_loss",
+           "shard_scanned_lm_step", "lm_loss", "mtp_loss",
            "init_lm_state", "apply_tp_sharding", "tp_sharding_tree",
            "ep_tp_sharding_tree",
            "init_lm_state_tp", "ep_state_specs", "init_lm_state_ep"]
@@ -297,12 +297,35 @@ def lm_loss(logits: jnp.ndarray, targets: jnp.ndarray) -> jnp.ndarray:
     55 ms of GPT-2 medium's 397 ms step (PERF.md §6, PR 34).
     """
     with jax.named_scope(names.SCOPE_LM_HEAD):
-        logits = jnp.asarray(logits, jnp.float32)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        ids = lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
-        tgt = jnp.sum(jnp.where(ids == targets[..., None], logits, 0.0),
-                      axis=-1)
-        return jnp.mean(lse - tgt)
+        return jnp.mean(_token_losses(logits, targets))
+
+
+def _token_losses(logits, targets):
+    """``logsumexp - target_logit`` a position, ``[B, T]`` float32."""
+    logits = jnp.asarray(logits, jnp.float32)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    ids = lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
+    tgt = jnp.sum(jnp.where(ids == targets[..., None], logits, 0.0),
+                  axis=-1)
+    return lse - tgt
+
+
+# the weight of the multi-token-prediction loss, which no config.json
+# gives: DeepSeek-V3's for its first 10 T tokens (arXiv:2412.19437 §4.2)
+MTP_LOSS_WEIGHT = 0.3
+
+
+def mtp_loss(logits: jnp.ndarray, targets: jnp.ndarray) -> jnp.ndarray:
+    """The multi-token-prediction module's cross-entropy: position ``i``'s
+    logits predict the token after next, ``targets[i + 1]``; the last
+    position's lies past the block and is left out of the mean.  The step
+    adds it to the trunk's at ``MTP_LOSS_WEIGHT``."""
+    with jax.named_scope(names.SCOPE_LM_HEAD):
+        t = targets.shape[-1]
+        losses = _token_losses(logits, jnp.roll(targets, -1, axis=-1))
+        kept = jnp.arange(t) < t - 1
+        return jnp.sum(jnp.where(kept, losses, 0.0)) / (
+            losses.size // t * (t - 1))
 
 
 def _sown(collection, name: str) -> list:
@@ -352,9 +375,12 @@ def build_lm_train_step(model, algorithm: GossipAlgorithm, tx, lr_schedule,
             with jax.named_scope(names.SCOPE_FORWARD):
                 logits, mutated = model.apply(
                     {"params": p}, toks, train=True,
-                    mutable=["losses", "moe_metrics"])
+                    mutable=["losses", "moe_metrics", "mtp"])
                 ce = lm_loss(logits, tgts)
                 loss = ce
+                ahead = _sown(mutated.get("mtp", {}), "logits")
+                if ahead:
+                    loss = loss + MTP_LOSS_WEIGHT * mtp_loss(ahead[0], tgts)
                 sown = jax.tree.leaves(mutated.get("losses", {}))
                 if sown:
                     loss = loss + moe_loss_coef * sum(

@@ -219,6 +219,30 @@ def test_flash_compiles_at_the_longest_visit_lists(one_chip):
         names.KERNEL_FLASH_DKV}
 
 
+def test_latent_widths_compile_as_the_pair(one_chip, on_tpu):
+    """Latent attention's call (joyai_sgp_w1_t8192: 32 heads, q·k 192
+    beside v 128, 8192 tokens) through ``jax.grad`` at the auto block:
+    the forward with a value block narrower than the query's, and the
+    shape rule sends the backward to the dq + dk/dv pair."""
+    t, heads = 8192, 32
+    assert not fused_backward_fits(t, 192)
+    qk = jax.ShapeDtypeStruct((1, heads, t, 192), jnp.bfloat16,
+                              sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, heads, t, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        with jax.named_scope(names.SCOPE_FORWARD):
+            return flash_attention(q, k, v, causal=True).astype(
+                jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        qk, qk, v).compile().as_text()
+    assert _kernel_names(text) == {
+        names.KERNEL_FLASH_FWD, names.KERNEL_FLASH_DQ,
+        names.KERNEL_FLASH_DKV}
+
+
 @pytest.mark.parametrize("d", [64, 128])
 def test_fused_backward_compiles_at_the_budget_in_fp32(one_chip, d):
     """The shape rule knows ``t`` and ``d`` only, so the longest sequence
