@@ -42,24 +42,25 @@ wherever :func:`fused_backward_fits`:
   a ``[seq, d]`` accumulator that lives for the whole sweep of one bh and
   is written once.
   Five products a tile, every operand read once.  VMEM is O(block²)
-  plus dq for one sequence, which is what the shape rule budgets.
-* Beyond the budget (``seq`` over 8192, heads wider than 128) the
-  dq + dk/dv PAIR: a dQ kernel, visits q-block-major as the forward's,
-  streams k/v and accumulates ``dq``; a dK/dV kernel on the fused
-  kernel's visits accumulates ``dk`` and ``dv``.  Each recomputes ``p``
-  and ``ds`` — seven products a tile, operands read twice — but VMEM
-  stays O(block²) at any length; what grows with the length is the
-  visit list in SMEM (:func:`tile_visits` gives the limit).  Same
-  operands, same casts, same order of accumulation: the two give the
-  same bits.
+  plus dq for one sequence, which is what the shape rule reckons: heads
+  of one register take the default scope, wider heads ask for twice it.
+* Beyond that (``seq`` over 8192, or heads so wide the larger scope
+  cannot hold their dq and tiles) the dq + dk/dv PAIR: a dQ kernel,
+  visits q-block-major as the forward's, streams k/v and accumulates
+  ``dq``; a dK/dV kernel on the fused kernel's visits accumulates ``dk``
+  and ``dv``.  Each recomputes ``p`` and ``ds`` — seven products a tile,
+  operands read twice — but VMEM stays O(block²) at any length; what
+  grows with the length is the visit list in SMEM (:func:`tile_visits`
+  gives the limit).  Same operands, same casts, same order of
+  accumulation: the two give the same bits.
 
 Values may be narrower or wider than queries and keys (latent attention
 takes q·k heads of 192 beside v heads of 128): ``v``, ``out``, ``do`` and
 ``dv`` are ``d_v`` wide, ``q``, ``k``, ``dq`` and ``dk`` ``d_qk``, and the
-softmax scale is ``d_qk ** -0.5``.  The fused backward takes one width
-only; at ``d_qk`` over 128 its shape rule hands the pair the call anyway.
-A call with ``d_v == d_qk`` builds the kernels it built before widths
-could differ.
+softmax scale is ``d_qk ** -0.5``: every kernel takes the two widths,
+the fused backward too (latent attention's 192 / 128 at 8192 tokens is
+fused, in the wider scope).  A call with ``d_v == d_qk`` up to 128 builds
+the kernels it built before widths could differ.
 
 The per-row residuals travel in compact ``[rows, 1]`` layouts: the
 forward's logsumexp and ``delta = rowsum(do · o)``, the latter computed
@@ -88,7 +89,7 @@ from ..telemetry import names
 
 __all__ = ["flash_attention", "flash_attention_forward",
            "flash_attention_backward", "fused_backward_fits",
-           "tile_visits"]
+           "fused_backward_vmem", "tile_visits"]
 
 NEG_INF = -1e30
 
@@ -123,11 +124,18 @@ def default_block(t: int) -> int:
     return min(128, t)
 
 
-# What the fused backward may keep in VMEM on top of its tiles: half of
-# the 16 MiB a v5e kernel may scope, which holds dq for 8192 tokens; the
-# tiles' 512 × 512 fp32 intermediates and operand blocks need most of the
-# other half (tests/test_chip_compile.py compiles both sides of it).
+# What the fused backward may keep in VMEM on top of its tiles where the
+# heads take one register a row: half of the 16 MiB a v5e kernel scopes
+# by default, which holds dq for 8192 tokens; the tiles' 512 × 512 fp32
+# intermediates and operand blocks need most of the other half
+# (tests/test_chip_compile.py compiles both sides of it).
 FUSED_BWD_VMEM_BUDGET = 8 * 2 ** 20
+# The scoped VMEM a call with heads over one register asks for, of the
+# v5e's 128 MiB: twice the default, since a dq row takes two registers
+# (dq for 8192 tokens at 192 lanes is 16 MiB).  The whole of what the
+# kernel holds must fit it (:func:`fused_backward_vmem`).
+FUSED_BWD_WIDE_VMEM = 32 * 2 ** 20
+_LANES = 128
 
 
 def _sds(shape, dtype, *like):
@@ -180,7 +188,8 @@ def _visit(i, q_blocks, k_blocks, rows):
 
 
 def _visit_call(kernel, name: str, visits, operands, in_specs, out_specs,
-                out_shape, scratch_shapes, interpret: bool):
+                out_shape, scratch_shapes, interpret: bool,
+                vmem_limit: int | None = None):
     """``kernel`` over ``operands`` ``[bh, ...]`` on grid (batch·head,
     visit): the two visit arrays go first, by scalar prefetch, so every
     index map is ``(bh, i, q_blocks, k_blocks)`` and the kernel takes the
@@ -195,7 +204,9 @@ def _visit_call(kernel, name: str, visits, operands, in_specs, out_specs,
     arrays to vary moves the refusal into the index maps'
     ``dynamic_slice``: PERF.md §6, PR 37); a ``cond``'s branches are not
     typed, so an interpreted body runs in one that is always taken — what
-    the rectangle's gate on the whole tile body did unnoticed."""
+    the rectangle's gate on the whole tile body did unnoticed.
+    ``vmem_limit`` is the scoped VMEM the compiled call asks for; ``None``
+    leaves the compiler's default."""
     def cell(*refs):
         i = pl.program_id(1)
         pl.when(i >= 0 if interpret else True)(lambda: kernel(i, *refs))
@@ -211,7 +222,8 @@ def _visit_call(kernel, name: str, visits, operands, in_specs, out_specs,
             scratch_shapes=scratch_shapes),
         out_shape=out_shape,
         compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit),
         interpret=interpret,
         name=name)(*visits, *operands)
 
@@ -407,10 +419,11 @@ def _flash_bwd_kernel(i, q_blocks, k_blocks, k_ref, v_ref, q_ref, do_ref,
                       dv_acc, *dq_scratch, block_q: int, block_k: int,
                       causal: bool):
     """Fused backward cell (bh, visit), visits k-block-major.  Refs:
-    k/v/dk/dv [block_k, d]; q/do [block_q, d] (streamed); lse/delta
-    [block_q, SCALAR_COLS]; dq [t, d], one block a bh.  Scratch, fp32:
-    dk/dv accumulators [block_k, d], alive over one k-block's visits, and
-    the dq accumulator [t, d], alive over all the visits of one bh — the
+    k/dk [block_k, d_qk], v/dv [block_k, d_v]; q [block_q, d_qk], do
+    [block_q, d_v] (streamed); lse/delta [block_q, SCALAR_COLS]; dq
+    [t, d_qk], one block a bh.  Scratch, fp32: dk/dv accumulators as wide
+    as dk/dv, alive over one k-block's visits, and the dq accumulator
+    [t, d_qk], alive over all the visits of one bh — the
     dq block itself where dq is fp32, which is resident for just that
     sweep."""
     qi, kj, first, last = _visit(i, q_blocks, k_blocks, k_blocks)
@@ -495,16 +508,49 @@ def _flash_dkv_kernel(i, q_blocks, k_blocks, k_ref, v_ref, q_ref, do_ref,
         dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def fused_backward_fits(t: int, d: int) -> bool:
-    """The backward's shape rule: one fused kernel while dq for the whole
-    sequence stays inside :data:`FUSED_BWD_VMEM_BUDGET`, the dq + dk/dv
-    pair beyond.  A row of dq takes a 128-lane register at any head size
-    up to 128, eight bytes a lane: an fp32 accumulator under a two-deep
-    16-bit dq block, or a two-deep fp32 dq block accumulated in place.
-    Wider heads stay with the pair: their tiles leave dq no such room
-    (t4096 / d256 fp32 is refused fused and compiles as the pair).  ``d``
-    is the q·k width."""
-    return d <= 128 and t * 128 * 8 <= FUSED_BWD_VMEM_BUDGET
+def _lanes_of(d: int) -> int:
+    return -(-d // _LANES) * _LANES
+
+
+def fused_backward_vmem(t: int, d_qk: int, d_v: int, block_q: int,
+                        block_k: int, dtype) -> int:
+    """Bytes of VMEM the fused backward holds at a shape, every row
+    padded to whole 128-lane registers: dq for the whole sequence, eight
+    bytes a lane (an fp32 accumulator under a two-deep 16-bit block, or a
+    two-deep fp32 block accumulated in place); the streamed q, k, v, do,
+    lse and delta blocks and the dk, dv blocks, two deep; the fp32 dk/dv
+    accumulators; and four fp32 ``[block_q, block_k]`` tiles (``s``,
+    ``p``, ``dp``, ``ds``).  Against the chip's compiler (a described v5e)
+    it errs high by at most a MiB: 24 MiB reckoned where 23 compile at
+    8192 tokens, q·k 192, v 128, bf16, blocks of 512."""
+    size = jnp.dtype(dtype).itemsize
+    w_qk, w_v = _lanes_of(d_qk), _lanes_of(d_v)
+    dq = t * w_qk * 8
+    streamed = 2 * size * (block_q + block_k) * (w_qk + w_v)
+    residuals = 2 * 2 * block_q * _LANES * 4
+    dkv_out = 2 * size * block_k * (w_qk + w_v)
+    dkv_acc = 4 * block_k * (w_qk + w_v)
+    tiles = 4 * 4 * block_q * block_k
+    return dq + streamed + residuals + dkv_out + dkv_acc + tiles
+
+
+def fused_backward_fits(t: int, d_qk: int, d_v: int | None = None,
+                        block_q: int = 512, block_k: int = 512,
+                        dtype=jnp.bfloat16) -> bool:
+    """The backward's shape rule: one fused kernel while what it holds in
+    VMEM fits the scope the call asks for, the dq + dk/dv pair beyond.
+    Heads of one register (``d_qk`` and ``d_v`` up to 128) ask for none:
+    dq for the whole sequence must stay inside
+    :data:`FUSED_BWD_VMEM_BUDGET`, 8192 tokens at any dtype and block.
+    Wider heads ask for :data:`FUSED_BWD_WIDE_VMEM`, which all of
+    :func:`fused_backward_vmem` must fit: latent attention's 192 / 128 at
+    8192 tokens does, at 16384 tokens or at heads of 384 lanes it does
+    not.  ``d_v`` defaults to ``d_qk``."""
+    d_v = d_qk if d_v is None else d_v
+    if max(d_qk, d_v) <= _LANES:
+        return t * _LANES * 8 <= FUSED_BWD_VMEM_BUDGET
+    return fused_backward_vmem(t, d_qk, d_v, block_q, block_k,
+                               dtype) <= FUSED_BWD_WIDE_VMEM
 
 
 def flash_attention_backward(q, k, v, out, lse, do, causal: bool = False,
@@ -513,8 +559,7 @@ def flash_attention_backward(q, k, v, out, lse, do, causal: bool = False,
     """Pallas backward: returns ``(dq, dk, dv)``, from one fused kernel
     where :func:`fused_backward_fits` and from the dq + dk/dv pair where
     the sequence is too long or the heads too wide for it.  ``v``,
-    ``out`` and ``do`` may be narrower or wider than ``q`` and ``k``;
-    the fused kernel refuses that with ``ValueError``.
+    ``out`` and ``do`` may be narrower or wider than ``q`` and ``k``.
 
     ``lse`` is the forward's row logsumexp ``[b, h, seq]``; it and
     ``delta = rowsum(do · out)`` (computed here, once, as a fused XLA
@@ -538,48 +583,50 @@ def flash_attention_backward(q, k, v, out, lse, do, causal: bool = False,
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1).reshape(b * h, t)[..., None]
 
-    backward = _backward_fused if fused_backward_fits(t, d) \
-        else _backward_pair
-    dq, dk, dv = backward(qf, kf, vf, dof, lsef, delta, causal, block_q,
-                          block_k, interpret)
+    operands = (qf, kf, vf, dof, lsef, delta, causal, block_q, block_k,
+                interpret)
+    if fused_backward_fits(t, d, d_v, block_q, block_k, q.dtype):
+        scope = None if max(d, d_v) <= _LANES else FUSED_BWD_WIDE_VMEM
+        dq, dk, dv = _backward_fused(*operands, scope)
+    else:
+        dq, dk, dv = _backward_pair(*operands)
     return (dq.reshape(b, h, t, d), dk.reshape(b, h, t, d),
             dv.reshape(b, h, t, d_v))
 
 
 def _backward_fused(qf, kf, vf, dof, lsef, delta, causal, block_q,
-                    block_k, interpret):
+                    block_k, interpret, vmem_limit=None):
     """One kernel over the k-block-major visits: every visited tile's
     ``p`` and ``ds`` computed once, dk/dv written a k-block, dq carried in
     VMEM over all the visits of one bh and written once."""
     bh, t, d = qf.shape
-    if vf.shape[-1] != d:
-        raise ValueError(f"the fused backward takes one head width: q·k "
-                         f"{d}, v {vf.shape[-1]}")
+    d_v = vf.shape[-1]
     q_row, k_row, s_row = _row_specs(d, block_q, block_k)
+    o_row, v_row, _ = _row_specs(d_v, block_q, block_k)
     return _visit_call(
         functools.partial(_flash_bwd_kernel, block_q=block_q,
                           block_k=block_k, causal=causal),
         names.KERNEL_FLASH_BWD,
         tile_visits(t, block_q, block_k, causal, "k"),
         (kf, vf, qf, dof, lsef, delta),
-        in_specs=[k_row, k_row, q_row, q_row, s_row, s_row],
+        in_specs=[k_row, v_row, q_row, o_row, s_row, s_row],
         out_specs=[
             pl.BlockSpec((None, t, d), lambda bh, i, *visits: (bh, 0, 0)),
             k_row,
-            k_row,
+            v_row,
         ],
         out_shape=[
             _sds((bh, t, d), qf.dtype, qf),
             _sds((bh, t, d), kf.dtype, kf),
-            _sds((bh, t, d), vf.dtype, vf),
+            _sds((bh, t, d_v), vf.dtype, vf),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d_v), jnp.float32),
             # an fp32 dq accumulates in its own block: no scratch for it
         ] + ([] if qf.dtype == jnp.float32
              else [pltpu.VMEM((t, d), jnp.float32)]),
-        interpret=interpret)
+        interpret=interpret, vmem_limit=vmem_limit)
 
 
 def _backward_pair(qf, kf, vf, dof, lsef, delta, causal, block_q, block_k,

@@ -219,17 +219,13 @@ def test_flash_compiles_at_the_longest_visit_lists(one_chip):
         names.KERNEL_FLASH_DKV}
 
 
-def test_latent_widths_compile_as_the_pair(one_chip, on_tpu):
-    """Latent attention's call (joyai_sgp_w1_t8192: 32 heads, q·k 192
-    beside v 128, 8192 tokens) through ``jax.grad`` at the auto block:
-    the forward with a value block narrower than the query's, and the
-    shape rule sends the backward to the dq + dk/dv pair."""
-    t, heads = 8192, 32
-    assert not fused_backward_fits(t, 192)
-    qk = jax.ShapeDtypeStruct((1, heads, t, 192), jnp.bfloat16,
-                              sharding=one_chip)
-    v = jax.ShapeDtypeStruct((1, heads, t, 128), jnp.bfloat16,
-                             sharding=one_chip)
+def _latent_grad_kernels(one_chip, t, dtype):
+    """The kernels latent attention's call (32 heads, q·k 192 beside v
+    128, ``t`` tokens) compiles to through ``jax.grad`` at the auto
+    block."""
+    heads = 32
+    qk = jax.ShapeDtypeStruct((1, heads, t, 192), dtype, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, heads, t, 128), dtype, sharding=one_chip)
 
     def loss(q, k, v):
         with jax.named_scope(names.SCOPE_FORWARD):
@@ -238,7 +234,28 @@ def test_latent_widths_compile_as_the_pair(one_chip, on_tpu):
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         qk, qk, v).compile().as_text()
-    assert _kernel_names(text) == {
+    return text.count("tpu_custom_call"), _kernel_names(text)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "fp32"])
+def test_latent_widths_compile_to_one_fused_backward(one_chip, on_tpu,
+                                                     dtype):
+    """joyai_sgp_w1_t8192's call: the forward with a value block narrower
+    than the query's, and ONE fused backward holding dq for 8192 tokens at
+    192 lanes (padded to 256) in the wider scope it asks for; the default
+    scope refuses it (PERF.md §6)."""
+    assert fused_backward_fits(8192, 192, 128, 512, 512, dtype)
+    assert _latent_grad_kernels(one_chip, 8192, dtype) == (
+        2, {names.KERNEL_FLASH_FWD, names.KERNEL_FLASH_BWD})
+
+
+def test_latent_widths_compile_as_the_pair(one_chip, on_tpu):
+    """Just past the edge, the cell's widths at 16384 tokens (dq alone
+    32 MiB): the shape rule sends the backward to the dq + dk/dv pair,
+    which asks for no scope and compiles."""
+    assert not fused_backward_fits(16384, 192, 128)
+    assert _latent_grad_kernels(one_chip, 16384, jnp.bfloat16)[1] == {
         names.KERNEL_FLASH_FWD, names.KERNEL_FLASH_DQ,
         names.KERNEL_FLASH_DKV}
 

@@ -47,18 +47,25 @@ def backward(request, monkeypatch):
     return request.param
 
 
-def _backward_kernels(shape, dtype):
-    """Names of the Pallas kernels ``flash_attention_backward`` calls at
-    ``shape`` (traced, not run)."""
+def _backward_calls(shape, dtype, d_v=None):
+    """The Pallas calls ``flash_attention_backward`` makes at ``shape``
+    (values ``d_v`` wide, or as wide as ``shape``'s heads), traced, not
+    run: their jaxpr equations."""
     x = jax.ShapeDtypeStruct(shape, dtype)
+    v = jax.ShapeDtypeStruct(shape[:3] + (d_v or shape[3],), dtype)
     lse = jax.ShapeDtypeStruct(shape[:3], jnp.float32)
     block = fa.default_block(shape[2])
     jaxpr = jax.make_jaxpr(lambda q, k, v, out, lse, do: (
         flash_attention_backward(q, k, v, out, lse, do, causal=True,
                                  block_q=block, block_k=block)))(
-        x, x, x, x, lse, x)
-    return [e.params["name"] for e in jaxpr.eqns
-            if e.primitive.name == "pallas_call"]
+        x, x, v, v, lse, v)
+    return [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+
+
+def _backward_kernels(shape, dtype):
+    """Names of the Pallas kernels ``flash_attention_backward`` calls at
+    ``shape`` (traced, not run)."""
+    return [e.params["name"] for e in _backward_calls(shape, dtype)]
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -243,7 +250,8 @@ def test_fused_backward_shape_rule():
     assert fused_backward_fits(8192, 128)
     assert not fused_backward_fits(16384, 64)
     assert not fused_backward_fits(32768, 64)     # a 32k ring shard
-    assert not fused_backward_fits(1024, 256)     # wider than a register
+    assert fused_backward_fits(1024, 256)         # two registers a row
+    assert not fused_backward_fits(8192, 384)     # three, at 8192 tokens
     one, two = [names.KERNEL_FLASH_BWD], [names.KERNEL_FLASH_DQ,
                                           names.KERNEL_FLASH_DKV]
     for dtype in (jnp.bfloat16, jnp.float32):
@@ -251,7 +259,53 @@ def test_fused_backward_shape_rule():
             assert _backward_kernels((1, heads, 8192, 64), dtype) == one
             assert _backward_kernels((1, heads, 16384, 64), dtype) == two
         assert _backward_kernels((2, 2, 1024, 128), dtype) == one
-        assert _backward_kernels((2, 2, 1024, 256), dtype) == two
+        assert _backward_kernels((2, 2, 1024, 256), dtype) == one
+        assert _backward_kernels((1, 2, 8192, 384), dtype) == two
+
+
+@pytest.mark.parametrize("t,d_qk,d_v,block,dtype,fits", [
+    (8192, 192, 128, 512, jnp.bfloat16, True),     # joyai_sgp_w1_t8192
+    (8192, 192, 128, 512, jnp.float32, True),
+    (8192, 192, 128, 256, jnp.bfloat16, True),
+    (12288, 192, 128, 512, jnp.bfloat16, True),    # 32 MiB reckoned
+    (16384, 192, 128, 512, jnp.bfloat16, False),   # longer: the pair
+    (8192, 256, 256, 512, jnp.float32, True),
+    (8192, 384, 128, 512, jnp.bfloat16, False),    # wider: the pair
+    (1024, 1024, 1024, 512, jnp.bfloat16, True),
+    (1024, 1024, 1024, 512, jnp.float32, False),
+], ids=["cell", "cell_fp32", "cell_b256", "t12288", "t16384", "d256_fp32",
+        "d384", "d1024", "d1024_fp32"])
+def test_fused_backward_shape_rule_at_two_widths(t, d_qk, d_v, block,
+                                                 dtype, fits):
+    """Heads over one register: fused while what the kernel holds fits
+    the wider scope it asks for, the pair beyond, on both sides of the
+    edge in length and in width; the reckoning is the buffers' bytes
+    (every side of it compiled for a described v5e: PERF.md §6)."""
+    held = fa.fused_backward_vmem(t, d_qk, d_v, block, block, dtype)
+    assert (held <= fa.FUSED_BWD_WIDE_VMEM) == fits
+    assert fused_backward_fits(t, d_qk, d_v, block, block, dtype) == fits
+    # dq for the whole sequence, eight bytes a lane, is most of it
+    assert held > t * 8 * -(-d_qk // 128) * 128
+
+
+@pytest.mark.parametrize("shape,d_v,limit", [
+    ((1, 2, 8192, 64), 64, None),
+    ((1, 2, 8192, 128), 128, None),
+    ((2, 2, 1024, 64), 64, None),
+    ((1, 2, 8192, 192), 128, fa.FUSED_BWD_WIDE_VMEM),
+    ((2, 2, 1024, 256), 256, fa.FUSED_BWD_WIDE_VMEM),
+    ((1, 2, 16384, 64), 64, None),
+    ((1, 2, 16384, 192), 128, None),
+], ids=["t8192", "d128", "t1024", "latent", "d256", "t16384_pair",
+        "latent_t16384_pair"])
+def test_only_wide_heads_ask_for_scoped_vmem(shape, d_v, limit):
+    """Every call the one-register rule admits, and the pair at any
+    width, leaves the compiler's default scope: the fused kernel at heads
+    over one register alone asks for :data:`FUSED_BWD_WIDE_VMEM`."""
+    for call in _backward_calls(shape, jnp.bfloat16, d_v):
+        params = call.params["compiler_params"]["mosaic_tpu"]
+        assert params.vmem_limit_bytes == limit, call.params["name"]
+        assert params.dimension_semantics == ("parallel", "arbitrary")
 
 
 def test_the_benchmark_reads_the_fused_backward_by_its_name():

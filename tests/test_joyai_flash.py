@@ -4,8 +4,9 @@ a routed scale and a shared expert, the flash kernels at q·k and v widths
 apart) against its plain reference (benchmark/reference/joyai_flash.py)
 at toy widths on the CPU: logits, both losses and every gradient leaf;
 the shares of the experts add up to the uncut layer with the shared
-expert counted once; the kernels at 192 / 128 in interpret mode and, on
-the chip only, compiled at the cell's sizes; the selection bias's
+expert counted once; the kernels at 192 / 128 in interpret mode (the fused
+backward bit for bit the pair) and, on the chip only, compiled at the
+cell's sizes; the selection bias's
 balancing (a set-up step of the cell's builder); the ``--model_json``
 way in and its refusals; and the steps and kernels of what was there
 before, lowered as before."""
@@ -224,9 +225,9 @@ def _full(q, k, v):
 
 @pytest.mark.parametrize("block_q,block_k", [(64, 64), (32, 64)])
 def test_the_kernels_take_q_k_192_beside_v_128(block_q, block_k):
-    """Forward, ``flash_dq`` and ``flash_dkv`` interpreted at the latent
-    widths against plain attention at ``192 ** -0.5``; the shape rule
-    hands the backward to the pair at heads over 128."""
+    """Forward and the fused backward interpreted at the latent widths
+    against plain attention at ``192 ** -0.5``: the shape rule hands a
+    backward at heads over 128 the fused kernel where its VMEM fits."""
     rng = np.random.default_rng(5)
     shape = (1, 2, 128)
     q, k = (jnp.asarray(rng.normal(size=shape + (192,)), jnp.float32)
@@ -238,6 +239,8 @@ def test_the_kernels_take_q_k_192_beside_v_128(block_q, block_k):
         interpret=True, return_lse=True)
     assert out.shape == shape + (128,)
     np.testing.assert_allclose(out, _full(q, k, v), atol=2e-5)
+    assert fa.fused_backward_fits(128, 192, 128, block_q, block_k,
+                                  jnp.float32)
     grads = flash_attention_backward(
         q, k, v, out, lse, do, causal=True, block_q=block_q,
         block_k=block_k, interpret=True)
@@ -262,26 +265,35 @@ def _plain_head(q, k, v, do):
     return (out, *vjp(do))
 
 
-def test_compiled_kernels_are_plain_attention_at_the_cells_sizes():
-    """On the chip: the compiled forward and ``flash_dq`` / ``flash_dkv``
-    pair at the JoyAI cell's sizes (32 heads of 8192 tokens, q·k 192
-    beside v 128, bf16 operands, causal, the auto blocks) against plain
-    attention in float32, head by head: the output and all three
-    gradients, each within 2 % of its largest entry.  ``correct`` compares
-    a forward pass only, so this is what holds the latent backward at
-    those widths (run it there with ``python -c "import sys;
-    sys.path.insert(0, 'tests'); import test_joyai_flash as t;
-    print(t.test_compiled_kernels_are_plain_attention_at_the_cells_sizes())"``
-    from the repository's root: pytest holds the suite to the CPU)."""
-    if jax.default_backend() != "tpu":
-        pytest.skip("the compiled kernels need the chip")
-    b, h, t, d_qk, d_v = 1, 32, 8192, 192, 128
-    assert not fa.fused_backward_fits(t, d_qk)
+# the JoyAI cell's attention: 32 heads of 8192 tokens, q·k 192 beside v 128
+CELL = (1, 32, 8192, 192, 128)
+
+
+def _cell_operands():
+    b, h, t, d_qk, d_v = CELL
     keys = jax.random.split(jax.random.PRNGKey(44), 4)
     q, k = (jax.random.normal(key, (b, h, t, d_qk), jnp.bfloat16)
             for key in keys[:2])
     v, do = (jax.random.normal(key, (b, h, t, d_v), jnp.bfloat16)
              for key in keys[2:])
+    return q, k, v, do
+
+
+def test_compiled_kernels_are_plain_attention_at_the_cells_sizes():
+    """On the chip: the compiled forward and fused backward at the JoyAI
+    cell's sizes (32 heads of 8192 tokens, q·k 192 beside v 128, bf16
+    operands, causal, the auto blocks) against plain attention in
+    float32, head by head: the output and all three gradients, each
+    within 2 % of its largest entry.  ``correct`` compares a forward pass
+    only, so this and the next test are what hold the latent backward at
+    those widths (run them there with ``python -c "import sys;
+    sys.path.insert(0, 'tests'); import test_joyai_flash as t;
+    print(t.test_compiled_kernels_are_plain_attention_at_the_cells_sizes())"``
+    from the repository's root: pytest holds the suite to the CPU)."""
+    if jax.default_backend() != "tpu":
+        pytest.skip("the compiled kernels need the chip")
+    assert fa.fused_backward_fits(*CELL[2:])
+    q, k, v, do = _cell_operands()
 
     @jax.jit
     def ours(q, k, v, do):
@@ -308,17 +320,67 @@ def test_compiled_kernels_are_plain_attention_at_the_cells_sizes():
     return worst
 
 
-def test_the_fused_backward_refuses_two_widths():
+def test_compiled_fused_backward_is_the_pair_at_the_cells_sizes():
+    """On the chip: the compiled fused backward and the compiled
+    ``flash_dq`` / ``flash_dkv`` pair at the JoyAI cell's sizes and
+    blocks give the same bits in dq, dk and dv (run it as the test
+    above)."""
+    if jax.default_backend() != "tpu":
+        pytest.skip("the compiled kernels need the chip")
+    q, k, v, do = _cell_operands()
+    b, h, t, d_qk, d_v = CELL
+    block = fa.default_block(t)
+
+    def rows(q, k, v, do):
+        out, lse = flash_attention_forward(
+            q, k, v, causal=True, block_q=block, block_k=block,
+            return_lse=True)
+        delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                        -1).reshape(b * h, t, 1)
+        flat = [x.reshape(b * h, t, -1) for x in (q, k, v, do)]
+        return (*flat, lse.reshape(b * h, t, 1), delta)
+
+    operands = jax.jit(rows)(q, k, v, do)
+    fused = jax.jit(lambda *a: fa._backward_fused(
+        *a, True, block, block, False, fa.FUSED_BWD_WIDE_VMEM))(*operands)
+    pair = jax.jit(lambda *a: fa._backward_pair(
+        *a, True, block, block, False))(*operands)
+    differing = {}
+    for name, got, want in zip(("dq", "dk", "dv"), fused, pair):
+        assert got.shape == want.shape and got.dtype == want.dtype, name
+        differing[name] = int(jnp.sum(got != want))
+    assert differing == {"dq": 0, "dk": 0, "dv": 0}, differing
+    return differing
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["fp32", "bf16"])
+@pytest.mark.parametrize("block_q,block_k", [(64, 64), (32, 64)])
+def test_the_fused_backward_is_the_pair_at_two_widths(dtype, block_q,
+                                                      block_k):
+    """At q·k 192 beside v 128 the fused kernel is the pair's arithmetic
+    in the pair's order: the same bits in dq, dk and dv, whether dq
+    accumulates in scratch (bf16) or in its own block (fp32)."""
     rng = np.random.default_rng(6)
-    q = k = jnp.asarray(rng.normal(size=(1, 1, 64, 32)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(1, 1, 64, 16)), jnp.float32)
-    out, lse = flash_attention_forward(q, k, v, causal=True, block_q=32,
-                                       block_k=32, interpret=True,
-                                       return_lse=True)
-    assert fa.fused_backward_fits(64, 32)
-    with pytest.raises(ValueError, match="one head width"):
-        flash_attention_backward(q, k, v, out, lse, out, causal=True,
-                                 block_q=32, block_k=32, interpret=True)
+    shape = (1, 2, 256)
+    q, k = (jnp.asarray(rng.normal(size=shape + (192,)), dtype)
+            for _ in range(2))
+    v, do = (jnp.asarray(rng.normal(size=shape + (128,)), dtype)
+             for _ in range(2))
+    out, lse = flash_attention_forward(q, k, v, causal=True,
+                                       block_q=block_q, block_k=block_k,
+                                       interpret=True, return_lse=True)
+    flat = [x.reshape(2, 256, -1) for x in (q, k, v, do)]
+    rows = [lse.reshape(2, 256, 1),
+            jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                    -1).reshape(2, 256, 1)]
+    fused = fa._backward_fused(*flat, *rows, True, block_q, block_k, True)
+    pair = fa._backward_pair(*flat, *rows, True, block_q, block_k, True)
+    for got, want, width in zip(fused, pair, (192, 192, 128)):
+        assert got.shape == want.shape == (2, 256, width)
+        assert got.dtype == want.dtype == dtype
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
 
 
 # sha256 of each text, taken on the tree before the latent widths, the
